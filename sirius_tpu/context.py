@@ -14,6 +14,7 @@ import dataclasses
 
 import numpy as np
 
+from sirius_tpu import runtime
 from sirius_tpu.config.schema import Config
 from sirius_tpu.core.fftgrid import FFTGrid
 from sirius_tpu.core.gvec import Gvec, GkVec
@@ -55,6 +56,12 @@ class SimulationContext:
 
     @staticmethod
     def create(cfg: Config, base_dir: str = ".") -> "SimulationContext":
+        # set-up tables are host work (runtime.py placement rule)
+        with runtime.host_scope():
+            return SimulationContext._create(cfg, base_dir)
+
+    @staticmethod
+    def _create(cfg: Config, base_dir: str) -> "SimulationContext":
         p = cfg.parameters
         uc = UnitCell.from_config(cfg.unit_cell, base_dir)
         if p.gk_cutoff <= 0 or p.pw_cutoff <= 0:

@@ -60,7 +60,12 @@ from sirius_tpu.ops.augmentation import (
     d_operator_device,
     rho_aug_g_device,
 )
-from sirius_tpu.parallel.batched import compute_h_diag_device, split_cplx
+from sirius_tpu.ops.hamiltonian import real_dtype_of
+from sirius_tpu.parallel.batched import (
+    compute_h_diag_device,
+    join_cplx,
+    split_cplx,
+)
 
 # indices into the per-iteration scalar record (the ONLY device->host
 # traffic of a fused iteration)
@@ -114,7 +119,12 @@ class FusedScf:
     """
 
     def __init__(self, ctx, xc, mixer, polarized: bool, do_symmetrize: bool,
-                 beta_dev=None, exec_cache=None):
+                 beta_dev=None, exec_cache=None, wf_dtype=jnp.complex128):
+        # one dtype policy: the step works in the band solve's precision —
+        # complex64/float32 on a TPU, where 64-bit types do not run
+        # (runtime.py); with complex128 the program is the f64 one
+        self.cdt = jnp.dtype(wf_dtype)
+        self.rdt = jnp.dtype(real_dtype_of(wf_dtype))
         self.ctx = ctx
         self.xc = xc
         self.polarized = bool(polarized)
@@ -139,8 +149,8 @@ class FusedScf:
             "pot": build_potential_device_tables(ctx),
             "fft_index_coarse": ctx.gvec_coarse.fft_index,
             "c2f": ctx.coarse_to_fine,
-            "ekin": np.asarray(ctx.gkvec.kinetic(), dtype=np.float64),
-            "gmask": np.asarray(ctx.gkvec.mask, dtype=np.float64),
+            "ekin": np.asarray(ctx.gkvec.kinetic()),
+            "gmask": np.asarray(ctx.gkvec.mask),
             "dion": np.real(np.asarray(ctx.beta.dion))
             if nbeta
             else np.zeros((0, 0)),
@@ -154,7 +164,7 @@ class FusedScf:
             tables["beta_re"], tables["beta_im"] = beta_dev
         elif nbeta:
             tables["beta_re"], tables["beta_im"] = split_cplx(
-                np.asarray(ctx.beta.beta_gk)
+                np.asarray(ctx.beta.beta_gk), self.rdt
             )
         else:
             nk = ctx.gkvec.num_kpoints
@@ -169,8 +179,8 @@ class FusedScf:
             tables["dm_sym"] = build_dm_sym_tables(ctx)
         # one-time upload; step() takes these as an argument so they are
         # program inputs, not baked-in constants
-        self.tables = jax.tree_util.tree_map(jnp.asarray, tables)
-        self.kweights_dev = jnp.asarray(np.asarray(ctx.kweights))
+        self.tables = jax.tree_util.tree_map(self._table, tables)
+        self.kweights_dev = self._table(np.asarray(ctx.kweights))
         if exec_cache is not None:
             # serving: reuse a previously-jitted step whose trace signature
             # matches. The jitted callable is a bound method of the FIRST
@@ -185,6 +195,15 @@ class FusedScf:
         else:
             self._step = jax.jit(self._step_impl, donate_argnums=(1,))
 
+    def _table(self, a):
+        """Upload one table leaf in the working precision (index leaves
+        keep their integer dtype)."""
+        if jnp.issubdtype(a.dtype, jnp.complexfloating):
+            return jnp.asarray(a, dtype=self.cdt)
+        if jnp.issubdtype(a.dtype, jnp.floating):
+            return jnp.asarray(a, dtype=self.rdt)
+        return jnp.asarray(a)
+
     def _trace_signature(self) -> tuple:
         """Everything _step_impl bakes into its trace (instance attrs used
         inside the jitted body) plus the shapes/dtypes of its table inputs
@@ -193,6 +212,7 @@ class FusedScf:
         leaves, treedef = jax.tree_util.tree_flatten(self.tables)
         tab = tuple((tuple(x.shape), str(x.dtype)) for x in leaves)
         return (
+            str(self.cdt), str(self.rdt),
             self.ns, self.ng, self.nx, self.omega,
             self.dims, self.dims_coarse,
             self.kind, self.mix_beta, self.max_history,
@@ -213,8 +233,9 @@ class FusedScf:
         `history` optionally restores a checkpointed mixer history
         ({'mix_x': [m, nx], 'mix_f': [m, nx]} complex, oldest first) so a
         resumed fused run continues the same Anderson trajectory."""
-        x_re, x_im = split_cplx(np.asarray(x_mix))
-        st = device_mixer_init(self.nx, self.max_history)
+        rdt = self.rdt
+        x_re, x_im = split_cplx(np.asarray(x_mix), rdt)
+        st = device_mixer_init(self.nx, self.max_history, dtype=rdt)
         if history and "mix_x" in history:
             hx = np.asarray(history["mix_x"])[-self.max_history:]
             hf = np.asarray(history["mix_f"])[-self.max_history:]
@@ -230,12 +251,12 @@ class FusedScf:
                 jnp.asarray(hf_re), jnp.asarray(hf_im),
                 jnp.asarray(np.int32(m)),
             )
-        v_re, v_im = split_cplx(np.asarray(pot.veff_g))
+        v_re, v_im = split_cplx(np.asarray(pot.veff_g), rdt)
         if self.polarized and pot.bz_g is not None:
-            b_re, b_im = split_cplx(np.asarray(pot.bz_g))
+            b_re, b_im = split_cplx(np.asarray(pot.bz_g), rdt)
         else:
             # distinct buffers (donated leaves must not alias)
-            b_re, b_im = np.zeros(self.ng), np.zeros(self.ng)
+            b_re, b_im = np.zeros(self.ng, rdt), np.zeros(self.ng, rdt)
         return FusedCarry(
             jnp.asarray(x_re), jnp.asarray(x_im),
             st.hx_re, st.hx_im, st.hf_re, st.hf_im, st.count,
@@ -249,23 +270,32 @@ class FusedScf:
         dft/recovery.py. Called OUTSIDE the scf::fused_step profile span:
         it is an explicit, supervised host transfer, not per-iteration
         traffic."""
-        x = np.asarray(carry.x_re) + 1j * np.asarray(carry.x_im)
+        x = join_cplx(carry.x_re, carry.x_im)
         if not with_history:
             return x, None
         m = int(np.asarray(carry.count))
         hist = {}
         if m > 0:
-            hist["mix_x"] = (np.asarray(carry.hx_re)[:m]
-                             + 1j * np.asarray(carry.hx_im)[:m])
-            hist["mix_f"] = (np.asarray(carry.hf_re)[:m]
-                             + 1j * np.asarray(carry.hf_im)[:m])
+            hist["mix_x"] = join_cplx(carry.hx_re, carry.hx_im)[:m]
+            hist["mix_f"] = join_cplx(carry.hf_re, carry.hf_im)[:m]
         return x, hist
+
+    def fetch_potential(self, carry: FusedCarry):
+        """Host copy of the carried potential in the shape init_carry
+        takes (veff_g / bz_g) — for re-seeding a carry in another
+        precision."""
+        from types import SimpleNamespace
+
+        return SimpleNamespace(
+            veff_g=join_cplx(carry.veff_re, carry.veff_im),
+            bz_g=join_cplx(carry.bz_re, carry.bz_im)
+            if self.polarized else None)
 
     def step(self, carry, acc, dm_re, dm_im, ev, occ_w, ent, pr, pi):
         """One fused iteration. acc: [ns, coarse box] occupation-weighted
         |psi(r)|^2 from density_kset; (dm_re, dm_im): [ns, nbeta, nbeta]
         from density_matrix_kset (empty for norm-conserving); ev: [nk, ns,
-        nb] float64 eigenvalues; occ_w = occ * kweights; ent: entropy sum;
+        nb] eigenvalues; occ_w = occ * kweights; ent: entropy sum;
         (pr, pi): [nk, ns, nb, ngk] band block (already live on device for
         density_kset — feeding it here adds no transfer) for the numerics
         ledger. All device arrays. Returns (new_carry, out_dict)."""
@@ -276,16 +306,14 @@ class FusedScf:
         """The single end-of-loop host fetch: mixed density, D matrices,
         density-matrix blocks and residual for the final report/forces."""
         ctx = self.ctx
-        x = np.asarray(carry.x_re) + 1j * np.asarray(carry.x_im)
+        x = join_cplx(carry.x_re, carry.x_im)
         rho_g = x[: self.ng]
         mag_g = x[self.ng :] if self.polarized else None
         d_by_spin = list(np.asarray(out["dion"], dtype=np.float64))
-        rho_resid_g = (
-            np.asarray(out["resid_re"]) + 1j * np.asarray(out["resid_im"])
-        )
+        rho_resid_g = join_cplx(out["resid_re"], out["resid_im"])
         dm_blocks_by_spin = []
         if self.has_aug:
-            dm = np.asarray(out["dm_re"]) + 1j * np.asarray(out["dm_im"])
+            dm = join_cplx(out["dm_re"], out["dm_im"])
             for ispn in range(self.ns):
                 dm_blocks_by_spin.append([
                     dm[ispn, off : off + nbf, off : off + nbf]
@@ -304,11 +332,11 @@ class FusedScf:
     def _step_impl(self, tables, carry, acc, dm_re, dm_im, ev, occ_w, ent,
                    pr, pi):
         ng, ns, omega = self.ng, self.ns, self.omega
-        cdt = jnp.complex128
+        cdt, rdt = self.cdt, self.rdt
 
         # density_from_coarse_acc, traced: 1/Omega, coarse r -> coarse G,
         # scatter onto the fine sphere
-        acc = acc.astype(jnp.float64)
+        acc = acc.astype(rdt)
         rho_c = r_to_g(
             (acc / omega).astype(cdt), tables["fft_index_coarse"],
             self.dims_coarse,
@@ -317,9 +345,7 @@ class FusedScf:
             rho_c
         )
 
-        dm = jax.lax.complex(
-            dm_re.astype(jnp.float64), dm_im.astype(jnp.float64)
-        )
+        dm = jax.lax.complex(dm_re.astype(rdt), dm_im.astype(rdt))
         if self.has_aug:
             if self.do_symmetrize:
                 dm = symmetrize_density_matrix_device(dm, tables["dm_sym"])
@@ -336,7 +362,7 @@ class FusedScf:
                 )
         mag_moment = (
             jnp.real(mag_new[0]) * omega if self.polarized
-            else jnp.zeros((), dtype=jnp.float64)
+            else jnp.zeros((), dtype=rdt)
         )
 
         # mixing (host-sequence semantics: rms pre-mix, eha post-mix)
@@ -408,17 +434,16 @@ class FusedScf:
         # host and device then score the identical quantity regardless of
         # where each path is in its D-refresh cycle.
         psi_c = jax.lax.complex(
-            pr.astype(jnp.float64), pi.astype(jnp.float64)
+            pr.astype(rdt), pi.astype(rdt)
         ) * tables["gmask"][:, None, None, :]
         beta_c = jax.lax.complex(
-            tables["beta_re"].astype(jnp.float64),
-            tables["beta_im"].astype(jnp.float64),
+            tables["beta_re"].astype(rdt), tables["beta_im"].astype(rdt),
         )
-        qmat64 = tables["qmat"].astype(jnp.float64)
+        qmat_r = tables["qmat"].astype(rdt)
         bp = jnp.einsum("kxg,ksbg->ksbx", jnp.conj(beta_c), psi_c)
         gram = jnp.einsum("ksbg,kscg->ksbc", jnp.conj(psi_c), psi_c)
         gram = gram + jnp.einsum(
-            "ksbx,xy,kscy->ksbc", jnp.conj(bp), qmat64, bp
+            "ksbx,xy,kscy->ksbc", jnp.conj(bp), qmat_r, bp
         )
         nb = psi_c.shape[2]
         s_ortho = jnp.max(jnp.abs(gram - jnp.eye(nb, dtype=gram.dtype)))
@@ -430,9 +455,9 @@ class FusedScf:
                 symmetrize_pw_device(rho_new, tables["sym"]) - rho_new
             ))
         else:
-            s_sym = jnp.zeros((), dtype=jnp.float64)
-        dion64 = tables["dion"].astype(jnp.float64)
-        h_nl = jnp.einsum("ksbx,xy,kscy->ksbc", jnp.conj(bp), dion64, bp)
+            s_sym = jnp.zeros((), dtype=rdt)
+        dion_r = tables["dion"].astype(rdt)
+        h_nl = jnp.einsum("ksbx,xy,kscy->ksbc", jnp.conj(bp), dion_r, bp)
         s_herm = jnp.max(jnp.abs(
             h_nl - jnp.conj(jnp.swapaxes(h_nl, -1, -2))
         ))
@@ -450,18 +475,18 @@ class FusedScf:
             & jnp.all(jnp.isfinite(jnp.real(veff_new)))
             & jnp.all(jnp.isfinite(jnp.imag(veff_new)))
             & jnp.all(jnp.isfinite(ev))
-        ).astype(jnp.float64)
+        ).astype(rdt)
         scalars = jnp.stack([
             rms, eha, e["vha"], e["vxc"], e["vloc"], e["veff"], e["exc"],
             e["bxc"], e1, e2, eval_sum, nel_got, mag_moment, v0,
-            ent.astype(jnp.float64), finite,
+            ent.astype(rdt), finite,
             s_ortho, s_chg, s_sym, s_herm,
         ])
 
         if self.polarized:
             bz_re, bz_im = jnp.real(bz_new), jnp.imag(bz_new)
         else:
-            bz_re = bz_im = jnp.zeros(ng, dtype=jnp.float64)
+            bz_re = bz_im = jnp.zeros(ng, dtype=rdt)
         new_carry = FusedCarry(
             jnp.real(x_mixed), jnp.imag(x_mixed),
             state.hx_re, state.hx_im, state.hf_re, state.hf_im, state.count,

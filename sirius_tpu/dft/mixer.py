@@ -310,6 +310,11 @@ class Mixer:
 # ---------------------------------------------------------------------------
 
 
+# relative eigenvalue cut of the device Anderson solve per working
+# precision: the host threshold in f64, ~200 eps in f32
+_ANDERSON_CUT = {"float64": 1e-12, "float32": 2.4e-5}
+
+
 class DeviceMixerState(NamedTuple):
     """Fixed-shape mixing history: [max_history, nx] real leaves."""
 
@@ -382,7 +387,10 @@ def device_mix(state: DeviceMixerState, x_in: jnp.ndarray, x_new: jnp.ndarray,
         # zero-padded history rows produce exactly-zero eigenvalues; the
         # host threshold (1e-12 * largest) removes them along with any
         # numerically collinear directions
-        thresh = 1e-12 * jnp.maximum(ew[-1], 0.0)
+        # (scaled to the working precision: below ~eps * largest an
+        # eigenvalue of the f32 Gram matrix is rounding noise)
+        cut = _ANDERSON_CUT[a.dtype.name]
+        thresh = cut * jnp.maximum(ew[-1], 0.0)
         keep = ew > thresh
         ew_safe = jnp.where(keep, ew, 1.0)
         g = v @ (jnp.where(keep, 1.0 / ew_safe, 0.0) * (v.T @ b))

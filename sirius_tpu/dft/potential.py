@@ -371,7 +371,7 @@ def generate_potential_device(
         "veff": inner_rr(rho_r, to_r(veff_g)),
         "exc": inner_rr(rho_r + rho_core_r, exc_r),
         "bxc": (inner_rr(mag_r, to_r(bz_g)) if polarized
-                else jnp.zeros((), dtype=jnp.float64)),
+                else jnp.zeros((), dtype=rho_r.dtype)),
     }
     return {
         "veff_g": veff_g,

@@ -43,6 +43,7 @@ from sirius_tpu.obs import spans as obs_spans
 from sirius_tpu.obs import tracing as obs_tracing
 from sirius_tpu.obs.log import get_logger
 from sirius_tpu.obs.trace import CAPTURE as obs_trace
+from sirius_tpu import runtime
 from sirius_tpu.utils import checksums as _cks
 from sirius_tpu.utils import devfail
 from sirius_tpu.utils import faults
@@ -116,8 +117,8 @@ def _initial_subspace(ctx: SimulationContext) -> jnp.ndarray:
         base *= ctx.gkvec.mask[ik]
         for ispn in range(ctx.num_spins):
             psi[ik, ispn] = base
-    # host numpy, NOT a device array: complex must never be device-resident
-    # outside jit (parallel/batched.py real-boundary contract)
+    # host numpy: the band solves upload it themselves, as a (re, im) pair
+    # on the batched path (parallel/batched.py real-boundary contract)
     return psi
 
 
@@ -145,7 +146,7 @@ def run_scf(*args, **kwargs) -> dict:
     one inherited from serve/campaigns (scheduler enters the job's
     trace_context) is kept, so every span/event of this run carries the
     end-to-end trace. See _run_scf_inner for the full contract."""
-    with obs_tracing.ensure_trace():
+    with obs_tracing.ensure_trace(), runtime.scf_scope():
         return _run_scf_inner(*args, **kwargs)
 
 
@@ -241,6 +242,16 @@ def _run_scf_inner(
             f"num_bands={nb} cannot hold {nel} electrons "
             f"(max {nb * ctx.max_occupancy * ctx.num_spins})"
         )
+    # compute devices of this run (a scheduler slice, or everything); the
+    # band solve and the fused step are placed on them explicitly, all other
+    # jnp work is host work (runtime.py placement rule)
+    _devs = list(devices) if devices is not None else jax.devices()
+    if p.precision_wf not in ("fp32", "fp64"):
+        raise ValueError(f"precision_wf must be fp32 or fp64, got '{p.precision_wf}'")
+    if p.precision_wf == "fp64":
+        runtime.refuse_64bit_on(_devs, 'parameters.precision_wf = "fp64"')
+    if cfg.settings.fp32_to_fp64_rms > 0:
+        runtime.refuse_64bit_on(_devs, "settings.fp32_to_fp64_rms > 0")
     if ctx.num_mag_dims == 3:
         from sirius_tpu.dft.scf_nc import run_scf_nc
 
@@ -261,8 +272,6 @@ def _run_scf_inner(
     # wave-function precision: fp32 runs the band solve in complex64
     # (reference precision_wf, dft_ground_state.cpp:216-304 fp32 SCF with
     # fp64 polish via settings.fp32_to_fp64_rms)
-    if p.precision_wf not in ("fp32", "fp64"):
-        raise ValueError(f"precision_wf must be fp32 or fp64, got '{p.precision_wf}'")
     wf_dtype = jnp.complex64 if p.precision_wf == "fp32" else jnp.complex128
 
     from sirius_tpu.ops.hubbard import (
@@ -421,8 +430,8 @@ def _run_scf_inner(
     # constant device tables, uploaded once (not per iteration); the full-
     # precision projector stack feeds the density-matrix accumulation
     # independently of the wave-function working dtype
-    # stored as a (re, im) real pair: complex arrays must never be device-
-    # resident outside jit (real-boundary contract, parallel/batched.py)
+    # stored as a (re, im) real pair (real-boundary contract,
+    # parallel/batched.py)
     if ctx.beta.num_beta_total:
         from sirius_tpu.parallel.batched import split_cplx as _sc
 
@@ -555,6 +564,7 @@ def _run_scf_inner(
     x_mix = pack(rho_g, mag_g, om_mixed, om_nl_mixed, paw_dm, hub_lagrange)
 
     evals = np.zeros((nk, ns, nb))
+    rho_spin = None  # host paths: last accumulated per-spin density
     pr = pi = None  # batched-path device-resident (re, im) wave functions
     # production multi-device mesh: k-points over "k", bands over "b"
     # (GSPMD — same program, XLA inserts the collectives; None on 1 device)
@@ -562,6 +572,21 @@ def _run_scf_inner(
 
     scf_mesh, psi_spec = (None, None) if serial_bands else production_mesh(
         nk, nb, devices=devices)
+
+    def _up(x, dtype=None):
+        """Upload one band-solve operand to the single compute device
+        (the one-device twin of the mesh placements below)."""
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
+        if dtype is not None and x.dtype != np.dtype(dtype):
+            x = x.astype(dtype)
+        return jax.device_put(x, _devs[0])
+
+    def _rtol(rdt):
+        # typed scalar: a python float would enter the band-solve program
+        # as a (weak) f64 parameter whatever the working precision
+        return np.dtype(rdt).type(res_tol)
+
     if scf_mesh is not None:
         from jax.sharding import NamedSharding
 
@@ -570,9 +595,7 @@ def _run_scf_inner(
         def _place_psi(x):
             return jax.device_put(x, _psi_sharding)
     else:
-
-        def _place_psi(x):
-            return x
+        _place_psi = _up
 
     # ---- G-sharded band solve (slab FFT over a "g" mesh): selected when
     # the replicated projector + wave-function footprint would not fit a
@@ -580,7 +603,6 @@ def _run_scf_inner(
     # regime — the Si-supercell flagship class. ----
     gsh = None
     g_flag = cfg.control.gshard
-    _devs = list(devices) if devices is not None else jax.devices()
     ndev = len(_devs)
     gsh_want = False
     if (
@@ -600,14 +622,14 @@ def _run_scf_inner(
             or (g_flag == "auto" and foot > cfg.control.gshard_budget_bytes)
         )
         if forced and not dims_ok:
-            import warnings
-
-            warnings.warn(
-                f"control.gshard forced but the coarse box "
+            raise ValueError(
+                f"control.gshard is forced but the coarse box "
                 f"{ctx.fft_coarse.dims} is not divisible by {ndev} devices "
-                "along x and y — falling back to the replicated band solve"
+                "along x and y, so the G-sharded band solve cannot engage"
             )
 
+    if scf_mesh is not None or gsh_want:
+        runtime.refuse_large_subspace_on_tpu_mesh(_devs, nb)
     if mgga and gsh_want:
         # the G-sharded operator has no tau term and the gshard density
         # branch never updates tau_g — it would silently produce SCAN
@@ -637,12 +659,17 @@ def _run_scf_inner(
             reorder_to_gshard(np.asarray(prm0.mask), g_order),
             reorder_to_gshard(np.asarray(prm0.beta), g_order),
             np.asarray(prm0.dion), np.asarray(prm0.qmat),
-            np.zeros(ctx.fft_coarse.dims),
+            np.zeros(ctx.fft_coarse.dims, dtype=real_dtype_of(dtype)),
         )
-        g_mask = jnp.asarray(reorder_to_gshard(np.asarray(prm0.mask), g_order))
+        sh_g = jax.sharding.NamedSharding(
+            g_mesh, jax.sharding.PartitionSpec("g"))
+        g_mask = jax.device_put(
+            reorder_to_gshard(np.asarray(prm0.mask), g_order), sh_g)
         return dict(fn=g_fn, order=g_order, sharding=g_sharding,
                     mask=g_mask, psi=None, dtype=dtype,
-                    rdt=real_dtype_of(dtype), mesh=g_mesh)
+                    rdt=real_dtype_of(dtype), mesh=g_mesh, sh_g=sh_g,
+                    sh_rep=jax.sharding.NamedSharding(
+                        g_mesh, jax.sharding.PartitionSpec()))
 
     if gsh_want:
         gsh = _setup_gshard(wf_dtype)
@@ -839,6 +866,8 @@ def _run_scf_inner(
             if bool(resume_scf.get("wf_fp64", p.precision_wf == "fp64"))
             else jnp.complex64
         )
+        if wf_dtype == jnp.complex128:
+            runtime.refuse_64bit_on(_devs, f"resume file {resume} (wf_fp64)")
 
     # ---- fused device-resident iteration (dft/fused.py): density ->
     # mixer -> potential -> D/H-diag refresh as ONE compiled program with a
@@ -877,42 +906,59 @@ def _run_scf_inner(
         else:
 
             def _repl(t):
-                return t
-
-        if beta_dev is not None:
-            beta_dev = _repl(beta_dev)
+                return jax.tree_util.tree_map(_up, t)
 
         def _fused_setup(x0, pot0, history=None, rebuild=True):
             # (re)build the fused program and/or its carry. The recovery
             # ladder calls this after a rollback: the donated carry of a
             # diverged step holds poisoned buffers, and a beta/kind change
             # needs a full rebuild because FusedScf bakes mixer.beta and
-            # mixer.kind into the trace.
-            nonlocal fused, fused_carry, fused_out, fused_np
+            # mixer.kind into the trace. The program, its tables and the
+            # scalars below are in the band solve's working precision
+            # (wf_dtype), so the fp32 -> fp64 polish switch rebuilds too.
+            nonlocal fused, fused_carry, fused_out, fused_np, fused_beta
+            nonlocal fused_nel, fused_width, fused_occmax, fused_dm0
             if rebuild or fused is None:
+                from sirius_tpu.ops.hamiltonian import real_dtype_of
+
+                frdt = np.dtype(real_dtype_of(wf_dtype))
+                fused_beta = None if beta_dev is None else _repl(tuple(
+                    b if b.dtype == frdt else np.asarray(b, dtype=frdt)
+                    for b in beta_dev))
                 fused = FusedScf(ctx, xc, mixer, polarized, do_symmetrize,
-                                 beta_dev=beta_dev, exec_cache=exec_cache)
+                                 beta_dev=fused_beta, exec_cache=exec_cache,
+                                 wf_dtype=wf_dtype)
                 fused.tables = _repl(fused.tables)
                 fused.kweights_dev = _repl(fused.kweights_dev)
+                # pre-wrapped device scalars: python floats fed to jit are
+                # implicit host->device transfers, which the fused loop
+                # must not make
+                fused_nel, fused_width, fused_occmax = _repl(tuple(
+                    np.asarray(float(v), dtype=frdt)
+                    for v in (nel, p.smearing_width, ctx.max_occupancy)))
+                fused_dm0 = _repl(
+                    (np.zeros((ns, 0, 0), frdt), np.zeros((ns, 0, 0), frdt))
+                )
             fused_carry = _repl(fused.init_carry(x0, pot0, history=history))
             fused_out = fused_np = None
 
+        def _fused_switch_precision():
+            # fp32 -> fp64 polish: rebuild the fused program in the new
+            # wf_dtype around the same mixed state (a one-off supervised
+            # fetch, like the rollback snapshot); the last step's outputs
+            # still feed the next band solve's one-time table rebuild
+            nonlocal fused_out, fused_np
+            x_sw, h_sw = fused.fetch_state(fused_carry, with_history=True)
+            keep = (fused_out, fused_np)
+            _fused_setup(x_sw, fused.fetch_potential(fused_carry),
+                         history=h_sw or None)
+            fused_out, fused_np = keep
+
+        fused_beta = fused_nel = fused_width = fused_occmax = fused_dm0 = None
         _fused_setup(
             x_mix, pot,
             history=mixer.export_history() or None
             if resume_scf is not None else None,
-        )
-        # pre-wrapped device scalars: python floats fed to jit are implicit
-        # host->device transfers, which the fused loop must not make
-        fused_nel = _repl(jnp.asarray(float(nel), dtype=jnp.float64))
-        fused_width = _repl(
-            jnp.asarray(float(p.smearing_width), dtype=jnp.float64)
-        )
-        fused_occmax = _repl(jnp.asarray(
-            float(ctx.max_occupancy), dtype=jnp.float64
-        ))
-        fused_dm0 = _repl(
-            (jnp.zeros((ns, 0, 0)), jnp.zeros((ns, 0, 0)))
         )
 
     # ---- SCF supervision & recovery (dft/recovery.py): the sentinels
@@ -1151,7 +1197,8 @@ def _run_scf_inner(
     _strag = {"healthy": [], "streak": 0, "fire": False, "delay": 0.0}
     _c_it = _stage_costs.get("scf.iteration")
     _strag_model_s = (
-        _c_it.flops / (obs_costs.peak_gflops() * 1e9) if _c_it else 0.0)
+        _c_it.flops / (obs_costs.peak_gflops(_devs[0].device_kind) * 1e9)
+        if _c_it else 0.0)
 
     def _straggler_tick(it, dt, path):
         """Feed one iteration wall clock to the straggler detector."""
@@ -1283,10 +1330,10 @@ def _run_scf_inner(
                 x0 = gsh["psi"]
                 if x0 is None:
                     x0 = jax.device_put(
-                        jnp.asarray(reorder_to_gshard(
+                        reorder_to_gshard(
                             np.asarray(psi[0, 0]).astype(wf_dtype),
                             gsh["order"],
-                        )),
+                        ),
                         gsh["sharding"],
                     )
                 h_diag, o_diag = _h_o_diag(ctx, 0, v0, d_by_spin[0])
@@ -1294,18 +1341,22 @@ def _run_scf_inner(
                 od = reorder_to_gshard(np.asarray(o_diag), gsh["order"])
                 od[od == 0.0] = 1.0  # padding slots: finite preconditioner
                 rdt = real_dtype_of(wf_dtype)
+                # every operand placed on the "g" mesh in the working
+                # precision (an f64 potential would promote the c64 apply)
                 veff_d = jax.device_put(
-                    jnp.asarray(pot.veff_r_coarse[0]),
+                    np.asarray(pot.veff_r_coarse[0], dtype=rdt),
                     gsh["fn"].sharding_veff,
                 )
                 ev, x, rn = davidson(
                     gsh["fn"],
-                    (veff_d, jnp.asarray(d_by_spin[0], dtype=gsh["rdt"])),
+                    (veff_d, jax.device_put(
+                        np.asarray(d_by_spin[0], dtype=rdt), gsh["sh_rep"])),
                     x0,
-                    jnp.asarray(hd, dtype=rdt), jnp.asarray(od, dtype=rdt),
+                    jax.device_put(np.asarray(hd, dtype=rdt), gsh["sh_g"]),
+                    jax.device_put(np.asarray(od, dtype=rdt), gsh["sh_g"]),
                     gsh["mask"],
                     num_steps=itsol.num_steps,
-                    res_tol=res_tol,
+                    res_tol=_rtol(rdt),
                 )
                 gsh["psi"] = x
                 evals[0, 0] = np.asarray(ev)
@@ -1386,11 +1437,11 @@ def _run_scf_inner(
                 rdt = real_dtype_of(wf_dtype)
                 if x_packed[0] is not None and x_packed[0].dtype != np.dtype(rdt):
                     # fp32 -> fp64 polish: re-cast the packed block
-                    x_packed = [jnp.asarray(x, dtype=rdt) for x in x_packed]
+                    x_packed = [_up(x, rdt) for x in x_packed]
                 if psi is not None and x_packed[0] is None:
                     # restart / warm start from full complex psi
                     x_packed = [
-                        jnp.asarray(gpack(gm, np.asarray(psi[0, ispn])), dtype=rdt)
+                        _up(gpack(gm, np.asarray(psi[0, ispn])), rdt)
                         for ispn in range(ns)
                     ]
                 psi_out = np.zeros(
@@ -1399,40 +1450,33 @@ def _run_scf_inner(
                 if rdt not in gamma_cache:
                     # constant tables (packed beta, gather maps) uploaded
                     # once per precision; per-iteration leaves swapped below
-                    gamma_cache[rdt] = make_gamma_params(
-                        ctx, np.zeros(ctx.fft_coarse.dims), gm, rdtype=rdt
-                    )
+                    gamma_cache[rdt] = jax.tree_util.tree_map(
+                        _up, make_gamma_params(
+                            ctx, np.zeros(ctx.fft_coarse.dims), gm,
+                            rdtype=rdt))
                 for ispn in range(ns):
                     gp = gamma_cache[rdt]._replace(
-                        veff_r=jnp.asarray(pot.veff_r_coarse[ispn], dtype=rdt),
-                        dion=jnp.asarray(np.real(d_by_spin[ispn]), dtype=rdt),
+                        veff_r=_up(pot.veff_r_coarse[ispn], rdt),
+                        dion=_up(np.real(d_by_spin[ispn]), rdt),
                     )
                     if x_packed[ispn] is None:
                         # first iteration: rotate the packed LCAO block to
                         # the lowest nb Ritz vectors (initialize_subspace)
-                        from sirius_tpu.solvers.davidson import (
-                            subspace_rotate,
+                        from sirius_tpu.ops.gamma import (
+                            initialize_subspace_gamma,
                         )
-                        from sirius_tpu.ops.gamma import apply_h_s_gamma
 
-                        xb = jnp.asarray(
-                            gpack(gm, psi_big[0, ispn]), dtype=rdt
-                        )
-                        hx, sx = apply_h_s_gamma(gp, xb)
-                        x_packed[ispn] = subspace_rotate(
-                            xb, hx, sx, nb, mask=gp.mask_p
-                        ).astype(rdt)
+                        x_packed[ispn] = initialize_subspace_gamma(
+                            gp, _up(gpack(gm, psi_big[0, ispn]), rdt), nb)
                         counters["num_loc_op_applied"] += psi_big.shape[2]
                     h_diag, o_diag = _h_o_diag(ctx, 0, v0, d_by_spin[ispn])
                     hd_p, od_p = pack_diags(
                         gm, np.asarray(h_diag), np.asarray(o_diag)
                     )
                     ev, xg, rn = davidson_gamma(
-                        gp, x_packed[ispn],
-                        jnp.asarray(hd_p, dtype=rdt),
-                        jnp.asarray(od_p, dtype=rdt),
+                        gp, x_packed[ispn], _up(hd_p, rdt), _up(od_p, rdt),
                         num_steps=itsol.num_steps,
-                        res_tol=res_tol,
+                        res_tol=_rtol(rdt),
                     )
                     evals[0, ispn] = np.asarray(ev)
                     x_packed[ispn] = xg
@@ -1495,7 +1539,7 @@ def _run_scf_inner(
                 # production path: the whole (k, spin) set as ONE program
                 # (parallel/batched.py; shards over the ("k", "b") mesh).
                 # Real-boundary: psi crosses the jit boundary as a (re, im)
-                # pair — the TPU backend cannot transfer complex arrays.
+                # pair.
                 from sirius_tpu.ops.hamiltonian import real_dtype_of
                 from sirius_tpu.parallel.batched import (
                     davidson_kset,
@@ -1530,7 +1574,7 @@ def _run_scf_inner(
                         pot.veff_r_coarse[:ns], np.stack(d_by_spin), v0,
                         vhub, wf_dtype,
                     )
-                ps = place_kset_params(ps, scf_mesh)
+                ps = place_kset_params(ps, scf_mesh, _devs[0])
                 if pr is None and psi is None and psi_big is not None:
                     # first iteration from a fresh LCAO block: rotate the
                     # full atomic-orbital subspace down to the lowest nb
@@ -1576,7 +1620,7 @@ def _run_scf_inner(
                     ev, pr, pi, rn = davidson_kset(
                         ps, pr, pi,
                         num_steps=itsol.num_steps,
-                        res_tol=res_tol,
+                        res_tol=_rtol(rdt),
                     )
                 # canonicalize the pair onto the explicit psi sharding (a
                 # no-op when GSPMD already placed it there): downstream
@@ -1593,7 +1637,7 @@ def _run_scf_inner(
                 if fused is not None:
                     # eigenvalues stay on device; the host copy is fetched
                     # once after the loop for the final report
-                    ev_dev = ev.astype(jnp.float64)
+                    ev_dev = ev.astype(fused.rdt)
                 else:
                     evals = np.asarray(ev, dtype=np.float64)
             # H*psi application count (reference num_loc_op_applied counter)
@@ -1754,9 +1798,9 @@ def _run_scf_inner(
                 # device-side update; a no-op dict lookup when unarmed, so
                 # the transfer-guard contract of this span is preserved)
                 acc = faults.corrupt("scf.density", it, acc)
-                if fused.has_aug and beta_dev is not None:
+                if fused.has_aug and fused_beta is not None:
                     dm_re, dm_im = density_matrix_kset(
-                        *beta_dev, pr, pi, occ_w
+                        *fused_beta, pr, pi, occ_w
                     )
                 else:
                     dm_re, dm_im = fused_dm0
@@ -1863,6 +1907,7 @@ def _run_scf_inner(
                 and rms < cfg.settings.fp32_to_fp64_rms
             ):
                 wf_dtype = jnp.complex128
+                _fused_switch_precision()
                 continue
             # autosave AFTER e_prev/precision bookkeeping: the saved state
             # must be exactly what the next iteration of an uninterrupted
@@ -2220,6 +2265,40 @@ def _run_scf_inner(
         _straggler_preempt(it)
 
     obs_trace.finish()
+    # read-only record of the path taken and of where each stage of the last
+    # iteration ran and in which dtype, read off the arrays themselves
+    placement = {
+        "path": ("gshard" if gsh is not None
+                 else "beta_chunked" if bchunk is not None
+                 else "gamma" if gamma_bands
+                 else "serial" if serial_bands
+                 else "batched+fused" if fused is not None else "batched"),
+        "devices": [str(d) for d in _devs],
+        "mesh": (dict(gsh["mesh"].shape) if gsh is not None
+                 else None if scf_mesh is None else dict(scf_mesh.shape)),
+    }
+    if fused is not None and fused_out is not None:
+        placement.update(
+            band_solve=runtime.where(pr),
+            occupations=runtime.where(occ_w),
+            density=runtime.where(acc),
+            fused_step=runtime.where(fused_out["scalars"]),
+            mixing=runtime.where(fused_carry.x_re),
+            potential=runtime.where(fused_out["veff_r_coarse"]),
+            psi_shard_devices=sorted(
+                s.device.id for s in pr.addressable_shards),
+        )
+    elif num_iter_done > it0:
+        placement.update(
+            band_solve=runtime.where(
+                gsh["psi"] if gsh is not None
+                else x_packed[0] if gamma_bands
+                else pr if pr is not None else psi),
+            occupations=runtime.where(occ),
+            density=runtime.where(rho_spin),
+            mixing=runtime.where(x_mix),
+            potential=runtime.where(pot.veff_r_coarse),
+        )
     # --- final report ---
     if fused is not None and fused_out is not None:
         # one-time exit fetch from the device-resident loop: mixed density,
@@ -2298,6 +2377,7 @@ def _run_scf_inner(
         "band_occupancies": occ_np.tolist(),
         "counters": dict(counters),
         "timers": timer_report(),
+        "placement": placement,
     }
     # convergence-forecast summary (obs/forecast.py via the supervisor):
     # consumed by serve/scheduler.py (deadline triage) and campaigns

@@ -3,11 +3,11 @@ shapes, the shared accelerator peak table, and roofline annotations.
 
 This is the single source of truth for "how much work is that stage":
 
-- `peak_gflops()` / `peak_gbps()`: the accelerator peak table (moved
-  here from bench.py's private copy) with env overrides
+- `peak_gflops()` / `peak_gbps()`: the device peak table, keyed by
+  ``device_kind`` as JAX reports it, with env overrides
   (``BENCH_PEAK_GFLOPS`` kept for compatibility, plus
-  ``SIRIUS_TPU_PEAK_GFLOPS`` / ``SIRIUS_TPU_PEAK_GBPS``) for unlisted
-  hardware;
+  ``SIRIUS_TPU_PEAK_GFLOPS`` / ``SIRIUS_TPU_PEAK_GBPS``); an accelerator
+  kind that is not in the table is an error, not a default;
 - per-kernel FLOP formulas (`fft_flops`, `hpsi_flops`,
   `beta_gemm_flops`, ...) — the self-reported work counters of the
   reference (wave_functions.hpp:1790-1833) generalized to every hot
@@ -31,23 +31,19 @@ import dataclasses
 import math
 import os
 
-# nominal fp32 peak GFLOPS per accelerator class (BASELINE.md anchors):
-# TPU v5p-class 229.5e3 (half the 459e3 bf16 MXU peak), P100 9.3e3, CPU
-# ~76.8/core (24 f32 FLOP/cycle @ 3.2 GHz)
-PEAK_GFLOPS = {
-    "tpu": 229.5e3,
-    "gpu": 9.3e3,
-    "cuda": 9.3e3,
+# published peaks per device kind (jax Device.device_kind). "TPU v5 lite"
+# is one TPU v5e chip — Google Cloud documentation, "TPU v5e": 197 TFLOP/s
+# in bf16, 16 GB of HBM at 819 GB/s. The flop figure is the bf16 MXU peak
+# and is named as such: the SCF's f32 matmuls run as several bf16 passes
+# (runtime.scf_scope), so an MFU against it is a lower bound by
+# construction, not an f32 roofline.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_gflops": 197e3, "hbm_gbps": 819.0},
 }
+# the host CPU is modelled per core: ~76.8 GFLOPS (24 f32 FLOP/cycle @
+# 3.2 GHz) and ~6.4 GB/s (shared DDR; deliberately coarse)
+CPU_KIND = "cpu"
 CPU_CORE_GFLOPS = 76.8
-
-# nominal memory bandwidth GB/s per class: TPU v5p HBM 2765, P100 HBM
-# 732, CPU ~6.4/core (shared DDR; deliberately coarse)
-PEAK_GBPS = {
-    "tpu": 2765.0,
-    "gpu": 732.0,
-    "cuda": 732.0,
-}
 CPU_CORE_GBPS = 6.4
 
 # Span names that deliberately have NO analytic flop model: wall-clock
@@ -85,43 +81,57 @@ UNCOSTED_SPANS = (
 
 
 def detect_platform() -> str:
-    """Backend platform string without forcing a jax init ("cpu" when
-    jax is unavailable or uninitialized-and-unneeded)."""
-    try:
-        import jax
+    """Platform of the default JAX device (initializes the backend; an
+    error there is the caller's to see)."""
+    import jax
 
-        return jax.devices()[0].platform
-    except Exception:
-        return "cpu"
+    return jax.devices()[0].platform
 
 
-def peak_gflops(platform: str | None = None,
+def detect_device_kind() -> str:
+    """``device_kind`` of the default JAX device — the peak table's key."""
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
+def _device_peak(device_kind: str | None, field: str, cpu_core: float) -> float:
+    if device_kind is None:
+        device_kind = detect_device_kind()
+    if device_kind == CPU_KIND:
+        return cpu_core * (os.cpu_count() or 1)
+    if device_kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no published peak for device kind {device_kind!r}: add it to "
+            "sirius_tpu/obs/costs.py DEVICE_PEAKS with its source (known: "
+            f"{sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[device_kind][field]
+
+
+def peak_gflops(device_kind: str | None = None,
                 override: float | None = None) -> float:
-    """Shared accelerator peak table (fp32 GFLOPS). Resolution order:
-    explicit ``override`` (config) > ``BENCH_PEAK_GFLOPS`` /
-    ``SIRIUS_TPU_PEAK_GFLOPS`` env > class table > per-core CPU model."""
+    """Peak GFLOPS of a device kind (bf16 MXU peak for a TPU, see
+    DEVICE_PEAKS). Resolution order: explicit ``override`` (config) >
+    ``BENCH_PEAK_GFLOPS`` / ``SIRIUS_TPU_PEAK_GFLOPS`` env > the table;
+    the per-core model for the CPU; KeyError for an unlisted accelerator."""
     if override:
         return float(override)
     env = (os.environ.get("BENCH_PEAK_GFLOPS")
            or os.environ.get("SIRIUS_TPU_PEAK_GFLOPS"))
     if env:
         return float(env)
-    if platform is None:
-        platform = detect_platform()
-    return PEAK_GFLOPS.get(platform, CPU_CORE_GFLOPS * (os.cpu_count() or 1))
+    return _device_peak(device_kind, "bf16_gflops", CPU_CORE_GFLOPS)
 
 
-def peak_gbps(platform: str | None = None,
+def peak_gbps(device_kind: str | None = None,
               override: float | None = None) -> float:
-    """Nominal memory bandwidth (GB/s) for the roofline ceiling."""
+    """Published memory bandwidth (GB/s) for the roofline ceiling."""
     if override:
         return float(override)
     env = os.environ.get("SIRIUS_TPU_PEAK_GBPS")
     if env:
         return float(env)
-    if platform is None:
-        platform = detect_platform()
-    return PEAK_GBPS.get(platform, CPU_CORE_GBPS * (os.cpu_count() or 1))
+    return _device_peak(device_kind, "hbm_gbps", CPU_CORE_GBPS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,35 +149,35 @@ class StageCost:
     def gflops(self, dur_s: float) -> float:
         return self.flops / dur_s / 1e9 if dur_s > 0 else 0.0
 
-    def roofline_gflops(self, platform: str | None = None,
+    def roofline_gflops(self, device_kind: str | None = None,
                         peak: float | None = None,
                         bw_gbps: float | None = None) -> float:
         """min(compute peak, intensity * bandwidth) — the ceiling this
         stage could reach on the given hardware."""
-        pk = peak if peak is not None else peak_gflops(platform)
-        bw = bw_gbps if bw_gbps is not None else peak_gbps(platform)
+        pk = peak if peak is not None else peak_gflops(device_kind)
+        bw = bw_gbps if bw_gbps is not None else peak_gbps(device_kind)
         if self.bytes <= 0:
             return pk
         return min(pk, self.intensity * bw)
 
-    def mfu(self, dur_s: float, platform: str | None = None,
+    def mfu(self, dur_s: float, device_kind: str | None = None,
             peak: float | None = None) -> float:
-        pk = peak if peak is not None else peak_gflops(platform)
+        pk = peak if peak is not None else peak_gflops(device_kind)
         return self.gflops(dur_s) / pk if pk > 0 else 0.0
 
 
 def annotate_span(dur_s: float, flops: float, bytes: float = 0.0,
-                  platform: str | None = None,
+                  device_kind: str | None = None,
                   peak: float | None = None) -> dict:
     """Roofline annotation fields for a measured span duration."""
     c = StageCost(flops=float(flops), bytes=float(bytes))
-    roof = c.roofline_gflops(platform=platform, peak=peak)
+    roof = c.roofline_gflops(device_kind=device_kind, peak=peak)
     return {
         "flops": c.flops,
         "bytes": c.bytes,
         "gflops": c.gflops(dur_s),
         "roofline_gflops": roof,
-        "mfu": c.mfu(dur_s, platform=platform, peak=peak),
+        "mfu": c.mfu(dur_s, device_kind=device_kind, peak=peak),
     }
 
 

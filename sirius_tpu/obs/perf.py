@@ -120,7 +120,7 @@ def run_tier(name: str, spec: dict, repeats: int | None = None,
     from sirius_tpu.dft.scf import run_scf
     from sirius_tpu.obs import metrics as obs_metrics
     from sirius_tpu.obs import spans as obs_spans
-    from sirius_tpu.obs.costs import detect_platform, peak_gflops
+    from sirius_tpu.obs.costs import peak_gflops
     from sirius_tpu.serve.scheduler import build_job_context
 
     nrep = int(repeats or spec["repeats"])
@@ -184,7 +184,7 @@ def run_tier(name: str, spec: dict, repeats: int | None = None,
         "iterations": len(iter_durs),
         "iteration_median_s": iter_med,
         "attributed_fraction": (attributed / iter_med) if iter_med else 0.0,
-        "peak_gflops": peak_gflops(detect_platform()),
+        "peak_gflops": peak_gflops(),
         "stages": stages,
     }
 

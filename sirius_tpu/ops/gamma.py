@@ -217,8 +217,8 @@ def apply_h_s_gamma(params: GammaParams, x: jax.Array):
     batch = x.shape[:-1]
     cdtype = jnp.complex64 if x.dtype == jnp.float32 else jnp.complex128
     # unpack to the complex sphere with gathers; lax.complex keeps the
-    # working precision (a bare `1j *` would promote f32 -> c128, which the
-    # TPU backend rejects)
+    # working precision (a bare `1j *` would promote f32 -> c128, which a
+    # TPU does not run)
     xr = jnp.take(x, params.slot_re, axis=-1)
     xi = jnp.take(x, params.slot_im, axis=-1)
     c = jax.lax.complex(params.scale * xr, params.scale * params.im_sign * xi)
@@ -255,7 +255,7 @@ def _pack_device(vg, slot_re, slot_im, im_sign, scale, zero_idx, npack):
     # NOTE float(...) keeps the scalar weakly typed: a bare np.float64
     # scalar would promote the whole f32 pipeline to f64
     half_sqrt2 = float(0.5 * SQRT2)
-    w = jnp.where(scale > 0, 1.0, 0.0)
+    w = (scale > 0).astype(scale.dtype)
     re_part = half_sqrt2 * jnp.real(vg) * w
     im_part = half_sqrt2 * jnp.imag(vg) * im_sign * w
     out = jnp.zeros(vg.shape[:-1] + (npack,), dtype=re_part.dtype)
@@ -265,6 +265,19 @@ def _pack_device(vg, slot_re, slot_im, im_sign, scale, zero_idx, npack):
     # the im-scatter) — overwrite with the exact real value
     zero_val = jnp.take(jnp.real(vg), zero_idx, axis=-1)
     return out.at[..., 0].set(zero_val)
+
+
+@partial(jax.jit, static_argnames=("nb",))
+def initialize_subspace_gamma(params: GammaParams, xb, nb: int):
+    """First-iteration LCAO rotation on packed-real vectors: one H/S
+    application to the full atomic-orbital block, keep the lowest nb Ritz
+    vectors (the Gamma twin of batched.initialize_subspace_kset) — one
+    program, so no python scalar of the eager form reaches the device as
+    a 64-bit operand."""
+    from sirius_tpu.solvers.davidson import subspace_rotate
+
+    hx, sx = apply_h_s_gamma(params, xb)
+    return subspace_rotate(xb, hx, sx, nb, mask=params.mask_p).astype(xb.dtype)
 
 
 @partial(jax.jit, static_argnames=("num_steps",))

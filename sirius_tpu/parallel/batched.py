@@ -7,13 +7,12 @@ make the entire k-set one vmapped davidson call — a single XLA program that
 shards over the mesh with zero hand-written collectives (density reduction
 over "k" is a psum XLA inserts from the einsum).
 
-REAL-BOUNDARY CONTRACT: the TPU backend in this environment cannot move
-complex arrays across any host<->device or jit boundary (transfers and
-executable I/O with complex dtypes fail with UNIMPLEMENTED and wedge the
-process; measured empirically — see bench.py). Every jitted entry point
-here therefore takes and returns REAL arrays only; complex leaves of the
-parameter pytree are stored as (re, im) pairs and the complex working
-arrays exist only inside the compiled programs.
+REAL-BOUNDARY CONTRACT: every jitted entry point here takes and returns
+REAL arrays only; complex leaves of the parameter pytree are stored as
+(re, im) pairs and the complex working arrays exist only inside the
+compiled programs. (The shape dates from a backend that could not move
+complex arrays; the stock TPU backend can, and the code works on it
+unchanged.)
 
 This is the PRODUCTION band-solve path: dft/scf.run_scf drives it each SCF
 iteration with the per-spin screened D matrices and Hubbard potentials

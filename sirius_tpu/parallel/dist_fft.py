@@ -30,10 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax < 0.5 ships shard_map under jax.experimental only
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
+_shard_map = jax.shard_map
 
 
 def x_slab_spec() -> P:

@@ -105,33 +105,24 @@ def production_mesh(nk: int, nb: int, devices=None):
     return mesh, P("k", None, band_ax, None)
 
 
-def place_kset_params(params, mesh: Mesh):
-    """device_put every leaf of an HkSetParams with its natural sharding:
-    leading-nk leaves split over "k", spin/shared tables replicated. A
-    device_put onto an identical sharding is a no-op, so calling this per
+# natural sharding of every HkSetParams leaf on the ("k", "b") mesh:
+# leading-nk leaves split over "k", spin/shared tables replicated
+_K1, _K2 = P("k", None), P("k", None, None)
+KSET_PARAM_SPECS = dict(
+    veff_r=P(), ekin=_K1, mask=_K1, fft_index=_K1, beta_re=_K2, beta_im=_K2,
+    dion=P(), qmat=P(), h_diag=_K2, o_diag=_K1, hub_re=_K2, hub_im=_K2,
+    vhub_re=P(), vhub_im=P(),
+)
+
+
+def place_kset_params(params, mesh: Mesh | None, device):
+    """device_put every leaf of an HkSetParams with its natural sharding
+    (KSET_PARAM_SPECS), or without a mesh onto the one compute `device`. A
+    device_put onto an identical placement is a no-op, so calling this per
     SCF iteration only moves the refreshed potential-dependent leaves."""
     if mesh is None:
-        return params
-    k1 = NamedSharding(mesh, P("k", None))
-    k2 = NamedSharding(mesh, P("k", None, None))
-    rep = NamedSharding(mesh, P())
-
-    def put(x, s):
-        return None if x is None else jax.device_put(x, s)
-
-    return params._replace(
-        veff_r=put(params.veff_r, rep),
-        ekin=put(params.ekin, k1),
-        mask=put(params.mask, k1),
-        fft_index=put(params.fft_index, k1),
-        beta_re=put(params.beta_re, k2),
-        beta_im=put(params.beta_im, k2),
-        dion=put(params.dion, rep),
-        qmat=put(params.qmat, rep),
-        h_diag=put(params.h_diag, k2),
-        o_diag=put(params.o_diag, k1),
-        hub_re=put(params.hub_re, k2),
-        hub_im=put(params.hub_im, k2),
-        vhub_re=put(params.vhub_re, rep),
-        vhub_im=put(params.vhub_im, rep),
-    )
+        return jax.device_put(params, device)
+    return params._replace(**{
+        name: jax.device_put(leaf, NamedSharding(mesh, KSET_PARAM_SPECS[name]))
+        for name, leaf in params._asdict().items() if leaf is not None
+    })

@@ -300,6 +300,23 @@ class Job:
             "events": [
                 {"t": t, "status": s, "detail": d} for t, s, d in self.events
             ],
+            "result": self._result_summary(),
+        }
+
+    def _result_summary(self) -> dict | None:
+        """What a stats row says of a finished job's physics: enough for
+        chip_smoke.py to check energy, compile count and placement through
+        the sirius-serve front door."""
+        r = self.result
+        if not isinstance(r, dict):
+            return None
+        return {
+            "converged": r.get("converged"),
+            "energy_total": (r.get("energy") or {}).get("total"),
+            "num_scf_iterations": r.get("num_scf_iterations"),
+            "compiled_executables": (r.get("serve") or {}).get(
+                "compiled_executables"),
+            "placement": r.get("placement"),
         }
 
 

@@ -60,7 +60,7 @@ def residual_health(rnorm, blowup: float = 1e2) -> tuple[float, bool]:
     return rmax, np.isfinite(rmax) and rmax <= blowup
 
 
-def _rayleigh_ritz(hsub: jax.Array, ssub: jax.Array, nev: int, big: float = 1e6):
+def _rayleigh_ritz(hsub: jax.Array, ssub: jax.Array, nev: int):
     """Lowest-nev gen-EVP of a possibly rank-deficient subspace pair."""
     s, u = jnp.linalg.eigh(ssub)
     smax = jnp.max(jnp.abs(s))
@@ -75,7 +75,13 @@ def _rayleigh_ritz(hsub: jax.Array, ssub: jax.Array, nev: int, big: float = 1e6)
     good = s > jnp.maximum(50.0 * eps, 1e-11) * smax
     t = u * jnp.where(good, jax.lax.rsqrt(jnp.where(good, s, 1.0)), 0.0)[None, :]
     at = t.conj().T @ hsub @ t
-    at = at + jnp.diag(jnp.where(good, 0.0, big).astype(at.dtype))
+    # park the projected-out directions just above the spectrum of the kept
+    # block (its inf-norm bounds it). The shift must stay on the scale of
+    # the problem: an eigensolver whose error is relative to the matrix norm
+    # (the TPU's; measured with a fixed 1e6 in f32: Ritz values off by O(1))
+    # otherwise loses the wanted eigenvalues under eps * shift
+    shift = 1.0 + jnp.max(jnp.sum(jnp.abs(at), axis=1))
+    at = at + jnp.diag(jnp.where(good, 0.0, shift).astype(at.dtype))
     e, y = jnp.linalg.eigh(at)
     c = t @ y
     return e[:nev], c[:, :nev]

@@ -2,9 +2,8 @@
 multi-chip sharding paths are exercised without TPU hardware (the driver
 separately dry-runs the multi-chip path via __graft_entry__.dryrun_multichip).
 
-Note: a pytest plugin imports jax before this conftest runs, so env-var
-configuration (JAX_PLATFORMS / XLA_FLAGS) is too late; jax.config still works
-because no backend has been initialized yet."""
+The backend is configured through jax.config here, before any test touches
+a device, so the suite needs no environment variable."""
 
 import os
 import sys
@@ -19,14 +18,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # Force CPU: the suite needs f64/c128 (unsupported on TPU) and a virtual
 # multi-device mesh. Set SIRIUS_TPU_TEST_PLATFORM to override.
 jax.config.update("jax_platforms", os.environ.get("SIRIUS_TPU_TEST_PLATFORM", "cpu"))
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # older jax (< 0.5) has no jax_num_cpu_devices option; XLA_FLAGS is still
-    # honored because the CPU backend has not been initialized yet
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _flags:
-        os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
+jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_enable_x64", True)
 
 def pytest_configure(config):

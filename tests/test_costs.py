@@ -52,7 +52,8 @@ def test_bench_delegates_to_shared_model():
 
     assert bench._hpsi_flops(8, 200, 18, (12, 12, 12)) == costs.hpsi_flops(
         8, 200, 18, (12, 12, 12))
-    assert bench._peak_gflops("tpu") == costs.peak_gflops("tpu")
+    assert bench._peak_gflops("TPU v5 lite") == costs.peak_gflops(
+        "TPU v5 lite")
 
 
 def test_davidson_applies_matches_solver():
@@ -70,19 +71,26 @@ def test_davidson_applies_matches_solver():
 def test_peak_table_and_overrides(monkeypatch):
     monkeypatch.delenv("BENCH_PEAK_GFLOPS", raising=False)
     monkeypatch.delenv("SIRIUS_TPU_PEAK_GFLOPS", raising=False)
-    assert costs.peak_gflops("tpu") == 229.5e3
-    assert costs.peak_gflops("gpu") == costs.peak_gflops("cuda") == 9.3e3
+    monkeypatch.delenv("SIRIUS_TPU_PEAK_GBPS", raising=False)
+    # keyed by device_kind; the v5e row is the published bf16 peak
+    assert costs.peak_gflops("TPU v5 lite") == 197e3
+    assert costs.peak_gbps("TPU v5 lite") == 819.0
     import os
 
     assert costs.peak_gflops("cpu") == 76.8 * (os.cpu_count() or 1)
-    # env override (unlisted hardware) wins over the class table
+    # an accelerator kind that is not in the table is an error
+    with pytest.raises(KeyError, match="TPU v9"):
+        costs.peak_gflops("TPU v9")
+    with pytest.raises(KeyError, match="tpu"):
+        costs.peak_gbps("tpu")
+    # env override (unlisted hardware) wins over the table
     monkeypatch.setenv("BENCH_PEAK_GFLOPS", "1234.5")
-    assert costs.peak_gflops("tpu") == 1234.5
+    assert costs.peak_gflops("TPU v5 lite") == 1234.5
     monkeypatch.delenv("BENCH_PEAK_GFLOPS")
     monkeypatch.setenv("SIRIUS_TPU_PEAK_GFLOPS", "42.0")
     assert costs.peak_gflops("whatever") == 42.0
     # explicit (config) override wins over everything
-    assert costs.peak_gflops("tpu", override=7.0) == 7.0
+    assert costs.peak_gflops("TPU v5 lite", override=7.0) == 7.0
 
 
 def test_roofline_and_mfu():

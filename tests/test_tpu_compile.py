@@ -1,0 +1,275 @@
+"""The main path's device programs, compiled by the TPU's own compiler for a
+described (not attached) v5e:2x2 at the smoke deck's real widths
+(chip_smoke.py: Si-2 ultrasoft, gk 6 / pw 20, 26 bands), in 32-bit types.
+
+A compile that passes is not a chip run: nothing executes, no result or time
+comes out of it. What it guards at no chip time is that the chip's compiler
+accepts every program (complex eigh/cholesky, non-power-of-two FFT boxes,
+the all_to_all pair and beta psum inside shard_map), that each fits the
+16 GB of one chip, and that no 64-bit type is left in a band-solve program.
+
+The topology is described inside a fixture of this file — never at import,
+in a skipif or in parametrize (on-chip-measurement guide section 2): only
+the xdist worker that runs this file may load the TPU library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+HBM_BYTES = 16e9
+NUM_STEPS = 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # whatever the TPU library raises where absent
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module", autouse=False)
+def no_compile_cache():
+    """A described-chip executable can be written to the persistent cache
+    but not read back without a chip; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _deck(ngridk, supercell=1):
+    return {
+        "parameters": {
+            "gk_cutoff": 6.0, "pw_cutoff": 20.0, "ngridk": list(ngridk),
+            "num_bands": 26 * supercell**3, "use_symmetry": False,
+            "precision_wf": "fp32",
+            "xc_functionals": ["XC_LDA_X", "XC_LDA_C_PZ"],
+        },
+        "synthetic": {"ultrasoft": True, "supercell": supercell},
+    }
+
+
+def _ctx(ngridk, supercell=1):
+    from sirius_tpu.config.schema import load_config
+    from sirius_tpu.serve.scheduler import build_job_context
+
+    return build_job_context(load_config(_deck(ngridk, supercell)), ".")
+
+
+@pytest.fixture(scope="module")
+def ctx_gamma():
+    return _ctx((1, 1, 1))
+
+
+@pytest.fixture(scope="module")
+def ctx_kmesh():
+    return _ctx((2, 2, 2))
+
+
+def _shapes(tree, sharding):
+    """Shapes, not arrays: nothing can be put on a described device.
+    `sharding` is one sharding for every leaf or a function leaf -> sharding."""
+    pick = sharding if callable(sharding) else (lambda a: sharding)
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=pick(a)),
+        tree)
+
+
+def _compile(lowered):
+    from sirius_tpu import runtime
+
+    with runtime.scf_scope():
+        return lowered().compile()
+
+
+def _check(compiled, no_64bit=False):
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes + m.generated_code_size_in_bytes)
+    assert total < HBM_BYTES, m
+    txt = compiled.as_text()
+    if no_64bit:
+        assert "c128[" not in txt and "f64[" not in txt
+    return txt
+
+
+def _kset_inputs(ctx, nb):
+    from sirius_tpu.parallel.batched import make_hkset_params
+
+    nk, ngk = ctx.gkvec.num_kpoints, ctx.gkvec.ngk_max
+    ps = make_hkset_params(
+        ctx, np.zeros(ctx.fft_coarse.dims), dtype=jnp.complex64)
+    psi = np.zeros((nk, 1, nb, ngk), np.float32)
+    return ps, psi
+
+
+def _fused(ctx):
+    from sirius_tpu.dft.fused import FusedScf
+    from sirius_tpu.dft.mixer import Mixer
+    from sirius_tpu.dft.xc import XCFunctional
+
+    cfg = ctx.cfg
+    mixer = Mixer(cfg.mixer, ctx.gvec.glen2, num_components=1,
+                  omega=ctx.unit_cell.omega)
+    return FusedScf(ctx, XCFunctional(cfg.parameters.xc_functionals), mixer,
+                    False, False, wf_dtype=jnp.complex64)
+
+
+def _fused_args(fused, ctx, nb, rep, psi_sh, ev_sh):
+    """ShapeDtypeStructs of FusedScf.step's operands as run_scf feeds them."""
+    from types import SimpleNamespace
+
+    nk, ngk, ng = ctx.gkvec.num_kpoints, ctx.gkvec.ngk_max, fused.ng
+    nbeta = ctx.beta.num_beta_total
+    f32 = np.float32
+    pot0 = SimpleNamespace(veff_g=np.zeros(ng, np.complex128), bz_g=None)
+    carry = fused.init_carry(np.zeros(fused.nx, np.complex128), pot0)
+
+    def sds(shape, sh):
+        return jax.ShapeDtypeStruct(shape, f32, sharding=sh)
+
+    return (
+        _shapes(fused.tables, rep), _shapes(carry, rep),
+        sds((1,) + fused.dims_coarse, rep),
+        sds((1, nbeta, nbeta), rep), sds((1, nbeta, nbeta), rep),
+        sds((nk, 1, nb), ev_sh), sds((nk, 1, nb), ev_sh), sds((), rep),
+        sds((nk, 1, nb, ngk), psi_sh), sds((nk, 1, nb, ngk), psi_sh),
+    )
+
+
+def test_gamma_band_solve_one_chip(topo, no_compile_cache, ctx_gamma):
+    """The packed-real Gamma solve of run_scf's `gamma_bands` path."""
+    from sirius_tpu.ops.gamma import (
+        build_gamma_map, davidson_gamma, initialize_subspace_gamma,
+        make_gamma_params,
+    )
+
+    ctx = ctx_gamma
+    one = SingleDeviceSharding(topo.devices[0])
+    gm = build_gamma_map(np.asarray(ctx.gkvec.millers[0]),
+                         np.asarray(ctx.gkvec.mask[0]))
+    gp = _shapes(make_gamma_params(
+        ctx, np.zeros(ctx.fft_coarse.dims), gm, rdtype=jnp.float32), one)
+    nb, ngk = ctx.num_bands, ctx.gkvec.ngk_max
+    x0 = jax.ShapeDtypeStruct((nb, ngk), np.float32, sharding=one)
+    diag = jax.ShapeDtypeStruct((ngk,), np.float32, sharding=one)
+    tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
+    _check(_compile(lambda: davidson_gamma.lower(
+        gp, x0, diag, diag, num_steps=NUM_STEPS, res_tol=tol)),
+        no_64bit=True)
+    nbig = jax.ShapeDtypeStruct((nb + 6, ngk), np.float32, sharding=one)
+    _check(_compile(lambda: initialize_subspace_gamma.lower(gp, nbig, nb=nb)),
+           no_64bit=True)
+
+
+def test_kset_band_solve_one_chip(topo, no_compile_cache, ctx_kmesh):
+    """The batched k-set solve (complex Hermitian eigh at 3*nb inside)."""
+    from sirius_tpu.parallel.batched import davidson_kset, density_kset
+
+    ctx = ctx_kmesh
+    one = SingleDeviceSharding(topo.devices[0])
+    ps, psi = _kset_inputs(ctx, ctx.num_bands)
+    ps, psi = _shapes(ps, one), _shapes(psi, one)
+    tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
+    _check(_compile(lambda: davidson_kset.lower(
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol)), no_64bit=True)
+    occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=one)
+    _check(_compile(lambda: density_kset.lower(ps, psi, psi, occ)),
+           no_64bit=True)
+
+
+def test_fused_step_one_chip(topo, no_compile_cache, ctx_kmesh):
+    """The FusedScf step program built for complex64, donated carry."""
+    ctx = ctx_kmesh
+    one = SingleDeviceSharding(topo.devices[0])
+    fused = _fused(ctx)
+    args = _fused_args(fused, ctx, ctx.num_bands, one, one, one)
+    _check(_compile(lambda: fused._step.lower(*args)), no_64bit=True)
+
+
+def test_fft_pair_c64_one_chip(topo, no_compile_cache, ctx_kmesh):
+    """r_to_g / g_to_r on the fine box (not a power of two) in complex64."""
+    from sirius_tpu.core.fftgrid import g_to_r, r_to_g
+
+    ctx = ctx_kmesh
+    one = SingleDeviceSharding(topo.devices[0])
+    dims = tuple(ctx.gvec.fft.dims)
+    idx = _shapes(np.asarray(ctx.gvec.fft_index), one)
+    box = jax.ShapeDtypeStruct(dims, np.complex64, sharding=one)
+    sph = jax.ShapeDtypeStruct((ctx.gvec.num_gvec,), np.complex64, sharding=one)
+    _check(_compile(lambda: r_to_g.lower(box, idx, dims)), no_64bit=True)
+    _check(_compile(lambda: g_to_r.lower(sph, idx, dims)), no_64bit=True)
+
+
+def test_kb_mesh_step_four_chips(topo, no_compile_cache, ctx_kmesh):
+    """Batched solve + fused step on the (k, b) production mesh, sharded as
+    run_scf shards them; the k-reduction of the density is a collective."""
+    from sirius_tpu.parallel.batched import davidson_kset, density_kset
+    from sirius_tpu.parallel.mesh import KSET_PARAM_SPECS, production_mesh
+
+    ctx = ctx_kmesh
+    nb = ctx.num_bands
+    mesh, psi_spec = production_mesh(
+        ctx.gkvec.num_kpoints, nb, devices=topo.devices)
+    assert mesh is not None and mesh.devices.size == 4
+    rep = NamedSharding(mesh, P())
+    psi_sh = NamedSharding(mesh, psi_spec)
+    ev_sh = NamedSharding(mesh, P(*psi_spec[:3]))
+    ps, psi = _kset_inputs(ctx, nb)
+    ps = ps._replace(**{
+        name: _shapes(leaf, NamedSharding(mesh, KSET_PARAM_SPECS[name]))
+        for name, leaf in ps._asdict().items() if leaf is not None})
+    psi = _shapes(psi, psi_sh)
+    tol = jax.ShapeDtypeStruct((), np.float32, sharding=rep)
+    _check(_compile(lambda: davidson_kset.lower(
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol)), no_64bit=True)
+    occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=ev_sh)
+    txt = _check(_compile(lambda: density_kset.lower(ps, psi, psi, occ)),
+                 no_64bit=True)
+    assert "all-reduce" in txt  # sum over the k-sharded axis
+    fused = _fused(ctx)
+    args = _fused_args(fused, ctx, nb, rep, psi_sh, ev_sh)
+    _check(_compile(lambda: fused._step.lower(*args)), no_64bit=True)
+
+
+def test_gshard_apply_four_chips(topo, no_compile_cache):
+    """The G-sharded H/S application (slab FFT over the "g" mesh) for the
+    Gamma supercell of chip_smoke.py --chips 4: the all_to_all pair and the
+    beta psum inside shard_map must survive the chip's compiler."""
+    from sirius_tpu.parallel.dist_fft import _gshard_inner, gshard_partition
+
+    ctx = _ctx((1, 1, 1), supercell=2)
+    dims = tuple(ctx.fft_coarse.dims)
+    assert dims[0] % 4 == 0 and dims[1] % 4 == 0, dims
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("g",))
+    _, lidx, _ = gshard_partition(np.asarray(ctx.gkvec.millers[0]), dims, 4)
+    ngk = lidx.size
+    nb, nbeta = ctx.num_bands, ctx.beta.num_beta_total
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    f32, c64 = np.float32, np.complex64
+    inner = _gshard_inner(mesh, dims[0] // 4, dims[1], dims[2])
+    txt = _check(_compile(lambda: inner.lower(
+        sds((nb, ngk), c64, P(None, "g")), sds((ngk,), f32, P("g")),
+        sds((ngk,), f32, P("g")), sds((nbeta, ngk), c64, P(None, "g")),
+        sds((ngk,), lidx.dtype, P("g")), sds((nbeta, nbeta), f32, P()),
+        sds((nbeta, nbeta), f32, P()), sds(dims, f32, P(None, "g", None)),
+    )), no_64bit=True)
+    assert "all-to-all" in txt and "all-reduce" in txt

@@ -458,8 +458,10 @@ def main(argv=None) -> int:
 
     import tempfile
 
+    from sirius_tpu.runtime import enable_compile_cache
     from sirius_tpu.serve.engine import ServeEngine
 
+    enable_compile_cache()
     workdir = args.workdir or tempfile.mkdtemp(prefix="sirius_loadgen_")
     if args.mix == "screening":
         return run_screening(args, workdir)

@@ -20,16 +20,9 @@ import tempfile
 # where the batched band solve (not the single-device Gamma packed-real
 # path) is taken — that is the path that carries the numerics ledger and
 # engages the fused program, so the fixtures must be recorded on it
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8").strip()
-
 import jax
 
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    pass  # older jax: XLA_FLAGS above is honored at backend init
+jax.config.update("jax_num_cpu_devices", 8)
 
 from sirius_tpu.obs import events as obs_events
 from sirius_tpu.testing import synthetic_silicon_context
