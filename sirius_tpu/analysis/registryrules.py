@@ -221,7 +221,7 @@ class UncostedSpan:
 
     name = "uncosted-span"
     wants_registry = True
-    _FNS = {"record", "span", "_stage_record"}
+    _FNS = {"record", "span", "open_span", "_stage"}
 
     def run(self, project: ProjectIndex, registry=None):
         reg = registry or load_registry(project)

@@ -145,9 +145,16 @@ def run_scf(*args, **kwargs) -> dict:
     """Trace-context front door: a standalone SCF gets its own trace_id;
     one inherited from serve/campaigns (scheduler enters the job's
     trace_context) is kept, so every span/event of this run carries the
-    end-to-end trace. See _run_scf_inner for the full contract."""
+    end-to-end trace. ``scf.run`` is the root of the run's span tree
+    (scf.setup, scf.iteration x N, scf.finalize): leaving it, by return
+    or by raise, closes whatever of the run is still open. See
+    _run_scf_inner for the full contract."""
+    cfg = args[0] if args else kwargs["cfg"]
     with obs_tracing.ensure_trace(), runtime.scf_scope():
-        return _run_scf_inner(*args, **kwargs)
+        obs_metrics.set_enabled(bool(getattr(
+            getattr(cfg, "control", None), "telemetry", True)))
+        with obs_spans.span("scf.run"):
+            return _run_scf_inner(*args, **kwargs)
 
 
 def _run_scf_inner(
@@ -199,7 +206,7 @@ def _run_scf_inner(
         # child processes (tools/soak_scf.py) inherit their fault plan via
         # the environment; in-process plans (faults.install) are untouched
         faults.load_env()
-    obs_metrics.set_enabled(bool(getattr(cfg.control, "telemetry", True)))
+    _setup_span = obs_spans.open_span("scf.setup")
     obs_metrics.install_jax_listeners()
     if cfg.control.verbosity >= 1:
         # deck-driven verbosity keeps printing per-iteration lines even
@@ -215,7 +222,8 @@ def _run_scf_inner(
         tc = cfg.control.trace_capture
         obs_trace.request(
             tc if os.path.isabs(tc) else os.path.join(base_dir, tc),
-            steps=int(getattr(cfg.control, "trace_capture_steps", 5)))
+            steps=int(getattr(cfg.control, "trace_capture_steps", 5)),
+            skip=1)  # from the job's second iteration: the steady state
     p = cfg.parameters
     if ctx is None:
         ctx = SimulationContext.create(cfg, base_dir)
@@ -766,10 +774,28 @@ def _run_scf_inner(
     except Exception:
         _stage_costs = {}
 
-    def _stage_record(stage, dur_s, **attrs):
+    def _stage(stage, **attrs):
+        # a live span of one stage, opened here; the caller closes it
         c = _stage_costs.get(stage)
-        obs_spans.record(stage, dur_s, flops=c.flops if c else 0.0,
-                         bytes=c.bytes if c else 0.0, **attrs)
+        return obs_spans.open_span(
+            stage, flops=c.flops if c else 0.0,
+            bytes=c.bytes if c else 0.0, **attrs)
+
+    _it_span = None
+
+    def _close_iteration():
+        # the loop's head and its exit close the iteration that is open:
+        # the loop has `continue` paths (recovery rollback, precision
+        # switch) and a `break`. An iteration that left before its
+        # bookkeeping is marked incomplete; a stage span still open
+        # under it is closed first, marked unwound (obs/spans.py)
+        nonlocal _it_span
+        if _it_span is not None:
+            if "path" in _it_span.attrs:
+                _it_span.close()
+            else:
+                _it_span.close(incomplete=True)
+            _it_span = None
 
     def _hbm_attr():
         # per-iteration HBM high-water sample (device memory_stats peak;
@@ -1084,6 +1110,7 @@ def _run_scf_inner(
         everything the resume path above needs to continue this run."""
         from sirius_tpu.io.checkpoint import save_state
 
+        _as_span = obs_spans.open_span("scf.autosave", it=it + 1)
         path = cfg.control.autosave_path or default_autosave_path(
             cfg, base_dir)
         if fused is not None and fused_carry is not None:
@@ -1125,6 +1152,7 @@ def _run_scf_inner(
         _AUTOSAVES.inc()
         obs_events.emit("autosave", it=it + 1, path=path,
                         fused=fused is not None)
+        _as_span.close()
         # fault site: a preemption right after the autosave (soak test /
         # tests drive the resume path through this)
         faults.check("scf.autosave_kill", it)
@@ -1242,12 +1270,13 @@ def _run_scf_inner(
         xc=list(p.xc_functionals), precision_wf=p.precision_wf,
     )
     # everything since run_scf entry (context/tables/initial guess/fused
-    # compile trigger) is one externally-timed setup span
-    obs_spans.record("scf.setup", time.time() - t0, t0=t0,
-                     fused=fused is not None)
+    # compile trigger) is the setup span
+    _setup_span.close(fused=fused is not None)
     _it_t0 = time.time()
     for it in range(it0, p.num_dft_iter):
-        obs_trace.tick()
+        _close_iteration()
+        obs_trace.tick(it + 1)
+        _it_span = obs_spans.open_span("scf.iteration", it=it + 1)
         _it_t0 = time.time()
         # ---- injectable device faults at the jit-dispatch boundary
         # (utils/faults.py fire/armed; tools/chaos_serve.py device phases).
@@ -1279,7 +1308,7 @@ def _run_scf_inner(
         if fused is None or fused_out is None:
             # host D/v0 from the host potential; once the fused step has
             # run, the refreshed D and v0 live on device (fused_out)
-            _dm_t0 = time.perf_counter()
+            _sp = _stage("scf.d_matrix", it=it + 1)
             d_by_spin = []
             for ispn in range(ns):
                 if ctx.aug is not None:
@@ -1294,8 +1323,9 @@ def _run_scf_inner(
                 # the screened D before the band solve
                 d_by_spin = paw_mod.add_dij_to_d(paw, paw_res["dij_atoms"], d_by_spin)
             v0 = float(np.real(pot.veff_g[0]))
-            _stage_record("scf.d_matrix", time.perf_counter() - _dm_t0,
-                          it=it + 1)
+            _sp.close()
+        _bs_span = _stage("scf.band_solve", it=it + 1,
+                          num_steps=itsol.num_steps)
         _bs_t0 = time.perf_counter()
         with profile("scf::band_solve"):
             if gsh is not None:
@@ -1653,9 +1683,6 @@ def _run_scf_inner(
                 _fence((ev_dev, pr, pi))
             elif pr is not None:
                 _fence((pr, pi))
-        _bs_dt = time.perf_counter() - _bs_t0
-        _stage_record("scf.band_solve", _bs_dt,
-                      it=it + 1, num_steps=itsol.num_steps)
         if gsh is not None and gsh.get("probe"):
             # split the measured solve wall into collective vs compute:
             # fenced per-collective probe costs (probe_collectives, taken
@@ -1665,6 +1692,8 @@ def _run_scf_inner(
             # against the 1-device baseline.
             from sirius_tpu.solvers.davidson import num_applies as _napp
 
+            _bs_dt = time.perf_counter() - _bs_t0
+            _bs_ns = time.time_ns() - int(_bs_dt * 1e9)
             _pb = gsh["probe"]
             _rows = nk * ns * _napp(itsol.num_steps, nb)
             _coll = sum(
@@ -1672,10 +1701,15 @@ def _run_scf_inner(
                 if k != "collective.fft_local"
             ) / _pb["batch"] * _rows
             _coll = min(_coll, _bs_dt)
-            _stage_record("scf.band_solve.collective", _coll, it=it + 1,
-                          method="probe", ndev=ndev)
-            _stage_record("scf.band_solve.compute", _bs_dt - _coll,
-                          it=it + 1, method="probe", ndev=ndev)
+            # a model's split of the measured interval, not two
+            # measurements: recorded from outside, under the band solve
+            obs_spans.record("scf.band_solve.collective", _coll,
+                             start_unix_ns=_bs_ns, it=it + 1,
+                             method="probe", ndev=ndev)
+            obs_spans.record("scf.band_solve.compute", _bs_dt - _coll,
+                             start_unix_ns=_bs_ns + int(_coll * 1e9),
+                             it=it + 1, method="probe", ndev=ndev)
+        _bs_span.close()
         # --- band-solve supervision (dft/recovery.py): a stagnated or
         # blown-up solve is retried with a deeper subspace; the serial
         # debug path additionally falls back to dense diagonalization for
@@ -1777,7 +1811,7 @@ def _run_scf_inner(
                 # guard of test_fused_no_host_transfers stays satisfied);
                 # unfenced, dispatch latency is recorded per stage and the
                 # queued compute lands in scf.readback below
-                _fu_t = time.perf_counter()
+                _sp = _stage("scf.occupations", it=it + 1)
                 mu, occ, entropy_sum = find_fermi(
                     ev_dev, fused.kweights_dev, fused_nel, fused_width,
                     kind=p.smearing, max_occupancy=fused_occmax,
@@ -1785,9 +1819,8 @@ def _run_scf_inner(
                 occ_w = occ * fused.kweights_dev[:, None, None]
                 if _span_fence:
                     _fence(occ_w)
-                _stage_record("scf.occupations",
-                              time.perf_counter() - _fu_t, it=it + 1)
-                _fu_t = time.perf_counter()
+                _sp.close()
+                _sp = _stage("scf.density", it=it + 1)
                 from sirius_tpu.parallel.batched import (
                     density_kset,
                     density_matrix_kset,
@@ -1806,22 +1839,19 @@ def _run_scf_inner(
                     dm_re, dm_im = fused_dm0
                 if _span_fence:
                     _fence((acc, dm_re, dm_im))
-                _stage_record("scf.density",
-                              time.perf_counter() - _fu_t, it=it + 1)
-                _fu_t = time.perf_counter()
+                _sp.close()
+                _sp = _stage("scf.fused_step", it=it + 1)
                 fused_carry, fused_out = fused.step(
                     fused_carry, acc, dm_re, dm_im, ev_dev, occ_w,
                     entropy_sum, pr, pi,
                 )
                 if _span_fence:
                     _fence(fused_out)
-                _stage_record("scf.fused_step",
-                              time.perf_counter() - _fu_t, it=it + 1)
+                _sp.close()
             # the ONLY per-iteration device->host fetch
-            _rb_t0 = time.perf_counter()
+            _sp = _stage("scf.readback", it=it + 1)
             fused_np = np.asarray(fused_out["scalars"])
-            _stage_record("scf.readback", time.perf_counter() - _rb_t0,
-                          it=it + 1)
+            _sp.close()
             if (not np.all(np.isfinite(fused_np))
                     or fused_np[S_FINITE] != 1.0):
                 # non-finite fields on device: roll back and escalate
@@ -1865,8 +1895,9 @@ def _run_scf_inner(
             _ITER_SECONDS.observe(_it_dt)
             _RMS.set(rms)
             _ETOT.set(e_total)
-            _stage_record("scf.iteration", _it_dt, t0=_it_t0, it=it + 1,
-                          path="fused", **_hbm_attr())
+            # the span runs on to the loop's head: snapshot, autosave and
+            # the straggler check below are the iteration's too
+            _it_span.set(path="fused", **_hbm_attr())
             # numerics ledger: the invariants ride the existing [NUM_SCALARS]
             # readback (dft/fused.py) — naming them here costs no transfer
             ledger = obs_numerics.ledger_from_scalars(fused_np)
@@ -1924,7 +1955,7 @@ def _run_scf_inner(
         # fault site: NaN into the band energies (detected with the other
         # non-finite fields after the density assembly below)
         evals = faults.corrupt("scf.evals", it, evals)
-        _oc_t0 = time.perf_counter()
+        _sp = _stage("scf.occupations", it=it + 1)
         mu, occ, entropy_sum = find_fermi(
             jnp.asarray(evals),
             jnp.asarray(ctx.kweights),
@@ -1934,8 +1965,7 @@ def _run_scf_inner(
             max_occupancy=ctx.max_occupancy,
         )
         occ_np = np.asarray(occ)  # self-fencing host fetch
-        _stage_record("scf.occupations", time.perf_counter() - _oc_t0,
-                      it=it + 1)
+        _sp.close()
 
         # --- Hubbard occupation matrix (mixed jointly with the density) ---
         om_new = None
@@ -1978,7 +2008,7 @@ def _run_scf_inner(
             )
 
         # --- density (per spin, then charge/magnetization assembly) ---
-        _de_t0 = time.perf_counter()
+        _sp = _stage("scf.density", it=it + 1)
         occ_w = jnp.asarray(occ_np * ctx.kweights[:, None, None])
         with profile("scf::density"):
             if (serial_bands or gamma_bands or gsh is not None
@@ -2062,7 +2092,7 @@ def _run_scf_inner(
                      hub_lagrange)
         # the span extends past profile("scf::density") through augmentation,
         # symmetrization and packing — the full "new density" stage
-        _stage_record("scf.density", time.perf_counter() - _de_t0, it=it + 1)
+        _sp.close()
         rho_resid_g = rho_new - rho_g  # output - input density (scf-corr force)
         if not np.all(np.isfinite(evals)) or not np.isfinite(
             np.sum(np.abs(x_new))
@@ -2085,7 +2115,7 @@ def _run_scf_inner(
             ]
             _recover("nonfinite_fields", detail=f"non-finite {bad}")
             continue
-        _mx_t0 = time.perf_counter()
+        _sp = _stage("scf.mixing", it=it + 1)
         rms = mixer.rms(x_mix, x_new)
         x_mix = mixer.mix(x_mix, x_new)
         # density criterion in the reference's metric: with use_hartree the
@@ -2100,7 +2130,7 @@ def _run_scf_inner(
         res_tol = schedule_res_tol(itsol, res_tol, dens_metric, nel,
                                    mixer.use_hartree and eha_res is not None)
         rho_g, mag_g, om_mixed, om_nl_mixed, paw_dm, lam_mixed = unpack(x_mix)
-        _stage_record("scf.mixing", time.perf_counter() - _mx_t0, it=it + 1)
+        _sp.close()
         if lam_mixed is not None:
             hub_lagrange = lam_mixed  # quasi-Newton-mixed multipliers
         if hub is not None:
@@ -2133,11 +2163,10 @@ def _run_scf_inner(
         e1 = _epot(rho_new, mag_new, pot)
 
         # --- potential + energies ---
-        _pt_t0 = time.perf_counter()
+        _sp = _stage("scf.potential", it=it + 1)
         with profile("scf::potential"):
             pot = generate_potential(ctx, rho_g, xc, mag_g, tau_g=tau_g)
-        _stage_record("scf.potential", time.perf_counter() - _pt_t0,
-                      it=it + 1)
+        _sp.close()
         # fault site: NaN into the generated effective potential
         pot.veff_r_coarse = faults.corrupt(
             "scf.potential", it, pot.veff_r_coarse)
@@ -2176,8 +2205,9 @@ def _run_scf_inner(
         _ITER_SECONDS.observe(_it_dt)
         _RMS.set(rms)
         _ETOT.set(e_total)
-        _stage_record("scf.iteration", _it_dt, t0=_it_t0, it=it + 1,
-                      path="host", **_hbm_attr())
+        # the span runs on to the loop's head: probe, snapshot and
+        # autosave below are the iteration's too
+        _it_span.set(path="host", **_hbm_attr())
         # numpy twin of the fused on-device numerics ledger (obs/numerics.py)
         # — same invariants from the same operands, so the fused values can
         # be validated against this path (tests/test_fused_scf.py)
@@ -2223,7 +2253,7 @@ def _run_scf_inner(
         # the current iterate, every numerics_probe_every iterations
         if (_numerics_probe and pr is not None
                 and (it + 1) % _numerics_every == 0):
-            _pb_t0 = time.perf_counter()
+            _sp = _stage("scf.numerics_probe", it=it + 1)
             _stages = obs_numerics.probe_stages(
                 ctx, xc, np.asarray(pr) + 1j * np.asarray(pi), occ_np,
                 np.asarray(evals), rho_g, mag_g,
@@ -2231,8 +2261,7 @@ def _run_scf_inner(
                 smearing_width=float(p.smearing_width),
             )
             obs_numerics.emit_probe_events(_stages, it=it + 1)
-            _stage_record("scf.numerics_probe",
-                          time.perf_counter() - _pb_t0, it=it + 1)
+            _sp.close()
         if sup.enabled:
             # host path: the snapshot is a cheap host copy — keep the last
             # finite post-mix state every iteration
@@ -2264,7 +2293,10 @@ def _run_scf_inner(
             break
         _straggler_preempt(it)
 
+    _close_iteration()
     obs_trace.finish()
+    # everything between the loop's end and the returned result
+    _fin_span = obs_spans.open_span("scf.finalize")
     # read-only record of the path taken and of where each stage of the last
     # iteration ran and in which dtype, read off the arrays themselves
     placement = {
@@ -2395,15 +2427,14 @@ def _run_scf_inner(
     # end-of-run precision-headroom probe on the final iterate (both
     # paths; the in-loop cadence above only covers the host path)
     if _numerics_probe and num_iter_done > 0 and psi is not None:
-        _pb_t0 = time.perf_counter()
+        _sp = _stage("scf.numerics_probe", it=num_iter_done)
         _stages = obs_numerics.probe_stages(
             ctx, xc, np.asarray(psi), occ_np, np.asarray(evals),
             rho_g, mag_g, mixer_beta=mixer.beta, smearing=p.smearing,
             smearing_width=float(p.smearing_width),
         )
         obs_numerics.emit_probe_events(_stages, it=num_iter_done)
-        _stage_record("scf.numerics_probe",
-                      time.perf_counter() - _pb_t0, it=num_iter_done)
+        _sp.close()
         result["numerics"] = _stages
     _RUNS.inc(outcome="converged" if converged else "unconverged")
     obs_events.emit(
@@ -2493,6 +2524,7 @@ def _run_scf_inner(
             save_to, ctx, rho_g, mag_g, pot.veff_g, pot.bz_g,
             np.asarray(psi), evals, occ_np, paw_dm=paw_dm,
         )
+    _fin_span.close()
     return result
 
 

@@ -54,13 +54,22 @@ CPU_CORE_GBPS = 6.4
 # so a new span is an explicit decision, not silent 0-FLOP noise in the
 # attribution report.
 UNCOSTED_SPANS = (
+    # structure of one job's span tree (obs/spans.py): containers and
+    # host bookkeeping, no stage of their own to count
+    "scf.run",
     "scf.setup",
+    "scf.finalize",
+    "scf.autosave",
     "md.integrate",
     "md.extrapolate",
     "md.scf",
+    "serve.job",
+    "serve.context_build",
     "serve.run",
-    "serve.compile",
     "serve.queue_wait",
+    # a profiler capture and what stopping it cost (obs/trace.py)
+    "trace.capture",
+    "trace.stop",
     "campaign.finalize",
     # model-based compute/collective split of the G-sharded band solve
     # (probe-timed collectives x analytic apply counts, dft/scf.py)

@@ -65,10 +65,11 @@ TIERS = {
     },
 }
 
-# stages the gate watches (scf.setup and serve.* are not per-iteration
-# and scf.readback is pure sync noise without a device)
+# stages the gate watches (scf.setup, scf.finalize, the scf.run root and
+# serve.* are not per-iteration, and scf.readback is pure sync noise
+# without a device)
 GATED_PREFIX = "scf."
-UNGATED = {"scf.setup", "scf.readback"}
+UNGATED = {"scf.setup", "scf.readback", "scf.run", "scf.finalize"}
 
 
 def tier_deck(spec: dict) -> dict:

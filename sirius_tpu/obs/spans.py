@@ -29,10 +29,27 @@ that launched it. run_scf wires this behind ``control.span_fence``
 (default off: production never pays the sync; bench_regress turns it on
 for truthful attribution).
 
+One clock: every record carries ``start_unix_ns`` / ``end_unix_ns``,
+integer Unix nanoseconds (``time.time_ns()``) read at open and at close
+(after the fence), beside the float ``t0`` and the perf_counter
+``dur_s``. obs/trace.py records the same clock just before the profiler
+session starts, so ``start_unix_ns - session_start_unix_ns`` is a span's
+place on the device trace's time axis. While such a capture is active
+(and only then: `set_mirror`) every live span is also entered as a
+``jax.profiler.TraceAnnotation`` of the same name, so the profiler's own
+file shows the program's spans above the device rows.
+
+Two forms. ``with span(name): ...`` where the block is short;
+``sp = open_span(name)`` ... ``sp.close()`` where the work between is
+too long to re-indent (run_scf's loop). Closing a span first closes
+whatever was opened under it and is still open (a ``continue`` path, an
+exception that jumped a ``close()``), marking those ``unwound``: nothing
+stays open and the contextvar is restored.
+
 When telemetry is disabled (``control.telemetry = false`` ->
 obs.metrics.set_enabled(False)) every span is a no-op: ``__enter__``
 returns after one flag test — no contextvar writes, no clock reads, no
-records anywhere.
+records anywhere; ``open_span`` hands back one shared inert object.
 """
 
 from __future__ import annotations
@@ -57,6 +74,19 @@ _next_id = itertools.count(1)
 
 _collectors_lock = threading.Lock()
 _collectors: list["SpanCapture"] = []
+
+# jax.profiler.TraceAnnotation while a profiler capture is active, else
+# None (obs/trace.py sets and clears it; this module never imports jax
+# at import time)
+_mirror = None
+
+
+def set_mirror(annotation) -> None:
+    """Enter every live span as ``annotation(name)`` too (a context
+    manager class: jax.profiler.TraceAnnotation), or stop doing so
+    (None). Spans already open are not touched."""
+    global _mirror
+    _mirror = annotation
 
 
 class SpanCapture:
@@ -120,8 +150,8 @@ class span:
     """
 
     __slots__ = ("name", "attrs", "fence", "flops", "bytes", "span_id",
-                 "parent_id", "depth", "dur_s", "_t0", "_t0_wall",
-                 "_token")
+                 "parent_id", "depth", "dur_s", "_t0", "_t0_ns", "_up",
+                 "_ann", "_token")
 
     def __init__(self, name: str, fence=None, flops: float = 0.0,
                  bytes: float = 0.0, **attrs):
@@ -139,14 +169,41 @@ class span:
         self.span_id = next(_next_id)
         self.parent_id = parent.span_id if parent is not None else None
         self.depth = (parent.depth + 1) if parent is not None else 0
+        self._up = parent
         self._token = _parent.set(self)
-        self._t0_wall = time.time()
+        self._ann = None
+        mirror = _mirror
+        if mirror is not None:
+            try:
+                ann = mirror(self.name)
+                ann.__enter__()
+                self._ann = ann
+            except Exception:
+                pass  # the mirror is a convenience of the capture
+        self._t0_ns = time.time_ns()
         self._t0 = time.perf_counter()
         return self
 
+    @property
+    def start_unix_ns(self) -> int:
+        """When the span was opened (live spans, telemetry on)."""
+        return self._t0_ns
+
+    def set(self, **attrs) -> "span":
+        """More record fields, known only once the work is under way."""
+        self.attrs.update(attrs)
+        return self
+
+    def close(self, **attrs) -> None:
+        """The explicit form's ``__exit__``; ``attrs`` as in `set`."""
+        self.attrs.update(attrs)
+        self.__exit__(None, None, None)
+
     def __exit__(self, exc_type, exc, tb):
         if not hasattr(self, "_token"):
-            return False  # telemetry was off at __enter__: stay a no-op
+            return False  # telemetry was off at __enter__, or closed twice
+        if _parent.get() is not self:
+            _unwind(self)
         if self.fence is not None:
             try:
                 import jax
@@ -156,15 +213,26 @@ class span:
             except Exception:
                 pass  # fencing is best-effort observability, never fatal
         self.dur_s = time.perf_counter() - self._t0
-        _parent.reset(self._token)
+        end_ns = time.time_ns()
+        if self._ann is not None:
+            try:
+                self._ann.__exit__(exc_type, exc, tb)
+            except Exception:
+                pass
+        try:
+            _parent.reset(self._token)
+        except ValueError:
+            pass  # closed from another context: nothing of ours to restore
         del self._token
         rec = {
             "name": self.name,
             "span_id": self.span_id,
             "parent_id": self.parent_id,
             "depth": self.depth,
-            "t0": self._t0_wall,
+            "t0": self._t0_ns * 1e-9,
             "dur_s": self.dur_s,
+            "start_unix_ns": self._t0_ns,
+            "end_unix_ns": end_ns,
             **_tracing.context_fields(),
         }
         if exc_type is not None:
@@ -180,21 +248,80 @@ class span:
         return False
 
 
-def record(name: str, dur_s: float, t0: float | None = None,
-           flops: float = 0.0, bytes: float = 0.0, **attrs) -> None:
-    """Record an externally-timed span (e.g. serve queue wait measured as
-    a timestamp delta, or a setup phase bracketed by plain perf_counter
-    reads). Lineage comes from the current contextvar like a live span."""
+def _unwind(to: span) -> None:
+    """Close, innermost first and marked ``unwound``, every span opened
+    under ``to`` in this context and still open. Does nothing where
+    ``to`` is not among the open spans of this context."""
+    cur = _parent.get()
+    chain = []
+    while cur is not None and cur is not to:
+        chain.append(cur)
+        cur = cur._up
+    if cur is None:
+        return
+    for sp in chain:
+        sp.attrs["unwound"] = True
+        sp.__exit__(None, None, None)
+
+
+class _Off:
+    """What `open_span` returns with telemetry off: nothing happens."""
+
+    __slots__ = ()
+    attrs: dict = {}
+    fence = None
+
+    def set(self, **attrs):
+        return self
+
+    def close(self, **attrs) -> None:
+        return None
+
+    def __setattr__(self, name, value) -> None:
+        return None  # ``sp.fence = out`` on the inert span
+
+
+_OFF = _Off()
+
+
+def open_span(name: str, **span_kw):
+    """The explicit form: a live span, opened now; the caller calls
+    ``close()`` where the work ends. With telemetry off: one flag test."""
+    if not _metrics.enabled():
+        return _OFF
+    return span(name, **span_kw).__enter__()
+
+
+def record(name: str, dur_s: float | None = None, t0: float | None = None,
+           flops: float = 0.0, bytes: float = 0.0,
+           start_unix_ns: int | None = None, end_unix_ns: int | None = None,
+           **attrs) -> None:
+    """Record an interval that was measured from outside (the serve
+    queue wait: a timestamp delta that began before any worker had the
+    job; a profiler capture that starts under one span and stops under
+    another). Give the start as ``start_unix_ns`` or ``t0`` (Unix
+    seconds); without either it is taken as ``dur_s`` before now. Work
+    of this process that can be bracketed is a live `span` instead.
+    Lineage comes from the current contextvar like a live span."""
     if not _metrics.enabled():
         return
+    if start_unix_ns is None:
+        start_unix_ns = (int(float(t0) * 1e9) if t0 is not None
+                         else time.time_ns() - int(float(dur_s) * 1e9))
+    if end_unix_ns is None:
+        end_unix_ns = start_unix_ns + int(float(dur_s) * 1e9)
+    if dur_s is None:
+        dur_s = (end_unix_ns - start_unix_ns) * 1e-9
     parent = _parent.get()
     rec = {
         "name": name,
         "span_id": next(_next_id),
         "parent_id": parent.span_id if parent is not None else None,
         "depth": (parent.depth + 1) if parent is not None else 0,
-        "t0": float(t0) if t0 is not None else time.time() - float(dur_s),
+        "t0": start_unix_ns * 1e-9,
         "dur_s": float(dur_s),
+        "start_unix_ns": int(start_unix_ns),
+        "end_unix_ns": int(end_unix_ns),
         **_tracing.context_fields(),
     }
     if attrs:

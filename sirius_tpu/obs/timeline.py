@@ -22,10 +22,14 @@ reads. This module folds them into the Chrome trace-event format
   (scf_iteration events), the decay-rate/forecast/early-warning series
   (scf_forecast events) and the per-stage precision-headroom probe
   impacts (numerics_probe events) each render as counter series;
-- optionally, the jax.profiler device traces (``*.trace.json.gz``
-  written by obs/trace.py captures) merged in with offset pids — one
-  track per device, stitched under the same timeline (best-effort: the
-  profiler's own format already IS Chrome JSON).
+- optionally, the jax.profiler device traces merged in with offset pids
+  — one track per device line, stitched under the same timeline. An
+  obs/trace.py capture writes the ``.xplane.pb`` only; it is read here
+  directly and put on the spans' clock (Unix time) by the session start
+  the profiler records in it. A ``*.trace.json(.gz)`` beside it (a
+  capture taken with ``jax.profiler.stop_trace``, or exported by
+  xprof/TensorBoard from the ``.xplane.pb``) is merged as it is: the
+  profiler's own format already IS Chrome JSON.
 
 The critical-path analyzer reads the campaign DAG shape from the
 ``campaign_submit`` event (runner.py ships ``edges``), node intervals
@@ -48,7 +52,6 @@ import gzip
 import json
 import os
 import sys
-import time
 
 from sirius_tpu.obs import events as _events
 from sirius_tpu.obs import spans as _spans
@@ -244,15 +247,53 @@ def _node_intervals(records: list[dict], cid: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# jax.profiler merge (best-effort: the profiler writes Chrome JSON itself)
+# jax.profiler merge (best-effort)
+
+
+def _merge_xplane(doc: dict, path: str, pid_base: int) -> int:
+    """One ``.xplane.pb`` as "X" events: a process per plane, a thread
+    per line. Event times are nanoseconds since the session's start; the
+    "Task Environment" plane's ``profile_start_time`` (Unix ns) puts
+    them on the spans' clock."""
+    import jax
+
+    pd = jax.profiler.ProfileData.from_file(path)
+    env = pd.find_plane_with_name("Task Environment")
+    start_ns = int(dict(env.stats).get("profile_start_time", 0)) if env else 0
+    ev = doc.setdefault("traceEvents", [])
+    merged = 0
+    for i, plane in enumerate(pd.planes):
+        pid = pid_base + i
+        named = False
+        for tid, line in enumerate(plane.lines, start=1):
+            first = True
+            for e in line.events:
+                if first:
+                    first = False
+                    if not named:
+                        named = True
+                        ev.append({"name": "process_name", "ph": "M",
+                                   "pid": pid, "tid": 0,
+                                   "args": {"name": plane.name}})
+                    ev.append({"name": "thread_name", "ph": "M", "pid": pid,
+                               "tid": tid, "args": {"name": line.name}})
+                ev.append({
+                    "name": e.name, "ph": "X", "cat": "xplane",
+                    "ts": (start_ns + e.start_ns) / 1000.0,
+                    "dur": max(e.duration_ns / 1000.0, 0.001),
+                    "pid": pid, "tid": tid,
+                })
+                merged += 1
+    return merged
 
 
 def merge_jax_profiler_trace(doc: dict, trace_dir: str,
                              pid_offset: int = 100000) -> int:
-    """Merge ``*.trace.json[.gz]`` files under ``trace_dir`` (written by
-    jax.profiler / obs.trace captures) into ``doc`` with offset pids so
-    device tracks sit next to the host tracks. Returns the number of
-    events merged; silently returns 0 when nothing usable is found."""
+    """Merge the profiler traces under ``trace_dir`` into ``doc`` with
+    offset pids so device tracks sit next to the host tracks:
+    ``*.trace.json[.gz]`` files where there are any, else the
+    ``*.xplane.pb`` that obs/trace.py captures write. Returns the number
+    of events merged; silently returns 0 when nothing usable is found."""
     merged = 0
     pats = ("**/*.trace.json.gz", "**/*.trace.json")
     files = []
@@ -273,6 +314,14 @@ def merge_jax_profiler_trace(doc: dict, trace_dir: str,
             e["pid"] = int(e.get("pid") or 0) + pid_offset + i * 1000
             doc.setdefault("traceEvents", []).append(e)
             merged += 1
+    if not files:
+        planes = sorted(glob.glob(os.path.join(trace_dir, "**/*.xplane.pb"),
+                                  recursive=True))
+        for i, f in enumerate(planes):
+            try:
+                merged += _merge_xplane(doc, f, pid_offset + i * 1000)
+            except Exception:
+                continue
     return merged
 
 
@@ -472,20 +521,18 @@ def export_timeline(events_path: str, out_path: str | None = None,
                     jax_trace_dir: str | None = None) -> dict:
     """events JSONL -> Chrome trace document (written to out_path when
     given). The export itself is a ``trace.export`` span."""
-    t0 = time.perf_counter()
-    records = _events.read_events(events_path)
-    doc = build_chrome_trace(records, trace_id=trace_id,
-                             campaign_id=campaign_id)
-    merged = 0
-    if jax_trace_dir:
-        merged = merge_jax_profiler_trace(doc, jax_trace_dir)
-    if out_path:
-        with open(out_path, "w", encoding="utf-8") as fh:
-            json.dump(doc, fh)
-    _spans.record("trace.export", time.perf_counter() - t0,
-                  events=len(records),
-                  trace_events=len(doc["traceEvents"]),
-                  device_events=merged)
+    with _spans.span("trace.export") as sp:
+        records = _events.read_events(events_path)
+        doc = build_chrome_trace(records, trace_id=trace_id,
+                                 campaign_id=campaign_id)
+        merged = 0
+        if jax_trace_dir:
+            merged = merge_jax_profiler_trace(doc, jax_trace_dir)
+        if out_path:
+            with open(out_path, "w", encoding="utf-8") as fh:
+                json.dump(doc, fh)
+        sp.set(events=len(records), trace_events=len(doc["traceEvents"]),
+               device_events=merged)
     return doc
 
 
@@ -504,7 +551,9 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--campaign", default=None,
                    help="campaign id for the synthetic node tracks")
     p.add_argument("--jax-trace-dir", default=None,
-                   help="merge jax.profiler *.trace.json(.gz) from here")
+                   help="merge the jax.profiler capture under this "
+                        "directory: its *.trace.json(.gz) if it has any, "
+                        "else its *.xplane.pb")
 
     p = sub.add_parser("validate",
                        help="check a file against the trace-event format")

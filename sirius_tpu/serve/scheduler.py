@@ -108,7 +108,15 @@ def build_job_context(cfg, base_dir: str = "."):
     form used by tests and tools/loadgen.py. Everything else (cutoffs,
     k-mesh, control knobs incl. ngk_pad_quantum) comes from the normal
     config sections.
+
+    Spanned here (``serve.context_build``), not at the call sites: the
+    scheduler, the MD driver and a plain ``run_scf`` client all get it.
     """
+    with obs_spans.span("serve.context_build"):
+        return _build_job_context(cfg, base_dir)
+
+
+def _build_job_context(cfg, base_dir: str):
     from sirius_tpu.context import SimulationContext
 
     syn = cfg.extra.get("synthetic") if isinstance(cfg.extra, dict) else None
@@ -247,7 +255,14 @@ class SliceScheduler:
             if faults.armed("serve.job_hang", job.attempts - 1):
                 self._hang(job, slice_idx, epoch)
                 return
-            self._run_job_inner(job, slice_idx, devs, epoch)
+            # the job as this worker has it: pop to terminal transition
+            # (or to the retry's requeue); the context build, the run
+            # and the queue wait that ended at its start hang under it
+            with obs_spans.span("serve.job", slice=slice_idx,
+                                attempt=job.attempts) as job_span:
+                self._run_job_inner(job, slice_idx, devs, epoch)
+                job_span.set(status=str(getattr(job.status, "value",
+                                                job.status)))
 
     def _hang(self, job: Job, slice_idx: int, epoch: int) -> None:
         """Simulate a wedged worker (serve.job_hang): park until the
@@ -362,13 +377,16 @@ class SliceScheduler:
             )
             if job.started_at is None:
                 job.started_at = job.events[-1][0]
-            if job.submitted_at is not None:
-                # externally-timed span: submit -> this worker popping it
+            job_span = obs_spans.current()  # serve.job; None: telemetry off
+            if job.submitted_at is not None and job_span is not None:
+                # measured from outside: submit -> this worker popping it
+                # (where serve.job starts), a wait that began before any
+                # worker had the job
+                t_sub = int(job.submitted_at * 1e9)
                 obs_spans.record(
-                    "serve.queue_wait",
-                    max(0.0, _time.time() - job.submitted_at),
-                    t0=job.submitted_at, slice=slice_idx,
-                    bucket="warm" if warm else "cold")
+                    "serve.queue_wait", start_unix_ns=t_sub,
+                    end_unix_ns=max(t_sub, job_span.start_unix_ns),
+                    slice=slice_idx, bucket="warm" if warm else "cold")
             guess = None
             handoff_mode = None
             if job.handoff_in:
@@ -379,7 +397,7 @@ class SliceScheduler:
             t_run0 = _time.time()
             final_positions = None
             with obs_spans.span("serve.run", slice=slice_idx,
-                                bucket="warm" if warm else "cold"):
+                                bucket="warm" if warm else "cold") as run_span:
                 with jax.default_device(devs[0]):
                     if task == "relax":
                         from sirius_tpu.dft.relax import relax_atoms
@@ -417,17 +435,19 @@ class SliceScheduler:
                             resume=job.resume_path,
                             initial_guess=guess, keep_state=keep_state,
                         )
+                compiled = (cache_mod.backend_compiles_this_thread()
+                            - compiles0)
+                # compile time attributed via the jax.monitoring listener's
+                # per-thread accumulator: run_scf happened on THIS thread,
+                # so the delta is exactly this job's XLA backend-compile
+                # seconds. Fields of serve.run, not a span: compilation is
+                # spread over the run and has no start of its own
+                csec = (obs_metrics.backend_compile_seconds_this_thread()
+                        - csec0)
+                run_span.set(compile_s=csec, compiled_executables=compiled)
             _RUN_SECONDS.observe(_time.time() - t_run0,
                                  bucket="warm" if warm else "cold",
                                  slice=slice_idx)
-            compiled = cache_mod.backend_compiles_this_thread() - compiles0
-            # compile time attributed via the jax.monitoring listener's
-            # per-thread accumulator: run_scf happened on THIS thread, so
-            # the delta is exactly this job's XLA backend-compile seconds
-            csec = obs_metrics.backend_compile_seconds_this_thread() - csec0
-            if compiled or csec:
-                obs_spans.record("serve.compile", csec, slice=slice_idx,
-                                 compiled_executables=compiled)
             counters["serve.backend_compiles"] += compiled
             state = result.pop("_state", None)
             result["serve"] = {

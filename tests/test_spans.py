@@ -192,7 +192,8 @@ def test_scf_spans_attribution_and_exactly_once_jsonl(tmp_path):
     assert len(iters) == 2
     per_iter = [n for n in cap.names()
                 if n.startswith("scf.")
-                and n not in ("scf.iteration", "scf.setup", "scf.readback")]
+                and n not in ("scf.iteration", "scf.setup", "scf.readback",
+                              "scf.run", "scf.finalize")]
     assert len(per_iter) >= 5
     attributed = sum(sum(cap.durations(n)) for n in per_iter)
     assert attributed / sum(iters) >= 0.90
@@ -219,4 +220,6 @@ def test_scf_spans_off_with_telemetry_disabled(tmp_path):
         res = _run(tmp_path, _span_deck("events.jsonl", telemetry=False))
     obs.close_events()
     assert res["num_scf_iterations"] == 2
-    assert cap.records == []
+    # the deck's control.telemetry takes effect at run_scf entry: the
+    # context the caller built before it is the one thing spanned
+    assert [r["name"] for r in cap.records] == ["serve.context_build"]

@@ -85,7 +85,9 @@ def test_events_inherit_trace_unless_explicit(tmp_path):
 
 
 def test_metric_exemplars_link_to_trace():
-    obs_metrics.REGISTRY.reset()
+    # families of this test's own; REGISTRY.reset() here would also drop
+    # the ones other modules registered at import and still write to
+    # (test_obs.py then misses serve_queue_depth on the same worker)
     c = obs_metrics.REGISTRY.counter("tr_demo_total", "exemplar demo")
     h = obs_metrics.REGISTRY.histogram("tr_demo_seconds", "exemplar demo")
     c.inc(outcome="cold")  # before any trace: no exemplar
@@ -106,7 +108,6 @@ def test_metric_exemplars_link_to_trace():
 
 
 def test_cardinality_guard_clips_to_overflow_child():
-    obs_metrics.REGISTRY.reset()
     prev = obs_metrics.set_max_labelsets(4)
     try:
         c = obs_metrics.REGISTRY.counter("tr_cardinality_total", "guard")
@@ -123,7 +124,6 @@ def test_cardinality_guard_clips_to_overflow_child():
         assert obs_metrics.cardinality_clips()["tr_cardinality_total"] >= 46
     finally:
         obs_metrics.set_max_labelsets(prev)
-        obs_metrics.REGISTRY.reset()
 
 
 def test_audited_registries_stay_bounded_by_default():
