@@ -8,15 +8,15 @@ config 1 = the reference's verification/test08: Si-2 ultrasoft, gk_cutoff
 Si, made in memory — there are no species files off this machine), in the
 32-bit types the chip runs:
 
-  1. run_scf on the Gamma deck: the packed-real `gamma_bands` solve on the
-     chip, the f64 potential/density/mixing tail on the host CPU backend;
+  1. run_scf on the Gamma deck: the packed-real `gamma_bands` solve and
+     the fused device-resident tail it feeds (FusedScf), both on the chip;
   2. run_scf on the same cell with a (2,2,2) k-mesh, no symmetry: the
-     batched k-set solve + the fused device-resident step (FusedScf);
+     batched k-set solve + the same fused step;
   3. three jobs of one shape bucket through sirius-serve's own main().
 
 Every phase's total energy must agree to 1e-5 Ha with the f64 energy of the
 same deck computed in this process on the CPU backend, and its `placement`
-record must show the band solve (and the fused step) on the TPU in 32-bit
+record must show the band solve and the fused step on the TPU in 32-bit
 types. One JSON object per phase goes to stdout; the last line is the
 verdict object. Any phase failure raises: there is no path to exit code 0
 that skips a check.
@@ -143,8 +143,8 @@ def check_placement(pl, platform, path):
     if pl["path"] != path:
         raise RuntimeError(f"path taken was {pl['path']!r}, expected {path!r}")
     stages = ["band_solve"] + (
-        ["fused_step", "density", "mixing", "potential"]
-        if path == "batched+fused" else [])
+        ["occupations", "fused_step", "density", "mixing", "potential"]
+        if path in ("batched+fused", "gamma") else [])
     for s in stages:  # entries are [platform, dtype, device ids]
         if pl[s][0] != platform or pl[s][1] not in ("float32", "complex64"):
             raise RuntimeError(

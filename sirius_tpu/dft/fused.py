@@ -25,9 +25,12 @@ embed them.
 
 Selection: run_scf uses this path when control.device_scf is "auto"/true
 and the deck is in the supported regime (PP-PW, no Hubbard/PAW/mGGA, plain
-or Anderson mixing, batched k-set band solve). control.device_scf = false
-keeps the host path — bit-identical to the pre-fusion code — as the debug
-fallback; tests/test_fused_scf.py pins the two paths to ~1e-8 Ha agreement.
+or Anderson mixing) behind either production band solve: the batched k-set
+solve, or on one device at Gamma the packed-real solve (ops/gamma.py),
+whose block reaches step() as the (re, im) pair of unpack_device.
+control.device_scf = false keeps the host path — bit-identical to the
+pre-fusion code — as the f64 reference and debug fallback;
+tests/test_fused_scf.py pins the two paths to ~1e-8 Ha agreement.
 """
 
 from __future__ import annotations
@@ -297,8 +300,8 @@ class FusedScf:
         from density_matrix_kset (empty for norm-conserving); ev: [nk, ns,
         nb] eigenvalues; occ_w = occ * kweights; ent: entropy sum;
         (pr, pi): [nk, ns, nb, ngk] band block (already live on device for
-        density_kset — feeding it here adds no transfer) for the numerics
-        ledger. All device arrays. Returns (new_carry, out_dict)."""
+        the density matrix — feeding it here adds no transfer) for the
+        numerics ledger. All device arrays. Returns (new_carry, out_dict)."""
         return self._step(self.tables, carry, acc, dm_re, dm_im, ev,
                           occ_w, ent, pr, pi)
 

@@ -898,13 +898,17 @@ def _run_scf_inner(
     # ---- fused device-resident iteration (dft/fused.py): density ->
     # mixer -> potential -> D/H-diag refresh as ONE compiled program with a
     # donated carry; per-iteration host traffic is a [NUM_SCALARS] vector.
-    # control.device_scf = false keeps the host path below as the debug
-    # fallback (tests/test_fused_scf.py pins the two to ~1e-8 Ha). ----
+    # One tail for both production band solves: the batched k-set solve
+    # and the packed-real Gamma solve hand it the same five arrays (acc,
+    # the density-matrix pair, ev, occ_w, the (re, im) band block).
+    # control.device_scf = false keeps the host path below as the f64
+    # reference/debug path (tests/test_fused_scf.py pins the two to ~1e-8
+    # Ha; benchmark/make_refs.py computes its references with it). ----
     fused = None
     fused_carry = fused_out = fused_np = None
     if (
         cfg.control.device_scf not in (False, "false", "off")
-        and not serial_bands and gsh is None and not gamma_bands
+        and not serial_bands and gsh is None
         and bchunk is None and hub is None and paw is None and not mgga
         and mixer.kind in ("linear", "anderson")
         and not _cks.enabled()
@@ -1455,14 +1459,12 @@ def _run_scf_inner(
                 evals[0, 0] = np.asarray(ev)
                 psi = np.asarray(x).astype(np.complex128)[None, None]
             elif gamma_bands:
-                from sirius_tpu.ops.gamma import (
-                    davidson_gamma,
-                    make_gamma_params,
-                    pack_diags,
-                )
-                from sirius_tpu.ops.gamma import pack as gpack
-                from sirius_tpu.ops.gamma import unpack as gunpack
+                from sirius_tpu.ops import gamma as gmod
                 from sirius_tpu.ops.hamiltonian import real_dtype_of
+                from sirius_tpu.parallel.batched import (
+                    compute_h_diag,
+                    compute_o_diag,
+                )
 
                 rdt = real_dtype_of(wf_dtype)
                 if x_packed[0] is not None and x_packed[0].dtype != np.dtype(rdt):
@@ -1471,48 +1473,75 @@ def _run_scf_inner(
                 if psi is not None and x_packed[0] is None:
                     # restart / warm start from full complex psi
                     x_packed = [
-                        _up(gpack(gm, np.asarray(psi[0, ispn])), rdt)
+                        _up(gmod.pack(gm, np.asarray(psi[0, ispn])), rdt)
                         for ispn in range(ns)
                     ]
-                psi_out = np.zeros(
-                    (1, ns, nb, ctx.gkvec.ngk_max), dtype=np.complex128
-                )
                 if rdt not in gamma_cache:
-                    # constant tables (packed beta, gather maps) uploaded
-                    # once per precision; per-iteration leaves swapped below
-                    gamma_cache[rdt] = jax.tree_util.tree_map(
-                        _up, make_gamma_params(
+                    # constant tables (packed beta, gather maps, the packed
+                    # S diagonal and the gather of pack_diags_device)
+                    # uploaded once per precision; per-iteration leaves are
+                    # swapped in below
+                    gamma_cache.clear()
+                    gamma_cache[rdt] = jax.tree_util.tree_map(_up, (
+                        gmod.make_gamma_params(
                             ctx, np.zeros(ctx.fft_coarse.dims), gm,
-                            rdtype=rdt))
+                            rdtype=rdt),
+                        gmod.pack_index(gm, ctx.gkvec.ngk_max),
+                        np.asarray(compute_o_diag(ctx)[0], dtype=rdt)))
+                gp0, pidx, o_diag_dev = gamma_cache[rdt]
+                # once the fused step has run, the potential, the screened
+                # D and the H diagonal of the next solve are its outputs,
+                # already on the device; the host potential feeds the first
+                # iteration and the one after a rollback
+                dev_inputs = None
+                if fused is not None and fused_out is not None:
+                    dev_inputs = gmod.solve_inputs_device(
+                        pidx, gp0.mask_p, o_diag_dev,
+                        fused_out["veff_r_coarse"], fused_out["dion"],
+                        fused_out["h_diag"])
+                ev_spin = []
                 for ispn in range(ns):
-                    gp = gamma_cache[rdt]._replace(
-                        veff_r=_up(pot.veff_r_coarse[ispn], rdt),
-                        dion=_up(np.real(d_by_spin[ispn]), rdt),
-                    )
+                    if dev_inputs is not None:
+                        veff_s, dion_s, hd_p, od_p = dev_inputs[ispn]
+                    else:
+                        veff_s = _up(pot.veff_r_coarse[ispn], rdt)
+                        dion_s = _up(np.real(d_by_spin[ispn]), rdt)
+                        h_diag = compute_h_diag(
+                            ctx, np.asarray(d_by_spin[ispn])[None], v0)[0, 0]
+                        hd_p, od_p = gmod.pack_diags_device(
+                            pidx, gp0.mask_p, _up(h_diag, rdt), o_diag_dev)
+                    gp = gp0._replace(veff_r=veff_s, dion=dion_s)
                     if x_packed[ispn] is None:
                         # first iteration: rotate the packed LCAO block to
                         # the lowest nb Ritz vectors (initialize_subspace)
-                        from sirius_tpu.ops.gamma import (
-                            initialize_subspace_gamma,
-                        )
-
-                        x_packed[ispn] = initialize_subspace_gamma(
-                            gp, _up(gpack(gm, psi_big[0, ispn]), rdt), nb)
+                        x_packed[ispn] = gmod.initialize_subspace_gamma(
+                            gp, _up(gmod.pack(gm, psi_big[0, ispn]), rdt), nb)
                         counters["num_loc_op_applied"] += psi_big.shape[2]
-                    h_diag, o_diag = _h_o_diag(ctx, 0, v0, d_by_spin[ispn])
-                    hd_p, od_p = pack_diags(
-                        gm, np.asarray(h_diag), np.asarray(o_diag)
-                    )
-                    ev, xg, rn = davidson_gamma(
-                        gp, x_packed[ispn], _up(hd_p, rdt), _up(od_p, rdt),
+                    ev, x_packed[ispn], rn = gmod.davidson_gamma(
+                        gp, x_packed[ispn], hd_p, od_p,
                         num_steps=itsol.num_steps,
-                        res_tol=_rtol(rdt),
+                        res_tol=_up(_rtol(rdt)),
                     )
-                    evals[0, ispn] = np.asarray(ev)
-                    x_packed[ispn] = xg
-                    psi_out[0, ispn] = gunpack(gm, np.asarray(xg))
-                psi = psi_out
+                    ev_spin.append(ev)
                 psi_big = None
+                if fused is not None:
+                    # the packed block and the eigenvalues stay on the
+                    # device; the tail below takes the band block as the
+                    # (re, im) pair of its sphere coefficients, and the
+                    # host complex psi is joined from that pair once, after
+                    # the loop (or by an autosave when one is due)
+                    pr, pi = (a[None] for a in gmod.unpack_device(
+                        gp0, jnp.stack(x_packed)))
+                    ev_dev = jnp.stack(ev_spin)[None].astype(fused.rdt)
+                    psi = None
+                else:
+                    psi = np.zeros(
+                        (1, ns, nb, ctx.gkvec.ngk_max), dtype=np.complex128
+                    )
+                    for ispn in range(ns):
+                        evals[0, ispn] = np.asarray(ev_spin[ispn])
+                        psi[0, ispn] = gmod.unpack(
+                            gm, np.asarray(x_packed[ispn]))
             elif serial_bands:
                 if psi is None and psi_big is not None:
                     # first iteration from a fresh LCAO block: rotate the
@@ -1677,7 +1706,7 @@ def _run_scf_inner(
                 itsol.num_steps, nb
             )
         if _span_fence:
-            # the host paths already fenced via np.asarray(ev); only the
+            # the host tails already fenced via np.asarray(ev); only a
             # device-resident (fused) solve still has compute in flight
             if fused is not None:
                 _fence((ev_dev, pr, pi))
@@ -1821,12 +1850,19 @@ def _run_scf_inner(
                     _fence(occ_w)
                 _sp.close()
                 _sp = _stage("scf.density", it=it + 1)
+                from sirius_tpu.ops.gamma import density_gamma
                 from sirius_tpu.parallel.batched import (
                     density_kset,
                     density_matrix_kset,
                 )
 
-                acc = density_kset(ps, pr, pi, occ_w)
+                if gamma_bands:
+                    # real field per band: |Re psi(r)|^2 off the packed block
+                    acc = density_gamma(
+                        gamma_cache[rdt][0], jnp.stack(x_packed),
+                        occ_w.reshape(ns, nb))
+                else:
+                    acc = density_kset(ps, pr, pi, occ_w)
                 # fault site: NaN into the accumulated density (functional
                 # device-side update; a no-op dict lookup when unarmed, so
                 # the transfer-guard contract of this span is preserved)
@@ -2311,7 +2347,7 @@ def _run_scf_inner(
     }
     if fused is not None and fused_out is not None:
         placement.update(
-            band_solve=runtime.where(pr),
+            band_solve=runtime.where(x_packed[0] if gamma_bands else pr),
             occupations=runtime.where(occ_w),
             density=runtime.where(acc),
             fused_step=runtime.where(fused_out["scalars"]),

@@ -24,7 +24,11 @@ isometry, making <beta|psi> and the D/Q expansions real GEMMs too.
 
 Eligibility (wired in dft/scf.run_scf): Gamma-only k-set, no Hubbard
 (complex per-k U apply), no mGGA, no G-sharding. Collinear spins are fine
-(per-spin solve).
+(per-spin solve). The solve hands the fused device-resident tail
+(dft/fused.py) what the batched solve does, without leaving the device:
+solve_inputs_device cuts the next solve's potential, D and packed
+preconditioner diagonal from the fused step's outputs, unpack_device gives
+the band block as a (re, im) pair, density_gamma the coarse-box density.
 """
 
 from __future__ import annotations
@@ -209,6 +213,59 @@ def pack_diags(gm: GammaMap, h_diag: np.ndarray, o_diag: np.ndarray):
     return hp, op
 
 
+def pack_index(gm: GammaMap, ngk: int) -> np.ndarray:
+    """Sphere index of each packed slot's G, [zero | rep | rep | 0...]
+    ([ngk], built once per context): the gather form of pack_diags for
+    pack_diags_device. The slots past 1 + 2P are the ones mask_p zeroes."""
+    P = len(gm.rep)
+    idx = np.zeros(ngk, dtype=np.int32)
+    idx[0] = gm.zero
+    idx[1 : 1 + P] = gm.rep
+    idx[1 + P : 1 + 2 * P] = gm.rep
+    return idx
+
+
+@jax.jit
+def pack_diags_device(idx, mask_p, h_diag, o_diag):
+    """Device twin of pack_diags: sphere-order diagonals [..., ngk] already
+    on the device (the fused step's h_diag) -> packed order, 1e4 / 1.0 in
+    the slots mask_p (GammaParams) marks unused. Two gathers, no host
+    round trip."""
+    hp = jnp.where(mask_p > 0, jnp.take(h_diag, idx, axis=-1), 1e4)
+    op = jnp.where(mask_p > 0, jnp.take(o_diag, idx, axis=-1), 1.0)
+    return hp, op
+
+
+@jax.jit
+def solve_inputs_device(idx, mask_p, o_diag, veff_r, dion, h_diag):
+    """The potential-dependent leaves of the next packed solves, cut from
+    the fused step's device outputs (veff_r [ns, n1,n2,n3], dion [ns,
+    nbeta, nbeta], h_diag [1, ns, ngk] in sphere order) in o_diag's
+    precision: per spin (veff_r, dion, packed h_diag, packed o_diag). One
+    program for all spins, so no index scalar travels from the host as an
+    eager `a[ispn]` would send."""
+    rdt = o_diag.dtype
+    hp, op = pack_diags_device(idx, mask_p, h_diag[0].astype(rdt), o_diag)
+    return [(veff_r[s].astype(rdt), dion[s].astype(rdt), hp[s], op)
+            for s in range(veff_r.shape[0])]
+
+
+def _unpack_pair(params: GammaParams, x: jax.Array):
+    """(re, im) of the complex sphere coefficients of packed x [..., ngk]:
+    pure gathers (no matmul), in x's precision."""
+    xr = jnp.take(x, params.slot_re, axis=-1)
+    xi = jnp.take(x, params.slot_im, axis=-1)
+    return params.scale * xr, params.scale * params.im_sign * xi
+
+
+@jax.jit
+def unpack_device(params: GammaParams, x: jax.Array):
+    """Device twin of unpack for consumers that take the band block as a
+    real (re, im) pair (FusedScf.step, density_matrix_kset): packed real
+    [..., ngk] -> (re, im), each [..., ngk] in sphere order."""
+    return _unpack_pair(params, x)
+
+
 def apply_h_s_gamma(params: GammaParams, x: jax.Array):
     """(H x, S x) for a packed-real band block x [nb, ngk]."""
     dims = params.veff_r.shape
@@ -219,9 +276,7 @@ def apply_h_s_gamma(params: GammaParams, x: jax.Array):
     # unpack to the complex sphere with gathers; lax.complex keeps the
     # working precision (a bare `1j *` would promote f32 -> c128, which a
     # TPU does not run)
-    xr = jnp.take(x, params.slot_re, axis=-1)
-    xi = jnp.take(x, params.slot_im, axis=-1)
-    c = jax.lax.complex(params.scale * xr, params.scale * params.im_sign * xi)
+    c = jax.lax.complex(*_unpack_pair(params, x))
     assert c.dtype == cdtype, (c.dtype, cdtype)
     box = jnp.zeros(batch + (n,), dtype=cdtype).at[..., params.fft_index].add(c)
     fr = jnp.fft.ifftn(box.reshape(batch + dims), axes=(-3, -2, -1))
@@ -295,16 +350,14 @@ def davidson_gamma(params: GammaParams, x0, h_diag_p, o_diag_p,
 
 @jax.jit
 def density_gamma(params: GammaParams, x: jax.Array, occ_w: jax.Array):
-    """Coarse-box density sum_b occ_w[b] |psi_b(r)|^2 from a packed-real
-    band block x [nb, ngk] (Gamma-only k-set; occ_w includes the k-weight
-    and max_occupancy). Returns [n1, n2, n3] real."""
+    """Coarse-box density sum_b occ_w[..., b] |psi_b(r)|^2 from a
+    packed-real band block x [..., nb, ngk] (Gamma-only k-set; occ_w
+    [..., nb] includes the k-weight and max_occupancy; a leading spin axis
+    rides along). Returns [..., n1, n2, n3] real."""
     dims = params.veff_r.shape
     n = dims[0] * dims[1] * dims[2]
-    x = x * params.mask_p
-    xr = jnp.take(x, params.slot_re, axis=-1)
-    xi = jnp.take(x, params.slot_im, axis=-1)
-    c = jax.lax.complex(params.scale * xr, params.scale * params.im_sign * xi)
+    c = jax.lax.complex(*_unpack_pair(params, x * params.mask_p))
     box = jnp.zeros(x.shape[:-1] + (n,), dtype=c.dtype).at[..., params.fft_index].add(c)
     fr = jnp.fft.ifftn(box.reshape(x.shape[:-1] + dims), axes=(-3, -2, -1)) * n
     # Hermitian coefficients -> real field; |Re|^2 drops only rounding noise
-    return jnp.einsum("b,bxyz->xyz", occ_w, jnp.real(fr) ** 2)
+    return jnp.einsum("...b,...bxyz->...xyz", occ_w, jnp.real(fr) ** 2)

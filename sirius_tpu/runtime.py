@@ -4,9 +4,10 @@ cache, the host/device placement rule and the device dtype policy.
 Placement rule (stated, not a fallback — it does not depend on whether an
 accelerator was found):
 
-  * the band solve on every path, and the fused step on the batched path,
-    run on the run's compute devices (``devices=`` of run_scf, default
-    ``jax.devices()``), placed there explicitly (device_put / NamedSharding);
+  * the band solve on every path, and the fused step behind the batched
+    and the packed-real Gamma solve, run on the run's compute devices
+    (``devices=`` of run_scf, default ``jax.devices()``), placed there
+    explicitly (device_put / NamedSharding);
   * every other stage that goes through ``jnp`` — set-up tables and the
     f64 potential/density/mixing tail of the host paths — runs on
     ``jax.devices("cpu")[0]``: run_scf and SimulationContext.create enter

@@ -138,18 +138,40 @@ def test_large_subspace_on_a_tpu_mesh_is_refused():
     runtime.refuse_large_subspace_on_tpu_mesh(jax.devices(), 500)
 
 
+FUSED_STAGES = ("band_solve", "occupations", "density", "fused_step",
+                "mixing", "potential")
+
+
 def test_placement_record_is_truthful_on_cpu():
-    """Compute device cpu:1, host device cpu:0: the band solve (and on the
-    batched path the fused tail) must sit on cpu:1 in the working precision,
-    the Gamma path's f64 tail on the host."""
+    """Compute device cpu:1, host device cpu:0: the band solve and the fused
+    tail, of the Gamma path and of the batched path, must sit on cpu:1 in
+    the working precision; with control.device_scf off the Gamma path's
+    f64 tail is on the host."""
     from sirius_tpu.dft.scf import run_scf
+    from sirius_tpu.obs import spans
 
     dev = jax.devices()[1:2]
     tol = {"density_tol": 1e-5, "energy_tol": 1e-5, "precision_wf": "fp32"}
 
     ctx = _ctx((1, 1, 1), **tol)
-    pl = run_scf(ctx.cfg, ctx=ctx, devices=dev)["placement"]
+    with spans.capture() as cap:
+        res = run_scf(ctx.cfg, ctx=ctx, devices=dev)
+    pl = res["placement"]
     assert pl["path"] == "gamma" and pl["mesh"] is None
+    for stage in FUSED_STAGES:
+        assert pl[stage] == ["cpu", "float32", [1]], (stage, pl[stage])
+    # the record is the engagement counter: every iteration's tail was the
+    # fused step, none of it a host stage
+    names = [r["name"] for r in cap.records]
+    assert names.count("scf.fused_step") == res["num_scf_iterations"]
+    assert names.count("scf.readback") == res["num_scf_iterations"]
+    assert names.count("scf.d_matrix") == 1  # the first iteration's host D
+    assert not {"scf.potential", "scf.mixing"} & set(names)
+
+    ctx = _ctx((1, 1, 1), **tol)
+    ctx.cfg.control.device_scf = False
+    pl = run_scf(ctx.cfg, ctx=ctx, devices=dev)["placement"]
+    assert pl["path"] == "gamma" and "fused_step" not in pl
     assert pl["band_solve"] == ["cpu", "float32", [1]]
     assert pl["density"][0] == "host" and pl["mixing"][0] == "host"
     assert pl["potential"][1] == "float64"
@@ -158,8 +180,7 @@ def test_placement_record_is_truthful_on_cpu():
     ctx = _ctx((2, 2, 2), **tol)
     pl = run_scf(ctx.cfg, ctx=ctx, devices=dev)["placement"]
     assert pl["path"] == "batched+fused"
-    for stage in ("band_solve", "occupations", "density", "fused_step",
-                  "mixing", "potential"):
+    for stage in FUSED_STAGES:
         assert pl[stage] == ["cpu", "float32", [1]], (stage, pl[stage])
     assert pl["psi_shard_devices"] == [1]
 

@@ -21,12 +21,43 @@ from sirius_tpu.dft.mixer import (
 from sirius_tpu.testing import synthetic_silicon_context
 
 
-def _run(device_scf, **deck):
+def _run(device_scf, devices=None, plan=None, control=None, run_kw=None,
+         **deck):
     from sirius_tpu.dft.scf import run_scf
+    from sirius_tpu.utils import faults
 
     ctx = synthetic_silicon_context(**deck)
     ctx.cfg.control.device_scf = device_scf
-    return run_scf(ctx.cfg, ctx=ctx)
+    for k, v in (control or {}).items():
+        setattr(ctx.cfg.control, k, v)
+    faults.install(plan or [])
+    return run_scf(ctx.cfg, ctx=ctx, devices=devices, **(run_kw or {}))
+
+
+# one compute device: a Gamma-only deck then takes the packed-real band
+# solve (`gamma_bands`), which feeds the same fused tail
+def _one_device():
+    return jax.devices()[1:2]
+
+
+GAMMA_DECK = dict(
+    gk_cutoff=3.0, pw_cutoff=7.0, ngridk=(1, 1, 1), num_bands=8,
+    ultrasoft=True, use_symmetry=False,
+    extra_params={"num_dft_iter": 40, "density_tol": 5e-9,
+                  "energy_tol": 1e-10},
+)
+GAMMA_POLARIZED = dict(
+    GAMMA_DECK, moments=[[0, 0, 0.5], [0, 0, 0.5]],
+    extra_params=dict(GAMMA_DECK["extra_params"], num_mag_dims=1),
+)
+
+
+@pytest.fixture(scope="module")
+def gamma_ref():
+    """The unperturbed f64 run of GAMMA_DECK through the fused tail."""
+    r = _run("auto", devices=_one_device(), **GAMMA_DECK)
+    assert r["converged"] and "fused_step" in r["placement"]
+    return r
 
 
 def test_fused_matches_host_ultrasoft():
@@ -62,15 +93,103 @@ def test_fused_matches_host_polarized_symmetry():
     assert abs(r_host["mag_history"][-1] - r_dev["mag_history"][-1]) < 1e-6
 
 
-def test_fused_no_host_transfers():
+@pytest.mark.parametrize("deck", ["ultrasoft", "polarized"])
+def test_fused_gamma_matches_host(deck):
+    """The packed-real Gamma solve feeding the fused tail against the host
+    f64 tail (control.device_scf = false), in f64 on one device."""
+    deck = {"ultrasoft": GAMMA_DECK, "polarized": GAMMA_POLARIZED}[deck]
+    r_host = _run("off", devices=_one_device(), **deck)
+    r_dev = _run("auto", devices=_one_device(), **deck)
+    assert r_host["placement"]["path"] == r_dev["placement"]["path"] == "gamma"
+    assert "fused_step" in r_dev["placement"]
+    assert "fused_step" not in r_host["placement"]
+    assert r_host["converged"] and r_dev["converged"]
+    assert abs(r_host["num_scf_iterations"]
+               - r_dev["num_scf_iterations"]) <= 1
+    assert abs(r_host["energy"]["total"] - r_dev["energy"]["total"]) < 1e-8
+    if deck is GAMMA_POLARIZED:
+        assert abs(r_dev["mag_history"][-1]) > 0.1
+        assert abs(r_host["mag_history"][-1] - r_dev["mag_history"][-1]) < 1e-6
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("plan", ["one_rollback", "ladder_to_host_tail"])
+def test_fused_gamma_rollback_converges(plan, gamma_ref):
+    """A NaN injected into the density of the Gamma + fused path: the
+    rollback resets the packed block and re-seeds the carry, and the run
+    converges to the unperturbed energy; three of them climb the ladder to
+    "disable device", where the host Gamma tail takes over mid-run."""
+    from sirius_tpu.dft.recovery import LADDER
+
+    deck = dict(GAMMA_DECK, extra_params=dict(
+        GAMMA_DECK["extra_params"], num_dft_iter=120))
+    its = {"one_rollback": (3,), "ladder_to_host_tail": (4, 7, 10)}[plan]
+    r = _run("auto", devices=_one_device(),
+             plan=[("scf.density", it, "nan") for it in its], **deck)
+    assert r["converged"] and r["placement"]["path"] == "gamma"
+    rec = r["recovery"]
+    assert rec["recoveries"] == len(its)
+    assert rec["ladder_history"][0]["sentinel"] == "device_nonfinite"
+    assert [h["action"] for h in rec["ladder_history"]] == list(
+        LADDER[:len(its)])
+    # after "disable device" the remaining iterations ran the host tail
+    assert ("fused_step" in r["placement"]) == (len(its) < 3)
+    assert abs(r["energy"]["total"] - gamma_ref["energy"]["total"]) < 1e-8
+
+
+@pytest.mark.parametrize("case", ["polish", "resume", "warm_start"])
+def test_fused_gamma_side_paths(case, tmp_path, gamma_ref):
+    """The paths around the Gamma + fused loop: the fp32 -> fp64 polish
+    (re-cast of the packed block, fused program rebuilt), a mid-SCF resume
+    from an autosave written off the device-resident state, and a warm
+    start from a complex psi. Each converges to the plain f64 run."""
+    r_ref, e_ref = gamma_ref, gamma_ref["energy"]["total"]
+    if case == "polish":
+        deck = dict(GAMMA_DECK, extra_params=dict(
+            GAMMA_DECK["extra_params"], precision_wf="fp32"))
+        ctx = synthetic_silicon_context(**deck)
+        ctx.cfg.settings.fp32_to_fp64_rms = 1e-4
+        from sirius_tpu.dft.scf import run_scf
+
+        r = run_scf(ctx.cfg, ctx=ctx, devices=_one_device())
+        assert r["placement"]["band_solve"][1] == "float64"
+        assert r["placement"]["fused_step"][1] == "float64"
+    elif case == "resume":
+        path = str(tmp_path / "auto.h5")
+        ctl = {"autosave_every": 2, "autosave_path": path}
+        cut = dict(GAMMA_DECK, extra_params=dict(
+            GAMMA_DECK["extra_params"], num_dft_iter=4))
+        r_cut = _run("auto", devices=_one_device(), control=ctl, **cut)
+        assert not r_cut["converged"]
+        r = _run("auto", devices=_one_device(), control=ctl,
+                 run_kw={"resume": path}, **GAMMA_DECK)
+        assert r["num_scf_iterations"] <= r_ref["num_scf_iterations"] + 1
+    else:
+        r0 = _run("auto", devices=_one_device(),
+                  run_kw={"keep_state": True}, **GAMMA_DECK)
+        st = r0["_state"]
+        assert st["psi"].dtype == np.complex128
+        r = _run("auto", devices=_one_device(),
+                 run_kw={"initial_guess": (st["rho_g"], st["psi"])},
+                 **GAMMA_DECK)
+        assert r["num_scf_iterations"] < r_ref["num_scf_iterations"]
+    assert r["converged"] and "fused_step" in r["placement"]
+    assert abs(r["energy"]["total"] - e_ref) < 1e-8
+
+
+@pytest.mark.parametrize("path", ["batched", "gamma"])
+def test_fused_no_host_transfers(path):
     """Everything between the band solve and the scalar fetch — fermi
     search, density accumulation, augmentation, mixing, potential, D/h_diag
-    refresh — must run without implicit host<->device transfers.
+    refresh — must run without implicit host<->device transfers; on the
+    Gamma path (one device) so must the band solve of a steady iteration,
+    whose potential, D and preconditioner diagonal are the fused step's
+    outputs (its tolerance scalar is an explicit device_put).
 
-    run_scf wraps exactly that region in profile("scf::fused_step"); hook
-    the profiler so the span also enters jax.transfer_guard("disallow"),
-    then run a small fused SCF: any per-iteration host round-trip inside
-    the span raises."""
+    run_scf wraps those regions in profile("scf::fused_step") and
+    profile("scf::band_solve"); hook the profiler so the span also enters
+    jax.transfer_guard("disallow"), then run a small fused SCF: any
+    per-iteration host round-trip inside the span raises."""
     import sirius_tpu.dft.scf as scf_mod
     from sirius_tpu.utils import profiler
 
@@ -80,8 +199,10 @@ def test_fused_no_host_transfers():
     @contextlib.contextmanager
     def guarded(name):
         with orig_profile(name):
-            if name == "scf::fused_step":
-                saw_span.append(name)
+            saw_span.append(name)
+            if name == "scf::fused_step" or (
+                    path == "gamma" and name == "scf::band_solve"
+                    and saw_span.count(name) > 1):
                 with jax.transfer_guard("disallow"):
                     yield
             else:
@@ -91,7 +212,7 @@ def test_fused_no_host_transfers():
     scf_mod.profile = guarded
     try:
         res = _run(
-            "auto",
+            "auto", devices=_one_device() if path == "gamma" else None,
             gk_cutoff=3.0, pw_cutoff=7.0, ngridk=(1, 1, 1), num_bands=8,
             ultrasoft=True, use_symmetry=False,
             extra_params={"num_dft_iter": 6, "density_tol": 1e-12,
@@ -99,7 +220,10 @@ def test_fused_no_host_transfers():
         )
     finally:
         scf_mod.profile = old
-    assert saw_span, "fused device path did not engage on the test deck"
+    assert "scf::fused_step" in saw_span, (
+        "fused device path did not engage on the test deck")
+    assert res["placement"]["path"] == (
+        "gamma" if path == "gamma" else "batched+fused")
     assert np.isfinite(res["energy"]["total"])
 
 

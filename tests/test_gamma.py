@@ -115,3 +115,61 @@ def test_davidson_gamma_matches_complex(ctx, gm):
         hp.mask, num_steps=25, res_tol=1e-12,
     )
     np.testing.assert_allclose(np.asarray(ev_g), np.asarray(ev_c), atol=5e-9)
+
+
+def test_unpack_device_matches_unpack(ctx, gm):
+    """The device-side unpack (the hand-off of the packed solve to the
+    fused tail) is the host unpack: the same gathers, as a (re, im) pair."""
+    from sirius_tpu.ops.gamma import make_gamma_params, unpack, unpack_device
+
+    gp = make_gamma_params(ctx, np.zeros(ctx.fft_coarse.dims), gm=gm)
+    x = _random_packed(gm, ctx, 5, seed=6).reshape(1, 5, -1)
+    re, im = unpack_device(gp, jnp.asarray(x))
+    c = unpack(gm, x)
+    assert re.shape == im.shape == c.shape
+    np.testing.assert_allclose(np.asarray(re), c.real, rtol=0, atol=1e-14)
+    np.testing.assert_allclose(np.asarray(im), c.imag, rtol=0, atol=1e-14)
+
+
+def test_pack_diags_device_matches_pack_diags(ctx, gm):
+    """Sphere-order preconditioner diagonals gathered to packed order on
+    the device, leading axes riding along, against the host pack_diags."""
+    from sirius_tpu.ops.gamma import (
+        make_gamma_params, pack_diags, pack_diags_device, pack_index)
+    from sirius_tpu.parallel.batched import compute_h_diag, compute_o_diag
+
+    rng = np.random.default_rng(5)
+    nbeta = ctx.beta.num_beta_total
+    dion = np.stack([
+        np.asarray(ctx.beta.dion) + 0.1 * np.diag(rng.standard_normal(nbeta))
+        for _ in range(2)])
+    h_diag = compute_h_diag(ctx, dion, v0=-0.3)[0]  # [ns, ngk], 1e4 padded
+    o_diag = compute_o_diag(ctx)[0]
+    gp = make_gamma_params(ctx, np.zeros(ctx.fft_coarse.dims), gm=gm)
+    hp, op = pack_diags_device(
+        jnp.asarray(pack_index(gm, ctx.gkvec.ngk_max)), gp.mask_p,
+        jnp.asarray(h_diag), jnp.asarray(o_diag))
+    for s in range(2):
+        hp_ref, op_ref = pack_diags(gm, h_diag[s], o_diag)
+        np.testing.assert_allclose(np.asarray(hp[s]), hp_ref, rtol=0,
+                                   atol=1e-14)
+        np.testing.assert_allclose(np.asarray(op), op_ref, rtol=0, atol=1e-14)
+
+
+def test_density_gamma_matches_complex(ctx, gm):
+    """|Re psi(r)|^2 off the packed block, a spin axis riding along, is the
+    complex path's density_kset of the unpacked block."""
+    from sirius_tpu.ops.gamma import density_gamma, make_gamma_params, unpack
+    from sirius_tpu.parallel.batched import (
+        density_kset, make_hkset_params, split_cplx)
+
+    gp = make_gamma_params(ctx, np.zeros(ctx.fft_coarse.dims), gm=gm)
+    x = np.stack([_random_packed(gm, ctx, 4, seed=s) for s in (7, 8)])
+    occ_w = np.random.default_rng(9).uniform(0.0, 2.0, size=(2, 4))
+    acc = density_gamma(gp, jnp.asarray(x), jnp.asarray(occ_w))
+    ps = make_hkset_params(ctx, np.zeros((2,) + tuple(ctx.fft_coarse.dims)))
+    pr, pi = split_cplx(unpack(gm, x)[None])
+    ref = density_kset(ps, jnp.asarray(pr), jnp.asarray(pi),
+                       jnp.asarray(occ_w[None]))
+    np.testing.assert_allclose(np.asarray(acc), np.asarray(ref), rtol=1e-12,
+                               atol=1e-12)

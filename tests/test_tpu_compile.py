@@ -51,11 +51,12 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def _deck(ngridk, supercell=1):
+def _deck(ngridk, supercell=1, num_bands=None):
     return {
         "parameters": {
             "gk_cutoff": 6.0, "pw_cutoff": 20.0, "ngridk": list(ngridk),
-            "num_bands": 26 * supercell**3, "use_symmetry": False,
+            "num_bands": num_bands or 26 * supercell**3,
+            "use_symmetry": False,
             "precision_wf": "fp32",
             "xc_functionals": ["XC_LDA_X", "XC_LDA_C_PZ"],
         },
@@ -63,11 +64,12 @@ def _deck(ngridk, supercell=1):
     }
 
 
-def _ctx(ngridk, supercell=1):
+def _ctx(ngridk, supercell=1, num_bands=None):
     from sirius_tpu.config.schema import load_config
     from sirius_tpu.serve.scheduler import build_job_context
 
-    return build_job_context(load_config(_deck(ngridk, supercell)), ".")
+    return build_job_context(
+        load_config(_deck(ngridk, supercell, num_bands)), ".")
 
 
 @pytest.fixture(scope="module")
@@ -174,6 +176,49 @@ def test_gamma_band_solve_one_chip(topo, no_compile_cache, ctx_gamma):
     nbig = jax.ShapeDtypeStruct((nb + 6, ngk), np.float32, sharding=one)
     _check(_compile(lambda: initialize_subspace_gamma.lower(gp, nbig, nb=nb)),
            no_64bit=True)
+
+
+def test_gamma_fused_tail_one_chip(topo, no_compile_cache):
+    """The Gamma path's iteration tail at the widths of the benchmark's
+    si16-gamma-us (16 atoms, 64 bands, gk 6 / pw 20): the hand-off of the
+    packed solve (solve_inputs_device, unpack_device), density_gamma, the
+    density matrix and the fused step, as run_scf's `gamma_bands` branch
+    feeds them."""
+    from sirius_tpu.ops.gamma import (
+        build_gamma_map, density_gamma, make_gamma_params, pack_index,
+        solve_inputs_device, unpack_device,
+    )
+    from sirius_tpu.parallel.batched import density_matrix_kset
+
+    ctx = _ctx((1, 1, 1), supercell=2, num_bands=64)
+    assert ctx.unit_cell.num_atoms == 16
+    one = SingleDeviceSharding(topo.devices[0])
+    f32 = np.float32
+    gm = build_gamma_map(np.asarray(ctx.gkvec.millers[0]),
+                         np.asarray(ctx.gkvec.mask[0]))
+    gp = _shapes(make_gamma_params(
+        ctx, np.zeros(ctx.fft_coarse.dims), gm, rdtype=jnp.float32), one)
+    nb, ngk = ctx.num_bands, ctx.gkvec.ngk_max
+    nbeta = ctx.beta.num_beta_total
+    dims = tuple(ctx.fft_coarse.dims)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, f32, sharding=one)
+
+    _check(_compile(lambda: solve_inputs_device.lower(
+        _shapes(pack_index(gm, ngk), one), gp.mask_p, sds(ngk),
+        sds(1, *dims), sds(1, nbeta, nbeta), sds(1, 1, ngk))),
+        no_64bit=True)
+    _check(_compile(lambda: unpack_device.lower(gp, sds(1, nb, ngk))),
+           no_64bit=True)
+    _check(_compile(lambda: density_gamma.lower(
+        gp, sds(1, nb, ngk), sds(1, nb))), no_64bit=True)
+    _check(_compile(lambda: density_matrix_kset.lower(
+        sds(1, nbeta, ngk), sds(1, nbeta, ngk), sds(1, 1, nb, ngk),
+        sds(1, 1, nb, ngk), sds(1, 1, nb))), no_64bit=True)
+    fused = _fused(ctx)
+    args = _fused_args(fused, ctx, nb, one, one, one)
+    _check(_compile(lambda: fused._step.lower(*args)), no_64bit=True)
 
 
 def test_kset_band_solve_one_chip(topo, no_compile_cache, ctx_kmesh):
