@@ -12,6 +12,8 @@ Si, made in memory — there are no species files off this machine), in the
      the fused device-resident tail it feeds (FusedScf), both on the chip;
   2. run_scf on the same cell with a (2,2,2) k-mesh, no symmetry: the
      batched k-set solve + the same fused step;
+     (on a host with four chips, then once more on all four: the (k, b)
+     mesh run_scf picks, and |E4 - E1|);
   3. three jobs of one shape bucket through sirius-serve's own main().
 
 Every phase's total energy must agree to 1e-5 Ha with the f64 energy of the
@@ -233,16 +235,9 @@ def serve_phase(size, chip, platform, rehearse, counters, cache, e_ref):
            placement=jobs[0]["result"]["placement"])
 
 
-def four_chip_phases(size, gsize, devices, platform, counters, cache):
-    """Only what exists across chips, and what it is compared with."""
-    if len(devices) != 4:
-        raise RuntimeError(f"--chips 4 needs 4 devices, found {len(devices)}")
-    d = deck(size, (2, 2, 2), "fp32")
-    r1 = run(d, devices[:1])
-    check_placement(r1["placement"], platform, "batched+fused")
-    report("kmesh_1chip", counters, energy_ha=r1["energy"]["total"],
-           scf_iterations=r1["num_scf_iterations"],
-           wall_s=round(r1["wall_s"], 3), placement=r1["placement"])
+def kmesh_on_all_chips(d, devices, e_one_chip, platform, counters, cache):
+    """The k-mesh deck on the host's four chips: run_scf picks the (k, b)
+    mesh; the energy against the same deck's on one chip."""
     r4 = run(d, devices)
     pl = r4["placement"]
     check_placement(pl, platform, "batched+fused")
@@ -253,12 +248,26 @@ def four_chip_phases(size, gsize, devices, platform, counters, cache):
         raise RuntimeError(
             f"wave-function shards not on 4 distinct devices: "
             f"{pl['psi_shard_devices']}")
-    de = energy_check("E4 vs E1", r4["energy"]["total"], r1["energy"]["total"])
+    de = energy_check("E4 vs E1", r4["energy"]["total"], e_one_chip)
     report("kmesh_4chip", counters, energy_ha=r4["energy"]["total"],
            abs_de_vs_1chip_ha=de, mesh=mesh,
            scf_iterations=r4["num_scf_iterations"],
            wall_s=round(r4["wall_s"], 3), peak_hbm_bytes=peak_hbm(devices),
            compile_cache_from_env=cache["from_env"], placement=pl)
+
+
+def four_chip_phases(size, gsize, devices, platform, counters, cache):
+    """Only what exists across chips, and what it is compared with."""
+    if len(devices) != 4:
+        raise RuntimeError(f"--chips 4 needs 4 devices, found {len(devices)}")
+    d = deck(size, (2, 2, 2), "fp32")
+    r1 = run(d, devices[:1])
+    check_placement(r1["placement"], platform, "batched+fused")
+    report("kmesh_1chip", counters, energy_ha=r1["energy"]["total"],
+           scf_iterations=r1["num_scf_iterations"],
+           wall_s=round(r1["wall_s"], 3), placement=r1["placement"])
+    kmesh_on_all_chips(d, devices, r1["energy"]["total"], platform, counters,
+                       cache)
     # Gamma supercell: G-sharded slab-FFT solve on the "g" mesh vs one chip
     g1 = run(deck(gsize, (1, 1, 1), "fp32", supercell=2), devices[:1])
     report("gshard_1chip", counters, energy_ha=g1["energy"]["total"],
@@ -320,8 +329,12 @@ def main(argv=None) -> int:
         chip = devices[:1]
         scf_phase("gamma", size, (1, 1, 1), "gamma", chip, cpu, platform,
                   counters, cache)
-        _, ref = scf_phase("kmesh", size, (2, 2, 2), "batched+fused", chip,
-                           cpu, platform, counters, cache)
+        r1, ref = scf_phase("kmesh", size, (2, 2, 2), "batched+fused", chip,
+                            cpu, platform, counters, cache)
+        if len(devices) >= 4:  # a four-chip host: the same deck on all of it
+            kmesh_on_all_chips(deck(size, (2, 2, 2), "fp32"), devices[:4],
+                               r1["energy"]["total"], platform, counters,
+                               cache)
         serve_phase(size, chip, platform, args.rehearse, counters, cache,
                     ref["energy"]["total"])
 

@@ -21,6 +21,8 @@ Conventions (matching the reference):
 from __future__ import annotations
 
 import dataclasses
+import os
+from concurrent.futures import ThreadPoolExecutor
 
 import jax.numpy as jnp
 import numpy as np
@@ -71,9 +73,53 @@ def _enumerate_sphere(
     return millers[order]
 
 
+_PHASE_ROWS = 1 << 16  # rows of the angle table one task of phase_factors takes
+
+
+def phase_factors(millers: np.ndarray, positions: np.ndarray,
+                  sign: float = 1.0) -> np.ndarray:
+    """exp(sign 2 pi i m . x) for integer Miller rows m [ng, 3] and
+    fractional positions x [na, 3], shape [ng, na]: cos + i sin of the real
+    angle, bit for bit what np.exp(sign * 2j * np.pi * (m @ x.T)) gives,
+    row blocks on as many threads as the host has cores (numpy's loops
+    release the interpreter lock). A 54-atom cell has 53 million pairs and
+    builds this table four times a job."""
+    x = np.atleast_2d(np.asarray(positions, dtype=np.float64))
+    dots = np.asarray(millers).reshape(-1, 3) @ x.T
+    out = np.empty(dots.shape, dtype=np.complex128)
+
+    def fill(lo):
+        theta = (sign * 2.0 * np.pi) * dots[lo:lo + _PHASE_ROWS]
+        out.real[lo:lo + _PHASE_ROWS] = np.cos(theta)
+        out.imag[lo:lo + _PHASE_ROWS] = np.sin(theta)
+
+    starts = range(0, len(dots), _PHASE_ROWS)
+    workers = min(len(starts), os.cpu_count() or 1)
+    if workers <= 1:
+        for lo in starts:
+            fill(lo)
+    else:
+        with ThreadPoolExecutor(max_workers=workers) as pool:
+            list(pool.map(fill, starts))
+    return out
+
+
 def _shells(glen2: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     """Group |G|^2 values into shells within tolerance. Returns
     (shell_index per G, shell |G|^2 values)."""
+    glen2 = np.asarray(glen2, dtype=np.float64)
+    if len(glen2) == 0:
+        return np.zeros(0, dtype=np.int32), np.zeros(0)
+    # a shell opens where |G|^2 leaves the shell's first value by more than
+    # the tolerance. In one pass over the steps between neighbours, kept
+    # where it provably is that rule (every member within the tolerance of
+    # its shell's first); else the rule itself, value by value.
+    tol = _SHELL_TOL * np.maximum(1.0, glen2)
+    opens = np.concatenate([[True], np.diff(glen2) > tol[1:]])
+    shell_idx = (np.cumsum(opens) - 1).astype(np.int32)
+    shell_g2 = glen2[opens]
+    if np.all(np.abs(glen2 - shell_g2[shell_idx]) <= tol):
+        return shell_idx, shell_g2
     shell_idx = np.zeros(len(glen2), dtype=np.int32)
     shell_g2 = []
     cur = -1.0
@@ -145,10 +191,15 @@ class Gvec:
 
         Used to map coefficient arrays between G-sets (coarse <-> fine grid,
         reference: Simulation_context gvec mappings)."""
-        lut = {tuple(m): i for i, m in enumerate(self.millers)}
-        return np.asarray(
-            [lut.get(tuple(m), -1) for m in np.asarray(millers)], dtype=np.int64
-        )
+        millers = np.asarray(millers).reshape(-1, 3)
+        dims = np.asarray(self.fft.dims)
+        lut = np.full(self.fft.num_points, -1, dtype=np.int64)
+        lut[self.fft_index] = np.arange(self.num_gvec)
+        # a Miller index outside the box's own range wraps onto another
+        # vector's slot: it is absent, not that vector
+        inside = np.all((millers >= -(dims // 2))
+                        & (millers <= (dims - 1) // 2), axis=1)
+        return np.where(inside, lut[self.fft.miller_to_linear(millers)], -1)
 
 
 @dataclasses.dataclass(frozen=True)

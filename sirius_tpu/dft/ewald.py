@@ -22,6 +22,8 @@ from __future__ import annotations
 import numpy as np
 from scipy.special import erfc
 
+from sirius_tpu.core.gvec import phase_factors
+
 
 def ewald_lambda(pw_cutoff: float, omega: float) -> float:
     lam = 1.0
@@ -50,7 +52,7 @@ def ewald_energy(
 
     # G-space sum (skip G=0)
     g2 = np.sum(gcart[1:] ** 2, axis=1)
-    phase = np.exp(2j * np.pi * (millers[1:] @ positions.T))  # (ng-1, natom)
+    phase = phase_factors(millers[1:], positions)  # (ng-1, natom)
     s = phase @ z
     ewald_g = float(np.sum(np.abs(s) ** 2 * np.exp(-g2 / (4 * lam)) / g2))
     ewald_g -= nel * nel / (4.0 * lam)

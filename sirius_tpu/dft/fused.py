@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sirius_tpu.core import hilo
 from sirius_tpu.core.fftgrid import r_to_g
 from sirius_tpu.dft.density import (
     build_dm_sym_tables,
@@ -95,6 +96,25 @@ S_CHG = 17  # |Re x_mixed[0] - Re x_new[0]| * omega (mixer charge drift)
 S_SYM = 18  # max |P_sym rho_new - rho_new| (symmetrization idempotency)
 S_HERM = 19  # max |H_nl - H_nl^H| (subspace nonlocal-H hermiticity)
 NUM_SCALARS = 20
+# The summed energy terms leave the device as two words each (core/hilo.py:
+# one float32 word at a few hundred Ha resolves 1.5e-5 Ha, and the loop asks
+# about 1e-5): the record is [NUM_SCALARS + len(S_PAIRED)], the second word
+# of S_PAIRED[i] at NUM_SCALARS + i, zero in float64. fold_scalars() adds
+# them on the host; everything downstream sees [NUM_SCALARS] float64.
+S_PAIRED = (S_VHA, S_VXC, S_VLOC, S_VEFF, S_EXC, S_BXC, S_E1, S_E2, S_EVAL)
+# How far a 32-bit step's accumulated electron count may lie from the count
+# and still be its rounding, in units of the type's eps times the count (the
+# chip reads 3 to 4 on the 2-atom cell: 8.0000029 to 8.0000038 electrons)
+CHARGE_EPS = 64
+
+
+def fold_scalars(record) -> np.ndarray:
+    """The [NUM_SCALARS] float64 scalars of one readback, the two words of
+    each paired term added."""
+    raw = np.asarray(record, dtype=np.float64)
+    out = raw[:NUM_SCALARS].copy()
+    out[list(S_PAIRED)] += raw[NUM_SCALARS:]
+    return out
 
 
 class FusedCarry(NamedTuple):
@@ -135,6 +155,10 @@ class FusedScf:
         self.ns = 2 if polarized else 1
         self.ng = ctx.gvec.num_gvec
         self.omega = float(ctx.unit_cell.omega)
+        self.nel = float(ctx.unit_cell.num_valence_electrons
+                         - ctx.cfg.parameters.extra_charge)
+        self.charge_tol = (CHARGE_EPS * float(jnp.finfo(self.rdt).eps)
+                           * max(self.nel, 1.0))
         self.dims = tuple(ctx.gvec.fft.dims)
         self.dims_coarse = tuple(ctx.fft_coarse.dims)
         self.kind = mixer.kind
@@ -216,7 +240,7 @@ class FusedScf:
         tab = tuple((tuple(x.shape), str(x.dtype)) for x in leaves)
         return (
             str(self.cdt), str(self.rdt),
-            self.ns, self.ng, self.nx, self.omega,
+            self.ns, self.ng, self.nx, self.omega, self.nel, self.charge_tol,
             self.dims, self.dims_coarse,
             self.kind, self.mix_beta, self.max_history,
             self.has_aug, self.do_symmetrize, self.polarized,
@@ -357,6 +381,21 @@ class FusedScf:
         rho_new = jnp.sum(rho_spin, axis=0)
         mag_new = rho_spin[0] - rho_spin[1] if self.polarized else None
         nel_got = jnp.real(rho_new[0]) * omega
+        # A 32-bit step accumulates the electron count to a few eps of it
+        # (band norms, the FFT's 1/N), differently in every iteration, and
+        # the occupations were solved for the count itself: where what was
+        # accumulated is the count to that rounding, the G = 0 component is
+        # set to it (the 54-atom cell converges in 14 iterations on the chip
+        # with this and in 17 without, PERF.md, PR 27). A count further off
+        # is not rounding but a fault (a lost band norm, wrong occupations,
+        # a wrong augmentation charge): it stays in the density, in S_NEL
+        # and in the energy. A 64-bit step, like the host tail, carries what
+        # it accumulated.
+        if hilo.compensated(rdt):
+            near = jnp.abs(nel_got - self.nel) <= self.charge_tol
+            rho_new = rho_new.at[0].set(jnp.where(
+                near, jnp.asarray(self.nel / omega, dtype=cdt), rho_new[0]
+            ))
         if self.do_symmetrize:
             rho_new = symmetrize_pw_device(rho_new, tables["sym"])
             if self.polarized:
@@ -383,11 +422,12 @@ class FusedScf:
         resid = rho_new - x_in[:ng]  # output - input density (scf-corr force)
 
         # Harris term e1 against the potential this iteration's bands saw
+        # (the energy terms are (hi, lo) pairs, core/hilo.py)
         veff_old = jax.lax.complex(carry.veff_re, carry.veff_im)
-        e1 = jnp.real(jnp.sum(jnp.conj(rho_new) * veff_old)) * omega
+        e1 = hilo.cdot_scaled(rho_new, veff_old, omega)
         if self.polarized:
             bz_old = jax.lax.complex(carry.bz_re, carry.bz_im)
-            e1 = e1 + jnp.real(jnp.sum(jnp.conj(mag_new) * bz_old)) * omega
+            e1 = hilo.add_pairs(e1, hilo.cdot_scaled(mag_new, bz_old, omega))
 
         # potential from the MIXED density
         rho_mix = x_mixed[:ng]
@@ -399,9 +439,9 @@ class FusedScf:
         )
         veff_new = pot["veff_g"]
         bz_new = pot["bz_g"]
-        e2 = jnp.real(jnp.sum(jnp.conj(rho_new) * veff_new)) * omega
+        e2 = hilo.cdot_scaled(rho_new, veff_new, omega)
         if self.polarized:
-            e2 = e2 + jnp.real(jnp.sum(jnp.conj(mag_new) * bz_new)) * omega
+            e2 = hilo.add_pairs(e2, hilo.cdot_scaled(mag_new, bz_new, omega))
         v0 = jnp.real(veff_new[0])
 
         # next iteration's D matrices and H diagonal
@@ -465,8 +505,10 @@ class FusedScf:
             h_nl - jnp.conj(jnp.swapaxes(h_nl, -1, -2))
         ))
 
-        eval_sum = jnp.sum(occ_w * ev)
+        eval_sum = hilo.dot_scaled(occ_w.astype(rdt), ev.astype(rdt), 1.0)
         e = pot["energies"]
+        paired = [e["vha"], e["vxc"], e["vloc"], e["veff"], e["exc"],
+                  e["bxc"], e1, e2, eval_sum]  # S_PAIRED's order
         # device-side health sentinel (dft/recovery.py): a NaN anywhere in
         # the mixed vector or the new potential collapses every scalar to
         # NaN anyway, but jnp.isfinite makes the check explicit and also
@@ -480,10 +522,10 @@ class FusedScf:
             & jnp.all(jnp.isfinite(ev))
         ).astype(rdt)
         scalars = jnp.stack([
-            rms, eha, e["vha"], e["vxc"], e["vloc"], e["veff"], e["exc"],
-            e["bxc"], e1, e2, eval_sum, nel_got, mag_moment, v0,
+            rms, eha, *[hi for hi, _ in paired], nel_got, mag_moment, v0,
             ent.astype(rdt), finite,
             s_ortho, s_chg, s_sym, s_herm,
+            *[lo for _, lo in paired],  # the second words
         ])
 
         if self.polarized:

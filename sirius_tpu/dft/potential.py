@@ -19,6 +19,7 @@ import numpy as np
 
 from sirius_tpu.context import SimulationContext
 from sirius_tpu.core.fftgrid import g_to_r, r_to_g
+from sirius_tpu.core.hilo import dot_scaled
 from sirius_tpu.dft.density import symmetrize_pw, symmetrize_pw_device
 from sirius_tpu.dft.poisson import hartree_potential_g
 from sirius_tpu.dft.xc import XCFunctional
@@ -265,7 +266,7 @@ def generate_potential_device(
 ) -> dict:
     """Traced generate_potential: returns veff_g/bz_g/vha_g/vxc_g (complex,
     program-internal), veff_r_coarse [ns, coarse box] real and the energy
-    integrals as traced scalars. sym_tb (density.build_sym_pw_tables)
+    integrals as traced (hi, lo) pairs of scalars (core/hilo.py). sym_tb (density.build_sym_pw_tables)
     enables the in-program PW symmetrization of veff/bz."""
     if xc.is_mgga:
         raise ValueError("device potential path does not support mGGA")
@@ -288,7 +289,10 @@ def generate_potential_device(
         )
 
     def inner_rr(f_r, g_r):
-        return jnp.sum(f_r * g_r) * (omega / n)
+        # (hi, lo): in float32 one word cannot hold a few hundred Ha to the
+        # 1e-5 Ha the convergence test asks about (core/hilo.py); in float64
+        # lo is zero and hi the plain sum
+        return dot_scaled(f_r, g_r, omega / n)
 
     vloc_g = jax.lax.complex(tb["vloc_re"], tb["vloc_im"]).astype(cdt)
     rho_core_g = jax.lax.complex(tb["core_re"], tb["core_im"]).astype(cdt)
@@ -364,14 +368,14 @@ def generate_potential_device(
     else:
         veff_r_coarse = to_coarse(veff_g)[None]
 
-    energies = {
+    zero = jnp.zeros((), dtype=rho_r.dtype)
+    energies = {  # each an (hi, lo) pair, see inner_rr
         "vha": inner_rr(rho_r, to_r(vha_g)),
         "vxc": inner_rr(rho_r, vxc_r),
         "vloc": inner_rr(rho_r, to_r(vloc_g)),
         "veff": inner_rr(rho_r, to_r(veff_g)),
         "exc": inner_rr(rho_r + rho_core_r, exc_r),
-        "bxc": (inner_rr(mag_r, to_r(bz_g)) if polarized
-                else jnp.zeros((), dtype=rho_r.dtype)),
+        "bxc": (inner_rr(mag_r, to_r(bz_g)) if polarized else (zero, zero)),
     }
     return {
         "veff_g": veff_g,

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from sirius_tpu.core.gvec import Gvec
+from sirius_tpu.core.gvec import Gvec, phase_factors
 from sirius_tpu.core.radial import Spline, spline_quadrature_weights
 from sirius_tpu.crystal.unit_cell import UnitCell
 
@@ -95,7 +95,7 @@ def rho_total_form_factor(atype, q: np.ndarray) -> np.ndarray:
 def structure_factors(uc: UnitCell, gvec: Gvec) -> np.ndarray:
     """S_t(G) = sum_{a in t} e^{2 pi i m . x_a}, shape (ntypes, ng)."""
     out = np.zeros((len(uc.atom_types), gvec.num_gvec), dtype=np.complex128)
-    phase = np.exp(2j * np.pi * (gvec.millers @ uc.positions.T))  # (ng, natom)
+    phase = phase_factors(gvec.millers, uc.positions)  # (ng, natom)
     for it in range(len(uc.atom_types)):
         sel = uc.type_of_atom == it
         out[it] = phase[:, sel].sum(axis=1)

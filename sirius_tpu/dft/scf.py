@@ -18,6 +18,7 @@ import numpy as np
 
 from sirius_tpu.config.schema import Config, load_config
 from sirius_tpu.context import SimulationContext
+from sirius_tpu.core.hilo import pair_eps
 from sirius_tpu.dft.density import (
     atomic_moments,
     generate_density_g,
@@ -917,6 +918,7 @@ def _run_scf_inner(
             FusedScf,
             S_BXC, S_CHG, S_E1, S_E2, S_EHA, S_ENT, S_EVAL, S_EXC, S_FINITE,
             S_HERM, S_MAG, S_NEL, S_ORTHO, S_RMS, S_SYM, S_V0, S_VHA, S_VXC,
+            fold_scalars,
         )
 
         if scf_mesh is not None:
@@ -1275,7 +1277,11 @@ def _run_scf_inner(
     )
     # everything since run_scf entry (context/tables/initial guess/fused
     # compile trigger) is the setup span
-    _setup_span.close(fused=fused is not None)
+    # ... and it says which mesh factorisation the job runs on, if any
+    _mesh = gsh["mesh"] if gsh is not None else scf_mesh
+    _setup_span.close(
+        fused=fused is not None,
+        **({} if _mesh is None else {"mesh": dict(_mesh.shape)}))
     _it_t0 = time.time()
     for it in range(it0, p.num_dft_iter):
         _close_iteration()
@@ -1886,7 +1892,7 @@ def _run_scf_inner(
                 _sp.close()
             # the ONLY per-iteration device->host fetch
             _sp = _stage("scf.readback", it=it + 1)
-            fused_np = np.asarray(fused_out["scalars"])
+            fused_np = fold_scalars(np.asarray(fused_out["scalars"]))
             _sp.close()
             if (not np.all(np.isfinite(fused_np))
                     or fused_np[S_FINITE] != 1.0):
@@ -2473,9 +2479,14 @@ def _run_scf_inner(
         _sp.close()
         result["numerics"] = _stages
     _RUNS.inc(outcome="converged" if converged else "unconverged")
+    # what one step of the loop's energy could resolve: the fused step's
+    # summed terms arrive as two words each (core/hilo.py), the host tail's
+    # as one float64
     obs_events.emit(
         "scf_done", converged=converged, iterations=num_iter_done,
         e_total=e_total, recoveries=sup.recoveries, wall_s=result["scf_time"],
+        energy_resolution_ha=abs(e_total) * pair_eps(
+            fused.rdt if fused is not None else np.float64),
     )
     if hub is not None:
         result["_hubbard_v"] = vhub  # ndarray, consumed by the band-path task

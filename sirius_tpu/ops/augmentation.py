@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sirius_tpu.core.gvec import Gvec
+from sirius_tpu.core.gvec import Gvec, phase_factors
 from sirius_tpu.core.radial import RadialIntegralTable
 from sirius_tpu.core.sht import gaunt_rlm, lm_index, num_lm, ylm_real
 from sirius_tpu.crystal.unit_cell import UnitCell
@@ -158,7 +158,7 @@ def rho_aug_g(
         dmp = np.stack(
             [w * np.real(dm[ia][at.xi1, at.xi2]) for ia in atoms]
         )  # (na_t, nqlm)
-        phases = np.exp(-2j * np.pi * (gvec.millers @ uc.positions[atoms].T))  # (ng, na_t)
+        phases = phase_factors(gvec.millers, uc.positions[atoms], -1.0)  # (ng, na_t)
         # (ng, na_t) @ (na_t, nqlm) -> then contract with q_pw
         out += np.einsum("ga,aq,qg->g", phases, dmp, q_pw, optimize=True)
     return out
@@ -186,7 +186,7 @@ def d_operator(
         if at is None:
             continue
         atoms = uc.atoms_of_type(it)
-        phases = np.exp(-2j * np.pi * (gvec.millers @ uc.positions[atoms].T))  # (ng, na_t)
+        phases = phase_factors(gvec.millers, uc.positions[atoms], -1.0)  # (ng, na_t)
         vq = omega * np.real(at.q_pw @ (np.conj(veff_g)[:, None] * phases))  # (nqlm, na_t)
         for j, ia in enumerate(atoms):
             vq_by_atom[ia] = (at, vq[:, j])
@@ -225,7 +225,7 @@ def build_aug_device_tables(uc: UnitCell, gvec: Gvec, aug: Augmentation,
         if at is None:
             continue
         atoms = uc.atoms_of_type(it)
-        phases = np.exp(-2j * np.pi * (gvec.millers @ uc.positions[atoms].T))
+        phases = phase_factors(gvec.millers, uc.positions[atoms], -1.0)
         gidx = np.stack([
             (offs[ia] + at.xi1).astype(np.int64) * nbeta + (offs[ia] + at.xi2)
             for ia in atoms

@@ -91,9 +91,9 @@ def compute_h_diag(ctx, dion, v0: float = 0.0):
         for ispn in range(ns):
             h = ekin[ik] + v0
             if nbeta:
-                h = h + np.real(
-                    np.einsum("xg,xy,yg->g", np.conj(b), dion[ispn], b)
-                )
+                # sum_xy conj(b_xg) D_xy b_yg as one GEMM and a column sum
+                # (the three-operand einsum walks nbeta^2 ngk terms)
+                h = h + np.real(np.sum(np.conj(b) * (dion[ispn] @ b), axis=0))
             h_diag[ik, ispn] = np.where(ctx.gkvec.mask[ik] > 0, h, 1e4)
     return h_diag
 
@@ -125,7 +125,7 @@ def compute_o_diag(ctx):
         o = np.ones(ctx.gkvec.ngk_max)
         if nbeta:
             b = ctx.beta.beta_gk[ik]
-            o = o + np.real(np.einsum("xg,xy,yg->g", np.conj(b), qmat, b))
+            o = o + np.real(np.sum(np.conj(b) * (qmat @ b), axis=0))
         o_diag[ik] = np.where(ctx.gkvec.mask[ik] > 0, o, 1.0)
     return o_diag
 

@@ -38,11 +38,11 @@ REFRESH_EVERY = 5
 
 
 def num_applies(num_steps: int, nb: int, refresh_every: int = REFRESH_EVERY) -> int:
-    """H-applications (in band rows) of one davidson() call: nb at the first
-    boundary (P still zero), 2nb at later chunk boundaries, nb per step for
-    the new block, nb on exit."""
+    """H-applications (in band rows) of one davidson() call: 2nb at every
+    chunk boundary ([X; P]; in the first chunk P is zero, and applied to all
+    the same), nb per step for the new block, nb on exit."""
     nchunks = -(-num_steps // refresh_every)
-    return nb * (num_steps + 2 * nchunks)
+    return nb * (num_steps + 2 * nchunks + 1)
 
 
 def residual_health(rnorm, blowup: float = 1e2) -> tuple[float, bool]:
@@ -189,25 +189,37 @@ def davidson(
         return (xn, hxn, sxn, pn * pscale, (cp.T @ hv) * mask * pscale,
                 (cp.T @ sv) * mask * pscale), rnorm
 
-    z = jnp.zeros_like(x)
-    p, hp, sp = z, z, z
-    done = 0
-    while done < num_steps:
-        steps = min(refresh_every, num_steps - done)
-        if done == 0:
-            # P is exactly zero before the first chunk: only X needs applying
-            with jax.named_scope("davidson_hpsi"):
-                hx, sx = apply_h_s(x)
-        else:
-            # chunk-boundary refresh: true H/S application to [X; P]
-            with jax.named_scope("davidson_hpsi"):
-                hxp, sxp = apply_h_s(jnp.concatenate([x, p], axis=0))
-            hx, sx = hxp[:nb], sxp[:nb]
-            hp, sp = hxp[nb:], sxp[nb:]
-        (x, hx, sx, p, hp, sp), rhist = jax.lax.scan(
-            step, (x, hx, sx, p, hp, sp), None, length=steps
+    def chunk(carry, steps):
+        """One refresh boundary, a true H/S application to [X; P], and the
+        `steps` steps after it on the carried blocks."""
+        x, p = carry
+        with jax.named_scope("davidson_hpsi"):
+            hxp, sxp = apply_h_s(jnp.concatenate([x, p], axis=0))
+        (x, _, _, p, _, _), _ = jax.lax.scan(
+            step, (x, hxp[:nb], sxp[:nb], p, hxp[nb:], sxp[nb:]), None,
+            length=steps,
         )
-        done += steps
+        return x, p
+
+    # The chunks of refresh_every steps are ONE loop over one body, not one
+    # loop each: the body holds the subspace eigensolver twice, which above
+    # 256 rows the TPU expands into a program of its own (QDWH divide and
+    # conquer), and a 648-row solve's four copies of it made an executable
+    # of 215 MB that no compile cache of 192 MiB could hold. The body is the
+    # same for every chunk, the first included: there P is exactly zero and
+    # H is applied to nb rows of zeros (1 in 29 of a solve's applications).
+    # Choosing the X-only application there by lax.cond gave non-finite
+    # fields on the TPU once the solve was vmapped over k (PERF.md, PR 27).
+    carry = (x, jnp.zeros_like(x))
+    nfull, rest = divmod(num_steps, refresh_every)
+    if nfull:
+        carry, _ = jax.lax.scan(
+            lambda c, _: (chunk(c, refresh_every), None), carry, None,
+            length=nfull,
+        )
+    if rest:
+        carry = chunk(carry, rest)
+    x = carry[0]
     # fresh application for the exit values: the carried H X accumulates
     # linear-combination rounding (matters in c64)
     with jax.named_scope("davidson_hpsi"):

@@ -77,3 +77,61 @@ def test_gkvec_padding(si_lattice):
         assert np.all(gk.mask[ik, n:] == 0.0)
     # Gamma sphere is inversion symmetric
     assert gk.num_gk[0] % 2 == 1
+
+
+@pytest.mark.parametrize("sign", [1.0, -1.0])
+def test_phase_factors_are_the_exponential_to_the_bit(si_lattice, sign,
+                                                      monkeypatch):
+    """phase_factors is cos + i sin of the real angle on several threads:
+    the numbers np.exp(sign 2 pi i m . x) gives, not merely close ones (two
+    tier-1 tests sit within the last bits of their thresholds), whatever the
+    block size and the number of rows."""
+    from sirius_tpu.core import gvec as gm
+
+    gv = Gvec.build(si_lattice, gmax=9.0)
+    x = np.random.default_rng(3).random((5, 3))
+    want = np.exp(sign * 2j * np.pi * (gv.millers @ x.T))
+    assert np.array_equal(gm.phase_factors(gv.millers, x, sign), want)
+    monkeypatch.setattr(gm, "_PHASE_ROWS", 37)  # many blocks, a ragged last
+    assert np.array_equal(gm.phase_factors(gv.millers, x, sign), want)
+    one = gm.phase_factors(gv.millers[:7], x[0], sign)  # one atom, as a row
+    assert one.shape == (7, 1) and np.array_equal(one[:, 0], want[:7, 0])
+    assert gm.phase_factors(gv.millers[:0], x, sign).shape == (0, 5)
+
+
+def test_index_of_millers_is_the_dictionary_lookup(si_lattice):
+    fine = Gvec.build(si_lattice, gmax=9.0)
+    coarse = Gvec.build(si_lattice, gmax=5.0)
+    lut = {tuple(m): i for i, m in enumerate(fine.millers)}
+    n1 = fine.fft.dims[0]
+    asked = np.vstack([coarse.millers, fine.millers[::-1],
+                       [[n1, 0, 0], [0, -n1, 0], [n1 // 2 + 1, 1, 0]],
+                       [[7, -7, 7]]])
+    want = np.array([lut.get(tuple(m), -1) for m in asked])
+    got = fine.index_of_millers(asked)
+    assert got.dtype == np.int64 and np.array_equal(got, want)
+    assert (want[-4:-1] == -1).all()  # outside the box: absent, not wrapped
+
+
+def test_shells_in_one_pass_are_the_rule_value_by_value(si_lattice):
+    from sirius_tpu.core import gvec as gm
+
+    def by_rule(glen2):
+        idx, first, cur = [], [], -1.0
+        for g2 in glen2:
+            if not first or g2 - cur > gm._SHELL_TOL * max(1.0, g2):
+                cur = g2
+                first.append(g2)
+            idx.append(len(first) - 1)
+        return np.array(idx), np.array(first)
+
+    gv = Gvec.build(si_lattice, gmax=9.0)
+    # a chain of values each within the tolerance of its neighbour but not
+    # of the shell's first: the pass over neighbours would merge them
+    chain = 1.0 + 0.6e-8 * np.arange(6)
+    for glen2 in (gv.glen2, np.concatenate([[0.0], chain, [2.0, 2.0]])):
+        idx, first = gm._shells(glen2)
+        want_idx, want_first = by_rule(glen2)
+        assert np.array_equal(idx, want_idx)
+        assert np.array_equal(first, want_first)
+    assert len(gm._shells(chain)[1]) > 1
