@@ -35,7 +35,7 @@ from sirius_tpu.dft.xc import XCFunctional
 from sirius_tpu.ops.atomic import atomic_orbitals
 from sirius_tpu.ops.augmentation import d_operator, rho_aug_g
 from sirius_tpu.ops.hamiltonian import apply_h_s, make_hk_params
-from sirius_tpu.solvers.davidson import davidson
+from sirius_tpu.solvers.davidson import apply_blocks, count_applies, davidson
 from sirius_tpu.obs import costs as obs_costs
 from sirius_tpu.obs import events as obs_events
 from sirius_tpu.obs import metrics as obs_metrics
@@ -1337,6 +1337,7 @@ def _run_scf_inner(
         _bs_span = _stage("scf.band_solve", it=it + 1,
                           num_steps=itsol.num_steps)
         _bs_t0 = time.perf_counter()
+        rows_per_box = 1  # band rows a complex FFT box carries (2 at Gamma)
         with profile("scf::band_solve"):
             if gsh is not None:
                 from sirius_tpu.ops.hamiltonian import real_dtype_of
@@ -1365,7 +1366,7 @@ def _run_scf_inner(
                         xb, np.asarray(hx, dtype=np.complex128),
                         np.asarray(sx, dtype=np.complex128), nb,
                     )
-                    counters["num_loc_op_applied"] += psi_big.shape[2]
+                    count_applies(counters, [(psi_big.shape[2], 1)])
                     psi_big = None
                 x0 = gsh["psi"]
                 if x0 is None:
@@ -1450,7 +1451,7 @@ def _run_scf_inner(
                         xb, np.asarray(hx, dtype=np.complex128),
                         np.asarray(sx, dtype=np.complex128), nb,
                     )
-                    counters["num_loc_op_applied"] += psi_big.shape[2]
+                    count_applies(counters, [(psi_big.shape[2], 1)])
                     psi_big = None
                 h_diag, o_diag = _h_o_diag(ctx, 0, v0, d_by_spin[0])
                 ev, x, rn = davidson(
@@ -1472,6 +1473,7 @@ def _run_scf_inner(
                     compute_o_diag,
                 )
 
+                rows_per_box = gmod.ROWS_PER_BOX
                 rdt = real_dtype_of(wf_dtype)
                 if x_packed[0] is not None and x_packed[0].dtype != np.dtype(rdt):
                     # fp32 -> fp64 polish: re-cast the packed block
@@ -1522,7 +1524,8 @@ def _run_scf_inner(
                         # the lowest nb Ritz vectors (initialize_subspace)
                         x_packed[ispn] = gmod.initialize_subspace_gamma(
                             gp, _up(gmod.pack(gm, psi_big[0, ispn]), rdt), nb)
-                        counters["num_loc_op_applied"] += psi_big.shape[2]
+                        count_applies(counters, [(psi_big.shape[2], 1)],
+                                      rows_per_box=rows_per_box)
                     ev, x_packed[ispn], rn = gmod.davidson_gamma(
                         gp, x_packed[ispn], hd_p, od_p,
                         num_steps=itsol.num_steps,
@@ -1571,7 +1574,7 @@ def _run_scf_inner(
                                 np.asarray(sx, dtype=np.complex128),
                                 nb,
                             )
-                    counters["num_loc_op_applied"] += nk * ns * psi_big.shape[2]
+                    count_applies(counters, [(psi_big.shape[2], 1)], copies=nk * ns)
                     psi = psi0
                     psi_big = None
                 new_psi = []
@@ -1664,7 +1667,7 @@ def _run_scf_inner(
                         ps, jnp.asarray(pb_re), jnp.asarray(pb_im), nb
                     )
                     pr, pi = _place_psi(pr), _place_psi(pi)
-                    counters["num_loc_op_applied"] += nk * ns * psi_big.shape[2]
+                    count_applies(counters, [(psi_big.shape[2], 1)], copies=nk * ns)
                     psi_big = None
                 if pr is None or pr.dtype != np.dtype(rdt):
                     # initial entry or precision switch; psi may be stale
@@ -1706,11 +1709,9 @@ def _run_scf_inner(
                 else:
                     evals = np.asarray(ev, dtype=np.float64)
             # H*psi application count (reference num_loc_op_applied counter)
-            from sirius_tpu.solvers.davidson import num_applies
-
-            counters["num_loc_op_applied"] += nk * ns * num_applies(
-                itsol.num_steps, nb
-            )
+            # and the FFT boxes behind it
+            count_applies(counters, apply_blocks(itsol.num_steps, nb),
+                          copies=nk * ns, rows_per_box=rows_per_box)
         if _span_fence:
             # the host tails already fenced via np.asarray(ev); only a
             # device-resident (fused) solve still has compute in flight
@@ -2485,6 +2486,8 @@ def _run_scf_inner(
     obs_events.emit(
         "scf_done", converged=converged, iterations=num_iter_done,
         e_total=e_total, recoveries=sup.recoveries, wall_s=result["scf_time"],
+        num_loc_op_applied=int(counters["num_loc_op_applied"]),
+        num_fft_boxes=int(counters["num_fft_boxes"]),
         energy_resolution_ha=abs(e_total) * pair_eps(
             fused.rdt if fused is not None else np.float64),
     )

@@ -203,9 +203,11 @@ def run_scf_nc(
             )
             psi = None
             evals = np.asarray(ev, dtype=np.float64)
-            from sirius_tpu.solvers.davidson import num_applies
+            from sirius_tpu.solvers.davidson import apply_blocks, count_applies
 
-            counters["num_loc_op_applied"] += nk * num_applies(itsol.num_steps, nb)
+            # a spinor row is two component boxes (ops/spinor.py)
+            count_applies(counters, apply_blocks(itsol.num_steps, nb),
+                          copies=nk, components=2)
 
         # --- occupations (spinor bands: max occupancy 1) ---
         mu, occ, entropy_sum = find_fermi(

@@ -17,10 +17,18 @@ instead of heevd) and the big band-block GEMMs become real (4x fewer real
 multiplies on the MXU than complex at equal slot count).
 
 The H application unpacks to the complex sphere with pure gathers (no
-matmul), runs the same FFT-multiply-FFT local pipeline (the box field is
-Hermitian-symmetric, so the real part is taken before the potential
-multiply), and re-packs. Beta projectors are packed once with the same
-isometry, making <beta|psi> and the D/Q expansions real GEMMs too.
+matmul) and sends TWO bands through every complex FFT box: at Gamma each
+band is real in r, so rows j and j + ceil(nb/2) of a block travel as psi_a +
+i psi_b (box coefficients c_a + i c_b). The real potential multiplies the complex
+field (real part psi_a v, imaginary part psi_b v), one forward transform
+brings back F = v_a + i v_b, and the re-pack, which averages each (G, -G)
+pair with the isometry's signs, is the projector onto the Hermitian part:
+v_a = pack(F), v_b = pack(-i F). Half the transforms, scatters and gathers
+of the one-band-a-box form, and nothing else: the density pairs its bands
+the same way (Re^2 and Im^2 of one inverse transform), and ROWS_PER_BOX
+tells the H-application counters of dft/scf.py. Beta projectors are packed
+once with the same isometry, making <beta|psi> and the D/Q expansions real
+GEMMs too.
 
 Eligibility (wired in dft/scf.run_scf): Gamma-only k-set, no Hubbard
 (complex per-k U apply), no mGGA, no G-sharding. Collinear spins are fine
@@ -41,6 +49,9 @@ import jax.numpy as jnp
 import numpy as np
 
 SQRT2 = np.sqrt(2.0)
+# real bands a complex FFT box carries through the local operator and the
+# density (the H-application counters of dft/scf.py read it)
+ROWS_PER_BOX = 2
 
 
 class GammaMap(NamedTuple):
@@ -266,30 +277,46 @@ def unpack_device(params: GammaParams, x: jax.Array):
     return _unpack_pair(params, x)
 
 
-def apply_h_s_gamma(params: GammaParams, x: jax.Array):
-    """(H x, S x) for a packed-real band block x [nb, ngk]."""
+def _pair_to_r(params: GammaParams, x: jax.Array):
+    """The packed (and masked) block x [..., nb, ngk], TWO rows a complex
+    box, in real space: row j and row j + h, h = ceil(nb/2), travel as
+    psi_a(r) + i psi_b(r); [..., h, n1, n2, n3], with ifftn's 1/N in it. The
+    two halves are contiguous slices: no strided gather on the way in, one
+    concatenate on the way out. An odd nb travels with a row of zeros (a
+    pad whose width is the static shape's parity)."""
     dims = params.veff_r.shape
     n = dims[0] * dims[1] * dims[2]
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, x.shape[-2] % 2), (0, 0)])
+    h = x.shape[-2] // 2
+    re, im = _unpack_pair(params, x)
+    # c_a + i c_b; lax.complex keeps the working precision (a bare `1j *`
+    # would promote f32 -> c128, which a TPU does not run)
+    c = jax.lax.complex(re[..., :h, :] - im[..., h:, :],
+                        im[..., :h, :] + re[..., h:, :])
+    lead = c.shape[:-1]
+    box = jnp.zeros(lead + (n,), dtype=c.dtype).at[..., params.fft_index].add(c)
+    return jnp.fft.ifftn(box.reshape(lead + dims), axes=(-3, -2, -1))
+
+
+def apply_h_s_gamma(params: GammaParams, x: jax.Array):
+    """(H x, S x) for a packed-real band block x [nb, ngk]."""
+    nb, npack = x.shape[-2:]
     x = x * params.mask_p
-    batch = x.shape[:-1]
-    cdtype = jnp.complex64 if x.dtype == jnp.float32 else jnp.complex128
-    # unpack to the complex sphere with gathers; lax.complex keeps the
-    # working precision (a bare `1j *` would promote f32 -> c128, which a
-    # TPU does not run)
-    c = jax.lax.complex(*_unpack_pair(params, x))
-    assert c.dtype == cdtype, (c.dtype, cdtype)
-    box = jnp.zeros(batch + (n,), dtype=cdtype).at[..., params.fft_index].add(c)
-    fr = jnp.fft.ifftn(box.reshape(batch + dims), axes=(-3, -2, -1))
-    # Hermitian-symmetric coefficients -> real field: drop the rounding-
-    # level imaginary part BEFORE the potential multiply (real multiply)
-    vr = jnp.real(fr) * params.veff_r
-    vg = (
-        jnp.fft.fftn(jax.lax.complex(vr, jnp.zeros_like(vr)), axes=(-3, -2, -1))
-        .reshape(batch + (n,))[..., params.fft_index]
+    fr = _pair_to_r(params, x)
+    # the potential is real: Re (fr v) = psi_a v, Im (fr v) = psi_b v
+    vr = jax.lax.complex(jnp.real(fr) * params.veff_r,
+                         jnp.imag(fr) * params.veff_r)
+    f = (
+        jnp.fft.fftn(vr, axes=(-3, -2, -1))
+        .reshape(fr.shape[:-3] + (-1,))[..., params.fft_index]
     )
-    # re-pack v(G): slot0 = v(0); Re/Im slots via the same isometry
-    vpack = _pack_device(vg, params.slot_re, params.slot_im, params.im_sign,
-                         params.scale, params.zero_idx, x.shape[-1])
+    # F = v_a + i v_b with v_a, v_b Hermitian: the re-pack's pair average
+    # is (F(G) + conj F(-G)) / 2, so pack(F) = v_a and pack(-i F) = v_b;
+    # one after the other they are the block's rows again
+    both = jnp.concatenate(
+        [f, jax.lax.complex(jnp.imag(f), -jnp.real(f))], axis=-2)
+    vpack = _pack_device(both, params.slot_re, params.slot_im, params.im_sign,
+                         params.scale, params.zero_idx, npack)[..., :nb, :]
     ekin = jnp.where(params.mask_p > 0, params.ekin_p, 0.0)
     hx = ekin * x + vpack
     sx = x
@@ -354,10 +381,12 @@ def density_gamma(params: GammaParams, x: jax.Array, occ_w: jax.Array):
     packed-real band block x [..., nb, ngk] (Gamma-only k-set; occ_w
     [..., nb] includes the k-weight and max_occupancy; a leading spin axis
     rides along). Returns [..., n1, n2, n3] real."""
-    dims = params.veff_r.shape
-    n = dims[0] * dims[1] * dims[2]
-    c = jax.lax.complex(*_unpack_pair(params, x * params.mask_p))
-    box = jnp.zeros(x.shape[:-1] + (n,), dtype=c.dtype).at[..., params.fft_index].add(c)
-    fr = jnp.fft.ifftn(box.reshape(x.shape[:-1] + dims), axes=(-3, -2, -1)) * n
-    # Hermitian coefficients -> real field; |Re|^2 drops only rounding noise
-    return jnp.einsum("...b,...bxyz->...xyz", occ_w, jnp.real(fr) ** 2)
+    nb = x.shape[-2]
+    n = params.veff_r.size
+    fr = _pair_to_r(params, x * params.mask_p) * n
+    # two bands a box: band j is the real part, band j + h the imaginary
+    # part; the zero row of an odd nb gets weight 0
+    w = jnp.pad(occ_w, [(0, 0)] * (occ_w.ndim - 1) + [(0, nb % 2)])
+    h = w.shape[-1] // 2
+    return (jnp.einsum("...b,...bxyz->...xyz", w[..., :h], jnp.real(fr) ** 2)
+            + jnp.einsum("...b,...bxyz->...xyz", w[..., h:], jnp.imag(fr) ** 2))

@@ -33,16 +33,38 @@ import jax
 import jax.numpy as jnp
 
 # refresh cadence of the carried H X / H P blocks; scf.py's H-application
-# counter derives from this, keep them in sync via this constant
+# counters derive from this, keep them in sync via this constant
 REFRESH_EVERY = 5
 
 
-def num_applies(num_steps: int, nb: int, refresh_every: int = REFRESH_EVERY) -> int:
-    """H-applications (in band rows) of one davidson() call: 2nb at every
-    chunk boundary ([X; P]; in the first chunk P is zero, and applied to all
-    the same), nb per step for the new block, nb on exit."""
+def apply_blocks(num_steps: int, nb: int, refresh_every: int = REFRESH_EVERY):
+    """The H applications of one davidson() call as (rows, times) pairs:
+    2nb rows at every chunk boundary ([X; P]; in the first chunk P is zero,
+    and applied to all the same), nb rows per step for the new block and
+    nb on exit. The one source of num_applies and of the box count."""
     nchunks = -(-num_steps // refresh_every)
-    return nb * (num_steps + 2 * nchunks + 1)
+    return ((2 * nb, nchunks), (nb, num_steps + 1))
+
+
+def num_applies(num_steps: int, nb: int, refresh_every: int = REFRESH_EVERY) -> int:
+    """H-applications (in band rows) of one davidson() call."""
+    return sum(rows * times
+               for rows, times in apply_blocks(num_steps, nb, refresh_every))
+
+
+def count_applies(counters, blocks, copies: int = 1, rows_per_box: int = 1,
+                  components: int = 1) -> None:
+    """Book `copies` times the H applications `blocks` ((rows, times) pairs)
+    into the run's counters: band rows into num_loc_op_applied (the
+    reference's counter) and, into num_fft_boxes, the complex 3-D box
+    transforms the local operator ran for them: one inverse and one forward
+    a box; a box a row (`components` of them for a spinor row), or
+    ceil(rows / rows_per_box) boxes an application where several real bands
+    share one (ops/gamma.ROWS_PER_BOX)."""
+    for rows, times in blocks:
+        counters["num_loc_op_applied"] += copies * times * rows
+        counters["num_fft_boxes"] += (
+            copies * times * 2 * components * -(-rows // rows_per_box))
 
 
 def residual_health(rnorm, blowup: float = 1e2) -> tuple[float, bool]:
