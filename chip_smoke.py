@@ -8,7 +8,7 @@ config 1 = the reference's verification/test08: Si-2 ultrasoft, gk_cutoff
 Si, made in memory — there are no species files off this machine), in the
 32-bit types the chip runs:
 
-  1. run_scf on the Gamma deck: the packed-real `gamma_bands` solve and
+  1. run_scf on the Gamma deck: the packed-real `gamma` solve and
      the fused device-resident tail it feeds (FusedScf), both on the chip;
   2. run_scf on the same cell with a (2,2,2) k-mesh, no symmetry: the
      batched k-set solve + the same fused step;

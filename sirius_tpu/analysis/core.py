@@ -482,7 +482,7 @@ def all_rules() -> list:
             + list(transferrules.RULES) + list(shardrules.RULES))
 
 
-DEFAULT_SCAN = ("sirius_tpu", "tools", "tests", "bench.py")
+DEFAULT_SCAN = ("sirius_tpu", "tools", "tests")
 _SKIP_DIRS = {"__pycache__", ".git", "csrc", ".github"}
 
 

@@ -46,7 +46,9 @@ _COLLECTIVES = {"psum", "pmean", "pmax", "pmin", "all_gather",
 _CONSTRAINT = {"with_sharding_constraint"}
 
 DRIVERS = (
-    ("scf", "sirius_tpu/dft/scf.py"),
+    # the SCF driver's meshes and shardings are decided behind
+    # band_solve.choose; dft/scf.py only replicates onto band.mesh
+    ("scf", "sirius_tpu/dft/band_solve.py"),
     ("serve", "sirius_tpu/serve/scheduler.py"),
     ("md", "sirius_tpu/md/driver.py"),
     ("relax", "sirius_tpu/dft/relax.py"),

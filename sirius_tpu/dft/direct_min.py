@@ -33,10 +33,11 @@ import numpy as np
 
 from sirius_tpu.config.schema import Config
 from sirius_tpu.context import SimulationContext
+from sirius_tpu.dft.band_solve import _subspace_rotate_host
 from sirius_tpu.dft.density import generate_density_g, initial_magnetization_g
 from sirius_tpu.dft.occupation import find_fermi
 from sirius_tpu.dft.potential import generate_potential
-from sirius_tpu.dft.scf import _initial_subspace, _subspace_rotate_host
+from sirius_tpu.dft.scf import _initial_subspace
 from sirius_tpu.dft.xc import XCFunctional
 from sirius_tpu.ops.hamiltonian import apply_h_s, make_hk_params
 

@@ -19,6 +19,7 @@ import numpy as np
 from sirius_tpu.config.schema import Config, load_config
 from sirius_tpu.context import SimulationContext
 from sirius_tpu.core.hilo import pair_eps
+from sirius_tpu.dft import band_solve
 from sirius_tpu.dft.density import (
     atomic_moments,
     generate_density_g,
@@ -34,8 +35,6 @@ from sirius_tpu.dft.recovery import ScfSupervisor
 from sirius_tpu.dft.xc import XCFunctional
 from sirius_tpu.ops.atomic import atomic_orbitals
 from sirius_tpu.ops.augmentation import d_operator, rho_aug_g
-from sirius_tpu.ops.hamiltonian import apply_h_s, make_hk_params
-from sirius_tpu.solvers.davidson import apply_blocks, count_applies, davidson
 from sirius_tpu.obs import costs as obs_costs
 from sirius_tpu.obs import events as obs_events
 from sirius_tpu.obs import metrics as obs_metrics
@@ -77,16 +76,6 @@ _STRAGGLER = obs_metrics.REGISTRY.counter(
     "runs preempted at a snapshot boundary by the straggler watchdog")
 
 
-def _h_o_diag(ctx: SimulationContext, ik: int, v0: float, dmat: np.ndarray):
-    """Diagonals of H and S for the preconditioner at one k (serial debug
-    path) — same formulas as the production k-set path, by construction."""
-    from sirius_tpu.parallel.batched import compute_h_diag, compute_o_diag
-
-    h = compute_h_diag(ctx, np.asarray(dmat)[None], v0)[ik, 0]
-    o = compute_o_diag(ctx)[ik]
-    return h, o
-
-
 def _initial_subspace(ctx: SimulationContext) -> jnp.ndarray:
     """LCAO + random-fill initial trial vectors [nk, nspin, nbig, ngk].
 
@@ -121,16 +110,6 @@ def _initial_subspace(ctx: SimulationContext) -> jnp.ndarray:
     # host numpy: the band solves upload it themselves, as a (re, im) pair
     # on the batched path (parallel/batched.py real-boundary contract)
     return psi
-
-
-def _subspace_rotate_host(x, hx, sx, nb):
-    """Host wrapper over the shared solvers.davidson.subspace_rotate
-    (serial debug path only)."""
-    from sirius_tpu.solvers.davidson import subspace_rotate
-
-    return np.asarray(
-        subspace_rotate(jnp.asarray(x), jnp.asarray(hx), jnp.asarray(sx), nb)
-    )
 
 
 def default_autosave_path(cfg, base_dir: str) -> str:
@@ -412,12 +391,6 @@ def _run_scf_inner(
         np.zeros((ns, ctx.gvec.num_gvec), dtype=np.complex128) if mgga else None
     )
     pot = generate_potential(ctx, rho_g, xc, mag_g, tau_g=tau_g)
-    psi_big = None
-    if psi is None:
-        # full atomic-orbital block (nbig >= nb); rotated down to the lowest
-        # nb Ritz vectors at the first band solve, once the screened D of
-        # the initial potential exists (reference initialize_subspace)
-        psi_big = _initial_subspace(ctx)
     om_size = 0 if hub is None else ns * hub.num_hub_total * hub.num_hub_total
     nl_sizes = [] if hub is None else [
         ns * (2 * e["il"] + 1) * (2 * e["jl"] + 1) for e in hub.nonloc
@@ -448,74 +421,6 @@ def _run_scf_inner(
         beta_dev = (jnp.asarray(_bre), jnp.asarray(_bim))
     else:
         beta_dev = None
-    hub_phi_stack = (
-        None if hub is None else np.stack([hub.phi_s_gk[ik] for ik in range(nk)])
-    )
-    # per-(k, dtype) Hamiltonian parameter cache: only veff_r/dion change
-    # between iterations, everything else is uploaded once via _replace
-    _params_cache: dict = {}
-    _kset_cache: dict = {}
-    _gkc_cache: dict = {}
-
-    def _gkc_dev(rdt):
-        """Device-resident cartesian G+k components [nk, ngk, 3] for the
-        mGGA tau operator, uploaded once per working precision."""
-        key = str(rdt)
-        if key not in _gkc_cache:
-            _gkc_cache.clear()  # drop the stale-precision copy
-            _gkc_cache[key] = jnp.asarray(ctx.gkvec.gkcart, dtype=rdt)
-        return _gkc_cache[key]
-
-    def kset_params(veff_stack, d_stack, v0, vhub_s, dtype):
-        """Batched-path parameters with cached constant tables (only the
-        potential-dependent leaves are re-uploaded per iteration)."""
-        from sirius_tpu.ops.hamiltonian import real_dtype_of
-        from sirius_tpu.parallel.batched import compute_h_diag, make_hkset_params
-
-        rdt = real_dtype_of(dtype)
-        if dtype not in _kset_cache:
-            # a lower-precision entry is dead after the fp32->fp64 polish
-            # switch: evict it so two full projector stacks never coexist
-            for other in list(_kset_cache):
-                if other != dtype:
-                    del _kset_cache[other]
-            _kset_cache[dtype] = make_hkset_params(
-                ctx, veff_stack, d_stack, dtype=dtype, v0=v0,
-                hub_phi=hub_phi_stack, vhub=vhub_s,
-            )
-            return _kset_cache[dtype]
-        from sirius_tpu.parallel.batched import split_cplx
-
-        h_diag = compute_h_diag(ctx, np.asarray(d_stack), v0)
-        vh = (None, None) if vhub_s is None else split_cplx(vhub_s, rdt)
-        # store the refreshed params back so the previous iteration's
-        # potential-dependent device buffers are released
-        _kset_cache[dtype] = _kset_cache[dtype]._replace(
-            veff_r=jnp.asarray(veff_stack, dtype=rdt),
-            dion=jnp.asarray(d_stack, dtype=rdt),
-            h_diag=jnp.asarray(h_diag, dtype=rdt),
-            vhub_re=None if vh[0] is None else jnp.asarray(vh[0]),
-            vhub_im=None if vh[1] is None else jnp.asarray(vh[1]),
-        )
-        return _kset_cache[dtype]
-
-    def hk_params(ik, veff_r, dmat, dtype, vhub_s=None):
-        from sirius_tpu.ops.hamiltonian import real_dtype_of
-
-        key = (ik, dtype)
-        if key not in _params_cache:
-            _params_cache[key] = make_hk_params(
-                ctx, ik, veff_r, dmat, dtype=dtype,
-                hub_phi=None if hub is None else hub.phi_s_gk[ik],
-                vhub=vhub_s,
-            )
-            return _params_cache[key]
-        rdt = real_dtype_of(dtype)
-        return _params_cache[key]._replace(
-            veff_r=jnp.asarray(veff_r, dtype=rdt),
-            dion=jnp.asarray(dmat if dmat is not None else ctx.beta.dion, dtype=rdt),
-            vhub=None if vhub_s is None else jnp.asarray(vhub_s, dtype=dtype),
-        )
     do_symmetrize = (
         p.use_symmetry and ctx.symmetry is not None and ctx.symmetry.num_ops > 1
     )
@@ -574,188 +479,18 @@ def _run_scf_inner(
 
     evals = np.zeros((nk, ns, nb))
     rho_spin = None  # host paths: last accumulated per-spin density
-    pr = pi = None  # batched-path device-resident (re, im) wave functions
-    # production multi-device mesh: k-points over "k", bands over "b"
-    # (GSPMD — same program, XLA inserts the collectives; None on 1 device)
-    from sirius_tpu.parallel.mesh import place_kset_params, production_mesh
-
-    scf_mesh, psi_spec = (None, None) if serial_bands else production_mesh(
-        nk, nb, devices=devices)
-
-    def _up(x, dtype=None):
-        """Upload one band-solve operand to the single compute device
-        (the one-device twin of the mesh placements below)."""
-        if not isinstance(x, jax.Array):
-            x = np.asarray(x)
-        if dtype is not None and x.dtype != np.dtype(dtype):
-            x = x.astype(dtype)
-        return jax.device_put(x, _devs[0])
-
-    def _rtol(rdt):
-        # typed scalar: a python float would enter the band-solve program
-        # as a (weak) f64 parameter whatever the working precision
-        return np.dtype(rdt).type(res_tol)
-
-    if scf_mesh is not None:
-        from jax.sharding import NamedSharding
-
-        _psi_sharding = NamedSharding(scf_mesh, psi_spec)
-
-        def _place_psi(x):
-            return jax.device_put(x, _psi_sharding)
+    # the band solve behind one seam (dft/band_solve.py): the path is
+    # decided here, once, and the loop does not know which one it holds
+    band = band_solve.choose(
+        ctx, cfg, devices, serial_bands=serial_bands, hub=hub, paw=paw,
+        mgga=mgga, wf_dtype=wf_dtype)
+    if psi is not None:
+        band.load(psi)
     else:
-        _place_psi = _up
-
-    # ---- G-sharded band solve (slab FFT over a "g" mesh): selected when
-    # the replicated projector + wave-function footprint would not fit a
-    # single device (cfg.control.gshard "auto"/True). Single-k no-U
-    # regime — the Si-supercell flagship class. ----
-    gsh = None
-    g_flag = cfg.control.gshard
-    ndev = len(_devs)
-    gsh_want = False
-    if (
-        not serial_bands and g_flag not in (False, "false", "off")
-        and nk == 1 and ns == 1 and hub is None and ndev > 1
-        and ctx.beta.num_beta_total
-    ):
-        # replicated per-device footprint: projector table + psi workspace
-        foot = (ctx.beta.num_beta_total + 4 * nb) * ctx.gkvec.ngk_max * 16
-        dims_ok = (
-            ctx.fft_coarse.dims[0] % ndev == 0
-            and ctx.fft_coarse.dims[1] % ndev == 0
-        )
-        forced = g_flag in (True, "force")
-        gsh_want = dims_ok and (
-            forced
-            or (g_flag == "auto" and foot > cfg.control.gshard_budget_bytes)
-        )
-        if forced and not dims_ok:
-            raise ValueError(
-                f"control.gshard is forced but the coarse box "
-                f"{ctx.fft_coarse.dims} is not divisible by {ndev} devices "
-                "along x and y, so the G-sharded band solve cannot engage"
-            )
-
-    if scf_mesh is not None or gsh_want:
-        runtime.refuse_large_subspace_on_tpu_mesh(_devs, nb)
-    if mgga and gsh_want:
-        # the G-sharded operator has no tau term and the gshard density
-        # branch never updates tau_g — it would silently produce SCAN
-        # energies from tau = 0
-        raise NotImplementedError(
-            "mGGA with the G-sharded band solve is not supported; set "
-            "control.gshard = false"
-        )
-
-    def _setup_gshard(dtype):
-        from jax.sharding import Mesh as _Mesh
-
-        from sirius_tpu.ops.hamiltonian import real_dtype_of
-        from sirius_tpu.parallel.dist_fft import (
-            gshard_partition,
-            make_apply_h_s_gshard,
-            reorder_to_gshard,
-        )
-
-        g_mesh = _Mesh(np.array(_devs).reshape(ndev), ("g",))
-        mill0 = np.asarray(ctx.gkvec.millers[0])
-        g_order, g_lidx, _ = gshard_partition(mill0, ctx.fft_coarse.dims, ndev)
-        prm0 = hk_params(0, np.zeros(ctx.fft_coarse.dims), None, dtype)
-        g_fn, g_sharding = make_apply_h_s_gshard(
-            g_mesh, ctx.fft_coarse.dims, g_lidx,
-            reorder_to_gshard(np.asarray(prm0.ekin), g_order),
-            reorder_to_gshard(np.asarray(prm0.mask), g_order),
-            reorder_to_gshard(np.asarray(prm0.beta), g_order),
-            np.asarray(prm0.dion), np.asarray(prm0.qmat),
-            np.zeros(ctx.fft_coarse.dims, dtype=real_dtype_of(dtype)),
-        )
-        sh_g = jax.sharding.NamedSharding(
-            g_mesh, jax.sharding.PartitionSpec("g"))
-        g_mask = jax.device_put(
-            reorder_to_gshard(np.asarray(prm0.mask), g_order), sh_g)
-        return dict(fn=g_fn, order=g_order, sharding=g_sharding,
-                    mask=g_mask, psi=None, dtype=dtype,
-                    rdt=real_dtype_of(dtype), mesh=g_mesh, sh_g=sh_g,
-                    sh_rep=jax.sharding.NamedSharding(
-                        g_mesh, jax.sharding.PartitionSpec()))
-
-    if gsh_want:
-        gsh = _setup_gshard(wf_dtype)
-        scf_mesh = None  # the "g" mesh replaces the (k, b) mesh
-        if obs_metrics.enabled() and getattr(
-                cfg.control, "collective_probe", True):
-            # measure each named collective of the sharded apply once, in
-            # isolation, at this deck's shapes — the per-iteration
-            # compute/collective split of scf.band_solve scales these by
-            # the analytic H-application row count
-            try:
-                from sirius_tpu.parallel.dist_fft import probe_collectives
-
-                _pbatch = max(1, min(nb, 64))
-                gsh["probe"] = {
-                    "batch": _pbatch,
-                    "per_call": probe_collectives(
-                        gsh["mesh"], tuple(ctx.fft_coarse.dims), _pbatch,
-                        nbeta=int(ctx.beta.num_beta_total),
-                        ngk=int(gsh["order"].size), dtype=wf_dtype,
-                        reps=2),
-                }
-            except Exception:
-                gsh["probe"] = None
-    # ---- chunked beta projectors (ops/beta_chunked.py): the dense
-    # [nbeta_total, ngk] table is never materialized — each atom chunk is
-    # regenerated inside the H application. Auto-dispatch mirrors gshard:
-    # engage when the dense table would exceed beta_chunk_budget_bytes
-    # (control.beta_chunked "auto"), or always when forced. Single-k
-    # unpolarized no-U regime, like gshard. ----
-    bchunk = None
-    bc_flag = cfg.control.beta_chunked
-    bc_foot = ctx.beta.num_beta_total * ctx.gkvec.ngk_max * 16
-    # regime eligibility, captured separately from the budget decision: the
-    # OOM degradation ladder (_recover "device_oom" below) engages the
-    # chunked path mid-run after an HBM exhaustion, even when the budget
-    # did not trip it at setup. The bchunk dispatch branch precedes
-    # gamma_bands in the band solve, so a mid-run engagement shadows the
-    # packed gamma path cleanly.
-    _bchunk_ok = bool(
-        not serial_bands and gsh is None
-        and bc_flag not in (False, "false", "off")
-        and nk == 1 and ns == 1 and hub is None and paw is None
-        and not mgga and ctx.beta.num_beta_total
-    )
-    if _bchunk_ok:
-        if bc_flag in (True, "force") or (
-            bc_flag == "auto"
-            and bc_foot > cfg.control.beta_chunk_budget_bytes
-        ):
-            bchunk = {"params": None, "dtype": None}
-    # Gamma-point real-storage band solve (ops/gamma.py; reference
-    # reduce_gvec, wave_functions.hpp:1589-1626): packed-real vectors make
-    # the solver's GEMMs/eigh real. Hubbard needs the complex per-k U
-    # apply and mGGA the complex tau operator — both keep the generic path.
-    gamma_bands = (
-        cfg.control.reduce_gvec
-        and not serial_bands
-        and gsh is None
-        and bchunk is None
-        and nk == 1
-        and float(np.abs(np.asarray(ctx.gkvec.kpoints[0])).max()) < 1e-12
-        and hub is None
-        and not mgga
-        # multi-device runs keep the band-sharded batched path — the packed
-        # solve is single-device and would idle the rest of the mesh
-        and ndev == 1
-    )
-    gm = None
-    x_packed: list = [None] * ns
-    gamma_cache: dict = {}  # rdtype -> constant-table GammaParams
-    if gamma_bands:
-        from sirius_tpu.ops.gamma import build_gamma_map
-
-        gm = build_gamma_map(
-            np.asarray(ctx.gkvec.millers[0]), np.asarray(ctx.gkvec.mask[0])
-        )
+        # full atomic-orbital block (nbig >= nb); rotated down to the lowest
+        # nb Ritz vectors at the first band solve, once the screened D of
+        # the initial potential exists (reference initialize_subspace)
+        band.restart(_initial_subspace(ctx))
     mu, occ, entropy_sum = 0.0, jnp.zeros((nk, ns, nb)), 0.0
     etot_history, rms_history, mag_history = [], [], []
     e_prev, converged, rms, scf_correction = None, False, 0.0, 0.0
@@ -908,9 +643,9 @@ def _run_scf_inner(
     fused = None
     fused_carry = fused_out = fused_np = None
     if (
-        cfg.control.device_scf not in (False, "false", "off")
-        and not serial_bands and gsh is None
-        and bchunk is None and hub is None and paw is None and not mgga
+        band.feeds_fused
+        and cfg.control.device_scf not in (False, "false", "off")
+        and hub is None and paw is None and not mgga
         and mixer.kind in ("linear", "anderson")
         and not _cks.enabled()
     ):
@@ -921,7 +656,7 @@ def _run_scf_inner(
             fold_scalars,
         )
 
-        if scf_mesh is not None:
+        if band.mesh is not None:
             # replicate the fused constants/state on the production mesh
             # ONCE: jit against mesh-sharded band-solve outputs would
             # otherwise reshard every uncommitted operand each iteration —
@@ -929,7 +664,7 @@ def _run_scf_inner(
             # transfer-guard test in tests/test_fused_scf.py)
             from jax.sharding import NamedSharding, PartitionSpec
 
-            _rep = NamedSharding(scf_mesh, PartitionSpec())
+            _rep = NamedSharding(band.mesh, PartitionSpec())
 
             def _repl(t):
                 return jax.tree_util.tree_map(
@@ -938,7 +673,8 @@ def _run_scf_inner(
         else:
 
             def _repl(t):
-                return jax.tree_util.tree_map(_up, t)
+                return jax.tree_util.tree_map(
+                    lambda a: band_solve.up(a, _devs[0]), t)
 
         def _fused_setup(x0, pot0, history=None, rebuild=True):
             # (re)build the fused program and/or its carry. The recovery
@@ -1014,9 +750,9 @@ def _run_scf_inner(
         the ladder or the recovery budget is exhausted."""
         nonlocal x_mix, rho_g, mag_g, om_mixed, om_nl_mixed, paw_dm
         nonlocal hub_lagrange, um_local, um_nl, e_hub, vhub
-        nonlocal paw_res, e_paw_one_el, pot, psi, psi_big, pr, pi
-        nonlocal x_packed, tau_g, fused, fused_carry, fused_out, fused_np
-        nonlocal e_prev, res_tol, bchunk, evals
+        nonlocal paw_res, e_paw_one_el, pot, band
+        nonlocal tau_g, fused, fused_carry, fused_out, fused_np
+        nonlocal e_prev, res_tol
         if os.environ.get("SIRIUS_TPU_DUMP_DIVERGED"):
             np.savez(
                 os.environ["SIRIUS_TPU_DUMP_DIVERGED"],
@@ -1026,10 +762,7 @@ def _run_scf_inner(
         d = sup.recover(sentinel, it, detail=detail, state={
             "mixer_beta": mixer.beta, "mixer_kind": mixer.kind,
             "device_scf": fused is not None,
-            # OOM-ladder applicability flags (dft/recovery.py _recover_oom)
-            "beta_chunked": bchunk is not None,
-            "beta_chunk_eligible": _bchunk_ok,
-            "beta_chunk_can_halve": int(cfg.control.beta_chunk_size) > 16,
+            **band_solve.oom_state(band, cfg),
         })
         if cfg.control.verbosity >= 1:
             logger.warning(
@@ -1069,37 +802,13 @@ def _run_scf_inner(
             tau_g = np.zeros((ns, ng), dtype=np.complex128)
         with profile("scf::potential"):
             pot = generate_potential(ctx, rho_g, xc, mag_g, tau_g=tau_g)
+        # the OOM ladder may swap the band solve for the chunked projectors
+        band = band_solve.degrade(band, d, cfg)
         # the diverged wave functions are part of the poisoned trajectory:
         # restart the band solve from a fresh LCAO subspace
-        psi = None
-        pr = pi = None
-        x_packed = [None] * ns
-        if gsh is not None:
-            gsh["psi"] = None
-        psi_big = _initial_subspace(ctx)
-        # the band-solve branches that rebind evals leave a read-only view
-        # of a device array behind; the in-place writers (chunked/gamma
-        # paths) the ladder may switch to need a writable buffer
-        evals = np.array(evals)
-        if d.shrink_beta_budget:
-            # OOM-ladder rung 0 (repeatable): quarter the dense-beta
-            # engagement budget to below the current table's footprint and
-            # halve the chunk size, so the next band solve allocates
-            # strictly less HBM than the one that exhausted it
-            cfg.control.beta_chunk_budget_bytes = min(
-                float(cfg.control.beta_chunk_budget_bytes) / 4.0,
-                bc_foot / 2.0)
-            cfg.control.beta_chunk_size = max(
-                16, int(cfg.control.beta_chunk_size) // 2)
-        if (d.shrink_beta_budget or d.force_beta_chunked) and _bchunk_ok \
-                and (d.force_beta_chunked or bchunk is not None
-                     or bc_foot > cfg.control.beta_chunk_budget_bytes):
-            # (re)engage the chunked projector path; params rebuild lazily
-            # at the next band solve (dtype mismatch forces make_chunked_hk
-            # at the new beta_chunk_size)
-            bchunk = {"params": None, "dtype": None}
+        band.restart(_initial_subspace(ctx))
         if fused is not None:
-            if d.disable_device or bchunk is not None:
+            if d.disable_device or not band.feeds_fused:
                 # rung 2: remaining iterations on the host path, which
                 # re-validates every field per iteration (the chunked
                 # projector path also runs under the host loop)
@@ -1126,14 +835,9 @@ def _run_scf_inner(
             x_now = np.array(x_mix)
             hist = mixer.export_history()
             ev_h = np.asarray(evals)
-        if pr is not None:
-            from sirius_tpu.parallel.batched import join_cplx as _jc
-
-            psi_h = np.asarray(_jc(pr, pi), dtype=np.complex128)
-        elif psi is not None:
-            psi_h = np.asarray(psi, dtype=np.complex128)
-        else:
-            psi_h = None
+        psi_h = band.host_psi()
+        if psi_h is not None:
+            psi_h = np.asarray(psi_h, dtype=np.complex128)
         r_s, m_s, _, _, pdm_s, _ = unpack(x_now)
         scf_state = {
             "x_mix": x_now,
@@ -1278,10 +982,9 @@ def _run_scf_inner(
     # everything since run_scf entry (context/tables/initial guess/fused
     # compile trigger) is the setup span
     # ... and it says which mesh factorisation the job runs on, if any
-    _mesh = gsh["mesh"] if gsh is not None else scf_mesh
     _setup_span.close(
         fused=fused is not None,
-        **({} if _mesh is None else {"mesh": dict(_mesh.shape)}))
+        **({} if band.mesh is None else {"mesh": dict(band.mesh.shape)}))
     _it_t0 = time.time()
     for it in range(it0, p.num_dft_iter):
         _close_iteration()
@@ -1334,506 +1037,53 @@ def _run_scf_inner(
                 d_by_spin = paw_mod.add_dij_to_d(paw, paw_res["dij_atoms"], d_by_spin)
             v0 = float(np.real(pot.veff_g[0]))
             _sp.close()
+            inputs = band_solve.Inputs(
+                pot=pot, d_by_spin=d_by_spin, v0=v0, vhub=vhub)
+        else:
+            # only the leaves the solve reads: all of fused_out held here
+            # would keep the previous step's residual and density-matrix
+            # buffers alive through the next step
+            inputs = band_solve.Inputs(
+                fused_out={k: fused_out[k]
+                           for k in ("veff_r_coarse", "dion", "h_diag")},
+                v0=float(fused_np[S_V0]), vhub=vhub)
         _bs_span = _stage("scf.band_solve", it=it + 1,
                           num_steps=itsol.num_steps)
         _bs_t0 = time.perf_counter()
-        rows_per_box = 1  # band rows a complex FFT box carries (2 at Gamma)
         with profile("scf::band_solve"):
-            if gsh is not None:
-                from sirius_tpu.ops.hamiltonian import real_dtype_of
-                from sirius_tpu.parallel.dist_fft import (
-                    reorder_from_gshard,
-                    reorder_to_gshard,
-                )
-
-                if gsh["dtype"] != wf_dtype:
-                    # fp32 -> fp64 polish: rebuild ekin/mask/beta tables at
-                    # the new precision (the serial path gets this from the
-                    # (ik, dtype)-keyed hk_params cache)
-                    gsh = _setup_gshard(wf_dtype)
-
-                if psi is None and psi_big is not None:
-                    # one-off LCAO subspace init on the replicated path
-                    params = hk_params(
-                        0, pot.veff_r_coarse[0], d_by_spin[0], wf_dtype
-                    )
-                    xb = psi_big[0, 0] * np.asarray(ctx.gkvec.mask[0])
-                    hx, sx = apply_h_s(params, jnp.asarray(xb, dtype=wf_dtype))
-                    psi = np.zeros(
-                        (1, 1, nb, ctx.gkvec.ngk_max), dtype=np.complex128
-                    )
-                    psi[0, 0] = _subspace_rotate_host(
-                        xb, np.asarray(hx, dtype=np.complex128),
-                        np.asarray(sx, dtype=np.complex128), nb,
-                    )
-                    count_applies(counters, [(psi_big.shape[2], 1)])
-                    psi_big = None
-                x0 = gsh["psi"]
-                if x0 is None:
-                    x0 = jax.device_put(
-                        reorder_to_gshard(
-                            np.asarray(psi[0, 0]).astype(wf_dtype),
-                            gsh["order"],
-                        ),
-                        gsh["sharding"],
-                    )
-                h_diag, o_diag = _h_o_diag(ctx, 0, v0, d_by_spin[0])
-                hd = reorder_to_gshard(np.asarray(h_diag), gsh["order"])
-                od = reorder_to_gshard(np.asarray(o_diag), gsh["order"])
-                od[od == 0.0] = 1.0  # padding slots: finite preconditioner
-                rdt = real_dtype_of(wf_dtype)
-                # every operand placed on the "g" mesh in the working
-                # precision (an f64 potential would promote the c64 apply)
-                veff_d = jax.device_put(
-                    np.asarray(pot.veff_r_coarse[0], dtype=rdt),
-                    gsh["fn"].sharding_veff,
-                )
-                ev, x, rn = davidson(
-                    gsh["fn"],
-                    (veff_d, jax.device_put(
-                        np.asarray(d_by_spin[0], dtype=rdt), gsh["sh_rep"])),
-                    x0,
-                    jax.device_put(np.asarray(hd, dtype=rdt), gsh["sh_g"]),
-                    jax.device_put(np.asarray(od, dtype=rdt), gsh["sh_g"]),
-                    gsh["mask"],
-                    num_steps=itsol.num_steps,
-                    res_tol=_rtol(rdt),
-                )
-                gsh["psi"] = x
-                evals[0, 0] = np.asarray(ev)
-                # host round-trip for the density consumer; a device-side
-                # gather + sharded density accumulation would avoid it
-                # (known cost on this path — the band solve dominates)
-                psi = jnp.asarray(
-                    reorder_from_gshard(
-                        np.asarray(x), gsh["order"], ctx.gkvec.ngk_max
-                    )
-                )[None, None]
-            elif bchunk is not None:
-                # chunk-generated projectors: the H/S application rebuilds
-                # each atom chunk's beta block on the fly (lax.scan), so the
-                # dense [nbeta, ngk] table never exists on device
-                from sirius_tpu.ops.beta_chunked import (
-                    apply_h_s_chunked,
-                    make_chunked_hk,
-                    pack_dmat_chunks,
-                )
-                from sirius_tpu.ops.hamiltonian import real_dtype_of
-
-                rdt = real_dtype_of(wf_dtype)
-                if bchunk["dtype"] != wf_dtype:
-                    bchunk["params"] = make_chunked_hk(
-                        ctx, 0, dtype=wf_dtype,
-                        chunk=cfg.control.beta_chunk_size,
-                    )
-                    bchunk["dtype"] = wf_dtype
-                prm = dict(
-                    bchunk["params"],
-                    veff_r=jnp.asarray(pot.veff_r_coarse[0], dtype=rdt),
-                    dmat=jnp.asarray(
-                        pack_dmat_chunks(
-                            ctx, np.real(np.asarray(d_by_spin[0])),
-                            cfg.control.beta_chunk_size,
-                        ),
-                        dtype=rdt,
-                    ),
-                )
-                if psi is None and psi_big is not None:
-                    # one-off LCAO subspace init through the chunked apply
-                    xb = psi_big[0, 0] * np.asarray(ctx.gkvec.mask[0])
-                    hx, sx = apply_h_s_chunked(
-                        prm, jnp.asarray(xb, dtype=wf_dtype)
-                    )
-                    psi = np.zeros(
-                        (1, 1, nb, ctx.gkvec.ngk_max), dtype=np.complex128
-                    )
-                    psi[0, 0] = _subspace_rotate_host(
-                        xb, np.asarray(hx, dtype=np.complex128),
-                        np.asarray(sx, dtype=np.complex128), nb,
-                    )
-                    count_applies(counters, [(psi_big.shape[2], 1)])
-                    psi_big = None
-                h_diag, o_diag = _h_o_diag(ctx, 0, v0, d_by_spin[0])
-                ev, x, rn = davidson(
-                    apply_h_s_chunked, prm,
-                    jnp.asarray(np.asarray(psi[0, 0]), dtype=wf_dtype),
-                    jnp.asarray(h_diag, dtype=rdt),
-                    jnp.asarray(o_diag, dtype=rdt),
-                    jnp.asarray(ctx.gkvec.mask[0], dtype=rdt),
-                    num_steps=itsol.num_steps,
-                    res_tol=res_tol,
-                )
-                evals[0, 0] = np.asarray(ev)
-                psi = np.asarray(x).astype(np.complex128)[None, None]
-            elif gamma_bands:
-                from sirius_tpu.ops import gamma as gmod
-                from sirius_tpu.ops.hamiltonian import real_dtype_of
-                from sirius_tpu.parallel.batched import (
-                    compute_h_diag,
-                    compute_o_diag,
-                )
-
-                rows_per_box = gmod.ROWS_PER_BOX
-                rdt = real_dtype_of(wf_dtype)
-                if x_packed[0] is not None and x_packed[0].dtype != np.dtype(rdt):
-                    # fp32 -> fp64 polish: re-cast the packed block
-                    x_packed = [_up(x, rdt) for x in x_packed]
-                if psi is not None and x_packed[0] is None:
-                    # restart / warm start from full complex psi
-                    x_packed = [
-                        _up(gmod.pack(gm, np.asarray(psi[0, ispn])), rdt)
-                        for ispn in range(ns)
-                    ]
-                if rdt not in gamma_cache:
-                    # constant tables (packed beta, gather maps, the packed
-                    # S diagonal and the gather of pack_diags_device)
-                    # uploaded once per precision; per-iteration leaves are
-                    # swapped in below
-                    gamma_cache.clear()
-                    gamma_cache[rdt] = jax.tree_util.tree_map(_up, (
-                        gmod.make_gamma_params(
-                            ctx, np.zeros(ctx.fft_coarse.dims), gm,
-                            rdtype=rdt),
-                        gmod.pack_index(gm, ctx.gkvec.ngk_max),
-                        np.asarray(compute_o_diag(ctx)[0], dtype=rdt)))
-                gp0, pidx, o_diag_dev = gamma_cache[rdt]
-                # once the fused step has run, the potential, the screened
-                # D and the H diagonal of the next solve are its outputs,
-                # already on the device; the host potential feeds the first
-                # iteration and the one after a rollback
-                dev_inputs = None
-                if fused is not None and fused_out is not None:
-                    dev_inputs = gmod.solve_inputs_device(
-                        pidx, gp0.mask_p, o_diag_dev,
-                        fused_out["veff_r_coarse"], fused_out["dion"],
-                        fused_out["h_diag"])
-                ev_spin = []
-                for ispn in range(ns):
-                    if dev_inputs is not None:
-                        veff_s, dion_s, hd_p, od_p = dev_inputs[ispn]
-                    else:
-                        veff_s = _up(pot.veff_r_coarse[ispn], rdt)
-                        dion_s = _up(np.real(d_by_spin[ispn]), rdt)
-                        h_diag = compute_h_diag(
-                            ctx, np.asarray(d_by_spin[ispn])[None], v0)[0, 0]
-                        hd_p, od_p = gmod.pack_diags_device(
-                            pidx, gp0.mask_p, _up(h_diag, rdt), o_diag_dev)
-                    gp = gp0._replace(veff_r=veff_s, dion=dion_s)
-                    if x_packed[ispn] is None:
-                        # first iteration: rotate the packed LCAO block to
-                        # the lowest nb Ritz vectors (initialize_subspace)
-                        x_packed[ispn] = gmod.initialize_subspace_gamma(
-                            gp, _up(gmod.pack(gm, psi_big[0, ispn]), rdt), nb)
-                        count_applies(counters, [(psi_big.shape[2], 1)],
-                                      rows_per_box=rows_per_box)
-                    ev, x_packed[ispn], rn = gmod.davidson_gamma(
-                        gp, x_packed[ispn], hd_p, od_p,
-                        num_steps=itsol.num_steps,
-                        res_tol=_up(_rtol(rdt)),
-                    )
-                    ev_spin.append(ev)
-                psi_big = None
-                if fused is not None:
-                    # the packed block and the eigenvalues stay on the
-                    # device; the tail below takes the band block as the
-                    # (re, im) pair of its sphere coefficients, and the
-                    # host complex psi is joined from that pair once, after
-                    # the loop (or by an autosave when one is due)
-                    pr, pi = (a[None] for a in gmod.unpack_device(
-                        gp0, jnp.stack(x_packed)))
-                    ev_dev = jnp.stack(ev_spin)[None].astype(fused.rdt)
-                    psi = None
-                else:
-                    psi = np.zeros(
-                        (1, ns, nb, ctx.gkvec.ngk_max), dtype=np.complex128
-                    )
-                    for ispn in range(ns):
-                        evals[0, ispn] = np.asarray(ev_spin[ispn])
-                        psi[0, ispn] = gmod.unpack(
-                            gm, np.asarray(x_packed[ispn]))
-            elif serial_bands:
-                if psi is None and psi_big is not None:
-                    # first iteration from a fresh LCAO block: rotate the
-                    # full atomic-orbital subspace down to nb Ritz vectors
-                    # (reference initialize_subspace)
-                    psi0 = np.zeros(
-                        (nk, ns, nb, ctx.gkvec.ngk_max), dtype=np.complex128
-                    )
-                    for ik in range(nk):
-                        for ispn in range(ns):
-                            params = hk_params(
-                                ik, pot.veff_r_coarse[ispn], d_by_spin[ispn],
-                                wf_dtype,
-                                vhub_s=None if vhub is None else vhub[ik, ispn],
-                            )
-                            xb = psi_big[ik, ispn] * np.asarray(ctx.gkvec.mask[ik])
-                            hx, sx = apply_h_s(params, jnp.asarray(xb, dtype=wf_dtype))
-                            psi0[ik, ispn] = _subspace_rotate_host(
-                                xb,
-                                np.asarray(hx, dtype=np.complex128),
-                                np.asarray(sx, dtype=np.complex128),
-                                nb,
-                            )
-                    count_applies(counters, [(psi_big.shape[2], 1)], copies=nk * ns)
-                    psi = psi0
-                    psi_big = None
-                new_psi = []
-                for ik in range(nk):
-                    per_spin = []
-                    for ispn in range(ns):
-                        from sirius_tpu.ops.hamiltonian import real_dtype_of
-
-                        params = hk_params(
-                            ik, pot.veff_r_coarse[ispn], d_by_spin[ispn], wf_dtype,
-                            vhub_s=None if vhub is None else vhub[ik, ispn],
-                        )
-                        h_diag, o_diag = _h_o_diag(ctx, ik, v0, d_by_spin[ispn])
-                        rdt = real_dtype_of(wf_dtype)
-                        ev, x, rn = davidson(
-                            apply_h_s,
-                            params,
-                            psi[ik, ispn].astype(wf_dtype),
-                            jnp.asarray(h_diag, dtype=rdt),
-                            jnp.asarray(o_diag, dtype=rdt),
-                            params.mask,
-                            num_steps=itsol.num_steps,
-                            res_tol=res_tol,
-                        )
-                        evals[ik, ispn] = np.asarray(ev)
-                        per_spin.append(x)
-                    new_psi.append(jnp.stack(per_spin))
-                psi = jnp.stack(new_psi)
-            else:
-                # production path: the whole (k, spin) set as ONE program
-                # (parallel/batched.py; shards over the ("k", "b") mesh).
-                # Real-boundary: psi crosses the jit boundary as a (re, im)
-                # pair.
-                from sirius_tpu.ops.hamiltonian import real_dtype_of
-                from sirius_tpu.parallel.batched import (
-                    davidson_kset,
-                    join_cplx,
-                    split_cplx,
-                )
-
-                rdt = real_dtype_of(wf_dtype)
-                if (
-                    fused is not None and fused_out is not None
-                    and wf_dtype in _kset_cache
-                ):
-                    # device-resident refresh: the fused step already
-                    # produced veff_r/D/h_diag on device — swap them into
-                    # the cached params without any host round-trip
-                    _kset_cache[wf_dtype] = _kset_cache[wf_dtype]._replace(
-                        veff_r=fused_out["veff_r_coarse"].astype(rdt),
-                        dion=fused_out["dion"].astype(rdt),
-                        h_diag=fused_out["h_diag"].astype(rdt),
-                    )
-                    ps = _kset_cache[wf_dtype]
-                elif fused is not None and fused_out is not None:
-                    # precision switch (fp32 -> fp64 polish): one-time host
-                    # fetch to build the new-precision constant tables
-                    ps = kset_params(
-                        np.asarray(fused_out["veff_r_coarse"]),
-                        np.asarray(fused_out["dion"]),
-                        float(fused_np[S_V0]), vhub, wf_dtype,
-                    )
-                else:
-                    ps = kset_params(
-                        pot.veff_r_coarse[:ns], np.stack(d_by_spin), v0,
-                        vhub, wf_dtype,
-                    )
-                ps = place_kset_params(ps, scf_mesh, _devs[0])
-                if pr is None and psi is None and psi_big is not None:
-                    # first iteration from a fresh LCAO block: rotate the
-                    # full atomic-orbital subspace down to the lowest nb
-                    # Ritz vectors (reference initialize_subspace.hpp:279)
-                    from sirius_tpu.parallel.batched import (
-                        initialize_subspace_kset,
-                    )
-
-                    pb_re, pb_im = split_cplx(psi_big, rdt)
-                    if scf_mesh is not None:
-                        # the LCAO block has nbig >= nb orbitals — shard it
-                        # over "k" only (nbig need not divide the band axis)
-                        from jax.sharding import (
-                            NamedSharding as _NS,
-                            PartitionSpec as _P,
-                        )
-
-                        _big = _NS(scf_mesh, _P("k", None, None, None))
-                        pb_re = jax.device_put(jnp.asarray(pb_re), _big)
-                        pb_im = jax.device_put(jnp.asarray(pb_im), _big)
-                    pr, pi = initialize_subspace_kset(
-                        ps, jnp.asarray(pb_re), jnp.asarray(pb_im), nb
-                    )
-                    pr, pi = _place_psi(pr), _place_psi(pi)
-                    count_applies(counters, [(psi_big.shape[2], 1)], copies=nk * ns)
-                    psi_big = None
-                if pr is None or pr.dtype != np.dtype(rdt):
-                    # initial entry or precision switch; psi may be stale
-                    # (None) if the previous iterations kept the pair only
-                    src = psi if psi is not None else join_cplx(pr, pi)
-                    pr, pi = split_cplx(np.asarray(src), rdt)
-                    pr, pi = _place_psi(jnp.asarray(pr)), _place_psi(jnp.asarray(pi))
-                if mgga and pot.vtau_r_coarse is not None:
-                    from sirius_tpu.ops.mgga import davidson_kset_mgga
-
-                    ev, pr, pi, rn = davidson_kset_mgga(
-                        ps, jnp.asarray(pot.vtau_r_coarse, dtype=rdt),
-                        _gkc_dev(rdt), pr, pi,
-                        num_steps=itsol.num_steps,
-                        res_tol=res_tol,
-                    )
-                else:
-                    ev, pr, pi, rn = davidson_kset(
-                        ps, pr, pi,
-                        num_steps=itsol.num_steps,
-                        res_tol=_rtol(rdt),
-                    )
-                # canonicalize the pair onto the explicit psi sharding (a
-                # no-op when GSPMD already placed it there): downstream
-                # consumers must see the SAME placement whether psi came
-                # from this solve or from a mid-SCF resume warm start,
-                # or the executables (and their reduction orders) differ
-                # and break bit-reproducible resume
-                pr, pi = _place_psi(pr), _place_psi(pi)
-                # psi stays device-resident as the (pr, pi) pair between
-                # iterations; the complex host copy is materialized only for
-                # consumers that need it (Hubbard occupations each
-                # iteration, forces/stress/checkpoint after the loop)
-                psi = join_cplx(pr, pi) if hub is not None else None
-                if fused is not None:
-                    # eigenvalues stay on device; the host copy is fetched
-                    # once after the loop for the final report
-                    ev_dev = ev.astype(fused.rdt)
-                else:
-                    evals = np.asarray(ev, dtype=np.float64)
-            # H*psi application count (reference num_loc_op_applied counter)
-            # and the FFT boxes behind it
-            count_applies(counters, apply_blocks(itsol.num_steps, nb),
-                          copies=nk * ns, rows_per_box=rows_per_box)
+            out = band.solve(inputs, res_tol, wf_dtype,
+                             tail_rdt=None if fused is None else fused.rdt)
         if _span_fence:
             # the host tails already fenced via np.asarray(ev); only a
-            # device-resident (fused) solve still has compute in flight
-            if fused is not None:
-                _fence((ev_dev, pr, pi))
-            elif pr is not None:
-                _fence((pr, pi))
-        if gsh is not None and gsh.get("probe"):
-            # split the measured solve wall into collective vs compute:
-            # fenced per-collective probe costs (probe_collectives, taken
-            # once at setup) x the analytic H-application row count. A
-            # host timer cannot see inside the jitted apply, so this is a
-            # model (attrs say so) — cross-checked by bench_gshard_large
-            # against the 1-device baseline.
-            from sirius_tpu.solvers.davidson import num_applies as _napp
-
-            _bs_dt = time.perf_counter() - _bs_t0
-            _bs_ns = time.time_ns() - int(_bs_dt * 1e9)
-            _pb = gsh["probe"]
-            _rows = nk * ns * _napp(itsol.num_steps, nb)
-            _coll = sum(
-                v for k, v in _pb["per_call"].items()
-                if k != "collective.fft_local"
-            ) / _pb["batch"] * _rows
-            _coll = min(_coll, _bs_dt)
-            # a model's split of the measured interval, not two
-            # measurements: recorded from outside, under the band solve
-            obs_spans.record("scf.band_solve.collective", _coll,
-                             start_unix_ns=_bs_ns, it=it + 1,
-                             method="probe", ndev=ndev)
-            obs_spans.record("scf.band_solve.compute", _bs_dt - _coll,
-                             start_unix_ns=_bs_ns + int(_coll * 1e9),
-                             it=it + 1, method="probe", ndev=ndev)
+            # device-resident solve still has compute in flight
+            _fence(out)
+        band.after_solve(_bs_t0, it)
         _bs_span.close()
         # --- band-solve supervision (dft/recovery.py): a stagnated or
         # blown-up solve is retried with a deeper subspace; the serial
         # debug path additionally falls back to dense diagonalization for
-        # small |G+k| spheres (the reference's "robust" exact-solver
-        # escape hatch). Host paths only — the fused loop's scalar record
-        # already carries an all-finite eigenvalue sentinel, and checking
-        # rn here would add per-iteration device->host traffic. On the
-        # serial multi-k path rn covers the last (k, spin) solve, a proxy
-        # that still catches whole-solve stagnation.
+        # small |G+k| spheres (band_solve's rescue methods). Host tail
+        # only — the fused loop's scalar record already carries an
+        # all-finite eigenvalue sentinel, and checking rn here would add
+        # per-iteration device->host traffic.
         if fused is None and sup.enabled:
             from sirius_tpu.solvers.davidson import residual_health
 
             rn_max, rn_ok = residual_health(
-                rn, blowup=cfg.control.band_residual_blowup)
+                out.rn, blowup=cfg.control.band_residual_blowup)
             if faults.armed("scf.band_stagnate", it):
                 rn_ok = False
-            if not rn_ok:
-                rescued = False
-                if (not serial_bands and not gamma_bands and gsh is None
-                        and bchunk is None and not mgga):
-                    # batched production path: one deeper retry, warm-
-                    # started from the stagnated block (static num_steps
-                    # means this compiles once and is then cached)
-                    from sirius_tpu.parallel.batched import (
-                        davidson_kset as _dk,
-                        join_cplx as _jcx,
-                    )
-
-                    ev, pr, pi, rn = _dk(
-                        ps, pr, pi, num_steps=2 * itsol.num_steps,
-                        res_tol=res_tol,
-                    )
-                    evals = np.asarray(ev, dtype=np.float64)
-                    if hub is not None:
-                        psi = _jcx(pr, pi)
-                    rescued = True
-                elif serial_bands and int(ctx.gkvec.ngk_max) <= int(
-                        cfg.control.exact_diag_max_ngk):
-                    from sirius_tpu.solvers.eigen import (
-                        build_h_s_matrices,
-                        exact_diag,
-                    )
-
-                    try:
-                        psi_r = np.asarray(psi, dtype=np.complex128).copy()
-                        qmat = (
-                            None if ctx.beta.qmat is None
-                            else np.asarray(ctx.beta.qmat)
-                        )
-                        for ik in range(nk):
-                            n_gk = int(ctx.gkvec.num_gk[ik])
-                            gkd = {
-                                "millers": np.asarray(
-                                    ctx.gkvec.millers[ik][:n_gk]),
-                                "ekin": np.asarray(
-                                    ctx.gkvec.kinetic()[ik][:n_gk]),
-                            }
-                            bk = (
-                                np.asarray(ctx.beta.beta_gk[ik])
-                                if ctx.beta.num_beta_total else None
-                            )
-                            for ispn in range(ns):
-                                vg = np.asarray(pot.veff_g)
-                                if polarized and pot.bz_g is not None:
-                                    vg = vg + np.asarray(
-                                        pot.bz_g if ispn == 0 else -pot.bz_g
-                                    )
-                                h, s = build_h_s_matrices(
-                                    gkd, vg, ctx.gvec.index_of_millers,
-                                    beta_k=bk,
-                                    dion=np.asarray(d_by_spin[ispn]),
-                                    qmat=qmat,
-                                )
-                                ev_d, vec = exact_diag(h, s, nb)
-                                evals[ik, ispn] = ev_d
-                                psi_r[ik, ispn] = 0.0
-                                psi_r[ik, ispn, :nb, :n_gk] = vec.T
-                        psi = psi_r
-                        rescued = True
-                    except ValueError:
-                        # fine G set lacks some G-G' differences
-                        # (pw_cutoff < 2*gk_cutoff): keep the iterative
-                        # result rather than build a truncated dense H
-                        pass
-                if rescued and cfg.control.verbosity >= 1:
+            rescued = None if rn_ok else band.rescue(inputs, out, res_tol)
+            if rescued is not None:
+                out = rescued
+                if cfg.control.verbosity >= 1:
                     logger.warning(
                         "band-solve rescue at it=%d (max rnorm %.2e)",
                         it + 1, rn_max)
+        if fused is not None:
+            ev_dev = out.ev
+        else:
+            evals = out.ev
         if _cks.enabled():
             _cks.checksum("evals", evals)
 
@@ -1857,26 +1107,16 @@ def _run_scf_inner(
                     _fence(occ_w)
                 _sp.close()
                 _sp = _stage("scf.density", it=it + 1)
-                from sirius_tpu.ops.gamma import density_gamma
-                from sirius_tpu.parallel.batched import (
-                    density_kset,
-                    density_matrix_kset,
-                )
+                from sirius_tpu.parallel.batched import density_matrix_kset
 
-                if gamma_bands:
-                    # real field per band: |Re psi(r)|^2 off the packed block
-                    acc = density_gamma(
-                        gamma_cache[rdt][0], jnp.stack(x_packed),
-                        occ_w.reshape(ns, nb))
-                else:
-                    acc = density_kset(ps, pr, pi, occ_w)
+                acc = band.density_acc(occ_w)
                 # fault site: NaN into the accumulated density (functional
                 # device-side update; a no-op dict lookup when unarmed, so
                 # the transfer-guard contract of this span is preserved)
                 acc = faults.corrupt("scf.density", it, acc)
                 if fused.has_aug and fused_beta is not None:
                     dm_re, dm_im = density_matrix_kset(
-                        *fused_beta, pr, pi, occ_w
+                        *fused_beta, out.pr, out.pi, occ_w
                     )
                 else:
                     dm_re, dm_im = fused_dm0
@@ -1886,7 +1126,7 @@ def _run_scf_inner(
                 _sp = _stage("scf.fused_step", it=it + 1)
                 fused_carry, fused_out = fused.step(
                     fused_carry, acc, dm_re, dm_im, ev_dev, occ_w,
-                    entropy_sum, pr, pi,
+                    entropy_sum, out.pr, out.pi,
                 )
                 if _span_fence:
                     _fence(fused_out)
@@ -2015,7 +1255,7 @@ def _run_scf_inner(
         om_nl_new = None
         if hub is not None:
             om_new, occ_T = occupation_matrix(
-                ctx, hub, psi, occ_np, ctx.max_occupancy
+                ctx, hub, band.host_psi(), occ_np, ctx.max_occupancy
             )
             # Constrained-occupancy runs keep the RAW k-weighted om: the
             # stable dual-ascent drives the om to a target that is NOT
@@ -2054,46 +1294,39 @@ def _run_scf_inner(
         _sp = _stage("scf.density", it=it + 1)
         occ_w = jnp.asarray(occ_np * ctx.kweights[:, None, None])
         with profile("scf::density"):
-            if (serial_bands or gamma_bands or gsh is not None
-                    or bchunk is not None):
-                rho_spin = generate_density_g(ctx, psi, occ_np)
+            from sirius_tpu.dft.density import density_from_coarse_acc
+
+            # the solver's own coarse-box accumulator where it has one,
+            # the host wave functions otherwise
+            acc_h = band.density_acc(occ_w)
+            if acc_h is None:
+                rho_spin = generate_density_g(ctx, band.host_psi(), occ_np)
             else:
-                from sirius_tpu.dft.density import density_from_coarse_acc
-                from sirius_tpu.parallel.batched import density_kset
-
-                rho_spin = density_from_coarse_acc(
-                    ctx, np.asarray(density_kset(ps, pr, pi, occ_w))
-                )
-                if mgga:
-                    from sirius_tpu.ops.mgga import tau_kset
-
-                    tau_acc = np.asarray(tau_kset(
-                        ps.fft_index, _gkc_dev(rdt), pr, pi, occ_w,
-                        tuple(ctx.fft_coarse.dims),
-                    ))
-                    # same 1/Omega + coarse->fine mapping as the density;
-                    # tau transforms as a scalar field, so the reduced
-                    # k-wedge sum needs the same point-group symmetrization
-                    # as rho
-                    tau_g = density_from_coarse_acc(ctx, tau_acc)
-                    if do_symmetrize:
-                        tau_g = np.stack(
-                            [symmetrize_pw(ctx, t) for t in tau_g]
-                        )
-                    # NOTE: the potential is built from the MIXED density
-                    # but the FRESH tau of the current wave functions (tau
-                    # is psi-derived and not part of the mixing vector);
-                    # near self-consistency the pair is consistent, and the
-                    # SCAN smoke test covers the transient
+                rho_spin = density_from_coarse_acc(ctx, np.asarray(acc_h))
+            if mgga:
+                # same 1/Omega + coarse->fine mapping as the density; tau
+                # transforms as a scalar field, so the reduced k-wedge sum
+                # needs the same point-group symmetrization as rho
+                tau_g = density_from_coarse_acc(
+                    ctx, np.asarray(band.tau_acc(occ_w)))
+                if do_symmetrize:
+                    tau_g = np.stack(
+                        [symmetrize_pw(ctx, t) for t in tau_g]
+                    )
+                # NOTE: the potential is built from the MIXED density but
+                # the FRESH tau of the current wave functions (tau is
+                # psi-derived and not part of the mixing vector); near
+                # self-consistency the pair is consistent, and the SCAN
+                # smoke test covers the transient
         dm_blocks_by_spin = []
         if ctx.aug is not None:
             from sirius_tpu.dft.density import symmetrize_density_matrix
             from sirius_tpu.parallel.batched import density_matrix_kset, split_cplx
 
-            if pr is not None:
-                ppair = (pr, pi)  # batched path: already device-resident
+            if out.pr is not None:
+                ppair = (out.pr, out.pi)  # already device-resident
             else:
-                ppair = split_cplx(np.asarray(psi))
+                ppair = split_cplx(np.asarray(band.host_psi()))
             dm_re, dm_im = density_matrix_kset(*beta_dev, *ppair, occ_w)
             from sirius_tpu.parallel.batched import join_cplx as _jc
 
@@ -2255,13 +1488,13 @@ def _run_scf_inner(
         # — same invariants from the same operands, so the fused values can
         # be validated against this path (tests/test_fused_scf.py)
         ledger = None
-        if pr is not None:
+        if out.pr is not None:
             _sym_resid = (
                 float(np.max(np.abs(symmetrize_pw(ctx, rho_new) - rho_new)))
                 if do_symmetrize else 0.0
             )
             ledger = obs_numerics.ledger_host(
-                np.asarray(pr) + 1j * np.asarray(pi),
+                np.asarray(out.pr) + 1j * np.asarray(out.pi),
                 np.asarray(ctx.beta.beta_gk)
                 if ctx.beta.num_beta_total else None,
                 ctx.beta.qmat, ctx.beta.dion,
@@ -2294,11 +1527,11 @@ def _run_scf_inner(
         # in-loop precision-headroom probes (obs/numerics.py): shadow
         # re-execution of the post-band stages at degraded precision on
         # the current iterate, every numerics_probe_every iterations
-        if (_numerics_probe and pr is not None
+        if (_numerics_probe and out.pr is not None
                 and (it + 1) % _numerics_every == 0):
             _sp = _stage("scf.numerics_probe", it=it + 1)
             _stages = obs_numerics.probe_stages(
-                ctx, xc, np.asarray(pr) + 1j * np.asarray(pi), occ_np,
+                ctx, xc, np.asarray(out.pr) + 1j * np.asarray(out.pi), occ_np,
                 np.asarray(evals), rho_g, mag_g,
                 mixer_beta=mixer.beta, smearing=p.smearing,
                 smearing_width=float(p.smearing_width),
@@ -2323,8 +1556,6 @@ def _run_scf_inner(
             and rms < cfg.settings.fp32_to_fp64_rms
         ):
             wf_dtype = jnp.complex128
-            if gsh is not None:
-                gsh["psi"] = None  # rebuild the sharded block in fp64
             continue
         # autosave AFTER e_prev/precision bookkeeping: the saved state must
         # be exactly what the next iteration of an uninterrupted run would
@@ -2343,32 +1574,24 @@ def _run_scf_inner(
     # read-only record of the path taken and of where each stage of the last
     # iteration ran and in which dtype, read off the arrays themselves
     placement = {
-        "path": ("gshard" if gsh is not None
-                 else "beta_chunked" if bchunk is not None
-                 else "gamma" if gamma_bands
-                 else "serial" if serial_bands
-                 else "batched+fused" if fused is not None else "batched"),
+        "path": band.name_fused if fused is not None else band.name,
         "devices": [str(d) for d in _devs],
-        "mesh": (dict(gsh["mesh"].shape) if gsh is not None
-                 else None if scf_mesh is None else dict(scf_mesh.shape)),
+        "mesh": None if band.mesh is None else dict(band.mesh.shape),
     }
     if fused is not None and fused_out is not None:
         placement.update(
-            band_solve=runtime.where(x_packed[0] if gamma_bands else pr),
+            band_solve=runtime.where(band.placed()),
             occupations=runtime.where(occ_w),
             density=runtime.where(acc),
             fused_step=runtime.where(fused_out["scalars"]),
             mixing=runtime.where(fused_carry.x_re),
             potential=runtime.where(fused_out["veff_r_coarse"]),
             psi_shard_devices=sorted(
-                s.device.id for s in pr.addressable_shards),
+                s.device.id for s in out.pr.addressable_shards),
         )
     elif num_iter_done > it0:
         placement.update(
-            band_solve=runtime.where(
-                gsh["psi"] if gsh is not None
-                else x_packed[0] if gamma_bands
-                else pr if pr is not None else psi),
+            band_solve=runtime.where(band.placed()),
             occupations=runtime.where(occ),
             density=runtime.where(rho_spin),
             mixing=runtime.where(x_mix),
@@ -2389,15 +1612,12 @@ def _run_scf_inner(
         dm_blocks_by_spin = fin["dm_blocks_by_spin"]
         with profile("scf::potential"):
             pot = generate_potential(ctx, rho_g, xc, mag_g)
-    if psi is None and pr is not None:
-        from sirius_tpu.parallel.batched import join_cplx
-
-        psi = join_cplx(pr, pi)
-    elif psi is None:
+    psi = band.host_psi()
+    if psi is None:
         # num_dft_iter == 0: no band solve ran, so the LCAO block was never
         # rotated; report its first nb rows for shape-valid output ONLY —
         # this truncation must not be persisted as a warm start
-        psi = psi_big[:, :, :nb] if psi_big is not None else None
+        psi = band.psi_big[:, :, :nb] if band.psi_big is not None else None
         keep_state = False
         save_to = None
     occ_np = np.asarray(occ)
@@ -2414,7 +1634,7 @@ def _run_scf_inner(
     result = {
         "converged": converged,
         "num_scf_iterations": num_iter_done,
-        "gshard_devices": ndev if gsh is not None else 0,
+        "gshard_devices": band.gshard_devices,
         "efermi": float(mu),
         "band_gap": band_gap,
         "rho_min": float(rho_r.min()),

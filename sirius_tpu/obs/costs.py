@@ -226,7 +226,7 @@ def hpsi_flops(nb: int, ngk: int, nbeta: int, box) -> float:
     the reference self-reports as GFLOPS): per band two complex FFTs on
     the coarse box, the pointwise V multiply, the kinetic diagonal, and
     the beta-projector einsums (project, D/Q apply, expand for both H
-    and S; 8 flops/cmac). Identical to the historical bench.py model."""
+    and S; 8 flops/cmac)."""
     n = _nbox(box)
     fft = 2 * 5.0 * n * math.log2(max(n, 2))
     local = 7.0 * n + 8.0 * ngk

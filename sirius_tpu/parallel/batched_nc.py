@@ -58,7 +58,7 @@ def make_nc_set_params(
 
     prev: pass the previous iteration's params to reuse the constant device
     tables (projectors, kinetic, masks, Q) — only the potential-dependent
-    leaves are re-uploaded (like the collinear _kset_cache in dft/scf.py)."""
+    leaves are re-uploaded (like KsetSolver._params in dft/band_solve.py)."""
     from sirius_tpu.ops.hamiltonian import real_dtype_of
     from sirius_tpu.parallel.batched import split_cplx
 

@@ -45,17 +45,6 @@ def test_hpsi_flops_hand_count():
     assert costs.hpsi_flops(6, 100, 5, (8, 8, 8)) == 6 * with_beta
 
 
-def test_bench_delegates_to_shared_model():
-    # satellite: bench.py's private copies are now thin wrappers — the
-    # two modules can never disagree again
-    import bench
-
-    assert bench._hpsi_flops(8, 200, 18, (12, 12, 12)) == costs.hpsi_flops(
-        8, 200, 18, (12, 12, 12))
-    assert bench._peak_gflops("TPU v5 lite") == costs.peak_gflops(
-        "TPU v5 lite")
-
-
 def test_davidson_applies_matches_solver():
     from sirius_tpu.solvers.davidson import num_applies
 

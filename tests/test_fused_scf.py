@@ -35,7 +35,7 @@ def _run(device_scf, devices=None, plan=None, control=None, run_kw=None,
 
 
 # one compute device: a Gamma-only deck then takes the packed-real band
-# solve (`gamma_bands`), which feeds the same fused tail
+# solve (`band_solve.GammaSolver`), which feeds the same fused tail
 def _one_device():
     return jax.devices()[1:2]
 

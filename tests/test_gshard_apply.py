@@ -78,7 +78,7 @@ def test_gshard_davidson_matches_replicated(setup):
     x0 = (
         rng.standard_normal((nb, ngk)) + 1j * rng.standard_normal((nb, ngk))
     ) * np.asarray(prm.mask)
-    from sirius_tpu.dft.scf import _h_o_diag
+    from sirius_tpu.dft.band_solve import _h_o_diag
 
     h_diag, o_diag = _h_o_diag(ctx, 0, 0.05, ctx.beta.dion)
     ev_ref, _, _ = davidson(

@@ -154,7 +154,7 @@ def _fused_args(fused, ctx, nb, rep, psi_sh, ev_sh):
 
 
 def test_gamma_band_solve_one_chip(topo, no_compile_cache, ctx_gamma):
-    """The packed-real Gamma solve of run_scf's `gamma_bands` path."""
+    """The packed-real Gamma solve of run_scf's `gamma` path (band_solve.GammaSolver)."""
     from sirius_tpu.ops.gamma import (
         build_gamma_map, davidson_gamma, initialize_subspace_gamma,
         make_gamma_params,
@@ -182,7 +182,7 @@ def test_gamma_fused_tail_one_chip(topo, no_compile_cache):
     """The Gamma path's iteration tail at the widths of the benchmark's
     si16-gamma-us (16 atoms, 64 bands, gk 6 / pw 20): the hand-off of the
     packed solve (solve_inputs_device, unpack_device), density_gamma, the
-    density matrix and the fused step, as run_scf's `gamma_bands` branch
+    density matrix and the fused step, as run_scf's `gamma` path
     feeds them."""
     from sirius_tpu.ops.gamma import (
         build_gamma_map, density_gamma, make_gamma_params, pack_index,
