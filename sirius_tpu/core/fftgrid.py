@@ -140,6 +140,44 @@ def g_to_r(coeffs: jax.Array, fft_index: jax.Array, dims: tuple[int, int, int]) 
     return jnp.fft.ifftn(box, axes=(-3, -2, -1)) * n
 
 
+def box_inverse_map(fft_index: np.ndarray, num_points: int) -> np.ndarray:
+    """Inverse of a sphere's fft_index over the whole box: for every box
+    cell the index of the sphere coefficient that lives there, and ng (one
+    past the end) for the cells outside the sphere. int32 [num_points];
+    host-side, built once. The indices must be unique (a G-sphere's are; a
+    padded GkVec's are not and keeps the additive scatter of g_to_r)."""
+    idx = np.asarray(fft_index)
+    ng = len(idx)
+    inv = np.full(num_points, ng, dtype=np.int32)
+    inv[idx] = np.arange(ng, dtype=np.int32)
+    placed = np.count_nonzero(inv != ng)
+    if placed != ng:
+        raise ValueError(
+            "box_inverse_map needs unique box indices: "
+            f"{ng - placed} of {ng} collide"
+        )
+    return inv
+
+
+@partial(jax.jit, static_argnums=(2,))
+def g_to_r_gather(coeffs: jax.Array, inv_index: jax.Array, dims: tuple[int, int, int]) -> jax.Array:
+    """g_to_r for a caller that has few rows and many indices (the fused
+    step: one field of the whole density sphere): the box is filled by a
+    gather through its inverse map (box_inverse_map) instead of a scatter,
+    whose cost on a TPU is per index, not per byte. The same box, bit for
+    bit, as the scatter-add of unique indices into zeros; callers with a
+    block of band rows keep g_to_r, where the scatter is row-vectorised.
+    coeffs: [..., ng]; inv_index: [n1*n2*n3]; returns [..., n1, n2, n3].
+    """
+    batch = coeffs.shape[:-1]
+    n = dims[0] * dims[1] * dims[2]
+    padded = jnp.concatenate(
+        [coeffs, jnp.zeros(batch + (1,), dtype=coeffs.dtype)], axis=-1
+    )
+    box = padded[..., inv_index].reshape(batch + dims)
+    return jnp.fft.ifftn(box, axes=(-3, -2, -1)) * n
+
+
 @partial(jax.jit, static_argnums=(2,))
 def r_to_g(values: jax.Array, fft_index: jax.Array, dims: tuple[int, int, int]) -> jax.Array:
     """Batched r -> G transform: FFT the box and gather sphere coefficients.

@@ -57,7 +57,9 @@ from sirius_tpu.dft.mixer import (
 )
 from sirius_tpu.dft.potential import (
     build_potential_device_tables,
+    constant_fields_device,
     generate_potential_device,
+    num_box_fills,
 )
 from sirius_tpu.ops.augmentation import (
     build_aug_device_tables,
@@ -142,7 +144,10 @@ class FusedScf:
     """
 
     def __init__(self, ctx, xc, mixer, polarized: bool, do_symmetrize: bool,
-                 beta_dev=None, exec_cache=None, wf_dtype=jnp.complex128):
+                 beta_dev=None, exec_cache=None, wf_dtype=jnp.complex128,
+                 place=None):
+        # place: run_scf's placement of a pytree on the compute device(s)
+        # (replicated on a mesh); None leaves the uploads where jnp puts them
         # one dtype policy: the step works in the band solve's precision —
         # complex64/float32 on a TPU, where 64-bit types do not run
         # (runtime.py); with complex128 the program is the f64 one
@@ -206,8 +211,16 @@ class FusedScf:
             tables["dm_sym"] = build_dm_sym_tables(ctx)
         # one-time upload; step() takes these as an argument so they are
         # program inputs, not baked-in constants
-        self.tables = jax.tree_util.tree_map(self._table, tables)
-        self.kweights_dev = self._table(np.asarray(ctx.kweights))
+        place = place or (lambda t: t)
+        self.tables = place(jax.tree_util.tree_map(self._table, tables))
+        self.kweights_dev = place(self._table(np.asarray(ctx.kweights)))
+        # rho_core(r) and v_loc(r) do not change in a job: transformed here,
+        # once, where the tables live and in their precision (the bits the
+        # step itself would compute), not in every iteration
+        self.tables["pot"].update(
+            constant_fields_device(self.tables["pot"], self.dims))
+        # sphere-to-box placements one step runs (counters.num_tail_box_fills)
+        self.box_fills = num_box_fills(xc, self.polarized)
         if exec_cache is not None:
             # serving: reuse a previously-jitted step whose trace signature
             # matches. The jitted callable is a bound method of the FIRST

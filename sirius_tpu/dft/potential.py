@@ -12,13 +12,19 @@ channels.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from sirius_tpu.context import SimulationContext
-from sirius_tpu.core.fftgrid import g_to_r, r_to_g
+from sirius_tpu.core.fftgrid import (
+    box_inverse_map,
+    g_to_r,
+    g_to_r_gather,
+    r_to_g,
+)
 from sirius_tpu.core.hilo import dot_scaled
 from sirius_tpu.dft.density import symmetrize_pw, symmetrize_pw_device
 from sirius_tpu.dft.poisson import hartree_potential_g
@@ -240,18 +246,48 @@ def generate_potential(
 
 
 def build_potential_device_tables(ctx: SimulationContext) -> dict:
-    """Constant context tables (numpy) for generate_potential_device."""
+    """Constant context tables (numpy) for generate_potential_device. The
+    two inverse maps are how the step fills its boxes (g_to_r_gather);
+    constant_fields_device adds the two real boxes that never change."""
     return {
         "glen2": ctx.gvec.glen2,
         "gcart": ctx.gvec.gcart,
         "fft_index": ctx.gvec.fft_index,
-        "fft_index_coarse": ctx.gvec_coarse.fft_index,
+        "inv_index": box_inverse_map(
+            ctx.gvec.fft_index, ctx.gvec.fft.num_points),
+        "inv_index_coarse": box_inverse_map(
+            ctx.gvec_coarse.fft_index, ctx.fft_coarse.num_points),
         "c2f": ctx.coarse_to_fine,
         "vloc_re": np.real(ctx.vloc_g),
         "vloc_im": np.imag(ctx.vloc_g),
         "core_re": np.real(ctx.rho_core_g),
         "core_im": np.imag(ctx.rho_core_g),
     }
+
+
+@partial(jax.jit, static_argnums=(1,))
+def constant_fields_device(tb: dict, dims: tuple) -> dict:
+    """The two fine-box fields of generate_potential_device whose input is
+    the same in every iteration of a job, from the uploaded tables, in their
+    precision and on their device: rho_core(r) and v_loc(r). Transformed
+    once a job (FusedScf.__init__) and carried in tb as real boxes."""
+    def to_r(re, im):
+        return jnp.real(
+            g_to_r_gather(jax.lax.complex(re, im), tb["inv_index"], dims))
+
+    return {
+        "rho_core_r": to_r(tb["core_re"], tb["core_im"]),
+        "vloc_r": to_r(tb["vloc_re"], tb["vloc_im"]),
+    }
+
+
+def num_box_fills(xc: XCFunctional, polarized: bool) -> int:
+    """Sphere-to-box placements one generate_potential_device runs: rho,
+    v_ha and v_eff on the fine box and v_eff on the coarse one; a moment
+    adds itself and b_z on both boxes; a gradient correction three
+    components a density and one divergence a potential."""
+    spins = 2 if polarized else 1
+    return 4 + (3 if polarized else 0) + (4 * spins if xc.is_gga else 0)
 
 
 def generate_potential_device(
@@ -267,7 +303,8 @@ def generate_potential_device(
     """Traced generate_potential: returns veff_g/bz_g/vha_g/vxc_g (complex,
     program-internal), veff_r_coarse [ns, coarse box] real and the energy
     integrals as traced (hi, lo) pairs of scalars (core/hilo.py). sym_tb (density.build_sym_pw_tables)
-    enables the in-program PW symmetrization of veff/bz."""
+    enables the in-program PW symmetrization of veff/bz. tb is
+    build_potential_device_tables plus constant_fields_device, uploaded."""
     if xc.is_mgga:
         raise ValueError("device potential path does not support mGGA")
     polarized = mag_g is not None
@@ -275,7 +312,7 @@ def generate_potential_device(
     cdt = rho_g.dtype
 
     def to_r(f_g):
-        return jnp.real(g_to_r(f_g, tb["fft_index"], tuple(dims)))
+        return jnp.real(g_to_r_gather(f_g, tb["inv_index"], tuple(dims)))
 
     def to_g(f_r):
         return r_to_g(f_r.astype(cdt), tb["fft_index"], tuple(dims))
@@ -298,7 +335,7 @@ def generate_potential_device(
     rho_core_g = jax.lax.complex(tb["core_re"], tb["core_im"]).astype(cdt)
     vha_g = hartree_potential_g(rho_g, tb["glen2"])
     rho_r = to_r(rho_g)
-    rho_core_r = to_r(rho_core_g)
+    rho_core_r = tb["rho_core_r"]
 
     if polarized:
         mag_r = to_r(mag_g)
@@ -358,8 +395,8 @@ def generate_potential_device(
             bz_g = symmetrize_pw_device(bz_g, sym_tb, axial_z=True)
 
     def to_coarse(f_g):
-        return jnp.real(g_to_r(
-            f_g[tb["c2f"]], tb["fft_index_coarse"], tuple(dims_coarse)))
+        return jnp.real(g_to_r_gather(
+            f_g[tb["c2f"]], tb["inv_index_coarse"], tuple(dims_coarse)))
 
     if polarized:
         v_r = to_coarse(veff_g)
@@ -372,7 +409,7 @@ def generate_potential_device(
     energies = {  # each an (hi, lo) pair, see inner_rr
         "vha": inner_rr(rho_r, to_r(vha_g)),
         "vxc": inner_rr(rho_r, vxc_r),
-        "vloc": inner_rr(rho_r, to_r(vloc_g)),
+        "vloc": inner_rr(rho_r, tb["vloc_r"]),
         "veff": inner_rr(rho_r, to_r(veff_g)),
         "exc": inner_rr(rho_r + rho_core_r, exc_r),
         "bxc": (inner_rr(mag_r, to_r(bz_g)) if polarized else (zero, zero)),

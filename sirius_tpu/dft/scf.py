@@ -695,9 +695,7 @@ def _run_scf_inner(
                     for b in beta_dev))
                 fused = FusedScf(ctx, xc, mixer, polarized, do_symmetrize,
                                  beta_dev=fused_beta, exec_cache=exec_cache,
-                                 wf_dtype=wf_dtype)
-                fused.tables = _repl(fused.tables)
-                fused.kweights_dev = _repl(fused.kweights_dev)
+                                 wf_dtype=wf_dtype, place=_repl)
                 # pre-wrapped device scalars: python floats fed to jit are
                 # implicit host->device transfers, which the fused loop
                 # must not make
@@ -1123,7 +1121,8 @@ def _run_scf_inner(
                 if _span_fence:
                     _fence((acc, dm_re, dm_im))
                 _sp.close()
-                _sp = _stage("scf.fused_step", it=it + 1)
+                _sp = _stage("scf.fused_step", it=it + 1, box_fill="gather")
+                counters["num_tail_box_fills"] += fused.box_fills
                 fused_carry, fused_out = fused.step(
                     fused_carry, acc, dm_re, dm_im, ev_dev, occ_w,
                     entropy_sum, out.pr, out.pi,
@@ -1708,6 +1707,7 @@ def _run_scf_inner(
         e_total=e_total, recoveries=sup.recoveries, wall_s=result["scf_time"],
         num_loc_op_applied=int(counters["num_loc_op_applied"]),
         num_fft_boxes=int(counters["num_fft_boxes"]),
+        num_tail_box_fills=int(counters["num_tail_box_fills"]),
         energy_resolution_ha=abs(e_total) * pair_eps(
             fused.rdt if fused is not None else np.float64),
     )

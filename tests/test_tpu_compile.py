@@ -247,8 +247,9 @@ def test_fused_step_one_chip(topo, no_compile_cache, ctx_kmesh):
 
 
 def test_fft_pair_c64_one_chip(topo, no_compile_cache, ctx_kmesh):
-    """r_to_g / g_to_r on the fine box (not a power of two) in complex64."""
-    from sirius_tpu.core.fftgrid import g_to_r, r_to_g
+    """r_to_g / g_to_r and the fused step's gather twin on the fine box (not
+    a power of two) in complex64."""
+    from sirius_tpu.core.fftgrid import g_to_r, g_to_r_gather, r_to_g
 
     ctx = ctx_kmesh
     one = SingleDeviceSharding(topo.devices[0])
@@ -258,6 +259,10 @@ def test_fft_pair_c64_one_chip(topo, no_compile_cache, ctx_kmesh):
     sph = jax.ShapeDtypeStruct((ctx.gvec.num_gvec,), np.complex64, sharding=one)
     _check(_compile(lambda: r_to_g.lower(box, idx, dims)), no_64bit=True)
     _check(_compile(lambda: g_to_r.lower(sph, idx, dims)), no_64bit=True)
+    inv = jax.ShapeDtypeStruct((int(np.prod(dims)),), np.int32, sharding=one)
+    txt = _check(_compile(lambda: g_to_r_gather.lower(sph, inv, dims)),
+                 no_64bit=True)
+    assert "scatter" not in txt
 
 
 def test_kb_mesh_step_four_chips(topo, no_compile_cache, ctx_kmesh):
