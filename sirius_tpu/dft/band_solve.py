@@ -17,7 +17,9 @@ same questions:
   ``restart(psi_big)`` / ``load(psi)`` (recovery, resume, warm start),
   ``rescue(...)`` (the stagnation sentinel), ``after_solve(t0, it)``;
 - what the result reports: ``name`` (``name_fused`` under the fused tail),
-  ``feeds_fused``, ``mesh``, ``gshard_devices``, ``placed()``.
+  ``feeds_fused``, ``mesh``, ``gshard_devices``, ``placed()``, and
+  ``plan(wf_dtype)``: the solver's fields of the ``scf.setup`` span (the
+  k-set program's size; nothing elsewhere).
 
 None of the five can go: ``batched`` and ``gamma`` each win where the code
 sends them, ``gshard`` and ``beta_chunked`` are each the only path for an
@@ -152,11 +154,68 @@ def _host_evals(ctx, ev_by_spin):
     return evals
 
 
+def time_reversal_index(gkvec) -> np.ndarray | None:
+    """[nk, ngk]: for every plane-wave slot G + k the slot of -G - 2k, where
+    every k-point of the set is time-reversal invariant (2k a reciprocal
+    lattice vector: Gamma and the zone-boundary points, e.g. all of a
+    Gamma-centred 2x2x2 mesh); None where one k-point is not. With it
+    Theta x (G) = conj(x(-G - 2k)) is complex conjugation of psi(r), which
+    commutes with H and S for a real local potential and real D and Q; a
+    block of Theta-real rows has real subspace matrices
+    (solvers/davidson.py, REAL SUBSPACE). Padding slots map to themselves."""
+    two_k = 2.0 * np.asarray(gkvec.kpoints, dtype=np.float64)
+    shift = np.rint(two_k).astype(np.int64)
+    if np.abs(two_k - shift).max() > 1e-9:
+        return None
+    nk, ngk = gkvec.mask.shape
+    out = np.tile(np.arange(ngk), (nk, 1))
+    for ik in range(nk):
+        n = int(np.sum(np.asarray(gkvec.mask[ik]) > 0))  # valid slots lead
+        m = np.asarray(gkvec.millers[ik, :n], dtype=np.int64)
+        off = int(np.abs(m).max(initial=0)) + int(np.abs(shift[ik]).max()) + 1
+        base = 2 * off + 1
+
+        def key(v):
+            v = v + off
+            return (v[:, 0] * base + v[:, 1]) * base + v[:, 2]
+
+        order = np.argsort(key(m))
+        have, want = key(m)[order], key(-m - shift[ik])
+        pos = np.clip(np.searchsorted(have, want), 0, max(n - 1, 0))
+        if n and not np.array_equal(have[pos], want):
+            return None  # a sphere that is not its own mirror image
+        out[ik, :n] = order[pos]
+    return out
+
+
+def _theta(x, tr):
+    """Theta x of a block [nk, ns, n, ngk] (host numpy)."""
+    return np.conj(np.take_along_axis(x, tr[:, None, None, :], axis=-1))
+
+
+def theta_real_block(x, tr):
+    """The block with each row replaced by a Theta-real one that spans the
+    same complex line where the row was Theta-real or Theta-imaginary (an
+    atomic orbital) and is as good a trial vector where it was neither (the
+    random tail): (x + Theta x) / 2, or i (x - Theta x) / 2 where that is
+    the larger of the two."""
+    tx = _theta(x, tr)
+    plus, minus = 0.5 * (x + tx), 0.5j * (x - tx)
+    norm2 = lambda a: np.sum(np.abs(a) ** 2, axis=-1, keepdims=True)
+    return np.where(norm2(plus) >= norm2(minus), plus, minus)
+
+
 class KsetSolver:
     """Production path: the whole (k, spin) set as ONE program
     (parallel/batched.py; shards over the ("k", "b") mesh). Real-boundary:
     psi crosses the jit boundary as a (re, im) pair and stays device-
-    resident between iterations."""
+    resident between iterations. Where the set has several k-points, every
+    one time-reversal invariant, and the operator commutes with conjugation
+    (no Hubbard V with its k-phases, no mGGA operator), every block that
+    enters is made Theta-real and the subspace eigenproblems are real
+    (``tr``): one program a deck, whatever the block came from. Gamma alone
+    has GammaSolver; where that is refused (several devices, reduce_gvec
+    off) the deck keeps the complex program it had."""
 
     name = "batched"
     name_fused = "batched+fused"  # the result's path word under the fused tail
@@ -172,6 +231,11 @@ class KsetSolver:
         self._gkc: dict = {}
         self.ps = self.rdt = None
         self.psi = self.psi_big = self.pr = self.pi = None
+        # the slot of -G - 2k where the solve runs on the real subspace
+        self.tr = (None if hub is not None or mgga
+                   or ctx.gkvec.num_kpoints == 1
+                   else time_reversal_index(ctx.gkvec))
+        self._tr_dev = None
         if mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -181,6 +245,39 @@ class KsetSolver:
         if self.mesh is not None:
             return jax.device_put(x, self._psi_sharding)
         return up(x, self.dev)
+
+    def _theta_index(self):
+        """The device copy of ``tr`` (sharded over "k" on a mesh); None
+        where the solve is the complex one."""
+        if self.tr is None:
+            return None
+        if self._tr_dev is None:
+            tr = self.tr.astype(np.int32)
+            if self.mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                self._tr_dev = jax.device_put(
+                    tr, NamedSharding(self.mesh, PartitionSpec("k", None)))
+            else:
+                self._tr_dev = up(tr, self.dev)
+        return self._tr_dev
+
+    def plan(self, wf_dtype) -> dict:
+        """Fields of the ``scf.setup`` span: the size of the one program.
+        The whole (k, spin) set goes through one vmap with no chunk over k,
+        so ``workspace_bytes`` is one temporary of one H application to
+        [X; P], a coarse FFT box a row, on one device (the compiler keeps
+        several of them; the peak is a small multiple)."""
+        ctx = self.ctx
+        nk, nb = ctx.gkvec.num_kpoints, ctx.num_bands
+        ndev = 1 if self.mesh is None else self.mesh.size
+        rows = nk * ctx.num_spins * 2 * nb
+        return {"kset": {
+            "nk": nk, "ngk_max": int(ctx.gkvec.ngk_max),
+            "subspace_rows": 3 * nb, "real_subspace": self.tr is not None,
+            "workspace_bytes": rows * int(np.prod(ctx.fft_coarse.dims))
+            * np.dtype(wf_dtype).itemsize // ndev,
+        }}
 
     def _gkc_dev(self, rdt):
         """Device-resident cartesian G+k components [nk, ngk, 3] for the
@@ -258,6 +355,8 @@ class KsetSolver:
             # (reference initialize_subspace.hpp:279)
             from sirius_tpu.parallel.batched import initialize_subspace_kset
 
+            if self.tr is not None:
+                self.psi_big = theta_real_block(self.psi_big, self.tr)
             pb_re, pb_im = split_cplx(self.psi_big, rdt)
             if self.mesh is not None:
                 # the LCAO block has nbig >= nb orbitals — shard it over
@@ -269,7 +368,8 @@ class KsetSolver:
                 pb_re = jax.device_put(jnp.asarray(pb_re), _big)
                 pb_im = jax.device_put(jnp.asarray(pb_im), _big)
             pr, pi = initialize_subspace_kset(
-                ps, jnp.asarray(pb_re), jnp.asarray(pb_im), nb
+                ps, jnp.asarray(pb_re), jnp.asarray(pb_im), nb,
+                theta_index=self._theta_index(),
             )
             pr, pi = self._place_psi(pr), self._place_psi(pi)
             count_applies(counters, [(self.psi_big.shape[2], 1)],
@@ -295,6 +395,7 @@ class KsetSolver:
                 ps, pr, pi,
                 num_steps=self.num_steps,
                 res_tol=_rtol(res_tol, rdt),
+                theta_index=self._theta_index(),
             )
         # canonicalize the pair onto the explicit psi sharding (a no-op
         # when GSPMD already placed it there): downstream consumers must
@@ -338,8 +439,13 @@ class KsetSolver:
         self.psi_big = psi_big
 
     def load(self, psi):
+        # a block from a resume file or a warm start enters the real
+        # subspace as the LCAO block does: what a real-subspace solve left
+        # comes back bit for bit (so a resumed run repeats the uninterrupted
+        # one), a row with another phase as the Theta-real row of its line
         self.restart(None)
-        self.psi = psi
+        self.psi = psi if self.tr is None else theta_real_block(
+            np.asarray(psi), self.tr)
 
     def rescue(self, inputs, out, res_tol):
         """One deeper retry, warm-started from the stagnated block (static
@@ -350,7 +456,7 @@ class KsetSolver:
 
         ev, self.pr, self.pi, rn = davidson_kset(
             self.ps, self.pr, self.pi, num_steps=2 * self.num_steps,
-            res_tol=res_tol,
+            res_tol=res_tol, theta_index=self._theta_index(),
         )
         return BandOut(np.asarray(ev, dtype=np.float64), rn, self.pr, self.pi)
 
@@ -387,6 +493,9 @@ class GammaSolver:
 
     def _up(self, x, dtype=None):
         return up(x, self.dev, dtype)
+
+    def plan(self, wf_dtype) -> dict:
+        return {}
 
     def solve(self, inputs, res_tol, wf_dtype, tail_rdt=None):
         from sirius_tpu.ops import gamma as gmod
@@ -575,6 +684,9 @@ class GshardSolver:
             reorder_to_gshard(np.asarray(prm0.mask), self.order), self.sh_g)
         self.dtype, self.x, self.probe = dtype, None, None
 
+    def plan(self, wf_dtype) -> dict:
+        return {}
+
     def solve(self, inputs, res_tol, wf_dtype, tail_rdt=None):
         from sirius_tpu.parallel.dist_fft import (
             reorder_from_gshard,
@@ -698,6 +810,9 @@ class ChunkedSolver:
         self.params = self.dtype = None
         self.psi = self.psi_big = None
 
+    def plan(self, wf_dtype) -> dict:
+        return {}
+
     def solve(self, inputs, res_tol, wf_dtype, tail_rdt=None):
         from sirius_tpu.ops.beta_chunked import (
             apply_h_s_chunked,
@@ -787,6 +902,9 @@ class SerialSolver:
             inputs.pot.veff_r_coarse[ispn], inputs.d_by_spin[ispn], wf_dtype,
             vhub_s=None if inputs.vhub is None else inputs.vhub[ik, ispn],
         )
+
+    def plan(self, wf_dtype) -> dict:
+        return {}
 
     def solve(self, inputs, res_tol, wf_dtype, tail_rdt=None):
         ctx, nb = self.ctx, self.ctx.num_bands

@@ -979,10 +979,13 @@ def _run_scf_inner(
     )
     # everything since run_scf entry (context/tables/initial guess/fused
     # compile trigger) is the setup span
-    # ... and it says which mesh factorisation the job runs on, if any
+    # ... and it says which mesh factorisation the job runs on, if any,
+    # and how large the batched solve's one program is
+    counters["num_kpoints_solved"] = nk
     _setup_span.close(
         fused=fused is not None,
-        **({} if band.mesh is None else {"mesh": dict(band.mesh.shape)}))
+        **({} if band.mesh is None else {"mesh": dict(band.mesh.shape)}),
+        **band.plan(wf_dtype))
     _it_t0 = time.time()
     for it in range(it0, p.num_dft_iter):
         _close_iteration()

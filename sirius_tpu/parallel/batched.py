@@ -224,14 +224,17 @@ def make_hkset_params(
 
 
 @partial(jax.jit, static_argnames=("nb",))
-def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int):
+def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int,
+                             theta_index=None):
     """LCAO subspace initialization for the whole (k, spin) set: one H/S
     application to the full atomic-orbital block (+ random tail), one
     generalized Rayleigh-Ritz, keep the lowest nb Ritz vectors (reference
     initialize_subspace.hpp:27 per-k, :279 kset driver). The input block is
     [nk, ns, nbig, ngk] with nbig >= nb; truncating atomic orbitals to nb
     BEFORE the rotation loses orbital characters and mis-seeds the band
-    solver (Fe 3d, test03).
+    solver (Fe 3d, test03). ``theta_index`` [nk, ngk]: every k-point is
+    time-reversal invariant, every row of the block Theta-real there, and so
+    are the rotated vectors (solvers/davidson.py, REAL SUBSPACE).
 
     Returns (psi_re, psi_im) [nk, ns, nb, ngk]."""
     from sirius_tpu.solvers.davidson import subspace_rotate
@@ -240,7 +243,7 @@ def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int):
     has_hub = params.hub_re is not None
 
     def one_k(ekin, mask, fft_index, beta_re, beta_im, hub_re_k, hub_im_k,
-              vhub_re_k, vhub_im_k, psi_k):
+              vhub_re_k, vhub_im_k, psi_k, theta_k):
         def one_spin(veff_s, dion_s, vhub_re_s, vhub_im_s, x0):
             pk = HkParams(
                 veff_r=veff_s,
@@ -255,7 +258,8 @@ def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int):
             )
             x = x0 * mask
             hx, sx = apply_h_s(pk, x)
-            return subspace_rotate(x, hx, sx, nb, mask=mask)
+            return subspace_rotate(x, hx, sx, nb, mask=mask,
+                                   theta_index=theta_k)
 
         return jax.vmap(
             one_spin,
@@ -266,20 +270,25 @@ def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int):
     hub_ax = 0 if has_hub else None
     x = jax.vmap(
         one_k,
-        in_axes=(0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0),
+        in_axes=(0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0,
+                 None if theta_index is None else 0),
     )(
         params.ekin, params.mask, params.fft_index, params.beta_re,
         params.beta_im, params.hub_re, params.hub_im,
-        params.vhub_re, params.vhub_im, psi,
+        params.vhub_re, params.vhub_im, psi, theta_index,
     )
     return jnp.real(x), jnp.imag(x)
 
 
 @partial(jax.jit, static_argnames=("num_steps",))
 def davidson_kset(
-    params: HkSetParams, psi_re, psi_im, num_steps: int = 20, res_tol: float = 1e-6
+    params: HkSetParams, psi_re, psi_im, num_steps: int = 20, res_tol: float = 1e-6,
+    theta_index=None,
 ):
-    """Solve bands at every (k, spin) in one vmapped call.
+    """Solve bands at every (k, spin) in one vmapped call. ``theta_index``
+    [nk, ngk]: every k-point of the set is time-reversal invariant and every
+    row of psi Theta-real there, so the subspace eigenproblems are real
+    symmetric (solvers/davidson.py, REAL SUBSPACE).
 
     psi_re/psi_im: [nk, ns, nb, ngk] real pair ->
     (evals [nk, ns, nb], psi_re', psi_im', rnorm [nk, ns, nb])."""
@@ -287,7 +296,7 @@ def davidson_kset(
     has_hub = params.hub_re is not None
 
     def one_k(ekin, mask, fft_index, beta_re, beta_im, h_diag_k, o_diag,
-              hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, psi_k):
+              hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, psi_k, theta_k):
         def one_spin(veff_s, dion_s, vhub_re_s, vhub_im_s, h_diag_s, x0):
             pk = HkParams(
                 veff_r=veff_s,
@@ -302,7 +311,7 @@ def davidson_kset(
             )
             return davidson(
                 apply_h_s, pk, x0, h_diag_s, o_diag, mask,
-                num_steps=num_steps, res_tol=res_tol,
+                num_steps=num_steps, res_tol=res_tol, theta_index=theta_k,
             )
 
         return jax.vmap(
@@ -315,11 +324,13 @@ def davidson_kset(
     hub_ax = 0 if has_hub else None
     ev, x, rn = jax.vmap(
         one_k,
-        in_axes=(0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0),
+        in_axes=(0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0,
+                 None if theta_index is None else 0),
     )(
         params.ekin, params.mask, params.fft_index, params.beta_re,
         params.beta_im, params.h_diag, params.o_diag,
         params.hub_re, params.hub_im, params.vhub_re, params.vhub_im, psi,
+        theta_index,
     )
     return ev, jnp.real(x), jnp.imag(x), rn
 

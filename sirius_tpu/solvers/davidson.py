@@ -23,6 +23,35 @@ every `refresh_every` steps the carried H X / H P are recomputed with a true
 application (chunked scan, still ~3x fewer H applies than re-applying to the
 full 3nb subspace each step). The iteration count is static (config
 iterative_solver.num_steps).
+
+REAL SUBSPACE (``theta_index``). Where H and S commute with an antiunitary
+map Theta and every row of the block is Theta-real (Theta x = x), the
+subspace matrices V^H H V and V^H S V are real symmetric: the Theta-real
+vectors are a real vector space on which H and S act as real symmetric
+operators. That is so at a time-reversal-invariant k-point (2k a reciprocal
+lattice vector) for a real local potential and real D and Q, with
+Theta x (G) = conj(x(-G - 2k)), complex conjugation of psi(r);
+``theta_index`` is the slot of -G - 2k for every slot
+(dft/band_solve.time_reversal_index). Given it, the eigensolver gets the
+real parts. On the TPU that is another program: a complex Hermitian eigh is
+expanded into Jacobi sweep loops of about a microsecond an operation, a real
+symmetric one up to 256 rows is one kernel (PERF.md section 6, PR 31).
+
+The block V = [X, W, P] has to be Theta-real exactly, not to rounding: what
+lies outside the real space is invisible to the real Rayleigh-Ritz step and
+is carried along by its coefficients all the same. The new block W is where
+it comes in: W is a residual divided by its own norm, so near convergence it
+is rounding noise of norm one, half of it outside the real space, and in
+float32 the bands lost their orthonormality within one SCF (a total energy
+1.2 Ha too low on the CPU backend). So W, and H W and S W as they leave the
+operator, are replaced by their Theta-real parts (w + Theta w) / 2, a gather
+each (it costs nothing measurable beside the transforms), and so is the
+block that enters. X and P then stay Theta-real by themselves: they are
+real combinations (`_combine`), masks and real scalings of Theta-real rows,
+and slot -G - 2k sees the conjugate of the arithmetic slot G sees.
+Projecting W alone was tried on the chip and is not enough there: 17 SCF
+iterations for 13 and an energy 1.0e-4 Ha off where this form reads 1e-5
+(PERF.md section 6, PR 31).
 """
 
 from __future__ import annotations
@@ -109,19 +138,48 @@ def _rayleigh_ritz(hsub: jax.Array, ssub: jax.Array, nev: int):
     return e[:nev], c[:, :nev]
 
 
-def subspace_rotate(x, hx, sx, nb: int, mask=None):
+def theta_real(a, theta_index):
+    """The Theta-real part of every row of a block [..., ng]; the block as
+    it is where the solve is complex (no index). Module docstring."""
+    if theta_index is None:
+        return a
+    return 0.5 * (a + jnp.conj(a[..., theta_index]))
+
+
+def _combine(c, v, theta_index):
+    """c^T v for Ritz coefficients c [m, n] and a block v [m, ng]. With real
+    coefficients (``theta_index``) it is two real products, of the real and
+    of the imaginary parts: half the arithmetic, and Theta-real rows give
+    Theta-real rows to the last bit, which a complex product with a zero
+    imaginary part does not on the TPU (its three-product form rounds the
+    imaginary part with the real one's size)."""
+    if theta_index is None:
+        return c.T @ v
+    return jax.lax.complex(c.T @ jnp.real(v), c.T @ jnp.imag(v))
+
+
+def _subspace_matrix(a, theta_index):
+    """A subspace matrix as the eigensolver gets it: real where the block
+    is Theta-real."""
+    return a if theta_index is None else jnp.real(a)
+
+
+def subspace_rotate(x, hx, sx, nb: int, mask=None, theta_index=None):
     """Lowest-nb Ritz vectors of the trial block x given carried H x / S x:
     shared by the LCAO initialize-subspace paths (serial host and batched
-    device); pure jnp, callable inside or outside jit."""
-    hsub = x.conj() @ hx.T
-    ssub = x.conj() @ sx.T
+    device); pure jnp, callable inside or outside jit. With ``theta_index``
+    the rows of x are Theta-real and so are the Ritz vectors."""
+    x = theta_real(x, theta_index)
+    hx, sx = theta_real(hx, theta_index), theta_real(sx, theta_index)
+    hsub = _subspace_matrix(x.conj() @ hx.T, theta_index)
+    ssub = _subspace_matrix(x.conj() @ sx.T, theta_index)
     hsub = 0.5 * (hsub + hsub.conj().T)
     ssub = 0.5 * (ssub + ssub.conj().T)
     _, c = _rayleigh_ritz(hsub, ssub, nb)
-    xn = c.T @ x
+    xn = _combine(c, x, theta_index)
     if mask is not None:
         xn = xn * mask
-    nrm = jnp.real(jnp.sum(xn.conj() * (c.T @ sx), axis=1))
+    nrm = jnp.real(jnp.sum(xn.conj() * _combine(c, sx, theta_index), axis=1))
     return xn / jnp.sqrt(jnp.maximum(nrm, 1e-30))[:, None]
 
 
@@ -144,21 +202,23 @@ def davidson(
     num_steps: int = 20,
     res_tol: float = 1e-6,
     refresh_every: int = REFRESH_EVERY,
+    theta_index: jax.Array | None = None,  # [ng] int: REAL SUBSPACE above
 ):
     """Returns (eval [nb], X [nb, ng], res_norms [nb])."""
     nb = x0.shape[0]
 
     def apply_h_s(psi):
-        return apply_fn(params, psi)
+        hpsi, spsi = apply_fn(params, psi)
+        return theta_real(hpsi, theta_index), theta_real(spsi, theta_index)
 
     def ortho(x):
-        g = (x * mask) @ (x * mask).conj().T
+        g = _subspace_matrix((x * mask) @ (x * mask).conj().T, theta_index)
         s, u = jnp.linalg.eigh(g)
         good = s > 50.0 * jnp.finfo(g.real.dtype).eps * jnp.max(jnp.abs(s))
         t = u * jnp.where(good, jax.lax.rsqrt(jnp.where(good, s, 1.0)), 0.0)[None, :]
-        return t.conj().T @ x
+        return _combine(t.conj(), x, theta_index)
 
-    x = ortho(x0 * mask)
+    x = ortho(theta_real(x0 * mask, theta_index))
 
     def step(carry, _):
         x, hx, sx, p, hp, sp = carry
@@ -177,7 +237,7 @@ def davidson(
         # project out X and normalize rows: keeps the 3nb overlap matrix
         # well-conditioned so the rank-revealing cutoff doesn't stall
         # convergence near the solution
-        w = w - (w @ x.conj().T) @ x
+        w = theta_real(w - (w @ x.conj().T) @ x, theta_index)
         w = w / jnp.maximum(jnp.linalg.norm(w, axis=1, keepdims=True), 1e-30)
         # the ONLY H/S application of the step: the new block.  The
         # named_scope blocks tag the emitted HLO so trace capture
@@ -190,26 +250,27 @@ def davidson(
         hv = jnp.concatenate([hx, hw, hp], axis=0)
         sv = jnp.concatenate([sx, sw, sp], axis=0)
         with jax.named_scope("davidson_inner"):
-            hsub = v.conj() @ hv.T
-            ssub = v.conj() @ sv.T
+            hsub = _subspace_matrix(v.conj() @ hv.T, theta_index)
+            ssub = _subspace_matrix(v.conj() @ sv.T, theta_index)
             hsub = 0.5 * (hsub + hsub.conj().T)
             ssub = 0.5 * (ssub + ssub.conj().T)
         with jax.named_scope("davidson_rr"):
             e, c = _rayleigh_ritz(hsub, ssub, nb)
         with jax.named_scope("davidson_rotate"):
             # X' = V C and the carried H X' = (H V) C, S X' = (S V) C exactly
-            xn = (c.T @ v) * mask
-            hxn = (c.T @ hv) * mask
-            sxn = (c.T @ sv) * mask
+            xn = _combine(c, v, theta_index) * mask
+            hxn = _combine(c, hv, theta_index) * mask
+            sxn = _combine(c, sv, theta_index) * mask
             # new search direction: the non-X part of the update
             # (row-normalized, with the same scale applied to the carried
             # H P / S P)
             cp = c.at[:nb, :].set(0.0)
-            pn = (cp.T @ v) * mask
+            pn = _combine(cp, v, theta_index) * mask
             pscale = 1.0 / jnp.maximum(
                 jnp.linalg.norm(pn, axis=1, keepdims=True), 1e-30)
-        return (xn, hxn, sxn, pn * pscale, (cp.T @ hv) * mask * pscale,
-                (cp.T @ sv) * mask * pscale), rnorm
+        return (xn, hxn, sxn, pn * pscale,
+                _combine(cp, hv, theta_index) * mask * pscale,
+                _combine(cp, sv, theta_index) * mask * pscale), rnorm
 
     def chunk(carry, steps):
         """One refresh boundary, a true H/S application to [X; P], and the

@@ -237,6 +237,37 @@ def test_kset_band_solve_one_chip(topo, no_compile_cache, ctx_kmesh):
            no_64bit=True)
 
 
+def test_kset_band_solve_real_subspace_one_chip(topo, no_compile_cache,
+                                                ctx_kmesh):
+    """The same solve with real subspace matrices (every k-point of the
+    2x2x2 mesh is time-reversal invariant; solvers/davidson.py, REAL
+    SUBSPACE): what it is for is the eigensolver the TPU builds. A complex
+    Hermitian eigh is expanded into Jacobi sweep loops, a real symmetric one
+    of 78 rows is the EighTpu kernel."""
+    from sirius_tpu.dft.band_solve import time_reversal_index
+    from sirius_tpu.parallel.batched import (
+        davidson_kset, initialize_subspace_kset,
+    )
+
+    ctx = ctx_kmesh
+    one = SingleDeviceSharding(topo.devices[0])
+    ps, psi = _kset_inputs(ctx, ctx.num_bands)
+    theta = time_reversal_index(ctx.gkvec)
+    assert theta is not None
+    ps, psi = _shapes(ps, one), _shapes(psi, one)
+    theta = _shapes(theta.astype(np.int32), one)
+    tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
+    real = _check(_compile(lambda: davidson_kset.lower(
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, theta_index=theta)),
+        no_64bit=True)
+    assert "EighTpu" in real and "EighJacobiSweeps" not in real
+    cplx = _check(_compile(lambda: davidson_kset.lower(
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol)), no_64bit=True)
+    assert "EighJacobiSweeps" in cplx and "EighTpu" not in cplx
+    _check(_compile(lambda: initialize_subspace_kset.lower(
+        ps, psi, psi, nb=ctx.num_bands, theta_index=theta)), no_64bit=True)
+
+
 def test_fused_step_one_chip(topo, no_compile_cache, ctx_kmesh):
     """The FusedScf step program built for complex64, donated carry."""
     ctx = ctx_kmesh
@@ -287,6 +318,15 @@ def test_kb_mesh_step_four_chips(topo, no_compile_cache, ctx_kmesh):
     tol = jax.ShapeDtypeStruct((), np.float32, sharding=rep)
     _check(_compile(lambda: davidson_kset.lower(
         ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol)), no_64bit=True)
+    # ... and with real subspace matrices, the index sharded over "k" as
+    # band_solve.KsetSolver places it (this mesh is all of them invariant)
+    from sirius_tpu.dft.band_solve import time_reversal_index
+
+    theta = _shapes(time_reversal_index(ctx.gkvec).astype(np.int32),
+                    NamedSharding(mesh, P("k", None)))
+    _check(_compile(lambda: davidson_kset.lower(
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, theta_index=theta)),
+        no_64bit=True)
     occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=ev_sh)
     txt = _check(_compile(lambda: density_kset.lower(ps, psi, psi, occ)),
                  no_64bit=True)
