@@ -267,16 +267,28 @@ class KsetSolver:
         The whole (k, spin) set goes through one vmap with no chunk over k,
         so ``workspace_bytes`` is one temporary of one H application to
         [X; P], a coarse FFT box a row, on one device (the compiler keeps
-        several of them; the peak is a small multiple)."""
+        several of them; the peak is a small multiple). ``local_rows``:
+        the rows one box transform of the local operator carries on one
+        device, in a step and at a chunk boundary ([X; P]), read off the
+        shard of the [nk, ns, nb, ngk] block a device is given: under the
+        vmap over k the k-points of a spin channel go through together,
+        rows on the minor axis (``local_layout``: the one form the k-set
+        programs have, ops/local.py)."""
         ctx = self.ctx
         nk, nb = ctx.gkvec.num_kpoints, ctx.num_bands
         ndev = 1 if self.mesh is None else self.mesh.size
         rows = nk * ctx.num_spins * 2 * nb
+        block = (nk, ctx.num_spins, nb, int(ctx.gkvec.ngk_max))
+        if self.mesh is not None:
+            block = self._psi_sharding.shard_shape(block)
+        local_rows = block[0] * block[2]
         return {"kset": {
             "nk": nk, "ngk_max": int(ctx.gkvec.ngk_max),
             "subspace_rows": 3 * nb, "real_subspace": self.tr is not None,
             "workspace_bytes": rows * int(np.prod(ctx.fft_coarse.dims))
             * np.dtype(wf_dtype).itemsize // ndev,
+            "local_rows": [local_rows, 2 * local_rows],
+            "local_layout": "rows_minor",
         }}
 
     def _gkc_dev(self, rdt):

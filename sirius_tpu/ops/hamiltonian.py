@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from sirius_tpu.ops.local import box_round_trip
+
 
 def real_dtype_of(dtype):
     """The real dtype paired with a complex working dtype (single source for
@@ -33,6 +35,9 @@ class HkParams(NamedTuple):
     qmat: jax.Array  # [nbeta, nbeta]; all-zero if norm-conserving
     hub: jax.Array = None  # [nhub, ngk] S-weighted Hubbard orbitals (or None)
     vhub: jax.Array = None  # [nhub, nhub] Hubbard potential matrix (or None)
+    # [m1, m2, m3] int32 ops/local.cube_inverse_map of this k-point: the
+    # k-set programs fill it, and only a vmap over the set reads it
+    cube: jax.Array = None
 
 
 def make_hk_params(
@@ -71,16 +76,10 @@ def make_hk_params(
 
 def apply_h_s(params: HkParams, psi: jax.Array) -> tuple[jax.Array, jax.Array]:
     """(H psi, S psi) for a band block psi [nb, ngk]."""
-    dims = params.veff_r.shape
-    n = dims[0] * dims[1] * dims[2]
     psi = psi * params.mask
-    batch = psi.shape[:-1]
-    box = jnp.zeros(batch + (n,), dtype=psi.dtype).at[..., params.fft_index].add(psi)
-    fr = jnp.fft.ifftn(box.reshape(batch + dims), axes=(-3, -2, -1))
-    vpsi = (
-        jnp.fft.fftn(fr * params.veff_r, axes=(-3, -2, -1))
-        .reshape(batch + (n,))[..., params.fft_index]
-    )
+    # one block runs the scatter / FFT / gather lines; vmapped over a k-set
+    # with one potential, the set's rows go through the box together
+    vpsi = box_round_trip(psi, params.fft_index, params.veff_r, params.cube)
     ekin = jnp.where(params.mask > 0, params.ekin, 0.0)
     hpsi = ekin * psi + vpsi
     spsi = psi

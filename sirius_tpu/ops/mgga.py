@@ -94,7 +94,7 @@ def davidson_kset_mgga(params, vtau_r, gkc, psi_re, psi_im,
     has_hub = params.hub_re is not None
 
     def one_k(ekin, mask, fft_index, gkc_k, beta_re, beta_im, h_diag_k,
-              o_diag, hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, psi_k):
+              o_diag, hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, psi_k, cube_k):
         def one_spin(veff_s, dion_s, vtau_s, vhub_re_s, vhub_im_s,
                      h_diag_s, x0):
             pk = HkParams(
@@ -107,6 +107,7 @@ def davidson_kset_mgga(params, vtau_r, gkc, psi_re, psi_im,
                 qmat=params.qmat,
                 hub=None if hub_re_k is None else _cplx(hub_re_k, hub_im_k),
                 vhub=None if vhub_re_s is None else _cplx(vhub_re_s, vhub_im_s),
+                cube=cube_k,
             )
 
             def apply_fn(p, x):
@@ -127,10 +128,11 @@ def davidson_kset_mgga(params, vtau_r, gkc, psi_re, psi_im,
     hub_ax = 0 if has_hub else None
     ev, x, rn = jax.vmap(
         one_k,
-        in_axes=(0, 0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0),
+        in_axes=(0, 0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0, 0),
     )(
         params.ekin, params.mask, params.fft_index, gkc, params.beta_re,
         params.beta_im, params.h_diag, params.o_diag,
         params.hub_re, params.hub_im, params.vhub_re, params.vhub_im, psi,
+        params.cube,
     )
     return ev, jnp.real(x), jnp.imag(x), rn

@@ -50,6 +50,9 @@ class HkSetParams(NamedTuple):
     qmat: jax.Array  # [nbeta, nbeta] shared
     h_diag: jax.Array  # [nk, ns, ngk]
     o_diag: jax.Array  # [nk, ngk] (S is spin-independent)
+    # [nk, m1, m2, m3] int32 ops/local.cube_inverse_map: the spheres' map on
+    # their bounding cube, for the local operator over the whole set
+    cube: jax.Array
     hub_re: jax.Array = None  # [nk, nhub, ngk] S-weighted Hubbard orbitals
     hub_im: jax.Array = None
     vhub_re: jax.Array = None  # [nk, ns, nhub, nhub] (per-k: +V phases)
@@ -180,6 +183,7 @@ def make_hkset_params(
     effective potential veff(G=0), included in the preconditioner diagonal
     exactly like the serial path (_h_o_diag). All leaves are REAL arrays."""
     from sirius_tpu.ops.hamiltonian import real_dtype_of
+    from sirius_tpu.ops.local import cube_inverse_map
 
     nbeta = ctx.beta.num_beta_total
     nk = ctx.gkvec.num_kpoints
@@ -220,6 +224,7 @@ def make_hkset_params(
         hub_im=None if hub_pair[1] is None else jnp.asarray(hub_pair[1]),
         vhub_re=None if vhub_pair[0] is None else jnp.asarray(vhub_pair[0]),
         vhub_im=None if vhub_pair[1] is None else jnp.asarray(vhub_pair[1]),
+        cube=jnp.asarray(cube_inverse_map(ctx.gkvec)),
     )
 
 
@@ -243,7 +248,7 @@ def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int,
     has_hub = params.hub_re is not None
 
     def one_k(ekin, mask, fft_index, beta_re, beta_im, hub_re_k, hub_im_k,
-              vhub_re_k, vhub_im_k, psi_k, theta_k):
+              vhub_re_k, vhub_im_k, psi_k, theta_k, cube_k):
         def one_spin(veff_s, dion_s, vhub_re_s, vhub_im_s, x0):
             pk = HkParams(
                 veff_r=veff_s,
@@ -255,6 +260,7 @@ def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int,
                 qmat=params.qmat,
                 hub=None if hub_re_k is None else _cplx(hub_re_k, hub_im_k),
                 vhub=None if vhub_re_s is None else _cplx(vhub_re_s, vhub_im_s),
+                cube=cube_k,
             )
             x = x0 * mask
             hx, sx = apply_h_s(pk, x)
@@ -271,11 +277,11 @@ def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int,
     x = jax.vmap(
         one_k,
         in_axes=(0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0,
-                 None if theta_index is None else 0),
+                 None if theta_index is None else 0, 0),
     )(
         params.ekin, params.mask, params.fft_index, params.beta_re,
         params.beta_im, params.hub_re, params.hub_im,
-        params.vhub_re, params.vhub_im, psi, theta_index,
+        params.vhub_re, params.vhub_im, psi, theta_index, params.cube,
     )
     return jnp.real(x), jnp.imag(x)
 
@@ -296,7 +302,7 @@ def davidson_kset(
     has_hub = params.hub_re is not None
 
     def one_k(ekin, mask, fft_index, beta_re, beta_im, h_diag_k, o_diag,
-              hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, psi_k, theta_k):
+              hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, psi_k, theta_k, cube_k):
         def one_spin(veff_s, dion_s, vhub_re_s, vhub_im_s, h_diag_s, x0):
             pk = HkParams(
                 veff_r=veff_s,
@@ -308,6 +314,7 @@ def davidson_kset(
                 qmat=params.qmat,
                 hub=None if hub_re_k is None else _cplx(hub_re_k, hub_im_k),
                 vhub=None if vhub_re_s is None else _cplx(vhub_re_s, vhub_im_s),
+                cube=cube_k,
             )
             return davidson(
                 apply_h_s, pk, x0, h_diag_s, o_diag, mask,
@@ -325,12 +332,12 @@ def davidson_kset(
     ev, x, rn = jax.vmap(
         one_k,
         in_axes=(0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0,
-                 None if theta_index is None else 0),
+                 None if theta_index is None else 0, 0),
     )(
         params.ekin, params.mask, params.fft_index, params.beta_re,
         params.beta_im, params.h_diag, params.o_diag,
         params.hub_re, params.hub_im, params.vhub_re, params.vhub_im, psi,
-        theta_index,
+        theta_index, params.cube,
     )
     return ev, jnp.real(x), jnp.imag(x), rn
 

@@ -111,7 +111,7 @@ _K1, _K2 = P("k", None), P("k", None, None)
 KSET_PARAM_SPECS = dict(
     veff_r=P(), ekin=_K1, mask=_K1, fft_index=_K1, beta_re=_K2, beta_im=_K2,
     dion=P(), qmat=P(), h_diag=_K2, o_diag=_K1, hub_re=_K2, hub_im=_K2,
-    vhub_re=P(), vhub_im=P(),
+    vhub_re=P(), vhub_im=P(), cube=P("k", None, None, None),
 )
 
 

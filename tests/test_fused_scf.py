@@ -65,7 +65,13 @@ def test_fused_matches_host_ultrasoft():
     deck = dict(
         gk_cutoff=3.0, pw_cutoff=7.0, ngridk=(2, 2, 2), num_bands=8,
         ultrasoft=True, use_symmetry=False,
-        extra_params={"num_dft_iter": 25, "density_tol": 5e-9,
+        # the density bar stands clear of a bump the fused run's residual
+        # makes in iteration 13, the one in which the energy meets its bar
+        # on both sides (1.7e-12 -> some 1e-9 -> 1e-12, the band solve's
+        # rounding noise through the device mixer): 3.1e-9 with the per-k
+        # FFT form of the local operator, 6.5e-9 with the k-set's DFT
+        # products (PR 33). A bar of 5e-9 left the count to the bump
+        extra_params={"num_dft_iter": 25, "density_tol": 2e-8,
                       "energy_tol": 1e-10},
     )
     r_host = _run("off", **deck)

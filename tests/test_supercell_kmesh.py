@@ -117,7 +117,9 @@ def test_setup_span_says_how_large_the_kset_program_is(rehearsal_deck,
     assert setup["kset"] == {
         "nk": nk, "ngk_max": int(ctx.gkvec.ngk_max), "subspace_rows": 3 * nb,
         "real_subspace": True,
-        "workspace_bytes": nk * 2 * nb * int(np.prod(ctx.fft_coarse.dims)) * 8}
+        "workspace_bytes": nk * 2 * nb * int(np.prod(ctx.fft_coarse.dims)) * 8,
+        # PR 33: the set's rows go through each box transform together
+        "local_rows": [nk * nb, 2 * nk * nb], "local_layout": "rows_minor"}
     assert setup["kset"]["ngk_max"] % 16 == 0  # control.ngk_pad_quantum
 
 
