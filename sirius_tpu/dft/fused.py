@@ -60,6 +60,7 @@ from sirius_tpu.dft.potential import (
     constant_fields_device,
     generate_potential_device,
     num_box_fills,
+    num_gradient_transforms,
 )
 from sirius_tpu.ops.augmentation import (
     build_aug_device_tables,
@@ -221,6 +222,11 @@ class FusedScf:
             constant_fields_device(self.tables["pot"], self.dims))
         # sphere-to-box placements one step runs (counters.num_tail_box_fills)
         self.box_fills = num_box_fills(xc, self.polarized)
+        # fine-box transforms the gradient correction adds to one step
+        # (counters.num_xc_gradient_transforms; 0 for LDA)
+        self.xc_gradient_transforms = num_gradient_transforms(
+            xc, self.polarized)
+        self.xc_kind = "gga" if xc.is_gga else "lda"  # the step's XC branch
         if exec_cache is not None:
             # serving: reuse a previously-jitted step whose trace signature
             # matches. The jitted callable is a bound method of the FIRST

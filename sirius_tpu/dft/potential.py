@@ -290,6 +290,14 @@ def num_box_fills(xc: XCFunctional, polarized: bool) -> int:
     return 4 + (3 if polarized else 0) + (4 * spins if xc.is_gga else 0)
 
 
+def num_gradient_transforms(xc: XCFunctional, polarized: bool) -> int:
+    """Fine-box transforms, both directions, that a gradient correction adds
+    to one generate_potential_device: a spin channel's three gradient
+    components back, its three products v_sigma grad n forward, their
+    divergence back; none for LDA."""
+    return 7 * (2 if polarized else 1) if xc.is_gga else 0
+
+
 def generate_potential_device(
     xc: XCFunctional,
     rho_g: jnp.ndarray,  # [ng] complex (inside the compiled program)
@@ -344,24 +352,25 @@ def generate_potential_device(
         n_up = 0.5 * (rho_xc + m)
         n_dn = 0.5 * (rho_xc - m)
         if xc.is_gga:
-            gu = gradient_r(0.5 * (rho_g + rho_core_g + mag_g))
-            gd = gradient_r(0.5 * (rho_g + rho_core_g - mag_g))
-            suu = sum(g * g for g in gu)
-            sdd = sum(g * g for g in gd)
-            sud = sum(a * b for a, b in zip(gu, gd))
-            out = xc.evaluate_polarized(
-                n_up.ravel(), n_dn.ravel(),
-                suu.ravel(), sud.ravel(), sdd.ravel(),
-            )
-            v_up = out["v_up"].reshape(dims)
-            v_dn = out["v_dn"].reshape(dims)
-            vsuu = out["vsigma_uu"].reshape(dims)
-            vsud = out["vsigma_ud"].reshape(dims)
-            vsdd = out["vsigma_dd"].reshape(dims)
-            v_up = v_up - to_r(divergence_g(
-                [2 * vsuu * a + vsud * b for a, b in zip(gu, gd)]))
-            v_dn = v_dn - to_r(divergence_g(
-                [2 * vsdd * b + vsud * a for a, b in zip(gu, gd)]))
+            with jax.named_scope("xc_gga"):
+                gu = gradient_r(0.5 * (rho_g + rho_core_g + mag_g))
+                gd = gradient_r(0.5 * (rho_g + rho_core_g - mag_g))
+                suu = sum(g * g for g in gu)
+                sdd = sum(g * g for g in gd)
+                sud = sum(a * b for a, b in zip(gu, gd))
+                out = xc.evaluate_polarized(
+                    n_up.ravel(), n_dn.ravel(),
+                    suu.ravel(), sud.ravel(), sdd.ravel(),
+                )
+                v_up = out["v_up"].reshape(dims)
+                v_dn = out["v_dn"].reshape(dims)
+                vsuu = out["vsigma_uu"].reshape(dims)
+                vsud = out["vsigma_ud"].reshape(dims)
+                vsdd = out["vsigma_dd"].reshape(dims)
+                v_up = v_up - to_r(divergence_g(
+                    [2 * vsuu * a + vsud * b for a, b in zip(gu, gd)]))
+                v_dn = v_dn - to_r(divergence_g(
+                    [2 * vsdd * b + vsud * a for a, b in zip(gu, gd)]))
         else:
             out = xc.evaluate_polarized(n_up.ravel(), n_dn.ravel())
             v_up = out["v_up"].reshape(dims)
@@ -372,12 +381,14 @@ def generate_potential_device(
     else:
         rho_xc = jnp.maximum(rho_r + rho_core_r, 0.0)
         if xc.is_gga:
-            g = gradient_r(rho_g + rho_core_g)
-            sigma = g[0] ** 2 + g[1] ** 2 + g[2] ** 2
-            out = xc.evaluate(rho_xc.ravel(), sigma.ravel())
-            vxc_r = out["v"].reshape(dims)
-            vs = out["vsigma"].reshape(dims)
-            vxc_r = vxc_r - to_r(divergence_g([2.0 * vs * gi for gi in g]))
+            with jax.named_scope("xc_gga"):
+                g = gradient_r(rho_g + rho_core_g)
+                sigma = g[0] ** 2 + g[1] ** 2 + g[2] ** 2
+                out = xc.evaluate(rho_xc.ravel(), sigma.ravel())
+                vxc_r = out["v"].reshape(dims)
+                vs = out["vsigma"].reshape(dims)
+                vxc_r = vxc_r - to_r(
+                    divergence_g([2.0 * vs * gi for gi in g]))
         else:
             out = xc.evaluate(rho_xc.ravel())
             vxc_r = out["v"].reshape(dims)

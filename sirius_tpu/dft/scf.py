@@ -1124,8 +1124,12 @@ def _run_scf_inner(
                 if _span_fence:
                     _fence((acc, dm_re, dm_im))
                 _sp.close()
-                _sp = _stage("scf.fused_step", it=it + 1, box_fill="gather")
+                _sp = _stage(
+                    "scf.fused_step", it=it + 1, box_fill="gather",
+                    box_fills=fused.box_fills, xc=fused.xc_kind)
                 counters["num_tail_box_fills"] += fused.box_fills
+                counters["num_xc_gradient_transforms"] += (
+                    fused.xc_gradient_transforms)
                 fused_carry, fused_out = fused.step(
                     fused_carry, acc, dm_re, dm_im, ev_dev, occ_w,
                     entropy_sum, out.pr, out.pi,
@@ -1612,7 +1616,10 @@ def _run_scf_inner(
         d_by_spin = fin["d_by_spin"]
         rho_resid_g = fin["rho_resid_g"]
         dm_blocks_by_spin = fin["dm_blocks_by_spin"]
-        with profile("scf::potential"):
+        # the reported energy's potential: f64 on the host, with a gradient
+        # correction its seven more transforms and the autodiff on the CPU
+        with profile("scf::potential"), obs_spans.span(
+                "scf.finalize.potential", xc=fused.xc_kind):
             pot = generate_potential(ctx, rho_g, xc, mag_g)
     psi = band.host_psi()
     if psi is None:
@@ -1711,6 +1718,8 @@ def _run_scf_inner(
         num_loc_op_applied=int(counters["num_loc_op_applied"]),
         num_fft_boxes=int(counters["num_fft_boxes"]),
         num_tail_box_fills=int(counters["num_tail_box_fills"]),
+        num_xc_gradient_transforms=int(
+            counters["num_xc_gradient_transforms"]),
         energy_resolution_ha=abs(e_total) * pair_eps(
             fused.rdt if fused is not None else np.float64),
     )

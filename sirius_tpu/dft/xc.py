@@ -30,6 +30,92 @@ _TINY = 1e-25
 _DENS_TH = 1e-13
 
 
+# ---------------------------------------------------------------------------
+# Elementary functions for the gradient-corrected functionals in float32.
+#
+# The TPU evaluates float32 exp, log, log1p and the general pow on its
+# transcendental unit to about 1e-6 relative, with a bias (PERF.md, PR 34:
+# exp -8e-7 +- 1.6e-6, log1p +- 6e-5 of itself on [0.004, 3]; the CPU backend
+# 3e-8), where sqrt, cbrt, division and the multiply-adds of a polynomial are
+# IEEE. PBE correlation is built on three logarithms and an exponential at
+# every point of the box, and the bias summed over the box (-5e-5 Ha on 16
+# atoms, 3e-6 Ha an atom of a 5e-6 bar) went into the energy through the
+# potential the bands saw. So the float32 forms below are the classic
+# range-reduction + polynomial ones (Cephes logf / expf, under one ulp) in
+# plain arithmetic, their derivatives written as what they are; any other
+# dtype takes the library's function, which is exact enough there. Cube
+# roots go through cbrt, which is accurate on both and whose derivative is
+# ans / (3 x), never a pow. Only the GGA functionals and PW92 use these:
+# the LDA pair X + PZ, which every LDA deck runs, is as it was, bit for bit.
+
+_LN2_HI = 0.693359375
+_LN2_LO = -2.12194440e-4
+
+
+def _is_f32(x) -> bool:
+    return jnp.asarray(x).dtype == jnp.float32
+
+
+def _log1p_f32(x):
+    """log(1 + x) of a float32 x > -1/2, Cephes logf on u = 1 + x = m 2^e
+    with m in [sqrt(1/2), sqrt(2)): a degree-8 polynomial in f = m - 1, e ln 2
+    in two words. Where e = 0, f is x itself, so the rounding of 1 + x never
+    enters (no compensation term for a compiler to fold away)."""
+    bits = jax.lax.bitcast_convert_type(1.0 + x, jnp.int32)
+    e = (bits >> 23) - 126
+    m = jax.lax.bitcast_convert_type(
+        (bits & 0x007FFFFF) | 0x3F000000, jnp.float32)  # [0.5, 1)
+    small = m < 0.70710678
+    e = jnp.where(small, e - 1, e)
+    f = jnp.where(e == 0, x, jnp.where(small, m + m, m) - 1.0)
+    e = e.astype(jnp.float32)
+    z = f * f
+    p = 7.0376836292e-2
+    for c in (-1.1514610310e-1, 1.1676998740e-1, -1.2420140846e-1,
+              1.4249322787e-1, -1.6668057665e-1, 2.0000714765e-1,
+              -2.4999993993e-1, 3.3333331174e-1):
+        p = p * f + c
+    y = f * z * p + _LN2_LO * e - 0.5 * z
+    return f + y + _LN2_HI * e
+
+
+def _exp_f32(x):
+    """exp of a float32 of moderate size (|x| < 80), Cephes expf: x = n ln 2
+    + r with ln 2 in two words, a degree-5 polynomial in r, 2^n by its bits."""
+    n = jnp.floor(1.44269504088896341 * x + 0.5)
+    r = x - n * _LN2_HI - n * _LN2_LO
+    p = 1.9875691500e-4
+    for c in (1.3981999507e-3, 8.3334519073e-3, 4.1665795894e-2,
+              1.6666665459e-1, 5.0000001201e-1):
+        p = p * r + c
+    two_n = jax.lax.bitcast_convert_type(
+        (n.astype(jnp.int32) + 127) << 23, jnp.float32)
+    return (p * r * r + r + 1.0) * two_n
+
+
+@jax.custom_jvp
+def _log1p(x):
+    return _log1p_f32(x) if _is_f32(x) else jnp.log1p(x)
+
+
+@_log1p.defjvp
+def _log1p_jvp(primals, tangents):
+    (x,), (g,) = primals, tangents
+    return _log1p(x), g / (1.0 + x)
+
+
+@jax.custom_jvp
+def _exp(x):
+    return _exp_f32(x) if _is_f32(x) else jnp.exp(x)
+
+
+@_exp.defjvp
+def _exp_jvp(primals, tangents):
+    (x,), (g,) = primals, tangents
+    ans = _exp(x)
+    return ans, g * ans
+
+
 def _lda_x_e(nu: jnp.ndarray, nd: jnp.ndarray) -> jnp.ndarray:
     """Slater exchange energy per volume, spin-scaled."""
     cx = (3.0 / 4.0) * (3.0 / jnp.pi) ** (1.0 / 3.0)
@@ -67,7 +153,7 @@ def _lda_c_pz_e(nu: jnp.ndarray, nd: jnp.ndarray) -> jnp.ndarray:
 def _pw92_g(rs: jnp.ndarray, a, a1, b1, b2, b3, b4) -> jnp.ndarray:
     s = jnp.sqrt(rs)
     den = 2.0 * a * (b1 * s + b2 * rs + b3 * rs * s + b4 * rs * rs)
-    return -2.0 * a * (1 + a1 * rs) * jnp.log1p(1.0 / den)
+    return -2.0 * a * (1 + a1 * rs) * _log1p(1.0 / den)
 
 
 def _lda_c_pw_e(nu: jnp.ndarray, nd: jnp.ndarray, mod: bool = False) -> jnp.ndarray:
@@ -80,7 +166,7 @@ def _lda_c_pw_e(nu: jnp.ndarray, nd: jnp.ndarray, mod: bool = False) -> jnp.ndar
     reproducible 1e-5 Ha-class shift on PBE deck totals."""
     n = nu + nd
     zeta = jnp.clip((nu - nd) / n, -1.0, 1.0)
-    rs = (3.0 / (4.0 * jnp.pi * n)) ** (1.0 / 3.0)
+    rs = jnp.cbrt(3.0 / (4.0 * jnp.pi * n))
     a0, a1, a2 = (
         (0.0310907, 0.01554535, 0.0168869) if mod
         else (0.031091, 0.015545, 0.016887)
@@ -141,7 +227,7 @@ _PBESOL_BETA = 0.046
 def _pbe_x_half(n2: jnp.ndarray, sigma4: jnp.ndarray, mu: float) -> jnp.ndarray:
     """PBE-family exchange per volume for a fully polarized channel
     (2n_sigma, 4 sigma_ss), halved by the caller's spin-scaling."""
-    kf = (3.0 * jnp.pi**2 * n2) ** (1.0 / 3.0)
+    kf = jnp.cbrt(3.0 * jnp.pi**2 * n2)
     ex_lda = -(3.0 / (4.0 * jnp.pi)) * kf * n2
     s2 = sigma4 / jnp.maximum(4.0 * kf**2 * n2**2, _TINY)
     fx = 1.0 + _PBE_KAPPA - _PBE_KAPPA / (1.0 + mu * s2 / _PBE_KAPPA)
@@ -159,14 +245,14 @@ def _pbe_c_e(nu, nd, suu, sud, sdd, beta: float = _PBE_BETA) -> jnp.ndarray:
     zeta = jnp.clip((nu - nd) / n, -1.0, 1.0)
     sigma = suu + 2 * sud + sdd
     eps_lda = _lda_c_pw_e(nu, nd, mod=True) / n  # libxc: PBE is on pw_mod
-    phi = 0.5 * ((1 + zeta) ** (2.0 / 3.0) + (1 - zeta) ** (2.0 / 3.0))
-    kf = (3.0 * jnp.pi**2 * n) ** (1.0 / 3.0)
+    phi = 0.5 * (jnp.cbrt(1 + zeta) ** 2 + jnp.cbrt(1 - zeta) ** 2)
+    kf = jnp.cbrt(3.0 * jnp.pi**2 * n)
     ks = jnp.sqrt(4.0 * kf / jnp.pi)
     t2 = sigma / jnp.maximum((2.0 * phi * ks * n) ** 2, _TINY)
-    a_den = jnp.exp(-eps_lda / (_PBE_GAMMA * phi**3)) - 1.0
+    a_den = _exp(-eps_lda / (_PBE_GAMMA * phi**3)) - 1.0
     aa = beta / _PBE_GAMMA / jnp.maximum(a_den, _TINY)
     num = 1.0 + aa * t2
-    h = _PBE_GAMMA * phi**3 * jnp.log1p(
+    h = _PBE_GAMMA * phi**3 * _log1p(
         beta / _PBE_GAMMA * t2 * num / (1.0 + aa * t2 + aa**2 * t2**2)
     )
     return n * (eps_lda + h)

@@ -59,6 +59,7 @@ UNCOSTED_SPANS = (
     "scf.run",
     "scf.setup",
     "scf.finalize",
+    "scf.finalize.potential",
     "scf.autosave",
     "md.integrate",
     "md.extrapolate",
