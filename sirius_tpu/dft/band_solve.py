@@ -44,11 +44,13 @@ from sirius_tpu.parallel.batched import (
     join_cplx,
     split_cplx,
 )
+from sirius_tpu.solvers import subspace_eigh
 from sirius_tpu.solvers.davidson import (
     apply_blocks,
     count_applies,
     davidson,
     num_applies,
+    num_eigh,
 )
 from sirius_tpu.utils.profiler import counters
 
@@ -141,9 +143,10 @@ def _hk_params(cache, ctx, hub, ik, veff_r, dmat, dtype, vhub_s=None):
 def _book_solve(num_steps, ctx, rows_per_box=1):
     # H*psi application count of one solve of the whole (k, spin) set
     # (reference num_loc_op_applied counter) and the FFT boxes behind it
+    copies = ctx.gkvec.num_kpoints * ctx.num_spins
     count_applies(counters, apply_blocks(num_steps, ctx.num_bands),
-                  copies=ctx.gkvec.num_kpoints * ctx.num_spins,
-                  rows_per_box=rows_per_box)
+                  copies=copies, rows_per_box=rows_per_box)
+    counters["num_subspace_eigh"] += copies * num_eigh(num_steps)
 
 
 def _host_evals(ctx, ev_by_spin):
@@ -282,6 +285,7 @@ class KsetSolver:
         if self.mesh is not None:
             block = self._psi_sharding.shard_shape(block)
         local_rows = block[0] * block[2]
+        sub_dtype = real_dtype_of(wf_dtype) if self.tr is not None else wf_dtype
         return {"kset": {
             "nk": nk, "ngk_max": int(ctx.gkvec.ngk_max),
             "subspace_rows": 3 * nb, "real_subspace": self.tr is not None,
@@ -289,6 +293,12 @@ class KsetSolver:
             * np.dtype(wf_dtype).itemsize // ndev,
             "local_rows": [local_rows, 2 * local_rows],
             "local_layout": "rows_minor",
+            # who diagonalises the subspace matrices, by the rule the
+            # program's own choice comes from, and what one call carries on
+            # one device (its k-points x spin channels)
+            "subspace_eigh": {
+                "form": subspace_eigh.form(sub_dtype, self.dev.platform),
+                "rows": 3 * nb, "batch": block[0] * block[1]},
         }}
 
     def _gkc_dev(self, rdt):
@@ -381,7 +391,7 @@ class KsetSolver:
                 pb_im = jax.device_put(jnp.asarray(pb_im), _big)
             pr, pi = initialize_subspace_kset(
                 ps, jnp.asarray(pb_re), jnp.asarray(pb_im), nb,
-                theta_index=self._theta_index(),
+                theta_index=self._theta_index(), mesh=self.mesh,
             )
             pr, pi = self._place_psi(pr), self._place_psi(pi)
             count_applies(counters, [(self.psi_big.shape[2], 1)],
@@ -407,7 +417,7 @@ class KsetSolver:
                 ps, pr, pi,
                 num_steps=self.num_steps,
                 res_tol=_rtol(res_tol, rdt),
-                theta_index=self._theta_index(),
+                theta_index=self._theta_index(), mesh=self.mesh,
             )
         # canonicalize the pair onto the explicit psi sharding (a no-op
         # when GSPMD already placed it there): downstream consumers must
@@ -468,7 +478,7 @@ class KsetSolver:
 
         ev, self.pr, self.pi, rn = davidson_kset(
             self.ps, self.pr, self.pi, num_steps=2 * self.num_steps,
-            res_tol=res_tol, theta_index=self._theta_index(),
+            res_tol=res_tol, theta_index=self._theta_index(), mesh=self.mesh,
         )
         return BandOut(np.asarray(ev, dtype=np.float64), rn, self.pr, self.pi)
 

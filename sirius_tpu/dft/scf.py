@@ -1717,6 +1717,7 @@ def _run_scf_inner(
         e_total=e_total, recoveries=sup.recoveries, wall_s=result["scf_time"],
         num_loc_op_applied=int(counters["num_loc_op_applied"]),
         num_fft_boxes=int(counters["num_fft_boxes"]),
+        num_subspace_eigh=int(counters["num_subspace_eigh"]),
         num_tail_box_fills=int(counters["num_tail_box_fills"]),
         num_xc_gradient_transforms=int(
             counters["num_xc_gradient_transforms"]),

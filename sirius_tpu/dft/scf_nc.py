@@ -203,11 +203,14 @@ def run_scf_nc(
             )
             psi = None
             evals = np.asarray(ev, dtype=np.float64)
-            from sirius_tpu.solvers.davidson import apply_blocks, count_applies
+            from sirius_tpu.solvers.davidson import (
+                apply_blocks, count_applies, num_eigh,
+            )
 
             # a spinor row is two component boxes (ops/spinor.py)
             count_applies(counters, apply_blocks(itsol.num_steps, nb),
                           copies=nk, components=2)
+            counters["num_subspace_eigh"] += nk * num_eigh(itsol.num_steps)
 
         # --- occupations (spinor bands: max occupancy 1) ---
         mu, occ, entropy_sum = find_fermi(

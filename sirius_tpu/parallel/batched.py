@@ -27,9 +27,14 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from sirius_tpu.ops.hamiltonian import HkParams, apply_h_s
+from sirius_tpu.parallel.mesh import KSET_PARAM_SPECS
 from sirius_tpu.solvers.davidson import davidson
+
+# over_k_pool's specs: a leading k axis split over "k", or one copy a device
+_K, _REP = PartitionSpec("k"), PartitionSpec()
 
 
 class HkSetParams(NamedTuple):
@@ -228,9 +233,31 @@ def make_hkset_params(
     )
 
 
-@partial(jax.jit, static_argnames=("nb",))
+def kset_param_specs(params: HkSetParams) -> HkSetParams:
+    """The ("k", "b") mesh's PartitionSpec of every leaf `params` has."""
+    return HkSetParams(**{
+        name: None if leaf is None else KSET_PARAM_SPECS[name]
+        for name, leaf in params._asdict().items()})
+
+
+def over_k_pool(fn, mesh, in_specs, out_specs):
+    """`fn` as it is where there is no mesh; on the ("k", "b") mesh, `fn`
+    inside a shard_map over "k" ("b" stays with the partitioner): every
+    device runs the per-k program on its own k-points. A k-pool shares
+    nothing inside the band solve, and the partitioner does not know it of
+    a kernel: left to it, the TPU's eigh custom call is run on the whole
+    k-set on every chip behind an all-gather of its operands (compiled for a
+    described v5e:2x2, PERF.md section 6, PR 35)."""
+    if mesh is None:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={"k"},
+                         check_vma=False)
+
+
+@partial(jax.jit, static_argnames=("nb", "mesh"))
 def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int,
-                             theta_index=None):
+                             theta_index=None, mesh=None):
     """LCAO subspace initialization for the whole (k, spin) set: one H/S
     application to the full atomic-orbital block (+ random tail), one
     generalized Rayleigh-Ritz, keep the lowest nb Ritz vectors (reference
@@ -239,9 +266,18 @@ def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int,
     BEFORE the rotation loses orbital characters and mis-seeds the band
     solver (Fe 3d, test03). ``theta_index`` [nk, ngk]: every k-point is
     time-reversal invariant, every row of the block Theta-real there, and so
-    are the rotated vectors (solvers/davidson.py, REAL SUBSPACE).
+    are the rotated vectors (solvers/davidson.py, REAL SUBSPACE). ``mesh``:
+    the ("k", "b") mesh the operands are sharded on (over_k_pool).
 
     Returns (psi_re, psi_im) [nk, ns, nb, ngk]."""
+    return over_k_pool(
+        partial(_initialize_subspace_kset, nb=nb), mesh,
+        (kset_param_specs(params), _K, _K, None if theta_index is None else _K),
+        (_K, _K),
+    )(params, psi_re, psi_im, theta_index)
+
+
+def _initialize_subspace_kset(params, psi_re, psi_im, theta_index, nb):
     from sirius_tpu.solvers.davidson import subspace_rotate
 
     psi = _cplx(psi_re, psi_im)
@@ -286,18 +322,28 @@ def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int,
     return jnp.real(x), jnp.imag(x)
 
 
-@partial(jax.jit, static_argnames=("num_steps",))
+@partial(jax.jit, static_argnames=("num_steps", "mesh"))
 def davidson_kset(
     params: HkSetParams, psi_re, psi_im, num_steps: int = 20, res_tol: float = 1e-6,
-    theta_index=None,
+    theta_index=None, mesh=None,
 ):
     """Solve bands at every (k, spin) in one vmapped call. ``theta_index``
     [nk, ngk]: every k-point of the set is time-reversal invariant and every
     row of psi Theta-real there, so the subspace eigenproblems are real
-    symmetric (solvers/davidson.py, REAL SUBSPACE).
+    symmetric (solvers/davidson.py, REAL SUBSPACE). ``mesh``: the ("k", "b")
+    mesh the operands are sharded on (over_k_pool).
 
     psi_re/psi_im: [nk, ns, nb, ngk] real pair ->
     (evals [nk, ns, nb], psi_re', psi_im', rnorm [nk, ns, nb])."""
+    return over_k_pool(
+        partial(_davidson_kset, num_steps=num_steps), mesh,
+        (kset_param_specs(params), _K, _K, _REP,
+         None if theta_index is None else _K),
+        (_K, _K, _K, _K),
+    )(params, psi_re, psi_im, res_tol, theta_index)
+
+
+def _davidson_kset(params, psi_re, psi_im, res_tol, theta_index, num_steps):
     psi = _cplx(psi_re, psi_im)
     has_hub = params.hub_re is not None
 

@@ -61,6 +61,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from sirius_tpu.solvers.subspace_eigh import eigh
+
 # refresh cadence of the carried H X / H P blocks; scf.py's H-application
 # counters derive from this, keep them in sync via this constant
 REFRESH_EVERY = 5
@@ -79,6 +81,12 @@ def num_applies(num_steps: int, nb: int, refresh_every: int = REFRESH_EVERY) -> 
     """H-applications (in band rows) of one davidson() call."""
     return sum(rows * times
                for rows, times in apply_blocks(num_steps, nb, refresh_every))
+
+
+def num_eigh(num_steps: int) -> int:
+    """Subspace eigenproblems of one davidson() call: the overlap and the
+    reduced H of every step's _rayleigh_ritz, and ortho's Gram matrix."""
+    return 2 * num_steps + 1
 
 
 def count_applies(counters, blocks, copies: int = 1, rows_per_box: int = 1,
@@ -113,7 +121,7 @@ def residual_health(rnorm, blowup: float = 1e2) -> tuple[float, bool]:
 
 def _rayleigh_ritz(hsub: jax.Array, ssub: jax.Array, nev: int):
     """Lowest-nev gen-EVP of a possibly rank-deficient subspace pair."""
-    s, u = jnp.linalg.eigh(ssub)
+    s, u = eigh(ssub)
     smax = jnp.max(jnp.abs(s))
     # rank cutoff must scale with the working precision: eigh noise sits at
     # ~eps*smax (1e-7 for c64), so a fixed 1e-13 would rsqrt-amplify noise
@@ -133,7 +141,7 @@ def _rayleigh_ritz(hsub: jax.Array, ssub: jax.Array, nev: int):
     # otherwise loses the wanted eigenvalues under eps * shift
     shift = 1.0 + jnp.max(jnp.sum(jnp.abs(at), axis=1))
     at = at + jnp.diag(jnp.where(good, 0.0, shift).astype(at.dtype))
-    e, y = jnp.linalg.eigh(at)
+    e, y = eigh(at)
     c = t @ y
     return e[:nev], c[:, :nev]
 
@@ -213,7 +221,7 @@ def davidson(
 
     def ortho(x):
         g = _subspace_matrix((x * mask) @ (x * mask).conj().T, theta_index)
-        s, u = jnp.linalg.eigh(g)
+        s, u = eigh(g)
         good = s > 50.0 * jnp.finfo(g.real.dtype).eps * jnp.max(jnp.abs(s))
         t = u * jnp.where(good, jax.lax.rsqrt(jnp.where(good, s, 1.0)), 0.0)[None, :]
         return _combine(t.conj(), x, theta_index)

@@ -304,3 +304,7 @@ def test_plan_says_how_many_rows_a_box_transform_carries(
     rows = per_device[0] * per_device[1]
     assert kset["local_rows"] == [rows, 2 * rows]
     assert kset["local_layout"] == "rows_minor"
+    # PR 35: the matrices one eigh call carries on one device are its
+    # k-points (a split over "b" does not divide them)
+    assert kset["subspace_eigh"] == {
+        "form": "library", "rows": 24, "batch": per_device[0]}

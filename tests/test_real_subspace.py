@@ -189,8 +189,12 @@ def test_real_subspace_program_has_no_complex_eigh(ctx222, tr222,
         lambda *a: davidson_kset(*a, num_steps=5, res_tol=tol))(
             ps, pr, pi).jaxpr, [])
     assert real and set(real) == {np.dtype(np.float32)}
-    assert cplx and set(cplx) == {np.dtype(np.complex64)}
-    assert len(real) == len(cplx)
+    # the complex program's eigh follows the backend it is lowered for
+    # (solvers/subspace_eigh.py): at every site the library's complex call
+    # and, for the TPU, a real one behind the tridiagonal reduction
+    assert set(cplx) == {np.dtype(np.complex64), np.dtype(np.float32)}
+    assert cplx.count(np.dtype(np.complex64)) == len(real)
+    assert cplx.count(np.dtype(np.float32)) == len(real)
 
 
 @pytest.fixture(scope="module")

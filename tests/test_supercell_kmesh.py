@@ -119,7 +119,9 @@ def test_setup_span_says_how_large_the_kset_program_is(rehearsal_deck,
         "real_subspace": True,
         "workspace_bytes": nk * 2 * nb * int(np.prod(ctx.fft_coarse.dims)) * 8,
         # PR 33: the set's rows go through each box transform together
-        "local_rows": [nk * nb, 2 * nk * nb], "local_layout": "rows_minor"}
+        "local_rows": [nk * nb, 2 * nk * nb], "local_layout": "rows_minor",
+        # PR 35: real matrices take the library's eigh on every backend
+        "subspace_eigh": {"form": "library", "rows": 3 * nb, "batch": nk}}
     assert setup["kset"]["ngk_max"] % 16 == 0  # control.ngk_pad_quantum
 
 
@@ -132,6 +134,8 @@ def test_result_counts_the_kpoints_it_solved(eight_k_f32):
     # boundaries of two blocks), and the LCAO block once a job
     per = c["num_loc_op_applied"] / c["num_kpoints_solved"] / iters / 8
     assert 29.0 <= per < 29.0 + 2.0 / iters + 1e-9
+    # PR 35: two eigenproblems a step and ortho's one, a solve a k-point
+    assert c["num_subspace_eigh"] == 8 * iters * (2 * 20 + 1)
 
 
 def test_time_reversal_leaves_36_of_the_444_mesh(one_device):
@@ -140,6 +144,10 @@ def test_time_reversal_leaves_36_of_the_444_mesh(one_device):
     assert r["counters"]["num_kpoints_solved"] == 36
     (setup,) = [s for s in r["_spans"] if s["name"] == "scf.setup"]
     assert setup["kset"]["nk"] == 36
+    # the complex subspace, lowered for the CPU: LAPACK's call (on a TPU the
+    # same deck reads "tridiagonal_real", solvers/subspace_eigh.form)
+    assert setup["kset"]["subspace_eigh"] == {
+        "form": "library", "rows": 24, "batch": 36}
 
 
 def test_gamma_job_has_no_kset_plan(one_device):

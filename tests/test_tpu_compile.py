@@ -13,6 +13,7 @@ in a skipif or in parametrize (on-chip-measurement guide section 2): only
 the xdist worker that runs this file may load the TPU library.
 """
 
+import contextlib
 import os
 
 import jax
@@ -91,10 +92,14 @@ def _shapes(tree, sharding):
         tree)
 
 
-def _compile(lowered):
+def runtime_scope():
     from sirius_tpu import runtime
 
-    with runtime.scf_scope():
+    return runtime.scf_scope()
+
+
+def _compile(lowered):
+    with runtime_scope():
         return lowered().compile()
 
 
@@ -107,6 +112,53 @@ def _check(compiled, no_64bit=False):
     if no_64bit:
         assert "c128[" not in txt and "f64[" not in txt
     return txt
+
+
+def _eigh_batches(txt):
+    """Leading dimension of every EighTpu custom call's eigenvalue output:
+    the matrices one call of the kernel carries on one chip."""
+    import re
+
+    return [int(m.group(1)) for m in re.finditer(
+        r"= \(f32\[(\d+),[^\n]*custom_call_target=\"EighTpu\"", txt)]
+
+
+def _no_jacobi(txt):
+    """A complex subspace program after PR 35: the real kernel behind the
+    tridiagonal reduction (solvers/subspace_eigh.py), no Jacobi sweep loop
+    and no conditional (subspace_eigh_share reads conditionals as the QDWH
+    program's: the platform switch has to be gone from the compiled text)."""
+    assert "EighTpu" in txt
+    assert "EighJacobiSweeps" not in txt and "ApplyRotations" not in txt
+    assert " conditional(" not in txt
+
+
+@contextlib.contextmanager
+def _library_eigh():
+    """Programs traced inside have the parent's call, jnp.linalg.eigh, at
+    the solver's three sites (solvers/davidson.py)."""
+    import importlib
+
+    dav = importlib.import_module("sirius_tpu.solvers.davidson")
+    old, dav.eigh = dav.eigh, lambda a: jnp.linalg.eigh(a)
+    jax.clear_caches()
+    try:
+        with runtime_scope():
+            yield
+    finally:
+        dav.eigh = old
+        jax.clear_caches()
+
+
+def _lowered_text(lower):
+    with runtime_scope():
+        return _strip_loc(lower().as_text())
+
+
+def _strip_loc(txt):
+    import re
+
+    return re.sub(r"loc\(.*?\)|#loc.*", "", txt)
 
 
 def _kset_inputs(ctx, nb):
@@ -170,9 +222,14 @@ def test_gamma_band_solve_one_chip(topo, no_compile_cache, ctx_gamma):
     x0 = jax.ShapeDtypeStruct((nb, ngk), np.float32, sharding=one)
     diag = jax.ShapeDtypeStruct((ngk,), np.float32, sharding=one)
     tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
-    _check(_compile(lambda: davidson_gamma.lower(
-        gp, x0, diag, diag, num_steps=NUM_STEPS, res_tol=tol)),
-        no_64bit=True)
+    lower = lambda: davidson_gamma.lower(
+        gp, x0, diag, diag, num_steps=NUM_STEPS, res_tol=tol)
+    _check(_compile(lower), no_64bit=True)
+    # real matrices bypass solvers/subspace_eigh.py's reduction by dtype:
+    # the program is lowered to the text it has with the library's call
+    mine = _lowered_text(lower)
+    with _library_eigh():
+        assert mine == _lowered_text(lower)
     nbig = jax.ShapeDtypeStruct((nb + 6, ngk), np.float32, sharding=one)
     _check(_compile(lambda: initialize_subspace_gamma.lower(gp, nbig, nb=nb)),
            no_64bit=True)
@@ -222,7 +279,8 @@ def test_gamma_fused_tail_one_chip(topo, no_compile_cache):
 
 
 def test_kset_band_solve_one_chip(topo, no_compile_cache, ctx_kmesh):
-    """The batched k-set solve (complex Hermitian eigh at 3*nb inside)."""
+    """The batched k-set solve (complex Hermitian eigh at 3*nb inside):
+    reduced to real tridiagonal matrices, all the set's in each kernel call."""
     from sirius_tpu.parallel.batched import davidson_kset, density_kset
 
     ctx = ctx_kmesh
@@ -230,8 +288,10 @@ def test_kset_band_solve_one_chip(topo, no_compile_cache, ctx_kmesh):
     ps, psi = _kset_inputs(ctx, ctx.num_bands)
     ps, psi = _shapes(ps, one), _shapes(psi, one)
     tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
-    _check(_compile(lambda: davidson_kset.lower(
+    txt = _check(_compile(lambda: davidson_kset.lower(
         ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol)), no_64bit=True)
+    _no_jacobi(txt)
+    assert set(_eigh_batches(txt)) == {ctx.gkvec.num_kpoints}
     occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=one)
     _check(_compile(lambda: density_kset.lower(ps, psi, psi, occ)),
            no_64bit=True)
@@ -241,9 +301,11 @@ def test_kset_band_solve_real_subspace_one_chip(topo, no_compile_cache,
                                                 ctx_kmesh):
     """The same solve with real subspace matrices (every k-point of the
     2x2x2 mesh is time-reversal invariant; solvers/davidson.py, REAL
-    SUBSPACE): what it is for is the eigensolver the TPU builds. A complex
-    Hermitian eigh is expanded into Jacobi sweep loops, a real symmetric one
-    of 78 rows is the EighTpu kernel."""
+    SUBSPACE): what it is for is the eigensolver the TPU builds. A real
+    symmetric eigh of 78 rows is the EighTpu kernel; the library's complex
+    Hermitian one is expanded into Jacobi sweep loops, which is why the
+    complex program reduces its matrices to real ones first (PR 35). The
+    real program does not go through that: its text is the parent's."""
     from sirius_tpu.dft.band_solve import time_reversal_index
     from sirius_tpu.parallel.batched import (
         davidson_kset, initialize_subspace_kset,
@@ -257,13 +319,18 @@ def test_kset_band_solve_real_subspace_one_chip(topo, no_compile_cache,
     ps, psi = _shapes(ps, one), _shapes(psi, one)
     theta = _shapes(theta.astype(np.int32), one)
     tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
-    real = _check(_compile(lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, theta_index=theta)),
-        no_64bit=True)
+    lower = lambda: davidson_kset.lower(
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, theta_index=theta)
+    real = _check(_compile(lower), no_64bit=True)
     assert "EighTpu" in real and "EighJacobiSweeps" not in real
-    cplx = _check(_compile(lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol)), no_64bit=True)
-    assert "EighJacobiSweeps" in cplx and "EighTpu" not in cplx
+    mine = _lowered_text(lower)
+    with _library_eigh():
+        assert mine == _lowered_text(lower)
+    # the library's complex eigh, the parent's program: Jacobi sweep loops
+    lower = lambda: davidson_kset.lower(
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol)
+    with _library_eigh():
+        assert "EighJacobiSweeps" in lower().compile().as_text()
     _check(_compile(lambda: initialize_subspace_kset.lower(
         ps, psi, psi, nb=ctx.num_bands, theta_index=theta)), no_64bit=True)
 
@@ -316,17 +383,37 @@ def test_kb_mesh_step_four_chips(topo, no_compile_cache, ctx_kmesh):
         for name, leaf in ps._asdict().items() if leaf is not None})
     psi = _shapes(psi, psi_sh)
     tol = jax.ShapeDtypeStruct((), np.float32, sharding=rep)
-    _check(_compile(lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol)), no_64bit=True)
+    # the benchmark's four-chip cell: the 36 k-points of the 4x4x4 mesh, the
+    # complex subspace. Inside the shard_map over "k" (batched.over_k_pool)
+    # each chip's kernel calls carry its own 9 matrices and the program holds
+    # no collective; left to the partitioner they carry all 36 behind
+    # all-gathers (compiled once for PR 35, not kept as a test's compile)
+    ctx444 = _ctx((4, 4, 4))
+    assert production_mesh(ctx444.gkvec.num_kpoints, nb,
+                           devices=topo.devices)[0].shape == mesh.shape
+    ps4, psi4 = _kset_inputs(ctx444, nb)
+    ps4 = ps4._replace(**{
+        name: _shapes(leaf, NamedSharding(mesh, KSET_PARAM_SPECS[name]))
+        for name, leaf in ps4._asdict().items() if leaf is not None})
+    psi4 = _shapes(psi4, psi_sh)
+    txt = _check(_compile(lambda: davidson_kset.lower(
+        ps4, psi4, psi4, num_steps=NUM_STEPS, res_tol=tol, mesh=mesh)),
+        no_64bit=True)
+    _no_jacobi(txt)
+    assert set(_eigh_batches(txt)) == {ctx444.gkvec.num_kpoints // 4} == {9}
+    for kind in ("all-gather", "all-reduce", "all-to-all",
+                 "collective-permute", "reduce-scatter"):
+        assert f" {kind}(" not in txt and f" {kind}-start(" not in txt, kind
     # ... and with real subspace matrices, the index sharded over "k" as
     # band_solve.KsetSolver places it (this mesh is all of them invariant)
     from sirius_tpu.dft.band_solve import time_reversal_index
 
     theta = _shapes(time_reversal_index(ctx.gkvec).astype(np.int32),
                     NamedSharding(mesh, P("k", None)))
-    _check(_compile(lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, theta_index=theta)),
-        no_64bit=True)
+    txt = _check(_compile(lambda: davidson_kset.lower(
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, theta_index=theta,
+        mesh=mesh)), no_64bit=True)
+    assert set(_eigh_batches(txt)) == {ctx.gkvec.num_kpoints // 4}
     occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=ev_sh)
     txt = _check(_compile(lambda: density_kset.lower(ps, psi, psi, occ)),
                  no_64bit=True)
