@@ -171,10 +171,11 @@ def g_to_r_gather(coeffs: jax.Array, inv_index: jax.Array, dims: tuple[int, int,
     """
     batch = coeffs.shape[:-1]
     n = dims[0] * dims[1] * dims[2]
-    padded = jnp.concatenate(
-        [coeffs, jnp.zeros(batch + (1,), dtype=coeffs.dtype)], axis=-1
-    )
-    box = padded[..., inv_index].reshape(batch + dims)
+    with jax.named_scope("box_fill"):  # the placement's name in a capture
+        padded = jnp.concatenate(
+            [coeffs, jnp.zeros(batch + (1,), dtype=coeffs.dtype)], axis=-1
+        )
+        box = padded[..., inv_index].reshape(batch + dims)
     return jnp.fft.ifftn(box, axes=(-3, -2, -1)) * n
 
 

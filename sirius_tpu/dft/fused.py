@@ -380,65 +380,74 @@ class FusedScf:
         ng, ns, omega = self.ng, self.ns, self.omega
         cdt, rdt = self.cdt, self.rdt
 
-        # density_from_coarse_acc, traced: 1/Omega, coarse r -> coarse G,
-        # scatter onto the fine sphere
-        acc = acc.astype(rdt)
-        rho_c = r_to_g(
-            (acc / omega).astype(cdt), tables["fft_index_coarse"],
-            self.dims_coarse,
-        )
-        rho_spin = jnp.zeros((ns, ng), dtype=cdt).at[:, tables["c2f"]].set(
-            rho_c
-        )
+        # The step_* scopes name the stages for a capture's scope table
+        # (obs/device_scopes.py; generate_potential_device holds
+        # step_hartree, step_xc and step_vloc): metadata of the emitted
+        # operations only, the traced order is the one it always was.
+        with jax.named_scope("step_density"):
+            # density_from_coarse_acc, traced: 1/Omega, coarse r -> coarse G,
+            # scatter onto the fine sphere
+            acc = acc.astype(rdt)
+            rho_c = r_to_g(
+                (acc / omega).astype(cdt), tables["fft_index_coarse"],
+                self.dims_coarse,
+            )
+            rho_spin = jnp.zeros((ns, ng), dtype=cdt).at[:, tables["c2f"]].set(
+                rho_c
+            )
 
-        dm = jax.lax.complex(dm_re.astype(rdt), dm_im.astype(rdt))
-        if self.has_aug:
+            dm = jax.lax.complex(dm_re.astype(rdt), dm_im.astype(rdt))
+            if self.has_aug:
+                if self.do_symmetrize:
+                    dm = symmetrize_density_matrix_device(dm, tables["dm_sym"])
+                rho_spin = rho_spin + rho_aug_g_device(dm, tables["aug"], ng)
+
+            rho_new = jnp.sum(rho_spin, axis=0)
+            mag_new = rho_spin[0] - rho_spin[1] if self.polarized else None
+            nel_got = jnp.real(rho_new[0]) * omega
+            # A 32-bit step accumulates the electron count to a few eps of
+            # it (band norms, the FFT's 1/N), differently in every iteration,
+            # and the occupations were solved for the count itself: where what
+            # was accumulated is the count to that rounding, the G = 0
+            # component is set to it (the 54-atom cell converges in 14
+            # iterations on the chip with this and in 17 without, PERF.md,
+            # PR 27). A count further off is not rounding but a fault (a lost
+            # band norm, wrong occupations, a wrong augmentation charge): it
+            # stays in the density, in S_NEL and in the energy. A 64-bit step,
+            # like the host tail, carries what it accumulated.
+            if hilo.compensated(rdt):
+                near = jnp.abs(nel_got - self.nel) <= self.charge_tol
+                rho_new = rho_new.at[0].set(jnp.where(
+                    near, jnp.asarray(self.nel / omega, dtype=cdt), rho_new[0]
+                ))
             if self.do_symmetrize:
-                dm = symmetrize_density_matrix_device(dm, tables["dm_sym"])
-            rho_spin = rho_spin + rho_aug_g_device(dm, tables["aug"], ng)
+                rho_new = symmetrize_pw_device(rho_new, tables["sym"])
+                if self.polarized:
+                    mag_new = symmetrize_pw_device(
+                        mag_new, tables["sym"], axial_z=True
+                    )
+            mag_moment = (
+                jnp.real(mag_new[0]) * omega if self.polarized
+                else jnp.zeros((), dtype=rdt)
+            )
 
-        rho_new = jnp.sum(rho_spin, axis=0)
-        mag_new = rho_spin[0] - rho_spin[1] if self.polarized else None
-        nel_got = jnp.real(rho_new[0]) * omega
-        # A 32-bit step accumulates the electron count to a few eps of it
-        # (band norms, the FFT's 1/N), differently in every iteration, and
-        # the occupations were solved for the count itself: where what was
-        # accumulated is the count to that rounding, the G = 0 component is
-        # set to it (the 54-atom cell converges in 14 iterations on the chip
-        # with this and in 17 without, PERF.md, PR 27). A count further off
-        # is not rounding but a fault (a lost band norm, wrong occupations,
-        # a wrong augmentation charge): it stays in the density, in S_NEL
-        # and in the energy. A 64-bit step, like the host tail, carries what
-        # it accumulated.
-        if hilo.compensated(rdt):
-            near = jnp.abs(nel_got - self.nel) <= self.charge_tol
-            rho_new = rho_new.at[0].set(jnp.where(
-                near, jnp.asarray(self.nel / omega, dtype=cdt), rho_new[0]
-            ))
-        if self.do_symmetrize:
-            rho_new = symmetrize_pw_device(rho_new, tables["sym"])
-            if self.polarized:
-                mag_new = symmetrize_pw_device(
-                    mag_new, tables["sym"], axial_z=True
-                )
-        mag_moment = (
-            jnp.real(mag_new[0]) * omega if self.polarized
-            else jnp.zeros((), dtype=rdt)
-        )
-
-        # mixing (host-sequence semantics: rms pre-mix, eha post-mix)
-        x_new = (
-            jnp.concatenate([rho_new, mag_new]) if self.polarized else rho_new
-        )
-        x_in = jax.lax.complex(carry.x_re, carry.x_im)
-        state = DeviceMixerState(
-            carry.hx_re, carry.hx_im, carry.hf_re, carry.hf_im, carry.count
-        )
-        state, x_mixed, rms, eha = device_mix(
-            state, x_in, x_new, tables["mixw"], self.mix_beta, self.kind,
-            self.max_history,
-        )
-        resid = rho_new - x_in[:ng]  # output - input density (scf-corr force)
+        with jax.named_scope("step_mixing"):
+            # mixing (host-sequence semantics: rms pre-mix, eha post-mix)
+            x_new = (
+                jnp.concatenate([rho_new, mag_new]) if self.polarized
+                else rho_new
+            )
+            x_in = jax.lax.complex(carry.x_re, carry.x_im)
+            state = DeviceMixerState(
+                carry.hx_re, carry.hx_im, carry.hf_re, carry.hf_im,
+                carry.count,
+            )
+            state, x_mixed, rms, eha = device_mix(
+                state, x_in, x_new, tables["mixw"], self.mix_beta, self.kind,
+                self.max_history,
+            )
+            # output - input density (scf-corr force)
+            resid = rho_new - x_in[:ng]
 
         # Harris term e1 against the potential this iteration's bands saw
         # (the energy terms are (hi, lo) pairs, core/hilo.py)
@@ -463,66 +472,68 @@ class FusedScf:
             e2 = hilo.add_pairs(e2, hilo.cdot_scaled(mag_new, bz_new, omega))
         v0 = jnp.real(veff_new[0])
 
-        # next iteration's D matrices and H diagonal
-        if self.has_aug:
-            ds = []
-            for s in range(ns):
-                if self.polarized:
-                    vs = veff_new + (bz_new if s == 0 else -bz_new)
-                else:
-                    vs = veff_new
-                ds.append(
-                    d_operator_device(vs, tables["dion"], tables["aug"],
-                                      omega)
+        with jax.named_scope("step_d_matrix"):
+            # next iteration's D matrices and H diagonal
+            if self.has_aug:
+                ds = []
+                for s in range(ns):
+                    if self.polarized:
+                        vs = veff_new + (bz_new if s == 0 else -bz_new)
+                    else:
+                        vs = veff_new
+                    ds.append(
+                        d_operator_device(vs, tables["dion"], tables["aug"],
+                                          omega)
+                    )
+                dion_new = jnp.stack(ds)
+            else:
+                dion_new = jnp.broadcast_to(
+                    tables["dion"][None], (ns,) + tables["dion"].shape
                 )
-            dion_new = jnp.stack(ds)
-        else:
-            dion_new = jnp.broadcast_to(
-                tables["dion"][None], (ns,) + tables["dion"].shape
+            h_diag = compute_h_diag_device(
+                tables["ekin"], tables["gmask"], tables["beta_re"],
+                tables["beta_im"], dion_new, v0,
             )
-        h_diag = compute_h_diag_device(
-            tables["ekin"], tables["gmask"], tables["beta_re"],
-            tables["beta_im"], dion_new, v0,
-        )
 
-        # ---- numerics ledger: per-iteration invariants, same record ----
-        # Note the choice of invariants: quantities whose exact value is
-        # known (I, 0) so the scalar directly reads as accumulated rounding
-        # + algorithmic drift. The Gram matrix itself and the density
-        # matrix are hermitian BITWISE in IEEE arithmetic (conjugate-mirror
-        # products round identically), so their asymmetry is useless; the
-        # chained-GEMM subspace H_nl below is not mirror-exact and does
-        # measure rounding. dion here is the BARE table (not dion_new):
-        # host and device then score the identical quantity regardless of
-        # where each path is in its D-refresh cycle.
-        psi_c = jax.lax.complex(
-            pr.astype(rdt), pi.astype(rdt)
-        ) * tables["gmask"][:, None, None, :]
-        beta_c = jax.lax.complex(
-            tables["beta_re"].astype(rdt), tables["beta_im"].astype(rdt),
-        )
-        qmat_r = tables["qmat"].astype(rdt)
-        bp = jnp.einsum("kxg,ksbg->ksbx", jnp.conj(beta_c), psi_c)
-        gram = jnp.einsum("ksbg,kscg->ksbc", jnp.conj(psi_c), psi_c)
-        gram = gram + jnp.einsum(
-            "ksbx,xy,kscy->ksbc", jnp.conj(bp), qmat_r, bp
-        )
-        nb = psi_c.shape[2]
-        s_ortho = jnp.max(jnp.abs(gram - jnp.eye(nb, dtype=gram.dtype)))
-        s_chg = jnp.abs(
-            jnp.real(x_mixed[0]) - jnp.real(x_new[0])
-        ) * omega
-        if self.do_symmetrize:
-            s_sym = jnp.max(jnp.abs(
-                symmetrize_pw_device(rho_new, tables["sym"]) - rho_new
+        with jax.named_scope("step_ledger"):
+            # ---- numerics ledger: per-iteration invariants, same record ----
+            # Note the choice of invariants: quantities whose exact value is
+            # known (I, 0) so the scalar directly reads as accumulated rounding
+            # + algorithmic drift. The Gram matrix itself and the density
+            # matrix are hermitian BITWISE in IEEE arithmetic (conjugate-mirror
+            # products round identically), so their asymmetry is useless; the
+            # chained-GEMM subspace H_nl below is not mirror-exact and does
+            # measure rounding. dion here is the BARE table (not dion_new):
+            # host and device then score the identical quantity regardless of
+            # where each path is in its D-refresh cycle.
+            psi_c = jax.lax.complex(
+                pr.astype(rdt), pi.astype(rdt)
+            ) * tables["gmask"][:, None, None, :]
+            beta_c = jax.lax.complex(
+                tables["beta_re"].astype(rdt), tables["beta_im"].astype(rdt),
+            )
+            qmat_r = tables["qmat"].astype(rdt)
+            bp = jnp.einsum("kxg,ksbg->ksbx", jnp.conj(beta_c), psi_c)
+            gram = jnp.einsum("ksbg,kscg->ksbc", jnp.conj(psi_c), psi_c)
+            gram = gram + jnp.einsum(
+                "ksbx,xy,kscy->ksbc", jnp.conj(bp), qmat_r, bp
+            )
+            nb = psi_c.shape[2]
+            s_ortho = jnp.max(jnp.abs(gram - jnp.eye(nb, dtype=gram.dtype)))
+            s_chg = jnp.abs(
+                jnp.real(x_mixed[0]) - jnp.real(x_new[0])
+            ) * omega
+            if self.do_symmetrize:
+                s_sym = jnp.max(jnp.abs(
+                    symmetrize_pw_device(rho_new, tables["sym"]) - rho_new
+                ))
+            else:
+                s_sym = jnp.zeros((), dtype=rdt)
+            dion_r = tables["dion"].astype(rdt)
+            h_nl = jnp.einsum("ksbx,xy,kscy->ksbc", jnp.conj(bp), dion_r, bp)
+            s_herm = jnp.max(jnp.abs(
+                h_nl - jnp.conj(jnp.swapaxes(h_nl, -1, -2))
             ))
-        else:
-            s_sym = jnp.zeros((), dtype=rdt)
-        dion_r = tables["dion"].astype(rdt)
-        h_nl = jnp.einsum("ksbx,xy,kscy->ksbc", jnp.conj(bp), dion_r, bp)
-        s_herm = jnp.max(jnp.abs(
-            h_nl - jnp.conj(jnp.swapaxes(h_nl, -1, -2))
-        ))
 
         eval_sum = hilo.dot_scaled(occ_w.astype(rdt), ev.astype(rdt), 1.0)
         e = pot["energies"]

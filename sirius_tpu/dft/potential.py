@@ -339,12 +339,73 @@ def generate_potential_device(
         # lo is zero and hi the plain sum
         return dot_scaled(f_r, g_r, omega / n)
 
+    # step_hartree / step_xc / step_vloc (and xc_gga inside step_xc) name
+    # the operations of the three stages for a capture's scope table
+    # (obs/device_scopes.py): metadata only, the traced order is untouched
     vloc_g = jax.lax.complex(tb["vloc_re"], tb["vloc_im"]).astype(cdt)
     rho_core_g = jax.lax.complex(tb["core_re"], tb["core_im"]).astype(cdt)
-    vha_g = hartree_potential_g(rho_g, tb["glen2"])
-    rho_r = to_r(rho_g)
-    rho_core_r = tb["rho_core_r"]
+    with jax.named_scope("step_hartree"):
+        vha_g = hartree_potential_g(rho_g, tb["glen2"])
+    with jax.named_scope("step_xc"):
+        rho_r = to_r(rho_g)
+        rho_core_r = tb["rho_core_r"]
+        xc_r = _xc_fields(xc, rho_g, mag_g, rho_r, rho_core_g, rho_core_r,
+                          dims, to_r, gradient_r, divergence_g)
+        vxc_r, bz_r, rho_xc, mag_r = (xc_r[k] for k in
+                                      ("vxc_r", "bz_r", "rho_xc", "mag_r"))
+        exc_r = xc_r["e_r"] / jnp.maximum(rho_xc, 1e-25)
+        vxc_g = to_g(vxc_r)
+    with jax.named_scope("step_vloc"):
+        veff_g = vloc_g + vha_g + vxc_g
+    with jax.named_scope("step_xc"):
+        bz_g = to_g(bz_r) if polarized else None
+    with jax.named_scope("step_vloc"):
+        if sym_tb is not None:
+            veff_g = symmetrize_pw_device(veff_g, sym_tb)
+            if bz_g is not None:
+                bz_g = symmetrize_pw_device(bz_g, sym_tb, axial_z=True)
 
+        def to_coarse(f_g):
+            return jnp.real(g_to_r_gather(
+                f_g[tb["c2f"]], tb["inv_index_coarse"], tuple(dims_coarse)))
+
+        if polarized:
+            v_r = to_coarse(veff_g)
+            b_r = to_coarse(bz_g)
+            veff_r_coarse = jnp.stack([v_r + b_r, v_r - b_r])
+        else:
+            veff_r_coarse = to_coarse(veff_g)[None]
+
+    zero = jnp.zeros((), dtype=rho_r.dtype)
+    # each an (hi, lo) pair, see inner_rr; traced in the order of the dict
+    with jax.named_scope("step_hartree"):
+        e_vha = inner_rr(rho_r, to_r(vha_g))
+    with jax.named_scope("step_xc"):
+        e_vxc = inner_rr(rho_r, vxc_r)
+    with jax.named_scope("step_vloc"):
+        e_vloc = inner_rr(rho_r, tb["vloc_r"])
+        e_veff = inner_rr(rho_r, to_r(veff_g))
+    with jax.named_scope("step_xc"):
+        e_exc = inner_rr(rho_r + rho_core_r, exc_r)
+        e_bxc = inner_rr(mag_r, to_r(bz_g)) if polarized else (zero, zero)
+    return {
+        "veff_g": veff_g,
+        "bz_g": bz_g,
+        "veff_r_coarse": veff_r_coarse,
+        "vha_g": vha_g,
+        "vxc_g": vxc_g,
+        "energies": {"vha": e_vha, "vxc": e_vxc, "vloc": e_vloc,
+                     "veff": e_veff, "exc": e_exc, "bxc": e_bxc},
+    }
+
+
+def _xc_fields(xc, rho_g, mag_g, rho_r, rho_core_g, rho_core_r, dims, to_r,
+               gradient_r, divergence_g) -> dict:
+    """The XC part of generate_potential_device on the fine box: v_xc(r),
+    b_z(r) (None without a moment), the energy density e(r), the density the
+    functional saw and the moment's field (None)."""
+    polarized = mag_g is not None
+    mag_r = None
     if polarized:
         mag_r = to_r(mag_g)
         rho_xc = jnp.maximum(rho_r + rho_core_r, 1e-20)
@@ -395,41 +456,5 @@ def generate_potential_device(
         e_r = out["e"].reshape(dims)
         bz_r = None
 
-    exc_r = e_r / jnp.maximum(rho_xc, 1e-25)
-
-    vxc_g = to_g(vxc_r)
-    veff_g = vloc_g + vha_g + vxc_g
-    bz_g = to_g(bz_r) if polarized else None
-    if sym_tb is not None:
-        veff_g = symmetrize_pw_device(veff_g, sym_tb)
-        if bz_g is not None:
-            bz_g = symmetrize_pw_device(bz_g, sym_tb, axial_z=True)
-
-    def to_coarse(f_g):
-        return jnp.real(g_to_r_gather(
-            f_g[tb["c2f"]], tb["inv_index_coarse"], tuple(dims_coarse)))
-
-    if polarized:
-        v_r = to_coarse(veff_g)
-        b_r = to_coarse(bz_g)
-        veff_r_coarse = jnp.stack([v_r + b_r, v_r - b_r])
-    else:
-        veff_r_coarse = to_coarse(veff_g)[None]
-
-    zero = jnp.zeros((), dtype=rho_r.dtype)
-    energies = {  # each an (hi, lo) pair, see inner_rr
-        "vha": inner_rr(rho_r, to_r(vha_g)),
-        "vxc": inner_rr(rho_r, vxc_r),
-        "vloc": inner_rr(rho_r, tb["vloc_r"]),
-        "veff": inner_rr(rho_r, to_r(veff_g)),
-        "exc": inner_rr(rho_r + rho_core_r, exc_r),
-        "bxc": (inner_rr(mag_r, to_r(bz_g)) if polarized else (zero, zero)),
-    }
-    return {
-        "veff_g": veff_g,
-        "bz_g": bz_g,
-        "veff_r_coarse": veff_r_coarse,
-        "vha_g": vha_g,
-        "vxc_g": vxc_g,
-        "energies": energies,
-    }
+    return {"vxc_r": vxc_r, "bz_r": bz_r, "e_r": e_r, "rho_xc": rho_xc,
+            "mag_r": mag_r}

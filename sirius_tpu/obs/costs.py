@@ -68,9 +68,11 @@ UNCOSTED_SPANS = (
     "serve.context_build",
     "serve.run",
     "serve.queue_wait",
-    # a profiler capture and what stopping it cost (obs/trace.py)
+    # a profiler capture, what stopping it cost and its table of device
+    # seconds by named scope (obs/trace.py, obs/device_scopes.py)
     "trace.capture",
     "trace.stop",
+    "trace.scopes",
     "campaign.finalize",
     # model-based compute/collective split of the G-sharded band solve
     # (probe-timed collectives x analytic apply counts, dft/scf.py)

@@ -254,10 +254,21 @@ def _merge_xplane(doc: dict, path: str, pid_base: int) -> int:
     """One ``.xplane.pb`` as "X" events: a process per plane, a thread
     per line. Event times are nanoseconds since the session's start; the
     "Task Environment" plane's ``profile_start_time`` (Unix ns) puts
-    them on the spans' clock."""
+    them on the spans' clock. A device operation under one of the
+    program's ``jax.named_scope`` names carries its scope path as
+    ``args.scope``, through the reader that makes a capture's
+    ``trace.scopes`` table (obs/device_scopes.py)."""
     import jax
 
-    pd = jax.profiler.ProfileData.from_file(path)
+    from sirius_tpu.obs import device_scopes
+
+    with open(path, "rb") as f:
+        data = f.read()
+    pd = jax.profiler.ProfileData.from_serialized_xspace(data)
+    try:
+        scopes = device_scopes.event_scopes(data)
+    except Exception:  # a file without the executables' text still merges
+        scopes = {}
     env = pd.find_plane_with_name("Task Environment")
     start_ns = int(dict(env.stats).get("profile_start_time", 0)) if env else 0
     ev = doc.setdefault("traceEvents", [])
@@ -277,12 +288,18 @@ def _merge_xplane(doc: dict, path: str, pid_base: int) -> int:
                                    "args": {"name": plane.name}})
                     ev.append({"name": "thread_name", "ph": "M", "pid": pid,
                                "tid": tid, "args": {"name": line.name}})
-                ev.append({
+                rec = {
                     "name": e.name, "ph": "X", "cat": "xplane",
                     "ts": (start_ns + e.start_ns) / 1000.0,
                     "dur": max(e.duration_ns / 1000.0, 0.001),
                     "pid": pid, "tid": tid,
-                })
+                }
+                scope = scopes and scopes.get((
+                    plane.name, device_scopes.instruction_name(e.name),
+                    float(e.start_ns)))
+                if scope:
+                    rec["args"] = {"scope": scope}
+                ev.append(rec)
                 merged += 1
     return merged
 

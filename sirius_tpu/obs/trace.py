@@ -27,6 +27,21 @@ the capture is active every live span is mirrored as a
 ``jax.profiler.TraceAnnotation`` (spans.set_mirror), so xprof/Perfetto
 show the program's spans above the device rows.
 
+A third record, ``trace.scopes``, says where the device's time went in
+the program's own names: the capture's file names a device operation by
+the compiler's label, but it also holds the executables that ran, whose
+instructions carry the ``jax.named_scope`` path that emitted them.
+obs/device_scopes.py joins the two from the serialized trace the stop
+has in hand (imported then, never at this module's import: with no
+capture armed none of it runs) into ``by_scope`` ({path: {s, ops}},
+seconds a union of intervals per device, a parent's holding its
+children's), ``by_module``, ``busy_s``, ``steps``, ``devices``,
+``unscoped_s`` / ``unscoped_top`` (what to name next), ``scopes_seen``,
+``modules_without_hlo``, ``source`` and ``reduce_s``, what making the
+table cost. It is recorded like ``trace.capture`` (an interval measured
+here, nobody's parent) and kept as ``status()["last_scopes"]`` for the
+operator of ``/debug/trace``.
+
 Cheap: the profiler's Python tracer is off (it slows the host it
 observes; the host tracer stays on for the annotations), and stopping
 writes the ``.xplane.pb`` only. ``jax.profiler.stop_trace`` is
@@ -66,6 +81,8 @@ class TraceCapture:
         self._active = False
         self._done_dirs: set[str] = set()
         self._session: dict = {}
+        self._iterations = 0
+        self._last_scopes: dict | None = None
 
     def request(self, trace_dir: str, steps: int = 5, *,
                 force: bool = False, skip: int = 0) -> bool:
@@ -100,6 +117,7 @@ class TraceCapture:
                 self._remaining -= 1
                 if self._remaining <= 0:
                     return self._stop_locked()
+                self._iterations += 1
                 return
             else:
                 return
@@ -122,7 +140,8 @@ class TraceCapture:
             return {"active": self._active,
                     "armed_dir": self._armed_dir,
                     "remaining": self._remaining,
-                    "completed": sorted(self._done_dirs)}
+                    "completed": sorted(self._done_dirs),
+                    "last_scopes": self._last_scopes}
 
     # -- internals (lock handling: _start runs unlocked because
     #    jax.profiler.start_trace can itself compile) ------------------
@@ -145,6 +164,7 @@ class TraceCapture:
             return
         with self._lock:
             self._active = True
+            self._iterations = 1  # the one whose head this is
             self._session = {
                 "session_start_unix_ns": session_ns,
                 "started_unix_ns": started_ns, "steps": self._remaining,
@@ -157,6 +177,7 @@ class TraceCapture:
         # called with self._lock held
         trace_dir = self._armed_dir
         session, self._session = self._session, {}
+        iterations = self._iterations
         self._active = False
         self._armed_dir = None
         self._remaining = 0
@@ -171,12 +192,14 @@ class TraceCapture:
                              end_unix_ns=time.time_ns(), **session)
             with spans.span("trace.stop", trace_dir=trace_dir) as sp:
                 try:
-                    sp.set(**_stop_session(trace_dir))
+                    fields, xspace = _stop_session(trace_dir)
+                    sp.set(**fields)
                 except Exception as exc:
                     logger.warning("trace capture failed to stop: %s", exc)
                     sp.set(error=type(exc).__name__)
                     return
             logger.info("trace capture written: %s", trace_dir)
+            self._last_scopes = _record_scopes(xspace, iterations, trace_dir)
             events.emit("trace_capture", phase="stop", trace_dir=trace_dir,
                         ts_stop=time.time())
         # release before touching the profiler: stopping flushes to disk
@@ -198,14 +221,15 @@ def _profile_options():
     return opts
 
 
-def _stop_session(trace_dir: str) -> dict:
+def _stop_session(trace_dir: str) -> tuple:
     """Stop the profiler session and write its ``.xplane.pb`` where
     ``jax.profiler.stop_trace`` would, without the conversion to
     ``.trace.json.gz`` that comes with it. The session object is jax's
     own (``jax._src.profiler``); where this jax keeps it elsewhere, fall
-    back to ``stop_trace``. Returns what `trace.stop` records: the
+    back to ``stop_trace``. Returns what `trace.stop` records (the
     seconds the session took to stop and hand over its data, the seconds
-    and bytes of the write."""
+    and bytes of the write) and the serialized trace, None from the
+    fallback."""
     import jax
 
     t0 = time.perf_counter()
@@ -215,8 +239,8 @@ def _stop_session(trace_dir: str) -> dict:
         state = _jp._profile_state
         session_stop = state.profile_session.stop
     except (ImportError, AttributeError):  # no session, or not kept there
-        jax.profiler.stop_trace()
-        return {"session_stop_s": time.perf_counter() - t0}
+        jax.profiler.stop_trace()  # writes the file; no trace in hand
+        return {"session_stop_s": time.perf_counter() - t0}, None
     with state.lock:
         try:
             xspace = session_stop()
@@ -230,7 +254,27 @@ def _stop_session(trace_dir: str) -> dict:
               "wb") as f:
         f.write(xspace)
     return {"session_stop_s": t1 - t0, "write_s": time.perf_counter() - t1,
-            "xplane_bytes": len(xspace)}
+            "xplane_bytes": len(xspace)}, xspace
+
+
+def _record_scopes(xspace: bytes, iterations: int, trace_dir: str):
+    """The ``trace.scopes`` record of one capture (module docstring), from
+    the serialized trace the stop just wrote; returns the table, None where
+    it could not be made (the capture itself is on disk either way)."""
+    if xspace is None:
+        return None
+    start_ns = time.time_ns()
+    try:
+        from sirius_tpu.obs import device_scopes
+
+        table = device_scopes.table(xspace, steps=iterations)
+    except Exception as exc:  # the table is a convenience of the capture
+        logger.warning("trace.scopes not recorded: %s: %s",
+                       type(exc).__name__, exc)
+        return None
+    spans.record("trace.scopes", start_unix_ns=start_ns,
+                 end_unix_ns=time.time_ns(), trace_dir=trace_dir, **table)
+    return table
 
 
 CAPTURE = TraceCapture()

@@ -302,28 +302,33 @@ def apply_h_s_gamma(params: GammaParams, x: jax.Array):
     """(H x, S x) for a packed-real band block x [nb, ngk]."""
     nb, npack = x.shape[-2:]
     x = x * params.mask_p
-    fr = _pair_to_r(params, x)
-    # the potential is real: Re (fr v) = psi_a v, Im (fr v) = psi_b v
-    vr = jax.lax.complex(jnp.real(fr) * params.veff_r,
-                         jnp.imag(fr) * params.veff_r)
-    f = (
-        jnp.fft.fftn(vr, axes=(-3, -2, -1))
-        .reshape(fr.shape[:-3] + (-1,))[..., params.fft_index]
-    )
-    # F = v_a + i v_b with v_a, v_b Hermitian: the re-pack's pair average
-    # is (F(G) + conj F(-G)) / 2, so pack(F) = v_a and pack(-i F) = v_b;
-    # one after the other they are the block's rows again
-    both = jnp.concatenate(
-        [f, jax.lax.complex(jnp.imag(f), -jnp.real(f))], axis=-2)
-    vpack = _pack_device(both, params.slot_re, params.slot_im, params.im_sign,
-                         params.scale, params.zero_idx, npack)[..., :nb, :]
+    # the two scopes name the operations for a capture's table
+    # (obs/device_scopes.py): metadata only
+    with jax.named_scope("local_op"):
+        fr = _pair_to_r(params, x)
+        # the potential is real: Re (fr v) = psi_a v, Im (fr v) = psi_b v
+        vr = jax.lax.complex(jnp.real(fr) * params.veff_r,
+                             jnp.imag(fr) * params.veff_r)
+        f = (
+            jnp.fft.fftn(vr, axes=(-3, -2, -1))
+            .reshape(fr.shape[:-3] + (-1,))[..., params.fft_index]
+        )
+        # F = v_a + i v_b with v_a, v_b Hermitian: the re-pack's pair average
+        # is (F(G) + conj F(-G)) / 2, so pack(F) = v_a and pack(-i F) = v_b;
+        # one after the other they are the block's rows again
+        both = jnp.concatenate(
+            [f, jax.lax.complex(jnp.imag(f), -jnp.real(f))], axis=-2)
+        vpack = _pack_device(both, params.slot_re, params.slot_im,
+                             params.im_sign, params.scale, params.zero_idx,
+                             npack)[..., :nb, :]
     ekin = jnp.where(params.mask_p > 0, params.ekin_p, 0.0)
     hx = ekin * x + vpack
     sx = x
     if params.beta_p.shape[0]:
-        bp = jnp.einsum("xg,bg->bx", params.beta_p, x)
-        hx = hx + jnp.einsum("bx,xy,yg->bg", bp, params.dion, params.beta_p)
-        sx = sx + jnp.einsum("bx,xy,yg->bg", bp, params.qmat, params.beta_p)
+        with jax.named_scope("beta_proj"):
+            bp = jnp.einsum("xg,bg->bx", params.beta_p, x)
+            hx = hx + jnp.einsum("bx,xy,yg->bg", bp, params.dion, params.beta_p)
+            sx = sx + jnp.einsum("bx,xy,yg->bg", bp, params.qmat, params.beta_p)
     return hx * params.mask_p, sx * params.mask_p
 
 
@@ -383,10 +388,11 @@ def density_gamma(params: GammaParams, x: jax.Array, occ_w: jax.Array):
     rides along). Returns [..., n1, n2, n3] real."""
     nb = x.shape[-2]
     n = params.veff_r.size
-    fr = _pair_to_r(params, x * params.mask_p) * n
-    # two bands a box: band j is the real part, band j + h the imaginary
-    # part; the zero row of an odd nb gets weight 0
-    w = jnp.pad(occ_w, [(0, 0)] * (occ_w.ndim - 1) + [(0, nb % 2)])
-    h = w.shape[-1] // 2
-    return (jnp.einsum("...b,...bxyz->...xyz", w[..., :h], jnp.real(fr) ** 2)
-            + jnp.einsum("...b,...bxyz->...xyz", w[..., h:], jnp.imag(fr) ** 2))
+    with jax.named_scope("density_gamma"):  # the name in a capture's table
+        fr = _pair_to_r(params, x * params.mask_p) * n
+        # two bands a box: band j is the real part, band j + h the imaginary
+        # part; the zero row of an odd nb gets weight 0
+        w = jnp.pad(occ_w, [(0, 0)] * (occ_w.ndim - 1) + [(0, nb % 2)])
+        h = w.shape[-1] // 2
+        return (jnp.einsum("...b,...bxyz->...xyz", w[..., :h], jnp.real(fr) ** 2)
+                + jnp.einsum("...b,...bxyz->...xyz", w[..., h:], jnp.imag(fr) ** 2))

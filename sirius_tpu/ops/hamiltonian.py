@@ -84,11 +84,12 @@ def apply_h_s(params: HkParams, psi: jax.Array) -> tuple[jax.Array, jax.Array]:
     hpsi = ekin * psi + vpsi
     spsi = psi
     if params.beta.shape[0]:
-        bp = jnp.einsum("xg,bg->bx", jnp.conj(params.beta), psi)
-        hpsi = hpsi + jnp.einsum("bx,xy,yg->bg", bp, params.dion, params.beta)
-        # qmat is all-zero for norm-conserving species; the extra einsum is
-        # negligible next to the FFTs and keeps the pytree static
-        spsi = spsi + jnp.einsum("bx,xy,yg->bg", bp, params.qmat, params.beta)
+        with jax.named_scope("beta_proj"):
+            bp = jnp.einsum("xg,bg->bx", jnp.conj(params.beta), psi)
+            hpsi = hpsi + jnp.einsum("bx,xy,yg->bg", bp, params.dion, params.beta)
+            # qmat is all-zero for norm-conserving species; the extra einsum
+            # is negligible next to the FFTs and keeps the pytree static
+            spsi = spsi + jnp.einsum("bx,xy,yg->bg", bp, params.qmat, params.beta)
     if params.hub is not None and params.hub.shape[0]:
         # Hubbard U: H psi += sum_{mn} phi_n V_{mn} <phi_m|psi>
         hp = jnp.einsum("mg,bg->bm", jnp.conj(params.hub), psi)

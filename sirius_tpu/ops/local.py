@@ -54,12 +54,15 @@ def _round_trip_block(psi, fft_index, veff_r):
     dims = veff_r.shape
     n = dims[0] * dims[1] * dims[2]
     batch = psi.shape[:-1]
-    box = jnp.zeros(batch + (n,), dtype=psi.dtype).at[..., fft_index].add(psi)
-    fr = jnp.fft.ifftn(box.reshape(batch + dims), axes=(-3, -2, -1))
-    return (
-        jnp.fft.fftn(fr * veff_r, axes=(-3, -2, -1))
-        .reshape(batch + (n,))[..., fft_index]
-    )
+    # named for the capture's scope table (obs/device_scopes.py): metadata
+    # of the emitted operations, nothing the compiler optimises by
+    with jax.named_scope("local_op"):
+        box = jnp.zeros(batch + (n,), dtype=psi.dtype).at[..., fft_index].add(psi)
+        fr = jnp.fft.ifftn(box.reshape(batch + dims), axes=(-3, -2, -1))
+        return (
+            jnp.fft.fftn(fr * veff_r, axes=(-3, -2, -1))
+            .reshape(batch + (n,))[..., fft_index]
+        )
 
 
 def sphere_cube(gkvec) -> tuple[int, int, int]:
@@ -138,24 +141,26 @@ def _round_trip_rows_minor(psi, fft_index, veff_r, cube):
     nk, rows, _ = psi.shape
     dims, m = veff_r.shape, cube.shape[1:]
     rdt = veff_r.dtype
-    psi_t = jnp.concatenate(
-        [jnp.swapaxes(psi, 1, 2), jnp.zeros((nk, 1, rows), psi.dtype)], axis=1)
-    x = jax.vmap(lambda p, i: p[i], out_axes=1)(psi_t, cube.reshape(nk, -1))
-    x = x.reshape(m + (nk * rows,))  # [m1, m2, m3, k x rows]
-    xr, xi = jnp.real(x), jnp.imag(x)
-    for axis in (2, 1, 0):
-        w = _dft_matrix(dims[axis], m[axis], True, rdt)
-        xr, xi = _dft_pass(w, xr, xi, axis)
-    v = veff_r[..., None]
-    xr, xi = xr * v, xi * v
-    for axis in (0, 1, 2):
-        w = _dft_matrix(dims[axis], m[axis], False, rdt)
-        xr, xi = _dft_pass(w, xr, xi, axis)
-    x = jax.lax.complex(xr, xi).reshape(-1, nk, rows)
-    # padded slots read the cell of G = 0; apply_h_s masks them
-    out = jax.vmap(lambda b, i: b[i], in_axes=(1, 0))(
-        x, _cube_cells(fft_index, dims, m))
-    return jnp.swapaxes(out, 1, 2)
+    with jax.named_scope("local_op"):
+        psi_t = jnp.concatenate(
+            [jnp.swapaxes(psi, 1, 2), jnp.zeros((nk, 1, rows), psi.dtype)],
+            axis=1)
+        x = jax.vmap(lambda p, i: p[i], out_axes=1)(psi_t, cube.reshape(nk, -1))
+        x = x.reshape(m + (nk * rows,))  # [m1, m2, m3, k x rows]
+        xr, xi = jnp.real(x), jnp.imag(x)
+        for axis in (2, 1, 0):
+            w = _dft_matrix(dims[axis], m[axis], True, rdt)
+            xr, xi = _dft_pass(w, xr, xi, axis)
+        v = veff_r[..., None]
+        xr, xi = xr * v, xi * v
+        for axis in (0, 1, 2):
+            w = _dft_matrix(dims[axis], m[axis], False, rdt)
+            xr, xi = _dft_pass(w, xr, xi, axis)
+        x = jax.lax.complex(xr, xi).reshape(-1, nk, rows)
+        # padded slots read the cell of G = 0; apply_h_s masks them
+        out = jax.vmap(lambda b, i: b[i], in_axes=(1, 0))(
+            x, _cube_cells(fft_index, dims, m))
+        return jnp.swapaxes(out, 1, 2)
 
 
 @jax.custom_batching.custom_vmap

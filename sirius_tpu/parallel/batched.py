@@ -405,7 +405,8 @@ def density_kset(params: HkSetParams, psi_re, psi_im, occ_w):
         fr = jnp.fft.ifftn(box.reshape(batch + dims), axes=(-3, -2, -1)) * n
         return jnp.einsum("sb,sbxyz->sxyz", ow, jnp.abs(fr) ** 2)
 
-    return jnp.sum(jax.vmap(one_k)(params.fft_index, psi, occ_w), axis=0)
+    with jax.named_scope("density_kset"):  # the name in a capture's table
+        return jnp.sum(jax.vmap(one_k)(params.fft_index, psi, occ_w), axis=0)
 
 
 @jax.jit
@@ -426,5 +427,6 @@ def density_matrix_kset(beta_re, beta_im, psi_re, psi_im, occ_w):
         bp = jnp.einsum("xg,sbg->sbx", jnp.conj(beta_k), psi_k)
         return jnp.einsum("sb,sbx,sby->sxy", ow, jnp.conj(bp), bp)
 
-    dm = jnp.sum(jax.vmap(one_k)(beta, psi, occ_w), axis=0)
-    return jnp.real(dm), jnp.imag(dm)
+    with jax.named_scope("density_matrix"):
+        dm = jnp.sum(jax.vmap(one_k)(beta, psi, occ_w), axis=0)
+        return jnp.real(dm), jnp.imag(dm)

@@ -226,7 +226,8 @@ def davidson(
         t = u * jnp.where(good, jax.lax.rsqrt(jnp.where(good, s, 1.0)), 0.0)[None, :]
         return _combine(t.conj(), x, theta_index)
 
-    x = ortho(theta_real(x0 * mask, theta_index))
+    with jax.named_scope("davidson_ortho"):
+        x = ortho(theta_real(x0 * mask, theta_index))
 
     def step(carry, _):
         x, hx, sx, p, hp, sp = carry
@@ -234,30 +235,34 @@ def davidson(
         # Guard the quotient: a rank-deficient Rayleigh-Ritz (heavy Kramers
         # degeneracy + locking) can hand back a ~zero Ritz vector, and a
         # 0/0 here NaN-poisons the whole scan (observed: Au SO spinor solve)
-        den = jnp.real(jnp.sum(x.conj() * sx, axis=1))
-        evals = jnp.real(jnp.sum(x.conj() * hx, axis=1)) / jnp.where(
-            jnp.abs(den) > 1e-30, den, 1.0
-        )
-        r = (hx - evals[:, None] * sx) * mask
-        rnorm = jnp.sqrt(jnp.real(jnp.sum(jnp.abs(r) ** 2, axis=1)))
-        conv = rnorm < res_tol
-        w = jnp.where(conv[:, None], 0.0, _precondition(r, h_diag, o_diag, evals)) * mask
-        # project out X and normalize rows: keeps the 3nb overlap matrix
-        # well-conditioned so the rank-revealing cutoff doesn't stall
-        # convergence near the solution
-        w = theta_real(w - (w @ x.conj().T) @ x, theta_index)
-        w = w / jnp.maximum(jnp.linalg.norm(w, axis=1, keepdims=True), 1e-30)
-        # the ONLY H/S application of the step: the new block.  The
-        # named_scope blocks tag the emitted HLO so trace capture
-        # (obs/trace.py) and XLA profiles attribute time to the same four
-        # stage names obs/costs.py models — host spans cannot cut inside
-        # this jit.
+        # The named_scope blocks tag the emitted HLO (every instruction's
+        # op_name) with the stage names: the four obs/costs.py models and
+        # davidson_residual for the new block's residual and
+        # preconditioning. A trace capture (obs/trace.py) reads them back
+        # from the executable it holds and records the device seconds of
+        # each in its trace.scopes table (obs/device_scopes.py, whose
+        # SCOPES lists every name) — host spans cannot cut inside this jit.
+        with jax.named_scope("davidson_residual"):
+            den = jnp.real(jnp.sum(x.conj() * sx, axis=1))
+            evals = jnp.real(jnp.sum(x.conj() * hx, axis=1)) / jnp.where(
+                jnp.abs(den) > 1e-30, den, 1.0
+            )
+            r = (hx - evals[:, None] * sx) * mask
+            rnorm = jnp.sqrt(jnp.real(jnp.sum(jnp.abs(r) ** 2, axis=1)))
+            conv = rnorm < res_tol
+            w = jnp.where(conv[:, None], 0.0, _precondition(r, h_diag, o_diag, evals)) * mask
+            # project out X and normalize rows: keeps the 3nb overlap
+            # matrix well-conditioned so the rank-revealing cutoff doesn't
+            # stall convergence near the solution
+            w = theta_real(w - (w @ x.conj().T) @ x, theta_index)
+            w = w / jnp.maximum(jnp.linalg.norm(w, axis=1, keepdims=True), 1e-30)
+        # the ONLY H/S application of the step: the new block
         with jax.named_scope("davidson_hpsi"):
             hw, sw = apply_h_s(w)
-        v = jnp.concatenate([x, w, p], axis=0)  # (3nb, ng)
-        hv = jnp.concatenate([hx, hw, hp], axis=0)
-        sv = jnp.concatenate([sx, sw, sp], axis=0)
         with jax.named_scope("davidson_inner"):
+            v = jnp.concatenate([x, w, p], axis=0)  # (3nb, ng)
+            hv = jnp.concatenate([hx, hw, hp], axis=0)
+            sv = jnp.concatenate([sx, sw, sp], axis=0)
             hsub = _subspace_matrix(v.conj() @ hv.T, theta_index)
             ssub = _subspace_matrix(v.conj() @ sv.T, theta_index)
             hsub = 0.5 * (hsub + hsub.conj().T)
@@ -276,9 +281,10 @@ def davidson(
             pn = _combine(cp, v, theta_index) * mask
             pscale = 1.0 / jnp.maximum(
                 jnp.linalg.norm(pn, axis=1, keepdims=True), 1e-30)
-        return (xn, hxn, sxn, pn * pscale,
-                _combine(cp, hv, theta_index) * mask * pscale,
-                _combine(cp, sv, theta_index) * mask * pscale), rnorm
+            pn = pn * pscale
+            hpn = _combine(cp, hv, theta_index) * mask * pscale
+            spn = _combine(cp, sv, theta_index) * mask * pscale
+        return (xn, hxn, sxn, pn, hpn, spn), rnorm
 
     def chunk(carry, steps):
         """One refresh boundary, a true H/S application to [X; P], and the
@@ -315,12 +321,13 @@ def davidson(
     # linear-combination rounding (matters in c64)
     with jax.named_scope("davidson_hpsi"):
         hx, sx = apply_h_s(x)
-    den = jnp.real(jnp.sum(x.conj() * sx, axis=1))
-    evals = jnp.real(jnp.sum(x.conj() * hx, axis=1)) / jnp.where(
-        jnp.abs(den) > 1e-30, den, 1.0
-    )
-    rnorm = jnp.sqrt(jnp.real(jnp.sum(jnp.abs(hx - evals[:, None] * sx) ** 2, axis=1)))
-    # normalize to <x|S|x> = 1 (den floored: a zero Ritz vector must come
-    # back as a zero row, not NaN/Inf)
-    x = x / jnp.sqrt(jnp.maximum(den, 1e-30))[:, None]
+    with jax.named_scope("davidson_residual"):
+        den = jnp.real(jnp.sum(x.conj() * sx, axis=1))
+        evals = jnp.real(jnp.sum(x.conj() * hx, axis=1)) / jnp.where(
+            jnp.abs(den) > 1e-30, den, 1.0
+        )
+        rnorm = jnp.sqrt(jnp.real(jnp.sum(jnp.abs(hx - evals[:, None] * sx) ** 2, axis=1)))
+        # normalize to <x|S|x> = 1 (den floored: a zero Ritz vector must
+        # come back as a zero row, not NaN/Inf)
+        x = x / jnp.sqrt(jnp.maximum(den, 1e-30))[:, None]
     return evals, x, rnorm

@@ -269,6 +269,31 @@ def test_capture_armed_at_entry_is_steady_state_and_on_the_spans_clock(
     assert stop["end_unix_ns"] <= its[4]["start_unix_ns"]
     assert stop["parent_id"] == run["span_id"]
 
+    # the table of device seconds by the program's scope names, made from
+    # the trace the stop wrote, on the job's trace id and nobody's parent
+    (scopes,) = cap.by_name("trace.scopes")
+    assert scopes["trace_id"] == run["trace_id"]
+    assert stop["end_unix_ns"] <= scopes["start_unix_ns"]
+    assert scopes["end_unix_ns"] <= its[4]["start_unix_ns"]
+    assert not [r for r in cap.records
+                if r["parent_id"] == scopes["span_id"]]
+    assert scopes["steps"] == 2 and scopes["devices"] >= 1
+    assert scopes["source"] == "xplane_hlo_proto"
+    assert scopes["modules_without_hlo"] == []
+    by_scope = scopes["by_scope"]
+    for path in ("davidson_hpsi", "davidson_hpsi/local_op",
+                 "davidson_hpsi/beta_proj", "davidson_rr",
+                 "davidson_rr/eigh_kernel", "davidson_rotate"):
+        assert 0 < by_scope[path]["s"] <= scopes["busy_s"], path
+    assert by_scope["davidson_hpsi/local_op"]["s"] <= \
+        by_scope["davidson_hpsi"]["s"]
+    assert {"davidson_hpsi", "local_op", "davidson_rr"} <= \
+        set(scopes["scopes_seen"])
+    assert any("davidson" in m for m in scopes["by_module"])
+    assert scopes["reduce_s"] == pytest.approx(scopes["dur_s"], abs=0.05)
+    last = CAPTURE.status()["last_scopes"]
+    assert last["by_scope"] == by_scope and last["busy_s"] == scopes["busy_s"]
+
     # only the .xplane.pb is written: no conversion to trace.json.gz
     path, pd = _xplane(tmp_path / "tracedir")
     assert stop["xplane_bytes"] == path.stat().st_size > 0
@@ -381,3 +406,9 @@ def test_timeline_merges_the_xplane_on_the_spans_clock(tmp_path):
     (it2,) = [r for r in cap.by_name("scf.iteration") if r["it"] == 2]
     (mirror,) = [e for e in merged if e["name"] == "scf.iteration"]
     assert abs(mirror["ts"] * 1e3 - it2["start_unix_ns"]) < 1e6
+    # device operations carry the scope path the capture's table put them to
+    (scopes,) = cap.by_name("trace.scopes")
+    labelled = [e["args"]["scope"] for e in merged if "args" in e]
+    assert len(labelled) >= sum(  # and the loops under a scope, no leaves
+        v["ops"] for p, v in scopes["by_scope"].items() if "/" not in p) > 0
+    assert set(labelled) <= set(scopes["by_scope"])
