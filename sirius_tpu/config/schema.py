@@ -206,7 +206,15 @@ class ParametersConfig:
 
 @dataclasses.dataclass
 class IterativeSolverConfig:
-    # reference input_schema.json "iterative_solver" section
+    # reference input_schema.json "iterative_solver" section. What the band
+    # solve reads of it (solvers/davidson.py, THE TRIP COUNT): num_steps is
+    # the MOST steps a solve takes; it ends as soon as no band is
+    # unconverged. With converge_by_energy 1 a band is converged when a step
+    # moved its eigenvalue by less than the tolerance, which starts at
+    # energy_tolerance; with 0, when its residual norm is under it, from
+    # residual_tolerance. Either way the SCF loop tightens it with the
+    # density residual (tolerance_scale, min_tolerance; dft/mixer.py), and
+    # the solver floors it at what the working precision resolves.
     type: str = "auto"  # davidson | exact | auto
     num_steps: int = 20
     subspace_size: int = 2

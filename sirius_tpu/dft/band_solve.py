@@ -13,6 +13,9 @@ same questions:
 - ``density_acc(occ_w)``: the coarse-box density accumulator off the
   solver's own storage, or None: the tail then uses
   ``generate_density_g(ctx, host_psi())``;
+- ``book()``: the counters of the solves since the last call, from the steps
+  and chunks each ran (device integers until then: the loop calls it where
+  it fetches anyway, at its end);
 - ``host_psi()`` (autosave, Hubbard occupations, finalize),
   ``restart(psi_big)`` / ``load(psi)`` (recovery, resume, warm start),
   ``rescue(...)`` (the stagnation sentinel), ``after_solve(t0, it)``;
@@ -46,11 +49,10 @@ from sirius_tpu.parallel.batched import (
 )
 from sirius_tpu.solvers import subspace_eigh
 from sirius_tpu.solvers.davidson import (
-    apply_blocks,
     count_applies,
+    count_solve,
     davidson,
     num_applies,
-    num_eigh,
 )
 from sirius_tpu.utils.profiler import counters
 
@@ -140,13 +142,41 @@ def _hk_params(cache, ctx, hub, ik, veff_r, dmat, dtype, vhub_s=None):
     )
 
 
-def _book_solve(num_steps, ctx, rows_per_box=1):
-    # H*psi application count of one solve of the whole (k, spin) set
-    # (reference num_loc_op_applied counter) and the FFT boxes behind it
-    copies = ctx.gkvec.num_kpoints * ctx.num_spins
-    count_applies(counters, apply_blocks(num_steps, ctx.num_bands),
-                  copies=copies, rows_per_box=rows_per_box)
-    counters["num_subspace_eigh"] += copies * num_eigh(num_steps)
+class _Booked:
+    """What every solver does with the steps and chunks a solve ran: they
+    leave davidson() as device integers and are held here, so that no solve
+    costs a fetch; book() fetches them and books the H applications
+    (reference num_loc_op_applied counter), the FFT boxes behind them, the
+    subspace eigenproblems and the steps (davidson.count_solve)."""
+
+    rows_per_box = 1
+
+    def _rule(self, itsol):
+        # the most steps a solve takes, and what res_tol bars: a step's
+        # move of the eigenvalue or the residual norm (davidson())
+        self.num_steps = itsol.num_steps
+        self.by_energy = bool(itsol.converge_by_energy)
+        self._unbooked: list = []
+
+    def _ran(self, ran, copies=1):
+        # one solve of the whole (k, spin) set: `ran` an array or a list of
+        # them, `copies` the (k, spin) lanes behind each of its rows
+        self._unbooked.append((ran, copies))
+
+    def last_cost(self, ngk, nbeta, box):
+        """obs/costs.band_solve_cost of the newest solve (a fetch of its
+        steps and chunks: for a caller that has the solve's values)."""
+        from sirius_tpu.obs.costs import band_solve_cost
+
+        ran, copies = jax.device_get(self._unbooked[-1])
+        return band_solve_cost(self.ctx.num_bands, ngk, nbeta, box, ran,
+                               copies=copies)
+
+    def book(self):
+        unbooked, self._unbooked = self._unbooked, []
+        for ran, copies in jax.device_get(unbooked):
+            count_solve(counters, ran, self.ctx.num_bands, copies=copies,
+                        rows_per_box=self.rows_per_box)
 
 
 def _host_evals(ctx, ev_by_spin):
@@ -208,7 +238,7 @@ def theta_real_block(x, tr):
     return np.where(norm2(plus) >= norm2(minus), plus, minus)
 
 
-class KsetSolver:
+class KsetSolver(_Booked):
     """Production path: the whole (k, spin) set as ONE program
     (parallel/batched.py; shards over the ("k", "b") mesh). Real-boundary:
     psi crosses the jit boundary as a (re, im) pair and stays device-
@@ -227,7 +257,7 @@ class KsetSolver:
 
     def __init__(self, ctx, cfg, devs, mesh, psi_spec, hub, mgga):
         self.ctx, self.dev, self.mesh, self.mgga = ctx, devs[0], mesh, mgga
-        self.num_steps = cfg.iterative_solver.num_steps
+        self._rule(cfg.iterative_solver)
         self.hub_phi = None if hub is None else np.stack(
             [hub.phi_s_gk[ik] for ik in range(ctx.gkvec.num_kpoints)])
         self._cache: dict = {}  # dtype -> HkSetParams, constant tables cached
@@ -406,18 +436,19 @@ class KsetSolver:
         if self.mgga and inputs.pot.vtau_r_coarse is not None:
             from sirius_tpu.ops.mgga import davidson_kset_mgga
 
-            ev, pr, pi, rn = davidson_kset_mgga(
+            ev, pr, pi, rn, ran = davidson_kset_mgga(
                 ps, jnp.asarray(inputs.pot.vtau_r_coarse, dtype=rdt),
                 self._gkc_dev(rdt), pr, pi,
                 num_steps=self.num_steps,
-                res_tol=res_tol,
+                res_tol=res_tol, by_energy=self.by_energy,
             )
         else:
-            ev, pr, pi, rn = davidson_kset(
+            ev, pr, pi, rn, ran = davidson_kset(
                 ps, pr, pi,
                 num_steps=self.num_steps,
                 res_tol=_rtol(res_tol, rdt),
                 theta_index=self._theta_index(), mesh=self.mesh,
+                by_energy=self.by_energy,
             )
         # canonicalize the pair onto the explicit psi sharding (a no-op
         # when GSPMD already placed it there): downstream consumers must
@@ -429,7 +460,7 @@ class KsetSolver:
         # need it (host_psi: Hubbard occupations each iteration,
         # forces/stress/checkpoint after the loop)
         self.psi = None
-        _book_solve(self.num_steps, ctx)
+        self._ran(ran, copies=ns)
         # with the fused tail the eigenvalues stay on device; the host copy
         # is fetched once after the loop for the final report
         return BandOut(
@@ -470,15 +501,16 @@ class KsetSolver:
             np.asarray(psi), self.tr)
 
     def rescue(self, inputs, out, res_tol):
-        """One deeper retry, warm-started from the stagnated block (static
-        num_steps means this compiles once and is then cached)."""
+        """One deeper retry, warm-started from the stagnated block (a static
+        bound means this compiles once and is then cached)."""
         if self.mgga:
             return None
         from sirius_tpu.parallel.batched import davidson_kset
 
-        ev, self.pr, self.pi, rn = davidson_kset(
+        ev, self.pr, self.pi, rn, _ = davidson_kset(
             self.ps, self.pr, self.pi, num_steps=2 * self.num_steps,
             res_tol=res_tol, theta_index=self._theta_index(), mesh=self.mesh,
+            by_energy=self.by_energy,
         )
         return BandOut(np.asarray(ev, dtype=np.float64), rn, self.pr, self.pi)
 
@@ -489,7 +521,7 @@ class KsetSolver:
         return self.pr if self.pr is not None else self.psi
 
 
-class GammaSolver:
+class GammaSolver(_Booked):
     """Gamma-point real-storage band solve on one device (ops/gamma.py;
     reference reduce_gvec, wave_functions.hpp:1589-1626): packed-real
     vectors make the solver's GEMMs/eigh real, two bands share a box."""
@@ -501,10 +533,11 @@ class GammaSolver:
     mesh = None
 
     def __init__(self, ctx, cfg, devs):
-        from sirius_tpu.ops.gamma import build_gamma_map
+        from sirius_tpu.ops.gamma import ROWS_PER_BOX, build_gamma_map
 
         self.ctx, self.dev = ctx, devs[0]
-        self.num_steps = cfg.iterative_solver.num_steps
+        self.rows_per_box = ROWS_PER_BOX  # two real bands share a box
+        self._rule(cfg.iterative_solver)
         self.gm = build_gamma_map(
             np.asarray(ctx.gkvec.millers[0]), np.asarray(ctx.gkvec.mask[0])
         )
@@ -557,7 +590,7 @@ class GammaSolver:
             dev_inputs = gmod.solve_inputs_device(
                 pidx, gp0.mask_p, o_diag_dev,
                 fo["veff_r_coarse"], fo["dion"], fo["h_diag"])
-        ev_spin = []
+        ev_spin, ran = [], []
         for ispn in range(ns):
             if dev_inputs is not None:
                 veff_s, dion_s, hd_p, od_p = dev_inputs[ispn]
@@ -577,15 +610,16 @@ class GammaSolver:
                     gp, _up(gmod.pack(gm, self.psi_big[0, ispn]), rdt), nb)
                 count_applies(counters, [(self.psi_big.shape[2], 1)],
                               rows_per_box=gmod.ROWS_PER_BOX)
-            ev, x_packed[ispn], rn = gmod.davidson_gamma(
+            ev, x_packed[ispn], rn, ran_s = gmod.davidson_gamma(
                 gp, x_packed[ispn], hd_p, od_p,
                 num_steps=self.num_steps,
-                res_tol=_up(_rtol(res_tol, rdt)),
+                res_tol=_up(_rtol(res_tol, rdt)), by_energy=self.by_energy,
             )
             ev_spin.append(ev)
+            ran.append(ran_s)
         self.x_packed = x_packed
         self.psi_big = None
-        _book_solve(self.num_steps, ctx, rows_per_box=gmod.ROWS_PER_BOX)
+        self._ran(ran)
         if tail_rdt is not None:
             # the packed block and the eigenvalues stay on the device; the
             # fused tail takes the band block as the (re, im) pair of its
@@ -638,7 +672,7 @@ class GammaSolver:
         return self.x_packed[0]
 
 
-class GshardSolver:
+class GshardSolver(_Booked):
     """G-sharded band solve (slab FFT over a "g" mesh): for a replicated
     projector + wave-function footprint that would not fit a single device.
     Single-k no-U regime — the Si-supercell flagship class."""
@@ -649,7 +683,7 @@ class GshardSolver:
     def __init__(self, ctx, cfg, devs, wf_dtype):
         self.ctx, self.devs = ctx, devs
         self.gshard_devices = len(devs)
-        self.num_steps = cfg.iterative_solver.num_steps
+        self._rule(cfg.iterative_solver)
         self._hk: dict = {}
         self.psi = self.psi_big = None
         self._setup(wf_dtype)
@@ -748,7 +782,7 @@ class GshardSolver:
             np.asarray(pot.veff_r_coarse[0], dtype=rdt),
             self.fn.sharding_veff,
         )
-        ev, x, rn = davidson(
+        ev, x, rn, self.last_ran = davidson(
             self.fn,
             (veff_d, jax.device_put(np.asarray(d0, dtype=rdt), self.sh_rep)),
             x0,
@@ -756,7 +790,7 @@ class GshardSolver:
             jax.device_put(np.asarray(od, dtype=rdt), self.sh_g),
             self.mask,
             num_steps=self.num_steps,
-            res_tol=_rtol(res_tol, rdt),
+            res_tol=_rtol(res_tol, rdt), by_energy=self.by_energy,
         )
         self.x = x
         # host round-trip for the density consumer; a device-side gather +
@@ -765,7 +799,7 @@ class GshardSolver:
         self.psi = jnp.asarray(
             reorder_from_gshard(np.asarray(x), self.order, ctx.gkvec.ngk_max)
         )[None, None]
-        _book_solve(self.num_steps, ctx)
+        self._ran(self.last_ran)
         return BandOut(_host_evals(ctx, [ev]), rn)
 
     def density_acc(self, occ_w):
@@ -796,8 +830,10 @@ class GshardSolver:
         ctx, ndev = self.ctx, len(self.devs)
         dt = time.perf_counter() - t0
         t_ns = time.time_ns() - int(dt * 1e9)
+        # this path's tail is the host's: the solve's values are fetched
+        # every iteration, its (steps, chunks) with them
         rows = ctx.gkvec.num_kpoints * ctx.num_spins * num_applies(
-            self.num_steps, ctx.num_bands)
+            *np.asarray(self.last_ran).tolist(), ctx.num_bands)
         coll = sum(
             v for k, v in self.probe["per_call"].items()
             if k != "collective.fft_local"
@@ -816,7 +852,7 @@ class GshardSolver:
         return self.x
 
 
-class ChunkedSolver:
+class ChunkedSolver(_Booked):
     """Chunk-generated beta projectors (ops/beta_chunked.py): the H/S
     application rebuilds each atom chunk's beta block on the fly
     (lax.scan), so the dense [nbeta, ngk] table never exists on device.
@@ -828,7 +864,7 @@ class ChunkedSolver:
 
     def __init__(self, ctx, cfg, mesh):
         self.ctx, self.control, self.mesh = ctx, cfg.control, mesh
-        self.num_steps = cfg.iterative_solver.num_steps
+        self._rule(cfg.iterative_solver)
         self.params = self.dtype = None
         self.psi = self.psi_big = None
 
@@ -868,17 +904,17 @@ class ChunkedSolver:
             count_applies(counters, [(self.psi_big.shape[2], 1)])
             self.psi_big = None
         h_diag, o_diag = _h_o_diag(ctx, 0, inputs.v0, d0)
-        ev, x, rn = davidson(
+        ev, x, rn, ran = davidson(
             apply_h_s_chunked, prm,
             jnp.asarray(np.asarray(self.psi[0, 0]), dtype=wf_dtype),
             jnp.asarray(h_diag, dtype=rdt),
             jnp.asarray(o_diag, dtype=rdt),
             jnp.asarray(ctx.gkvec.mask[0], dtype=rdt),
             num_steps=self.num_steps,
-            res_tol=res_tol,
+            res_tol=res_tol, by_energy=self.by_energy,
         )
         self.psi = np.asarray(x).astype(np.complex128)[None, None]
-        _book_solve(self.num_steps, ctx)
+        self._ran(ran)
         return BandOut(_host_evals(ctx, [ev]), rn)
 
     def density_acc(self, occ_w):
@@ -903,7 +939,7 @@ class ChunkedSolver:
         return self.psi
 
 
-class SerialSolver:
+class SerialSolver(_Booked):
     """Per-(k, spin) debug path: the reference the tests compare the
     production paths against."""
 
@@ -914,7 +950,7 @@ class SerialSolver:
 
     def __init__(self, ctx, cfg, hub):
         self.ctx, self.control, self.hub = ctx, cfg.control, hub
-        self.num_steps = cfg.iterative_solver.num_steps
+        self._rule(cfg.iterative_solver)
         self._hk: dict = {}
         self.psi = self.psi_big = None
 
@@ -948,14 +984,14 @@ class SerialSolver:
             self.psi, self.psi_big = psi0, None
         rdt = real_dtype_of(wf_dtype)
         evals = np.zeros((nk, ns, nb))
-        new_psi = []
+        new_psi, ran = [], []
         for ik in range(nk):
             per_spin = []
             for ispn in range(ns):
                 params = self._params(inputs, ik, ispn, wf_dtype)
                 h_diag, o_diag = _h_o_diag(
                     ctx, ik, inputs.v0, inputs.d_by_spin[ispn])
-                ev, x, rn = davidson(
+                ev, x, rn, ran_ks = davidson(
                     apply_h_s,
                     params,
                     self.psi[ik, ispn].astype(wf_dtype),
@@ -963,13 +999,14 @@ class SerialSolver:
                     jnp.asarray(o_diag, dtype=rdt),
                     params.mask,
                     num_steps=self.num_steps,
-                    res_tol=res_tol,
+                    res_tol=res_tol, by_energy=self.by_energy,
                 )
                 evals[ik, ispn] = np.asarray(ev)
                 per_spin.append(x)
+                ran.append(ran_ks)
             new_psi.append(jnp.stack(per_spin))
         self.psi = jnp.stack(new_psi)
-        _book_solve(self.num_steps, ctx)
+        self._ran(ran)
         # rn covers the last (k, spin) solve, a proxy that still catches
         # whole-solve stagnation
         return BandOut(evals, rn)
@@ -1152,6 +1189,7 @@ def degrade(band, d, cfg):
             or foot > cfg.control.beta_chunk_budget_bytes):
         # (re)engage the chunked projector path; a fresh solver builds its
         # tables at the next band solve, at the new beta_chunk_size
+        band.book()  # what the solver that goes has run
         new = ChunkedSolver(band.ctx, cfg, band.mesh)
         new.chunk_ok, new.chunk_foot = True, foot
         return new

@@ -67,10 +67,10 @@ def band_path(
                 + 1j * rng.standard_normal((nb, gk.ngk_max))
             ) / (1.0 + ekin)[None, :]
             h_diag = np.where(gk.mask[ik] > 0, ekin + float(np.real(pot.veff_g[0])), 1e4)
-            ev, x, rn = davidson(
+            ev, _, _, _ = davidson(
                 apply_h_s, params, jnp.asarray(x0 * gk.mask[ik]),
                 jnp.asarray(h_diag), jnp.ones(gk.ngk_max), jnp.asarray(gk.mask[ik]),
-                num_steps=40, res_tol=1e-8,
+                num_steps=40, res_tol=1e-8, by_energy=False,
             )
             evals[ik, ispn] = np.asarray(ev)
     return {"kpoints": kpts.tolist(), "bands": evals.tolist()}

@@ -422,9 +422,19 @@ def device_mix(state: DeviceMixerState, x_in: jnp.ndarray, x_new: jnp.ndarray,
     return new_state, out, rms, eha
 
 
+def initial_res_tol(itsol) -> float:
+    """The bar the band solve starts from, and what it bars: with
+    converge_by_energy (the reference's default) a band's eigenvalue move in
+    one Davidson step, from energy_tolerance; otherwise its residual norm,
+    from residual_tolerance. A solve ends when every band is under the bar
+    (solvers/davidson.py, THE TRIP COUNT)."""
+    return float(itsol.energy_tolerance if itsol.converge_by_energy
+                 else itsol.residual_tolerance)
+
+
 def schedule_res_tol(itsol, res_tol: float, dens_metric: float, nel: float,
                      hartree_metric: bool) -> float:
-    """Next iteration's band-solve residual bar from the density residual
+    """Next iteration's band-solve bar (initial_res_tol) from the density residual
     (reference dft_ground_state.cpp:252-259): tol = min(scale0 * metric,
     scale1 * tol_prev), clamped at min_tolerance. With the Hartree metric
     the density bar is an energy — scale it per electron as the reference

@@ -28,7 +28,7 @@ from sirius_tpu.dft.density import (
     rho_real_space,
     symmetrize_pw,
 )
-from sirius_tpu.dft.mixer import Mixer, schedule_res_tol
+from sirius_tpu.dft.mixer import Mixer, initial_res_tol, schedule_res_tol
 from sirius_tpu.dft.occupation import find_fermi
 from sirius_tpu.dft.potential import generate_potential
 from sirius_tpu.dft.recovery import ScfSupervisor
@@ -551,7 +551,7 @@ def _run_scf_inner(
     # density residual (reference schedule dft_ground_state.cpp:252-259);
     # a static bar leaves a locked-band noise floor in the density that can
     # sit just above density_tol and stall tight decks at num_dft_iter
-    res_tol = itsol.residual_tolerance
+    res_tol = initial_res_tol(itsol)
     it0 = 0
     warm_secants = None
     if guess_scf:
@@ -775,7 +775,7 @@ def _run_scf_inner(
             mixer.beta = d.beta
         if d.kind is not None:
             mixer.kind = d.kind
-        res_tol = float(snap.get("res_tol", itsol.residual_tolerance))
+        res_tol = float(snap.get("res_tol", initial_res_tol(itsol)))
         e_prev = None
         rho_g, mag_g, om_mixed, om_nl_mixed, paw_dm, _lam = unpack(x_mix)
         if _lam is not None:
@@ -1048,8 +1048,10 @@ def _run_scf_inner(
                 fused_out={k: fused_out[k]
                            for k in ("veff_r_coarse", "dion", "h_diag")},
                 v0=float(fused_np[S_V0]), vhub=vhub)
-        _bs_span = _stage("scf.band_solve", it=it + 1,
-                          num_steps=itsol.num_steps)
+        # the span carries no cost until the solve has said what it ran:
+        # the model's "scf.band_solve" is the cost of all num_steps steps
+        _bs_span = obs_spans.open_span("scf.band_solve", it=it + 1,
+                                       num_steps=itsol.num_steps)
         _bs_t0 = time.perf_counter()
         with profile("scf::band_solve"):
             out = band.solve(inputs, res_tol, wf_dtype,
@@ -1059,6 +1061,17 @@ def _run_scf_inner(
             # device-resident solve still has compute in flight
             _fence(out)
         band.after_solve(_bs_t0, it)
+        if (obs_metrics.enabled() and _stage_costs
+                and (fused is None or _span_fence)):
+            # the span's time is the solve's (a host tail has fetched its
+            # values, a fenced one has waited for them): it carries the
+            # cost of the steps that ran. On the device path without a
+            # fence the span times a dispatch and the steps are still on
+            # the device: no cost, so no utilization is made of a bound
+            _c = band.last_cost(int(ctx.gkvec.ngk_max),
+                                int(ctx.beta.num_beta_total),
+                                tuple(ctx.fft_coarse.dims))
+            _bs_span.flops, _bs_span.bytes = _c.flops, _c.bytes
         _bs_span.close()
         # --- band-solve supervision (dft/recovery.py): a stagnated or
         # blown-up solve is retried with a deeper subspace; the serial
@@ -1577,6 +1590,9 @@ def _run_scf_inner(
     obs_trace.finish()
     # everything between the loop's end and the returned result
     _fin_span = obs_spans.open_span("scf.finalize")
+    # the steps and chunks every band solve ran leave the device here, and
+    # become the counters of the H applications and eigenproblems that ran
+    band.book()
     # read-only record of the path taken and of where each stage of the last
     # iteration ran and in which dtype, read off the arrays themselves
     placement = {
@@ -1718,6 +1734,7 @@ def _run_scf_inner(
         num_loc_op_applied=int(counters["num_loc_op_applied"]),
         num_fft_boxes=int(counters["num_fft_boxes"]),
         num_subspace_eigh=int(counters["num_subspace_eigh"]),
+        num_davidson_steps=int(counters["num_davidson_steps"]),
         num_tail_box_fills=int(counters["num_tail_box_fills"]),
         num_xc_gradient_transforms=int(
             counters["num_xc_gradient_transforms"]),

@@ -27,7 +27,7 @@ from sirius_tpu.dft.density import (
     symmetrize_density_matrix_nc,
     symmetrize_pw,
 )
-from sirius_tpu.dft.mixer import Mixer, schedule_res_tol
+from sirius_tpu.dft.mixer import Mixer, initial_res_tol, schedule_res_tol
 from sirius_tpu.dft.occupation import find_fermi
 from sirius_tpu.dft.potential_nc import (
     generate_potential_nc,
@@ -162,7 +162,7 @@ def run_scf_nc(
     # adaptive band-solve tolerance (reference dft_ground_state.cpp:252-259);
     # see run_scf — a static bar stalls tight decks (test09: density_tol 1e-6
     # with a 1e-6 locked-band noise floor never meets the bar in 100 iters)
-    res_tol = itsol.residual_tolerance
+    res_tol = initial_res_tol(itsol)
 
     for it in range(p.num_dft_iter):
         # --- spin-block D operator ---
@@ -196,21 +196,19 @@ def run_scf_nc(
             if pr is None or pr.dtype != np.dtype(rdt):
                 src = psi if psi is not None else join_cplx(pr, pi)
                 pr, pi = split_cplx(np.asarray(src), rdt)
-            ev, pr, pi, rn = davidson_kset_nc(
+            ev, pr, pi, rn, ran = davidson_kset_nc(
                 ps, pr, pi,
                 num_steps=itsol.num_steps,
                 res_tol=res_tol,
+                by_energy=bool(itsol.converge_by_energy),
             )
             psi = None
             evals = np.asarray(ev, dtype=np.float64)
-            from sirius_tpu.solvers.davidson import (
-                apply_blocks, count_applies, num_eigh,
-            )
+            from sirius_tpu.solvers.davidson import count_solve
 
-            # a spinor row is two component boxes (ops/spinor.py)
-            count_applies(counters, apply_blocks(itsol.num_steps, nb),
-                          copies=nk, components=2)
-            counters["num_subspace_eigh"] += nk * num_eigh(itsol.num_steps)
+            # what the solve ran, fetched with its eigenvalues (a host
+            # tail); a spinor row is two component boxes (ops/spinor.py)
+            count_solve(counters, ran, nb, components=2)
 
         # --- occupations (spinor bands: max occupancy 1) ---
         mu, occ, entropy_sum = find_fermi(

@@ -307,8 +307,8 @@ def davidson_fv(p: FvParams, nev: int, num_steps: int = 30,
         x0 = jnp.asarray(x0)
     h_diag, o_diag = fv_diag(p)
     mask = jnp.ones(ntot)
-    ev, x, rn = davidson(
+    ev, x, rn, _ = davidson(
         apply_fv_h_o, p, x0, h_diag, o_diag, mask,
-        num_steps=num_steps, res_tol=res_tol,
+        num_steps=num_steps, res_tol=res_tol, by_energy=False,
     )
     return ev, x, rn

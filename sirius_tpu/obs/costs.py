@@ -247,22 +247,25 @@ def hpsi_bytes(nb: int, ngk: int, nbeta: int, box,
     return nb * per_band + itemsize * (nbeta * ngk + 2.0 * nb * nbeta)
 
 
-def davidson_applies(num_steps: int, nb: int,
-                     refresh_every: int | None = None) -> int:
-    """H-applications in band rows of one davidson() call (delegates to
-    solvers/davidson.num_applies so the counts can never drift)."""
-    from sirius_tpu.solvers.davidson import REFRESH_EVERY, num_applies
+def davidson_applies(steps: int, nb: int, chunks: int | None = None) -> int:
+    """H-applications in band rows of one davidson() call that ran `steps`
+    steps in `chunks` chunks (delegates to solvers/davidson.num_applies so
+    the counts can never drift). Without `chunks`: a solve that ran all of
+    its `steps`, the bound a configuration's num_steps sets."""
+    from sirius_tpu.solvers.davidson import max_chunks, num_applies
 
-    return num_applies(num_steps, nb,
-                       refresh_every=refresh_every or REFRESH_EVERY)
+    return num_applies(
+        steps, max_chunks(steps) if chunks is None else chunks, nb)
 
 
 def davidson_cost(nb: int, ngk: int, nbeta: int, box,
-                  num_steps: int) -> StageCost:
-    """One davidson() solve: the H/S applications plus the per-step
-    dense subspace algebra (3nb x 3nb Gram products, the Rayleigh-Ritz
-    eigensolve, and the rotation GEMMs back to the band block)."""
-    rows = davidson_applies(num_steps, nb)
+                  num_steps: int, chunks: int | None = None) -> StageCost:
+    """One davidson() solve of `num_steps` steps in `chunks` chunks (what a
+    solve ran; without `chunks`, the bound): the H/S applications plus the
+    per-step dense subspace algebra (3nb x 3nb Gram products, the
+    Rayleigh-Ritz eigensolve, and the rotation GEMMs back to the band
+    block)."""
+    rows = davidson_applies(num_steps, nb, chunks)
     apply_f = hpsi_flops(1, ngk, nbeta, box) * rows
     apply_b = hpsi_bytes(1, ngk, nbeta, box) * rows
     m = 3 * nb  # [X, K R, P] subspace
@@ -274,6 +277,22 @@ def davidson_cost(nb: int, ngk: int, nbeta: int, box,
     return StageCost(flops=apply_f + sub_f, bytes=apply_b + sub_b)
 
 
+def band_solve_cost(nb: int, ngk: int, nbeta: int, box, ran,
+                    copies: int = 1) -> StageCost:
+    """A set's band solve from what its loops ran: `ran` holds the fetched
+    (steps, chunks) of every loop of the solve ([..., 2], as
+    solvers/davidson.count_solve takes them), `copies` the (k, spin) lanes
+    behind each."""
+    import numpy as np
+
+    flops = bytes_ = 0.0
+    for steps, chunks in np.asarray(ran).reshape(-1, 2).tolist():
+        c = davidson_cost(nb, ngk, nbeta, box, steps, chunks)
+        flops += copies * c.flops
+        bytes_ += copies * c.bytes
+    return StageCost(flops=flops, bytes=bytes_)
+
+
 def scf_stage_costs(nk: int, ns: int, nb: int, ngk: int, nbeta: int,
                     box, ng: int, num_steps: int,
                     box_fine=None, mix_history: int = 8,
@@ -283,7 +302,11 @@ def scf_stage_costs(nk: int, ns: int, nb: int, ngk: int, nbeta: int,
     Shapes come straight from the SimulationContext: `box` is the coarse
     FFT grid (wave functions), `box_fine` the fine grid (density and
     potential; defaults to the coarse box when not given), `ng` the fine
-    G set, `ngk` the padded |G+k| sphere."""
+    G set, `ngk` the padded |G+k| sphere. `num_steps` is the band solve's
+    bound (iterative_solver.num_steps): "scf.band_solve" here is the cost of
+    a solve that takes every step, what the straggler model and the perf
+    gate budget for. The span itself carries band_solve_cost of the steps
+    that ran, or no cost where its time is not the solve's (dft/scf.py)."""
     bf = box_fine if box_fine is not None else box
     nf = _nbox(bf)
     c: dict[str, StageCost] = {}

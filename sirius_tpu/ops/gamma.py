@@ -367,16 +367,17 @@ def initialize_subspace_gamma(params: GammaParams, xb, nb: int):
     return subspace_rotate(xb, hx, sx, nb, mask=params.mask_p).astype(xb.dtype)
 
 
-@partial(jax.jit, static_argnames=("num_steps",))
+@partial(jax.jit, static_argnames=("num_steps", "by_energy"))
 def davidson_gamma(params: GammaParams, x0, h_diag_p, o_diag_p,
-                   num_steps: int = 20, res_tol: float = 1e-6):
+                   num_steps: int = 20, res_tol: float = 1e-2,
+                   by_energy: bool = True):
     """Jit wrapper: the generic fixed-shape solver on packed real arrays
     (subspace blocks become real-symmetric; GEMMs real)."""
     from sirius_tpu.solvers.davidson import davidson
 
     return davidson(
         apply_h_s_gamma, params, x0, h_diag_p, o_diag_p, params.mask_p,
-        num_steps=num_steps, res_tol=res_tol,
+        num_steps=num_steps, res_tol=res_tol, by_energy=by_energy,
     )
 
 

@@ -82,57 +82,68 @@ def tau_kset(fft_index, gkc, psi_re, psi_im, occ_w, dims: tuple):
     return jnp.sum(jax.vmap(one_k)(fft_index, gkc, psi, occ_w), axis=0)
 
 
-@partial(jax.jit, static_argnames=("num_steps",))
+@partial(jax.jit, static_argnames=("num_steps", "by_energy"))
 def davidson_kset_mgga(params, vtau_r, gkc, psi_re, psi_im,
-                       num_steps: int = 20, res_tol: float = 1e-6):
+                       num_steps: int = 20, res_tol: float = 1e-2,
+                       by_energy: bool = True):
     """davidson_kset with the tau term in the operator. params: HkSetParams;
     vtau_r: [ns, n1,n2,n3] real; gkc: [nk, ngk, 3] real. Same returns as
     parallel.batched.davidson_kset."""
-    from sirius_tpu.solvers.davidson import davidson
+    from sirius_tpu.solvers.davidson import Stages, solve, stages
 
-    psi = _cplx(psi_re, psi_im)
     has_hub = params.hub_re is not None
-
-    def one_k(ekin, mask, fft_index, gkc_k, beta_re, beta_im, h_diag_k,
-              o_diag, hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, psi_k, cube_k):
-        def one_spin(veff_s, dion_s, vtau_s, vhub_re_s, vhub_im_s,
-                     h_diag_s, x0):
-            pk = HkParams(
-                veff_r=veff_s,
-                ekin=ekin,
-                mask=mask,
-                fft_index=fft_index,
-                beta=_cplx(beta_re, beta_im),
-                dion=dion_s,
-                qmat=params.qmat,
-                hub=None if hub_re_k is None else _cplx(hub_re_k, hub_im_k),
-                vhub=None if vhub_re_s is None else _cplx(vhub_re_s, vhub_im_s),
-                cube=cube_k,
-            )
-
-            def apply_fn(p, x):
-                return apply_h_s_mgga(p, vtau_s, gkc_k, x)
-
-            return davidson(
-                apply_fn, pk, x0, h_diag_s, o_diag, mask,
-                num_steps=num_steps, res_tol=res_tol,
-            )
-
-        return jax.vmap(
-            one_spin,
-            in_axes=(0, 0, 0, None if not has_hub else 0,
-                     None if not has_hub else 0, 0, 0),
-        )(params.veff_r, params.dion, vtau_r, vhub_re_k, vhub_im_k,
-          h_diag_k, psi_k)
-
     hub_ax = 0 if has_hub else None
-    ev, x, rn = jax.vmap(
-        one_k,
-        in_axes=(0, 0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0, 0),
-    )(
-        params.ekin, params.mask, params.fft_index, gkc, params.beta_re,
-        params.beta_im, params.h_diag, params.o_diag,
-        params.hub_re, params.hub_im, params.vhub_re, params.vhub_im, psi,
-        params.cube,
-    )
-    return ev, jnp.real(x), jnp.imag(x), rn
+
+    def stage(name):
+        # the set's stage `name`, every (k, spin) lane's, as in
+        # parallel/batched._davidson_kset: the loops stay outside the vmap
+        def one_k(ekin, mask, fft_index, gkc_k, beta_re, beta_im, h_diag_k,
+                  o_diag, hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, cube_k,
+                  *blocks_k):
+            def one_spin(veff_s, dion_s, vtau_s, vhub_re_s, vhub_im_s,
+                         h_diag_s, *blocks):
+                pk = HkParams(
+                    veff_r=veff_s,
+                    ekin=ekin,
+                    mask=mask,
+                    fft_index=fft_index,
+                    beta=_cplx(beta_re, beta_im),
+                    dion=dion_s,
+                    qmat=params.qmat,
+                    hub=None if hub_re_k is None else _cplx(hub_re_k, hub_im_k),
+                    vhub=(None if vhub_re_s is None
+                          else _cplx(vhub_re_s, vhub_im_s)),
+                    cube=cube_k,
+                )
+
+                def apply_fn(p, x):
+                    return apply_h_s_mgga(p, vtau_s, gkc_k, x)
+
+                return getattr(stages(
+                    apply_fn, pk, h_diag_s, o_diag, mask, res_tol,
+                    by_energy=by_energy), name)(*blocks)
+
+            return jax.vmap(
+                one_spin,
+                in_axes=(0, 0, 0, hub_ax, hub_ax, 0) + (0,) * len(blocks_k),
+            )(params.veff_r, params.dion, vtau_r, vhub_re_k, vhub_im_k,
+              h_diag_k, *blocks_k)
+
+        def over_set(*blocks):
+            return jax.vmap(
+                one_k,
+                in_axes=(0, 0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax,
+                         hub_ax, 0) + (0,) * len(blocks),
+            )(
+                params.ekin, params.mask, params.fft_index, gkc,
+                params.beta_re, params.beta_im, params.h_diag, params.o_diag,
+                params.hub_re, params.hub_im, params.vhub_re, params.vhub_im,
+                params.cube, *blocks,
+            )
+
+        return over_set
+
+    ev, x, rn, ran = solve(Stages(*map(stage, Stages._fields)),
+                           _cplx(psi_re, psi_im), num_steps)
+    return (ev, jnp.real(x), jnp.imag(x), rn,
+            jnp.broadcast_to(ran, (ev.shape[0], 2)))

@@ -3,7 +3,8 @@ computation, shardable over the ("k", "b") mesh.
 
 The reference loops local k-points serially per MPI rank
 (diagonalize.hpp:58); on TPU the padded fixed-shape per-k arrays (GkVec)
-make the entire k-set one vmapped davidson call — a single XLA program that
+make the entire k-set one solve (the solver's loops over stages vmapped over
+the set, solvers/davidson.py) — a single XLA program that
 shards over the mesh with zero hand-written collectives (density reduction
 over "k" is a psum XLA inserts from the einsum).
 
@@ -31,7 +32,7 @@ from jax.sharding import PartitionSpec
 
 from sirius_tpu.ops.hamiltonian import HkParams, apply_h_s
 from sirius_tpu.parallel.mesh import KSET_PARAM_SPECS
-from sirius_tpu.solvers.davidson import davidson
+from sirius_tpu.solvers.davidson import Stages, solve, stages
 
 # over_k_pool's specs: a leading k axis split over "k", or one copy a device
 _K, _REP = PartitionSpec("k"), PartitionSpec()
@@ -322,70 +323,93 @@ def _initialize_subspace_kset(params, psi_re, psi_im, theta_index, nb):
     return jnp.real(x), jnp.imag(x)
 
 
-@partial(jax.jit, static_argnames=("num_steps", "mesh"))
+@partial(jax.jit, static_argnames=("num_steps", "mesh", "by_energy"))
 def davidson_kset(
-    params: HkSetParams, psi_re, psi_im, num_steps: int = 20, res_tol: float = 1e-6,
-    theta_index=None, mesh=None,
+    params: HkSetParams, psi_re, psi_im, num_steps: int = 20,
+    res_tol: float = 1e-2, theta_index=None, mesh=None, by_energy: bool = True,
 ):
-    """Solve bands at every (k, spin) in one vmapped call. ``theta_index``
+    """Solve bands at every (k, spin) in one program: one pair of loops over
+    the solver's stages, each vmapped over the set (solvers/davidson.py, THE
+    TRIP COUNT: the set takes its slowest k-point's steps, a k-point that is
+    done is held). ``theta_index``
     [nk, ngk]: every k-point of the set is time-reversal invariant and every
     row of psi Theta-real there, so the subspace eigenproblems are real
     symmetric (solvers/davidson.py, REAL SUBSPACE). ``mesh``: the ("k", "b")
-    mesh the operands are sharded on (over_k_pool).
+    mesh the operands are sharded on (over_k_pool): each device's loop ends
+    on its own k-points, so the program still holds no collective.
 
     psi_re/psi_im: [nk, ns, nb, ngk] real pair ->
-    (evals [nk, ns, nb], psi_re', psi_im', rnorm [nk, ns, nb])."""
+    (evals [nk, ns, nb], psi_re', psi_im', rnorm [nk, ns, nb], ran [nk, 2]:
+    the steps and chunks the set's loop ran on the device that holds the
+    k-point)."""
     return over_k_pool(
-        partial(_davidson_kset, num_steps=num_steps), mesh,
+        partial(_davidson_kset, num_steps=num_steps, by_energy=by_energy),
+        mesh,
         (kset_param_specs(params), _K, _K, _REP,
          None if theta_index is None else _K),
-        (_K, _K, _K, _K),
+        (_K, _K, _K, _K, _K),
     )(params, psi_re, psi_im, res_tol, theta_index)
 
 
-def _davidson_kset(params, psi_re, psi_im, res_tol, theta_index, num_steps):
-    psi = _cplx(psi_re, psi_im)
+def _davidson_kset(params, psi_re, psi_im, res_tol, theta_index, num_steps,
+                   by_energy):
     has_hub = params.hub_re is not None
-
-    def one_k(ekin, mask, fft_index, beta_re, beta_im, h_diag_k, o_diag,
-              hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, psi_k, theta_k, cube_k):
-        def one_spin(veff_s, dion_s, vhub_re_s, vhub_im_s, h_diag_s, x0):
-            pk = HkParams(
-                veff_r=veff_s,
-                ekin=ekin,
-                mask=mask,
-                fft_index=fft_index,
-                beta=_cplx(beta_re, beta_im),
-                dion=dion_s,
-                qmat=params.qmat,
-                hub=None if hub_re_k is None else _cplx(hub_re_k, hub_im_k),
-                vhub=None if vhub_re_s is None else _cplx(vhub_re_s, vhub_im_s),
-                cube=cube_k,
-            )
-            return davidson(
-                apply_h_s, pk, x0, h_diag_s, o_diag, mask,
-                num_steps=num_steps, res_tol=res_tol, theta_index=theta_k,
-            )
-
-        return jax.vmap(
-            one_spin,
-            in_axes=(0, 0, None if not has_hub else 0,
-                     None if not has_hub else 0, 0, 0),
-        )(params.veff_r, params.dion, vhub_re_k, vhub_im_k,
-          h_diag_k, psi_k)
-
     hub_ax = 0 if has_hub else None
-    ev, x, rn = jax.vmap(
-        one_k,
-        in_axes=(0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0,
-                 None if theta_index is None else 0, 0),
-    )(
-        params.ekin, params.mask, params.fft_index, params.beta_re,
-        params.beta_im, params.h_diag, params.o_diag,
-        params.hub_re, params.hub_im, params.vhub_re, params.vhub_im, psi,
-        theta_index, params.cube,
-    )
-    return ev, jnp.real(x), jnp.imag(x), rn
+
+    def stage(name):
+        """The set's stage `name` (solvers/davidson.Stages): every (k, spin)
+        lane's, vmapped over blocks [nk, ns, nb, ngk]. The loops stay
+        outside the vmap, so the set has one trip count."""
+
+        def one_k(ekin, mask, fft_index, beta_re, beta_im, h_diag_k, o_diag,
+                  hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, theta_k, cube_k,
+                  *blocks_k):
+            def one_spin(veff_s, dion_s, vhub_re_s, vhub_im_s, h_diag_s,
+                         *blocks):
+                pk = HkParams(
+                    veff_r=veff_s,
+                    ekin=ekin,
+                    mask=mask,
+                    fft_index=fft_index,
+                    beta=_cplx(beta_re, beta_im),
+                    dion=dion_s,
+                    qmat=params.qmat,
+                    hub=None if hub_re_k is None else _cplx(hub_re_k, hub_im_k),
+                    vhub=(None if vhub_re_s is None
+                          else _cplx(vhub_re_s, vhub_im_s)),
+                    cube=cube_k,
+                )
+                return getattr(stages(
+                    apply_h_s, pk, h_diag_s, o_diag, mask, res_tol,
+                    theta_index=theta_k, by_energy=by_energy), name)(*blocks)
+
+            return jax.vmap(
+                one_spin,
+                in_axes=(0, 0, hub_ax, hub_ax, 0) + (0,) * len(blocks_k),
+            )(params.veff_r, params.dion, vhub_re_k, vhub_im_k, h_diag_k,
+              *blocks_k)
+
+        def over_set(*blocks):
+            return jax.vmap(
+                one_k,
+                in_axes=(0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax,
+                         None if theta_index is None else 0, 0)
+                + (0,) * len(blocks),
+            )(
+                params.ekin, params.mask, params.fft_index, params.beta_re,
+                params.beta_im, params.h_diag, params.o_diag,
+                params.hub_re, params.hub_im, params.vhub_re, params.vhub_im,
+                theta_index, params.cube, *blocks,
+            )
+
+        return over_set
+
+    ev, x, rn, ran = solve(Stages(*map(stage, Stages._fields)),
+                           _cplx(psi_re, psi_im), num_steps)
+    # the loop's count beside each of the device's k-points: under the
+    # shard_map every device hands back its own
+    return (ev, jnp.real(x), jnp.imag(x), rn,
+            jnp.broadcast_to(ran, (ev.shape[0], 2)))
 
 
 @jax.jit

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sirius_tpu.ops.spinor import NcHkParams, apply_h_s_nc, nc_h_o_diag
-from sirius_tpu.solvers.davidson import davidson
+from sirius_tpu.solvers.davidson import Stages, solve, stages
 
 
 class NcSetParams(NamedTuple):
@@ -98,34 +98,40 @@ def make_nc_set_params(
     )
 
 
-@partial(jax.jit, static_argnames=("num_steps",))
+@partial(jax.jit, static_argnames=("num_steps", "by_energy"))
 def davidson_kset_nc(
-    params: NcSetParams, psi_re, psi_im, num_steps: int = 20, res_tol: float = 1e-6
+    params: NcSetParams, psi_re, psi_im, num_steps: int = 20,
+    res_tol: float = 1e-2, by_energy: bool = True,
 ):
     """psi_re/psi_im: [nk, nb, 2*ngk] flattened spinors ->
-    (evals [nk, nb], psi_re', psi_im', rnorm [nk, nb])."""
-    psi = _cplx(psi_re, psi_im)
+    (evals [nk, nb], psi_re', psi_im', rnorm [nk, nb], ran [nk, 2]: the
+    steps and chunks the set's loop ran, beside every k-point)."""
     dmat = _cplx(params.dmat_re, params.dmat_im)
     qmat = _cplx(params.qmat_re, params.qmat_im)
 
-    def one_k(ekin, mask, fft_index, beta_re, beta_im, h_diag, o_diag, x0):
-        pk = NcHkParams(
-            veff_uu=params.veff_uu, veff_dd=params.veff_dd,
-            bx=params.bx, by=params.by,
-            ekin=ekin, mask=mask, fft_index=fft_index,
-            beta=_cplx(beta_re, beta_im), dmat=dmat, qmat=qmat,
-        )
-        mask2 = jnp.tile(mask, 2)
-        return davidson(
-            apply_h_s_nc, pk, x0, h_diag, o_diag, mask2,
-            num_steps=num_steps, res_tol=res_tol,
-        )
+    def stage(name):
+        # the set's stage `name` (solvers/davidson.Stages), every k-point's:
+        # the loops stay outside the vmap, so the set has one trip count
+        def one_k(ekin, mask, fft_index, beta_re, beta_im, h_diag, o_diag,
+                  *blocks):
+            pk = NcHkParams(
+                veff_uu=params.veff_uu, veff_dd=params.veff_dd,
+                bx=params.bx, by=params.by,
+                ekin=ekin, mask=mask, fft_index=fft_index,
+                beta=_cplx(beta_re, beta_im), dmat=dmat, qmat=qmat,
+            )
+            return getattr(stages(
+                apply_h_s_nc, pk, h_diag, o_diag, jnp.tile(mask, 2), res_tol,
+                by_energy=by_energy), name)(*blocks)
 
-    ev, x, rn = jax.vmap(one_k)(
-        params.ekin, params.mask, params.fft_index,
-        params.beta_re, params.beta_im, params.h_diag, params.o_diag, psi,
-    )
-    return ev, jnp.real(x), jnp.imag(x), rn
+        return lambda *blocks: jax.vmap(one_k)(
+            params.ekin, params.mask, params.fft_index, params.beta_re,
+            params.beta_im, params.h_diag, params.o_diag, *blocks)
+
+    ev, x, rn, ran = solve(Stages(*map(stage, Stages._fields)),
+                           _cplx(psi_re, psi_im), num_steps)
+    return (ev, jnp.real(x), jnp.imag(x), rn,
+            jnp.broadcast_to(ran, (ev.shape[0], 2)))
 
 
 @jax.jit

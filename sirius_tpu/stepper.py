@@ -142,9 +142,10 @@ class GroundStateStepper:
                 ps, jnp.asarray(pb_re), jnp.asarray(pb_im), self.nb
             )
             self._psi_big = None
-        ev, self._pr, self._pi, rn = davidson_kset(
+        ev, self._pr, self._pi, rn, _ = davidson_kset(
             ps, self._pr, self._pi,
             num_steps=steps, res_tol=itsol.residual_tolerance,
+            by_energy=False,
         )
         self.evals = np.asarray(ev, dtype=np.float64)
         return self.evals

@@ -48,9 +48,14 @@ def test_hpsi_flops_hand_count():
 def test_davidson_applies_matches_solver():
     from sirius_tpu.solvers.davidson import num_applies
 
-    assert costs.davidson_applies(10, 8) == num_applies(10, 8)
-    assert costs.davidson_applies(7, 4, refresh_every=3) == num_applies(
-        7, 4, refresh_every=3)
+    # what ran: steps and chunks; without chunks, a solve that takes every
+    # step of its bound (a chunk every REFRESH_EVERY steps)
+    assert costs.davidson_applies(10, 8) == num_applies(10, 2, 8)
+    assert costs.davidson_applies(7, 4, chunks=3) == num_applies(7, 3, 4)
+    assert costs.davidson_applies(7, 4) == num_applies(7, 2, 4)
+    full = costs.davidson_cost(8, 200, 18, (12, 12, 12), 20)
+    ran = costs.davidson_cost(8, 200, 18, (12, 12, 12), 6, chunks=2)
+    assert 0 < ran.flops < full.flops and 0 < ran.bytes < full.bytes
 
 
 # ---------------------------------------------------------------------------

@@ -418,13 +418,18 @@ def test_reader_returns_nothing_without_the_span(name):
     assert _read_metric(name, {"trace_job": {"spans": [bare]}}) is None
 
 
-def test_benchmark_lists_the_five_metrics_last():
+def test_benchmark_lists_the_five_metrics_in_one_run():
+    """PR 36's five, appended together and in this order (entries are only
+    ever appended: PR 37's counter follows them)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         layer = json.load(f)["per_layer"]
-    assert [m["name"] for m in layer[-5:]] == [
+    names = [m["name"] for m in layer]
+    at = names.index("hpsi_device_share")
+    assert names[at:at + 5] == [
         "hpsi_device_share", "local_op_share", "rayleigh_ritz_share",
         "xc_gga_ms", "unscoped_share"]
-    assert all(m["source"] == "device_trace" for m in layer[-5:])
+    assert all(m["source"] == "device_trace" for m in layer[at:at + 5])
+    assert names[at + 5:] == ["davidson_steps_per_scf"]
 
 
 # ---- the registry and the call sites ----------------------------------------

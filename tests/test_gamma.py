@@ -106,17 +106,17 @@ def test_davidson_gamma_matches_complex(ctx, gm):
     o_diag = compute_o_diag(ctx)[0]
     hd_p, od_p = pack_diags(gm, h_diag, o_diag)
     x0 = _random_packed(gm, ctx, nb, seed=4)
-    ev_g, xg, rn_g = davidson_gamma(
+    ev_g, xg, rn_g, _ = davidson_gamma(
         gp, jnp.asarray(x0), jnp.asarray(hd_p), jnp.asarray(od_p),
-        num_steps=25, res_tol=1e-12,
+        num_steps=25, res_tol=1e-12, by_energy=False,
     )
     from sirius_tpu.ops.gamma import unpack as _unpack
 
     c0 = _unpack(gm, x0)
-    ev_c, xc, rn_c = davidson(
+    ev_c, xc, rn_c, _ = davidson(
         apply_h_s, hp, jnp.asarray(c0),
         jnp.asarray(h_diag), jnp.asarray(o_diag),
-        hp.mask, num_steps=25, res_tol=1e-12,
+        hp.mask, num_steps=25, res_tol=1e-12, by_energy=False,
     )
     np.testing.assert_allclose(np.asarray(ev_g), np.asarray(ev_c), atol=5e-9)
 
@@ -212,8 +212,10 @@ def test_davidson_gamma_ffts_are_paired(ctx, gm, nb):
 
 
 def _counted_run(ngridk, num_bands, iters=3):
+    """The result, and the (steps, chunks) every band solve booked."""
     import jax
 
+    from sirius_tpu.dft import band_solve
     from sirius_tpu.dft.scf import run_scf
     from sirius_tpu.testing import synthetic_silicon_context
 
@@ -221,9 +223,17 @@ def _counted_run(ngridk, num_bands, iters=3):
         gk_cutoff=3.0, pw_cutoff=7.0, ngridk=ngridk, num_bands=num_bands,
         ultrasoft=True, use_symmetry=False,
         extra_params={"num_dft_iter": iters})
-    # one compute device: a Gamma-only deck then takes the packed solve
-    r = run_scf(c.cfg, ctx=c, devices=jax.devices()[1:2])
-    return r, c.cfg.iterative_solver.num_steps
+    ran = []
+    book = band_solve.count_solve
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(band_solve, "count_solve", lambda cnt, r, *a, **kw: (
+            ran.append(np.reshape(r, (-1, 2))), book(cnt, r, *a, **kw)))
+        # one compute device: a Gamma-only deck then takes the packed solve
+        r = run_scf(c.cfg, ctx=c, devices=jax.devices()[1:2])
+    assert len(ran) == r["num_scf_iterations"]
+    assert all(1 <= s <= c.cfg.iterative_solver.num_steps
+               for solve in ran for s in solve[:, 0])
+    return r, ran
 
 
 def test_num_fft_boxes_counts_paired_applications():
@@ -234,22 +244,31 @@ def test_num_fft_boxes_counts_paired_applications():
     from sirius_tpu.solvers.davidson import apply_blocks, num_applies
 
     nb = 7
-    r, num_steps = _counted_run((1, 1, 1), nb)
+    r, ran = _counted_run((1, 1, 1), nb)
     assert r["placement"]["path"] == "gamma"
-    iters = r["num_scf_iterations"]
     cnt = r["counters"]
-    lcao = cnt["num_loc_op_applied"] - iters * num_applies(num_steps, nb)
-    assert lcao >= nb
-    want = 2 * -(-lcao // 2) + iters * sum(
+    # one loop a solve (one spin channel); the counters are of what it ran
+    ran = [tuple(int(v) for v in solve[0]) for solve in ran]
+    assert cnt["num_davidson_steps"] == sum(steps for steps, _ in ran)
+    assert cnt["num_subspace_eigh"] == sum(2 * steps + 1 for steps, _ in ran)
+    lcao = cnt["num_loc_op_applied"] - sum(
+        num_applies(steps, chunks, nb) for steps, chunks in ran)
+    assert nb <= lcao <= 2 * nb
+    want = 2 * -(-lcao // 2) + sum(
         2 * times * -(-rows // 2)
-        for rows, times in apply_blocks(num_steps, nb))
+        for steps, chunks in ran
+        for rows, times in apply_blocks(steps, chunks, nb))
     assert cnt["num_fft_boxes"] == want
     assert 1.0 <= cnt["num_fft_boxes"] / cnt["num_loc_op_applied"] < 1.0 + 2 / nb
 
 
 def test_num_fft_boxes_is_two_a_row_on_a_kmesh():
-    r, _ = _counted_run((2, 2, 2), 8, iters=2)
+    r, ran = _counted_run((2, 2, 2), 8, iters=2)
     assert r["placement"]["path"].startswith("batched")
     cnt = r["counters"]
+    # a row of `ran` a k-point, each the one loop's count on its device
+    assert all(solve.shape == (8, 2) and (solve == solve[0]).all()
+               for solve in ran)
+    assert cnt["num_davidson_steps"] == sum(int(s[0, 0]) for s in ran)
     assert cnt["num_loc_op_applied"] > 0
     assert cnt["num_fft_boxes"] == 2 * cnt["num_loc_op_applied"]

@@ -81,7 +81,7 @@ def test_gshard_davidson_matches_replicated(setup):
     from sirius_tpu.dft.band_solve import _h_o_diag
 
     h_diag, o_diag = _h_o_diag(ctx, 0, 0.05, ctx.beta.dion)
-    ev_ref, _, _ = davidson(
+    ev_ref, _, _, _ = davidson(
         apply_h_s, prm, jnp.asarray(x0), jnp.asarray(h_diag),
         jnp.asarray(o_diag), prm.mask, num_steps=12,
     )
@@ -90,7 +90,7 @@ def test_gshard_davidson_matches_replicated(setup):
     od_s = np.asarray(reorder_to_gshard(o_diag, order))
     od_s[od_s == 0.0] = 1.0  # padding slots: keep the preconditioner finite
     mask_s = jnp.asarray(reorder_to_gshard(np.asarray(prm.mask), order))
-    ev_s, _, _ = davidson(
+    ev_s, _, _, _ = davidson(
         fn, None, x0_s, hd_s, jnp.asarray(od_s), mask_s, num_steps=12,
     )
     np.testing.assert_allclose(

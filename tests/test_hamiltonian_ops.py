@@ -104,7 +104,7 @@ def test_davidson_matches_dense_eigh():
     rng = np.random.default_rng(11)
     x0 = rng.standard_normal((nev, gk.ngk_max)) + 1j * rng.standard_normal((nev, gk.ngk_max))
     h_diag = np.where(gk.mask[0] > 0, gk.kinetic()[0] + veff_r.mean(), 1e4)
-    evals, x, rnorm = davidson(
+    evals, x, rnorm, _ = davidson(
         apply_hk,
         params,
         jnp.asarray(x0),
@@ -113,6 +113,7 @@ def test_davidson_matches_dense_eigh():
         params.mask,
         num_steps=60,
         res_tol=1e-9,
+        by_energy=False,
     )
     np.testing.assert_allclose(np.asarray(evals), e_ref, atol=1e-8)
     assert np.asarray(rnorm).max() < 1e-6
@@ -133,7 +134,7 @@ def test_davidson_generalized():
     hj, sj = jnp.asarray(h), jnp.asarray(s)
 
     x0 = jnp.asarray(rng.standard_normal((nev, n)) + 1j * rng.standard_normal((nev, n)))
-    evals, x, rnorm = davidson(
+    evals, x, rnorm, _ = davidson(
         _dense_apply,
         (hj, sj),
         x0,
@@ -142,6 +143,7 @@ def test_davidson_generalized():
         jnp.ones(n),
         num_steps=60,
         res_tol=1e-10,
+        by_energy=False,
     )
     np.testing.assert_allclose(np.asarray(evals), e_ref, atol=1e-6)
     # eigh_gen agrees too

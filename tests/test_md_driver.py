@@ -25,9 +25,11 @@ DECK = dict(
 )
 
 
-def _md_cfg(tmpdir, tag, **md):
+def _md_cfg(tmpdir, tag, itsol=None, **md):
     ctx = synthetic_silicon_context(**DECK)
     cfg = ctx.cfg
+    for key, value in (itsol or {}).items():
+        setattr(cfg.iterative_solver, key, value)
     cfg.md.dt_fs = 1.0
     cfg.md.temperature_k = 300.0
     cfg.md.seed = 11
@@ -132,17 +134,30 @@ def test_md_forces_match_finite_difference():
     np.testing.assert_allclose(f[1, 0], f_fd, atol=5e-5)
 
 
-def test_kill_resume_replays_trajectory(tmp_path):
+@pytest.mark.parametrize("itsol, vtol, etol", [
+    (None, 1e-9, 1e-8),
+    ({"converge_by_energy": 0, "residual_tolerance": 1e-10}, 1e-12, 1e-10),
+], ids=["by-energy", "by-residual"])
+def test_kill_resume_replays_trajectory(tmp_path, itsol, vtol, etol):
     """An MD run killed right after the step-2 checkpoint
     (utils/faults.py md.autosave_kill) and resumed from the /md group
-    reproduces the uninterrupted trajectory exactly on the host path:
-    positions, velocities and the conserved quantity all match. NVT so
-    the thermostat's counter-based noise replay is exercised too."""
+    reproduces the uninterrupted trajectory on the host path: positions,
+    velocities and the conserved quantity all match. NVT so the
+    thermostat's counter-based noise replay is exercised too.
+
+    A resumed step starts without the previous step's mixer history and
+    band tolerance (the checkpoint holds densities and wave functions), so
+    it meets the uninterrupted one as far as its SCF converges. Under the
+    default rule of the band solve's exit (a step's move of the eigenvalue,
+    PR 37) that is 5.7e-11 in the velocities and 3.8e-10 in the conserved
+    quantity; with band solves converged by their residuals to 1e-10 from
+    the first iteration on, 3.4e-13 and 4.1e-14: the 1e-12 and 1e-10 the
+    static 20 steps were held to."""
     from sirius_tpu.md.driver import default_md_autosave_path, run_md
 
     d = str(tmp_path)
     md = dict(ensemble="nvt_csvr", thermostat_tau_fs=20.0, num_steps=3,
-              autosave_every=1)
+              autosave_every=1, itsol=itsol)
     cfg_ref, ctx_ref = _md_cfg(d, "ref", **md)
     ref = run_md(cfg_ref, base_dir=d, ctx=ctx_ref)
 
@@ -161,11 +176,11 @@ def test_kill_resume_replays_trajectory(tmp_path):
         res["positions_cart"], ref["positions_cart"], atol=1e-10
     )
     np.testing.assert_allclose(
-        res["velocities"], ref["velocities"], atol=1e-12
+        res["velocities"], ref["velocities"], atol=vtol
     )
     assert abs(
         res["records"][-1]["e_cons"] - ref["records"][-1]["e_cons"]
-    ) < 1e-10
+    ) < etol
 
 
 def test_resume_rejects_non_md_checkpoint(tmp_path):

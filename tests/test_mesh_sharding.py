@@ -58,13 +58,14 @@ def _shard_params(params, mesh):
 def test_davidson_kset_sharded_matches_serial(kset_problem):
     ctx, params, pr, pi = kset_problem
     assert len(jax.devices()) >= 8, "conftest must provide 8 virtual devices"
-    ev_ref, pr_ref, pi_ref, rn_ref = davidson_kset(params, pr, pi, num_steps=6)
+    ev_ref, pr_ref, pi_ref, rn_ref, _ = davidson_kset(
+        params, pr, pi, num_steps=6)
 
     mesh = make_mesh(num_k=4, num_b=2)
     with mesh:
         ps = _shard_params(params, mesh)
         pr_sh, pi_sh = shard_kset(mesh, pr), shard_kset(mesh, pi)
-        ev, pr2, pi2, rn = davidson_kset(ps, pr_sh, pi_sh, num_steps=6)
+        ev, pr2, pi2, rn, _ = davidson_kset(ps, pr_sh, pi_sh, num_steps=6)
         jax.block_until_ready(ev)
     np.testing.assert_allclose(np.asarray(ev), np.asarray(ev_ref), atol=1e-9)
     np.testing.assert_allclose(np.asarray(rn), np.asarray(rn_ref), atol=1e-7)
@@ -96,7 +97,7 @@ def test_full_iteration_sharded_end_to_end(kset_problem):
     with mesh:
         ps = _shard_params(params, mesh)
         pr_sh, pi_sh = shard_kset(mesh, pr), shard_kset(mesh, pi)
-        ev, pr2, pi2, rn = davidson_kset(ps, pr_sh, pi_sh, num_steps=4)
+        ev, pr2, pi2, rn, _ = davidson_kset(ps, pr_sh, pi_sh, num_steps=4)
         mu, occ, ent = find_fermi(
             ev, jnp.asarray(ctx.kweights), 8.0, 0.025, max_occupancy=2.0
         )

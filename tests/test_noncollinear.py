@@ -17,7 +17,7 @@ from sirius_tpu.dft.scf import run_scf
 from sirius_tpu.testing import synthetic_silicon_context
 
 
-def _run(mag_dims, moments, nb=10, **extra):
+def _run(mag_dims, moments, nb=10, converge_by_energy=1, **extra):
     params = {
         "num_mag_dims": mag_dims,
         "smearing_width": 0.01,
@@ -31,20 +31,28 @@ def _run(mag_dims, moments, nb=10, **extra):
         ultrasoft=True, use_symmetry=False, extra_params=params,
         moments=np.asarray(moments, float),
     )
+    ctx.cfg.iterative_solver.converge_by_energy = converge_by_energy
     return run_scf(ctx.cfg, ctx=ctx)
 
 
-def test_nc_matches_collinear_for_z_moments():
+@pytest.mark.parametrize("converge_by_energy, mtol", [(1, 1e-4), (0, 1e-6)],
+                         ids=["by-energy", "by-residual"])
+def test_nc_matches_collinear_for_z_moments(converge_by_energy, mtol):
     mom_z = [[0, 0, 0.5], [0, 0, 0.5]]
-    r_col = _run(1, mom_z, nb=8)
-    r_nc = _run(3, mom_z, nb=16)
+    # a transverse moment is first order in the bands' error, an eigenvalue
+    # second order: under the default rule of the band solve's exit (a
+    # step's move of the eigenvalue, PR 37) the spinor run leaves 2.5e-5 at
+    # density_tol 1e-7 where symmetry says 0, with band solves converged by
+    # their residuals 2.0e-10 (the 1e-6 the static 20 steps were held to)
+    r_col = _run(1, mom_z, nb=8, converge_by_energy=converge_by_energy)
+    r_nc = _run(3, mom_z, nb=16, converge_by_energy=converge_by_energy)
     assert r_col["converged"] and r_nc["converged"]
     assert abs(r_nc["energy"]["total"] - r_col["energy"]["total"]) < 2e-6
     # z-moments agree; transverse components vanish
     mz_col = r_col["magnetisation"]["total"][2]
     m_nc = r_nc["magnetisation"]["total"]
     assert abs(m_nc[2] - mz_col) < 1e-4
-    assert abs(m_nc[0]) < 1e-6 and abs(m_nc[1]) < 1e-6
+    assert abs(m_nc[0]) < mtol and abs(m_nc[1]) < mtol
 
 
 def test_nc_energy_invariant_under_moment_rotation():

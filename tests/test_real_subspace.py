@@ -148,8 +148,9 @@ def test_real_subspace_solve_gives_the_complex_solves_bands(
     out = {}
     for name, index in (("complex", None), ("real", theta)):
         a, b = initialize_subspace_kset(ps, pr, pi, 8, theta_index=index)
-        ev, a, b, rn = davidson_kset(ps, a, b, num_steps=40,
-                                     res_tol=rdt(1e-12), theta_index=index)
+        ev, a, b, rn, _ = davidson_kset(
+            ps, a, b, num_steps=40, res_tol=rdt(1e-12), theta_index=index,
+            by_energy=False)
         out[name] = (np.asarray(ev), np.asarray(a) + 1j * np.asarray(b),
                      np.asarray(rn))
     ev_c, x_c, rn_c = out["complex"]
@@ -207,11 +208,17 @@ def _run(d, devices, **kw):
     return run_scf(cfg, ctx=ctx, devices=devices, **kw), ctx
 
 
-def test_scf_with_real_subspace_meets_the_complex_one(one_device):
-    real, _ = _run(deck((2, 2, 2)), one_device)
+@pytest.mark.parametrize("itsol", [{}, {"converge_by_energy": 0}],
+                         ids=["by-energy", "by-residual"])
+def test_scf_with_real_subspace_meets_the_complex_one(one_device, itsol):
+    # two programs held to one iteration count and 1e-9 Ha, under the
+    # default rule of the band solve's exit (two to five steps a solve
+    # here, 12 iterations) and under the residual rule (9 iterations)
+    d = dict(deck((2, 2, 2)), iterative_solver=itsol)
+    real, _ = _run(d, one_device)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(band_solve, "time_reversal_index", lambda gkvec: None)
-        cplx, _ = _run(deck((2, 2, 2)), one_device)
+        cplx, _ = _run(d, one_device)
     for r in (real, cplx):
         assert r["converged"] and r["placement"]["path"] == "batched+fused"
     assert abs(real["energy"]["total"] - cplx["energy"]["total"]) <= 1e-9

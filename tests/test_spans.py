@@ -181,6 +181,42 @@ def _run(tmp_path, deck):
     return run_scf(cfg, base_dir=str(tmp_path), ctx=ctx)
 
 
+@pytest.mark.parametrize("fence", [True, False])
+def test_band_solve_span_carries_the_cost_of_the_steps_that_ran(tmp_path,
+                                                                fence):
+    """The band solve's trip count is dynamic (PR 37): the model's
+    "scf.band_solve" is the cost of all num_steps steps, the span carries
+    the cost of the steps its solve ran (fenced: its time is the solve's)
+    or, on the device path without a fence, where it times a dispatch and
+    the steps are still on the device, no cost and so no utilization."""
+    from sirius_tpu.config.schema import load_config
+    from sirius_tpu.dft.scf import run_scf
+    from sirius_tpu.obs import costs
+    from sirius_tpu.serve.scheduler import build_job_context
+
+    deck = _span_deck("events.jsonl", span_fence=fence)
+    deck["parameters"]["ngridk"] = [2, 2, 2]
+    cfg = load_config(deck)
+    ctx = build_job_context(cfg, str(tmp_path))
+    with spans.capture() as cap:
+        res = run_scf(cfg, base_dir=str(tmp_path), ctx=ctx)
+    obs.close_events()
+    assert res["placement"]["path"] == "batched+fused"
+    assert res["counters"]["num_davidson_steps"] < 2 * 20
+    bs = cap.by_name("scf.band_solve")
+    assert len(bs) == 2 and all(r["num_steps"] == 20 for r in bs)
+    if not fence:
+        assert not any("flops" in r or "mfu" in r for r in bs)
+        return
+    shapes = (ctx.num_bands, int(ctx.gkvec.ngk_max),
+              int(ctx.beta.num_beta_total), tuple(ctx.fft_coarse.dims))
+    lanes = ctx.gkvec.num_kpoints * ctx.num_spins
+    bound = lanes * costs.davidson_cost(*shapes, 20).flops
+    least = lanes * costs.davidson_cost(*shapes, 1, chunks=1).flops
+    for r in bs:
+        assert least <= r["flops"] < bound and r["gflops"] > 0
+
+
 def test_scf_spans_attribution_and_exactly_once_jsonl(tmp_path):
     with spans.capture() as cap:
         res = _run(tmp_path, _span_deck("events.jsonl", span_fence=True))

@@ -234,8 +234,8 @@ def test_davidson_with_the_reduction_meets_the_librarys_bands():
 
     out = {"library": solve_with(se._library),
            "reduction": solve_with(se.eigh_tridiagonal_real)}
-    ev_l, _, rn_l = (np.asarray(x) for x in out["library"])
-    ev_r, x_r, rn_r = (np.asarray(x) for x in out["reduction"])
+    ev_l, _, rn_l, _ = (np.asarray(x) for x in out["library"])
+    ev_r, x_r, rn_r, _ = (np.asarray(x) for x in out["reduction"])
     assert np.all(np.isfinite(x_r))
     assert np.abs(ev_r - ev_l).max() <= 2e-5
     assert rn_r.max() <= max(10.0 * rn_l.max(), 1e-4)

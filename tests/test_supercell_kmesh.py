@@ -129,13 +129,20 @@ def test_result_counts_the_kpoints_it_solved(eight_k_f32):
     c = eight_k_f32["counters"]
     assert c["num_kpoints_solved"] == 8
     iters = eight_k_f32["num_scf_iterations"]
-    # rows a k-point an iteration, readable without knowing about time
-    # reversal: 29 applications a band (20 steps + exit + 4 chunk
-    # boundaries of two blocks), and the LCAO block once a job
-    per = c["num_loc_op_applied"] / c["num_kpoints_solved"] / iters / 8
-    assert 29.0 <= per < 29.0 + 2.0 / iters + 1e-9
+    # PR 37: the counts are of what ran. The steps of every solve's loop
+    # (one loop the set, on one device), under the bound of 20 a solve
+    steps = c["num_davidson_steps"]
+    assert iters <= steps < 20 * iters
+    # rows a band of a k-point over the job, readable without knowing about
+    # time reversal: one application a step and one on exit, two blocks at
+    # each chunk boundary (a chunk every five steps of a solve, so between
+    # steps / 5 and (steps + 4 iters) / 5 of them), and the LCAO block
+    # (between one and two rows a band) once a job
+    per = c["num_loc_op_applied"] / c["num_kpoints_solved"] / 8
+    assert steps + iters + 2 * steps / 5 + 1 <= per
+    assert per <= steps + iters + 2 * (steps + 4 * iters) / 5 + 2
     # PR 35: two eigenproblems a step and ortho's one, a solve a k-point
-    assert c["num_subspace_eigh"] == 8 * iters * (2 * 20 + 1)
+    assert c["num_subspace_eigh"] == 8 * (2 * steps + iters)
 
 
 def test_time_reversal_leaves_36_of_the_444_mesh(one_device):

@@ -24,6 +24,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 HBM_BYTES = 16e9
 NUM_STEPS = 20
+# the band solve as run_scf gives it to the chip by default: num_steps the
+# bound of its two while loops, the reference's convergence rule
+# (iterative_solver.converge_by_energy; solvers/davidson.py, THE TRIP COUNT)
+RULE = {"by_energy": True}
 
 
 @pytest.fixture(scope="module")
@@ -121,6 +125,13 @@ def _eigh_batches(txt):
 
     return [int(m.group(1)) for m in re.finditer(
         r"= \(f32\[(\d+),[^\n]*custom_call_target=\"EighTpu\"", txt)]
+
+
+def _one_step_body(txt, target="EighTpu"):
+    """The solve's chunks and steps are one loop each around ONE step: its
+    two eigenproblems and ortho's one are all the program holds (a copy a
+    chunk made si54's executable 215 MB, solvers/davidson.py)."""
+    assert txt.count(f'custom_call_target="{target}"') == 3
 
 
 def _no_jacobi(txt):
@@ -223,8 +234,8 @@ def test_gamma_band_solve_one_chip(topo, no_compile_cache, ctx_gamma):
     diag = jax.ShapeDtypeStruct((ngk,), np.float32, sharding=one)
     tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
     lower = lambda: davidson_gamma.lower(
-        gp, x0, diag, diag, num_steps=NUM_STEPS, res_tol=tol)
-    _check(_compile(lower), no_64bit=True)
+        gp, x0, diag, diag, num_steps=NUM_STEPS, res_tol=tol, **RULE)
+    _one_step_body(_check(_compile(lower), no_64bit=True))
     # real matrices bypass solvers/subspace_eigh.py's reduction by dtype:
     # the program is lowered to the text it has with the library's call
     mine = _lowered_text(lower)
@@ -289,8 +300,9 @@ def test_kset_band_solve_one_chip(topo, no_compile_cache, ctx_kmesh):
     ps, psi = _shapes(ps, one), _shapes(psi, one)
     tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
     txt = _check(_compile(lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol)), no_64bit=True)
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, **RULE)), no_64bit=True)
     _no_jacobi(txt)
+    _one_step_body(txt)
     assert set(_eigh_batches(txt)) == {ctx.gkvec.num_kpoints}
     occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=one)
     _check(_compile(lambda: density_kset.lower(ps, psi, psi, occ)),
@@ -320,7 +332,7 @@ def test_kset_band_solve_real_subspace_one_chip(topo, no_compile_cache,
     theta = _shapes(theta.astype(np.int32), one)
     tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
     lower = lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, theta_index=theta)
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, **RULE, theta_index=theta)
     real = _check(_compile(lower), no_64bit=True)
     assert "EighTpu" in real and "EighJacobiSweeps" not in real
     mine = _lowered_text(lower)
@@ -328,7 +340,7 @@ def test_kset_band_solve_real_subspace_one_chip(topo, no_compile_cache,
         assert mine == _lowered_text(lower)
     # the library's complex eigh, the parent's program: Jacobi sweep loops
     lower = lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol)
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, **RULE)
     with _library_eigh():
         assert "EighJacobiSweeps" in lower().compile().as_text()
     _check(_compile(lambda: initialize_subspace_kset.lower(
@@ -397,9 +409,10 @@ def test_kb_mesh_step_four_chips(topo, no_compile_cache, ctx_kmesh):
         for name, leaf in ps4._asdict().items() if leaf is not None})
     psi4 = _shapes(psi4, psi_sh)
     txt = _check(_compile(lambda: davidson_kset.lower(
-        ps4, psi4, psi4, num_steps=NUM_STEPS, res_tol=tol, mesh=mesh)),
+        ps4, psi4, psi4, num_steps=NUM_STEPS, res_tol=tol, **RULE, mesh=mesh)),
         no_64bit=True)
     _no_jacobi(txt)
+    _one_step_body(txt)
     assert set(_eigh_batches(txt)) == {ctx444.gkvec.num_kpoints // 4} == {9}
     for kind in ("all-gather", "all-reduce", "all-to-all",
                  "collective-permute", "reduce-scatter"):
@@ -411,7 +424,7 @@ def test_kb_mesh_step_four_chips(topo, no_compile_cache, ctx_kmesh):
     theta = _shapes(time_reversal_index(ctx.gkvec).astype(np.int32),
                     NamedSharding(mesh, P("k", None)))
     txt = _check(_compile(lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, theta_index=theta,
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, **RULE, theta_index=theta,
         mesh=mesh)), no_64bit=True)
     assert set(_eigh_batches(txt)) == {ctx.gkvec.num_kpoints // 4}
     occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=ev_sh)
