@@ -29,6 +29,7 @@ from sirius_tpu.dft.radial_tables import (
     structure_factors,
     vloc_ff,
 )
+from sirius_tpu.obs import spans as obs_spans
 from sirius_tpu.ops.augmentation import Augmentation
 from sirius_tpu.ops.beta import BetaProjectors
 
@@ -72,14 +73,22 @@ class SimulationContext:
                 f"({2 * p.gk_cutoff}) to hold wave-function products"
             )
         sym = None
-        if p.use_symmetry:
-            sym = CrystalSymmetry.find(
-                uc.lattice, uc.positions, uc.type_of_atom, uc.moments, p.num_mag_dims
+        # the group search and the wedge of the mesh: a child span of
+        # whatever builds the context (serve.context_build)
+        with obs_spans.span("context.symmetry") as sp:
+            if p.use_symmetry:
+                sym = CrystalSymmetry.find(
+                    uc.lattice, uc.positions, uc.type_of_atom, uc.moments,
+                    p.num_mag_dims
+                )
+            kpts, kw = irreducible_kmesh(
+                p.ngridk, p.shiftk, sym,
+                use_symmetry=p.use_symmetry and p.use_ibz,
+                time_reversal=p.num_mag_dims != 3,
             )
-        kpts, kw = irreducible_kmesh(
-            p.ngridk, p.shiftk, sym, use_symmetry=p.use_symmetry and p.use_ibz,
-            time_reversal=p.num_mag_dims != 3,
-        )
+            sp.set(num_ops=0 if sym is None else int(sym.num_ops),
+                   kpoints_mesh=int(np.prod(p.ngridk)),
+                   kpoints_irreducible=len(kpts))
         if len(p.vk):
             kpts = np.asarray(p.vk, dtype=np.float64)
             kw = np.full(len(kpts), 1.0 / len(kpts))

@@ -17,6 +17,7 @@ import numpy as np
 
 from sirius_tpu.context import SimulationContext
 from sirius_tpu.core.fftgrid import g_to_r, r_to_g
+from sirius_tpu.obs import spans as obs_spans
 
 
 def initial_density_g(ctx: SimulationContext) -> np.ndarray:
@@ -168,22 +169,30 @@ def symmetrize_pw(
     magnetization / B_xc): each op's contribution carries its spin_sign
     (= det(R) R_zz, reference spin_rotation S(2,2)) — without it AFM
     sublattice-swap ops average the staggered field to zero."""
-    sym = ctx.symmetry
-    gv = ctx.gvec
+    out = np.zeros_like(f_g)
+    for idx, phase, ssign in sym_rot_cache(ctx):
+        np.add.at(out, idx, f_g * (phase * ssign if axial_z else phase))
+    return out / ctx.symmetry.num_ops
+
+
+def sym_rot_cache(ctx: SimulationContext) -> list:
+    """Per operation (idx, phase, spin_sign): G-vector ig goes to idx[ig]
+    with the phase e^{-2 pi i g'.t}. Built once a context. Each idx row is a
+    permutation of the sphere (an image outside it raises)."""
     cache = getattr(ctx, "_sym_rot_cache", None)
     if cache is None:
-        lut = {tuple(m): i for i, m in enumerate(gv.millers)}
+        gv = ctx.gvec
         cache = []
-        for op in sym.ops:
+        for op in ctx.symmetry.ops:
             gm = gv.millers @ op.w_k.T  # rows g' = w_k g
-            idx = np.asarray([lut[tuple(m)] for m in gm], dtype=np.int64)
+            idx = gv.index_of_millers(gm)
+            if idx.min() < 0:
+                raise KeyError("a symmetry operation takes a G-vector out "
+                               "of the density sphere")
             phase = np.exp(-2j * np.pi * (gm @ op.t))
             cache.append((idx, phase, op.spin_sign))
         ctx._sym_rot_cache = cache
-    out = np.zeros_like(f_g)
-    for idx, phase, ssign in cache:
-        np.add.at(out, idx, f_g * (phase * ssign if axial_z else phase))
-    return out / sym.num_ops
+    return cache
 
 
 def _beta_rotation_blocks(ctx: SimulationContext, op):
@@ -321,41 +330,106 @@ def atomic_moments(ctx: SimulationContext, mag_g: np.ndarray) -> np.ndarray:
 # ---------------------------------------------------------------------------
 # Device-resident symmetrization (jit twins of symmetrize_pw /
 # symmetrize_density_matrix for the fused SCF step). The host variants keep
-# python loops over ops with np.add.at; on device the rotation tables become
-# dense [nops, ...] arrays built once, and the op loop becomes one batched
-# gather-scatter / einsum inside the compiled program.
+# python loops over ops with np.add.at; on device the sum over the ops of a
+# plane-wave field is pre-contracted into one small matrix a star of
+# G-vectors, and the density matrix's into one batched einsum, from tables
+# built once a context (symmetry_tables).
 # ---------------------------------------------------------------------------
 
 
 def build_sym_pw_tables(ctx: SimulationContext):
-    """Dense per-op PW rotation tables for symmetrize_pw_device:
-    (idx [nops, ng] int32, phase_re/phase_im [nops, ng], ssign [nops]).
-    Reuses (and fills) the same _sym_rot_cache the host path uses."""
-    # prime the cache through the host function (identity op is cheap)
-    if getattr(ctx, "_sym_rot_cache", None) is None:
-        symmetrize_pw(ctx, np.zeros(ctx.gvec.num_gvec, dtype=np.complex128))
-    idx = np.stack([c[0] for c in ctx._sym_rot_cache]).astype(np.int32)
-    phase = np.stack([c[1] for c in ctx._sym_rot_cache])
-    ssign = np.array([c[2] for c in ctx._sym_rot_cache], dtype=np.float64)
-    return {
-        "idx": idx,
-        "phase_re": np.real(phase),
-        "phase_im": np.imag(phase),
-        "ssign": ssign,
+    """Star-block tables for symmetrize_pw_device. An operation permutes the
+    sphere inside the stars (orbits) of the group, so the sum over the
+    operations is one small matrix a star, summed here on the host in f64:
+
+        f'(g_i) = sum_j M_s[i, j] f(g_j),
+        M_s[i, j] = (1/N) sum_{S: S g_j = g_i} e^{-2 pi i g_i . t_S}
+
+    for the members g_1..g_m of star s (m <= N; smaller stars are padded
+    with zero rows and columns). The stars are the minor axis of every
+    table: ``members`` int32 [m, nstars] (the sphere index of member i of
+    star s; a pad points at 0), ``slot`` int32 [ng] (where in the flattened
+    [m, nstars] block a G-vector lives), ``m_re``/``m_im`` [m, m, nstars].
+    A collinear moment adds ``ax_re``/``ax_im``, the same sum with each
+    operation's spin_sign (the z-component of an axial vector).
+
+    The device needs two gathers of about ng elements a call and reads the
+    matrices once; the scatter-add of every operation's image, 48 x ng
+    elements, cost 0.14 s a call on a TPU v5e at ng = 36 325 (PERF.md
+    section 6, PR 38)."""
+    cache = sym_rot_cache(ctx)
+    ng = ctx.gvec.num_gvec
+    nops = len(cache)
+    # {S g: S in the group} is g's star, so its least index names it
+    label = np.minimum.reduce([c[0] for c in cache])
+    order = np.argsort(label, kind="stable")
+    first = np.flatnonzero(np.r_[True, np.diff(label[order]) > 0])
+    sizes = np.diff(np.r_[first, ng])
+    nstars, m = len(first), int(sizes.max())
+    star = np.empty(ng, dtype=np.int64)
+    pos = np.empty(ng, dtype=np.int64)
+    star[order] = np.repeat(np.arange(nstars), sizes)
+    pos[order] = np.arange(ng) - np.repeat(first, sizes)
+    members = np.zeros((m, nstars), dtype=np.int32)
+    members[pos, star] = np.arange(ng)
+    mat = np.zeros((m, m, nstars), dtype=np.complex128)
+    axial = np.zeros_like(mat) if ctx.num_mag_dims == 1 else None
+    for idx, phase, ssign in cache:
+        # one operation is a permutation: no (i, j, s) repeats inside it
+        mat[pos[idx], pos, star] += phase
+        if axial is not None:
+            axial[pos[idx], pos, star] += phase * ssign
+    tb = {
+        "members": members,
+        "slot": (pos * nstars + star).astype(np.int32),
+        "m_re": np.real(mat) / nops,
+        "m_im": np.imag(mat) / nops,
     }
+    if axial is not None:
+        tb["ax_re"] = np.real(axial) / nops
+        tb["ax_im"] = np.imag(axial) / nops
+    return tb
+
+
+def symmetry_tables(ctx: SimulationContext) -> dict:
+    """The fused step's two rotation tables ("sym": build_sym_pw_tables,
+    "dm_sym": build_dm_sym_tables), built once a context under the host span
+    ``scf.setup.symmetry``: run_scf asks for them before its first host
+    symmetrisation, so the span holds the whole of the group's table work."""
+    tb = getattr(ctx, "_sym_tables", None)
+    if tb is None:
+        with obs_spans.span("scf.setup.symmetry",
+                            num_ops=int(ctx.symmetry.num_ops),
+                            ng=int(ctx.gvec.num_gvec)):
+            tb = {"sym": build_sym_pw_tables(ctx),
+                  "dm_sym": build_dm_sym_tables(ctx)}
+        ctx._sym_tables = tb
+    return tb
 
 
 def symmetrize_pw_device(f_g: jnp.ndarray, tb: dict,
                          axial_z: bool = False) -> jnp.ndarray:
     """Jit-safe symmetrize_pw: f_g complex [ng] (inside the compiled
-    program), tb from build_sym_pw_tables as device arrays."""
-    nops = tb["idx"].shape[0]
-    phase = jax.lax.complex(tb["phase_re"], tb["phase_im"])
-    if axial_z:
-        phase = phase * tb["ssign"][:, None]
-    vals = f_g[None, :] * phase
-    out = jnp.zeros_like(f_g).at[tb["idx"].reshape(-1)].add(vals.reshape(-1))
-    return out / nops
+    program), tb from build_sym_pw_tables as device arrays. Every star's
+    members are gathered into a column, multiplied by the star's matrix
+    (products and a sum in the working precision, no matmul unit and so no
+    matmul precision) and put back."""
+    with jax.named_scope("sym_pw"):
+        m_re, m_im = ((tb["ax_re"], tb["ax_im"]) if axial_z
+                      else (tb["m_re"], tb["m_im"]))
+        f = f_g[tb["members"]]  # [m, nstars]
+        f_re, f_im = jnp.real(f)[None], jnp.imag(f)[None]
+        out = jax.lax.complex(
+            jnp.sum(m_re * f_re - m_im * f_im, axis=1),
+            jnp.sum(m_re * f_im + m_im * f_re, axis=1))
+        return out.reshape(-1)[tb["slot"]]
+
+
+def num_sym_pw(do_symmetrize: bool, polarized: bool) -> int:
+    """symmetrize_pw_device calls one fused step runs: the new density, the
+    effective potential and the ledger's idempotency invariant; a moment
+    adds itself and b_z (counters.num_sym_pw; 0 without symmetry)."""
+    return (3 + (2 if polarized else 0)) if do_symmetrize else 0
 
 
 def build_dm_sym_tables(ctx: SimulationContext):
@@ -395,13 +469,14 @@ def symmetrize_density_matrix_device(dm: jnp.ndarray, tb: dict) -> jnp.ndarray:
     the spin channels swap under flipneg ops exactly like the host."""
     ns = dm.shape[0]
     nops = tb["s_ops"].shape[0]
-    if ns == 2:
-        dms = jnp.where(tb["flipneg"][:, None, None, None],
-                        dm[None, ::-1], dm[None])
-    else:
-        dms = jnp.broadcast_to(dm[None], (nops,) + dm.shape)
-    out = jnp.einsum("oij,osjk,olk->sil", tb["s_ops"], dms, tb["s_ops"])
-    return out * tb["blockmask"][None] / nops
+    with jax.named_scope("sym_dm"):
+        if ns == 2:
+            dms = jnp.where(tb["flipneg"][:, None, None, None],
+                            dm[None, ::-1], dm[None])
+        else:
+            dms = jnp.broadcast_to(dm[None], (nops,) + dm.shape)
+        out = jnp.einsum("oij,osjk,olk->sil", tb["s_ops"], dms, tb["s_ops"])
+        return out * tb["blockmask"][None] / nops
 
 
 def atomic_moments_vec(ctx: SimulationContext, mvec_g: np.ndarray) -> np.ndarray:

@@ -44,10 +44,10 @@ import numpy as np
 from sirius_tpu.core import hilo
 from sirius_tpu.core.fftgrid import r_to_g
 from sirius_tpu.dft.density import (
-    build_dm_sym_tables,
-    build_sym_pw_tables,
+    num_sym_pw,
     symmetrize_density_matrix_device,
     symmetrize_pw_device,
+    symmetry_tables,
 )
 from sirius_tpu.dft.mixer import (
     DeviceMixerState,
@@ -208,8 +208,7 @@ class FusedScf:
                 ctx.unit_cell, ctx.gvec, ctx.aug, ctx.beta
             )
         if self.do_symmetrize:
-            tables["sym"] = build_sym_pw_tables(ctx)
-            tables["dm_sym"] = build_dm_sym_tables(ctx)
+            tables.update(symmetry_tables(ctx))
         # one-time upload; step() takes these as an argument so they are
         # program inputs, not baked-in constants
         place = place or (lambda t: t)
@@ -222,6 +221,11 @@ class FusedScf:
             constant_fields_device(self.tables["pot"], self.dims))
         # sphere-to-box placements one step runs (counters.num_tail_box_fills)
         self.box_fills = num_box_fills(xc, self.polarized)
+        # plane-wave symmetrisations one step runs (counters.num_sym_pw) and
+        # the operations each sums over
+        self.sym_pw = num_sym_pw(self.do_symmetrize, self.polarized)
+        self.sym_ops = (int(ctx.symmetry.num_ops) if self.do_symmetrize
+                        else 0)
         # fine-box transforms the gradient correction adds to one step
         # (counters.num_xc_gradient_transforms; 0 for LDA)
         self.xc_gradient_transforms = num_gradient_transforms(

@@ -20,7 +20,7 @@ import dataclasses
 import numpy as np
 
 from sirius_tpu.context import SimulationContext
-from sirius_tpu.dft.density import symmetrize_pw
+from sirius_tpu.dft.density import sym_rot_cache, symmetrize_pw
 from sirius_tpu.dft.poisson import hartree_potential_g
 from sirius_tpu.dft.potential import (
     _divergence_g,
@@ -50,12 +50,7 @@ def symmetrize_vector_pw(ctx: SimulationContext, mvec_g: np.ndarray) -> np.ndarr
     (reference symmetrize_field4d.hpp with the ops' spin rotations; the
     scalar index/phase cache from symmetrize_pw is reused)."""
     sym = ctx.symmetry
-    gv = ctx.gvec
-    # reuse/build the (idx, phase) cache symmetrize_pw maintains
-    cache = getattr(ctx, "_sym_rot_cache", None)
-    if cache is None:
-        symmetrize_pw(ctx, np.zeros(gv.num_gvec, dtype=np.complex128))
-        cache = ctx._sym_rot_cache
+    cache = sym_rot_cache(ctx)
     out = np.zeros_like(mvec_g)
     for op, (idx, phase, _ssign) in zip(sym.ops, cache):
         rot = np.linalg.det(op.rot_cart) * op.rot_cart  # axial vector

@@ -27,6 +27,7 @@ from sirius_tpu.dft.density import (
     initial_magnetization_g,
     rho_real_space,
     symmetrize_pw,
+    symmetry_tables,
 )
 from sirius_tpu.dft.mixer import Mixer, initial_res_tol, schedule_res_tol
 from sirius_tpu.dft.occupation import find_fermi
@@ -424,6 +425,10 @@ def _run_scf_inner(
     do_symmetrize = (
         p.use_symmetry and ctx.symmetry is not None and ctx.symmetry.num_ops > 1
     )
+    if do_symmetrize:
+        # the group's rotation tables, before the first host symmetrisation
+        # would build half of them unspanned (span scf.setup.symmetry)
+        symmetry_tables(ctx)
 
     ng = ctx.gvec.num_gvec
 
@@ -984,6 +989,10 @@ def _run_scf_inner(
     counters["num_kpoints_solved"] = nk
     _setup_span.close(
         fused=fused is not None,
+        **({"symmetry": {
+            "num_ops": int(ctx.symmetry.num_ops),
+            "kpoints_mesh": int(np.prod(p.ngridk)),
+            "kpoints_irreducible": nk}} if do_symmetrize else {}),
         **({} if band.mesh is None else {"mesh": dict(band.mesh.shape)}),
         **band.plan(wf_dtype))
     _it_t0 = time.time()
@@ -1139,8 +1148,10 @@ def _run_scf_inner(
                 _sp.close()
                 _sp = _stage(
                     "scf.fused_step", it=it + 1, box_fill="gather",
-                    box_fills=fused.box_fills, xc=fused.xc_kind)
+                    box_fills=fused.box_fills, xc=fused.xc_kind,
+                    sym_ops=fused.sym_ops)
                 counters["num_tail_box_fills"] += fused.box_fills
+                counters["num_sym_pw"] += fused.sym_pw
                 counters["num_xc_gradient_transforms"] += (
                     fused.xc_gradient_transforms)
                 fused_carry, fused_out = fused.step(
@@ -1736,6 +1747,7 @@ def _run_scf_inner(
         num_subspace_eigh=int(counters["num_subspace_eigh"]),
         num_davidson_steps=int(counters["num_davidson_steps"]),
         num_tail_box_fills=int(counters["num_tail_box_fills"]),
+        num_sym_pw=int(counters["num_sym_pw"]),
         num_xc_gradient_transforms=int(
             counters["num_xc_gradient_transforms"]),
         energy_resolution_ha=abs(e_total) * pair_eps(

@@ -58,6 +58,10 @@ UNCOSTED_SPANS = (
     # host bookkeeping, no stage of their own to count
     "scf.run",
     "scf.setup",
+    # the symmetry group's host tables (dft/density.symmetry_tables) and,
+    # inside a context build, the group search with the mesh's wedge
+    "scf.setup.symmetry",
+    "context.symmetry",
     "scf.finalize",
     "scf.finalize.potential",
     "scf.autosave",

@@ -71,6 +71,10 @@ SCOPES = (
     "step_vloc", "step_d_matrix", "step_ledger",
     # core/fftgrid.g_to_r_gather
     "box_fill",
+    # dft/density.py: the symmetrisers of a deck with use_symmetry, under
+    # step_density (the density matrix, the new density), step_vloc (v_eff)
+    # and step_ledger (the idempotency invariant)
+    "sym_pw", "sym_dm",
     # the programs between band solve and step: parallel/batched.py
     # density_kset and density_matrix_kset, ops/gamma.density_gamma
     "density_kset", "density_gamma", "density_matrix",

@@ -420,7 +420,7 @@ def test_reader_returns_nothing_without_the_span(name):
 
 def test_benchmark_lists_the_five_metrics_in_one_run():
     """PR 36's five, appended together and in this order (entries are only
-    ever appended: PR 37's counter follows them)."""
+    ever appended: PR 37's counter follows them, then later PRs')."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         layer = json.load(f)["per_layer"]
     names = [m["name"] for m in layer]
@@ -429,7 +429,7 @@ def test_benchmark_lists_the_five_metrics_in_one_run():
         "hpsi_device_share", "local_op_share", "rayleigh_ritz_share",
         "xc_gga_ms", "unscoped_share"]
     assert all(m["source"] == "device_trace" for m in layer[at:at + 5])
-    assert names[at + 5:] == ["davidson_steps_per_scf"]
+    assert names[at + 5] == "davidson_steps_per_scf"
 
 
 # ---- the registry and the call sites ----------------------------------------
@@ -469,14 +469,18 @@ def _sds(tree):
         lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), tree)
 
 
-def _tiny_ctx(ngridk, xc=("XC_LDA_X", "XC_LDA_C_PZ")):
+LDA = ("XC_LDA_X", "XC_LDA_C_PZ")
+PBE = ("XC_GGA_X_PBE", "XC_GGA_C_PBE")
+
+
+def _tiny_ctx(ngridk, xc=LDA, symmetry=False):
     from sirius_tpu.config.schema import load_config
     from sirius_tpu.serve.scheduler import build_job_context
 
     return build_job_context(load_config({
         "parameters": {"gk_cutoff": 3.0, "pw_cutoff": 7.0,
                        "ngridk": list(ngridk), "num_bands": 8,
-                       "use_symmetry": False, "precision_wf": "fp32",
+                       "use_symmetry": symmetry, "precision_wf": "fp32",
                        "xc_functionals": list(xc)},
         "synthetic": {"ultrasoft": True}}), ".")
 
@@ -514,21 +518,21 @@ def _lower_gamma():
                                         res_tol=tol)
 
 
-def _lower_step():
+def _lower_step(xc=PBE, symmetry=False):
     from types import SimpleNamespace
 
     from sirius_tpu.dft.fused import FusedScf
     from sirius_tpu.dft.mixer import Mixer
     from sirius_tpu.dft.xc import XCFunctional
 
-    ctx = _tiny_ctx((1, 1, 1), xc=("XC_GGA_X_PBE", "XC_GGA_C_PBE"))
+    ctx = _tiny_ctx((1, 1, 1), xc=xc, symmetry=symmetry)
     cfg = ctx.cfg
     mixer = Mixer(cfg.mixer, ctx.gvec.glen2, num_components=1,
                   omega=ctx.unit_cell.omega)
 
     def lower():  # a FusedScf of its own: its jit is made at construction
         fused = FusedScf(ctx, XCFunctional(cfg.parameters.xc_functionals),
-                         mixer, False, False, wf_dtype=jnp.complex64)
+                         mixer, False, symmetry, wf_dtype=jnp.complex64)
         nk, ngk, nb = ctx.gkvec.num_kpoints, ctx.gkvec.ngk_max, ctx.num_bands
         nbeta = ctx.beta.num_beta_total
         pot0 = SimpleNamespace(veff_g=np.zeros(fused.ng, np.complex128),
@@ -554,6 +558,11 @@ def _lower_step():
     ("step", ["step_density", "step_mixing", "step_hartree", "step_xc",
               "xc_gga", "box_fill", "step_vloc", "step_d_matrix",
               "step_ledger"]),
+    ("step_lda", ["step_density", "step_mixing", "step_hartree", "step_xc",
+                  "box_fill", "step_vloc", "step_d_matrix", "step_ledger"]),
+    # PR 38: the symmetrisers' scopes, in a step that runs them
+    ("step_sym", ["step_density", "sym_dm", "sym_pw", "step_vloc",
+                  "step_ledger"]),
 ])
 def test_named_scopes_are_metadata_only(program, scopes, monkeypatch):
     """The lowered program with locations stripped is the same with
@@ -561,8 +570,9 @@ def test_named_scopes_are_metadata_only(program, scopes, monkeypatch):
     names are in the text that keeps them."""
     from sirius_tpu import runtime
 
-    lower = {"kset": _lower_kset, "gamma": _lower_gamma,
-             "step": _lower_step}[program]()
+    lower = {"kset": _lower_kset, "gamma": _lower_gamma, "step": _lower_step,
+             "step_lda": lambda: _lower_step(LDA),
+             "step_sym": lambda: _lower_step(LDA, symmetry=True)}[program]()
     with runtime.scf_scope():
         jax.clear_caches()
         named = lower()

@@ -257,5 +257,7 @@ def test_scf_spans_off_with_telemetry_disabled(tmp_path):
     obs.close_events()
     assert res["num_scf_iterations"] == 2
     # the deck's control.telemetry takes effect at run_scf entry: the
-    # context the caller built before it is the one thing spanned
-    assert [r["name"] for r in cap.records] == ["serve.context_build"]
+    # context the caller built before it is the one thing spanned (with its
+    # child, the group search)
+    assert [r["name"] for r in cap.records] == ["context.symmetry",
+                                                "serve.context_build"]

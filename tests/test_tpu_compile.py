@@ -56,12 +56,12 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def _deck(ngridk, supercell=1, num_bands=None):
+def _deck(ngridk, supercell=1, num_bands=None, symmetry=False):
     return {
         "parameters": {
             "gk_cutoff": 6.0, "pw_cutoff": 20.0, "ngridk": list(ngridk),
             "num_bands": num_bands or 26 * supercell**3,
-            "use_symmetry": False,
+            "use_symmetry": symmetry,
             "precision_wf": "fp32",
             "xc_functionals": ["XC_LDA_X", "XC_LDA_C_PZ"],
         },
@@ -69,12 +69,12 @@ def _deck(ngridk, supercell=1, num_bands=None):
     }
 
 
-def _ctx(ngridk, supercell=1, num_bands=None):
+def _ctx(ngridk, supercell=1, num_bands=None, symmetry=False):
     from sirius_tpu.config.schema import load_config
     from sirius_tpu.serve.scheduler import build_job_context
 
     return build_job_context(
-        load_config(_deck(ngridk, supercell, num_bands)), ".")
+        load_config(_deck(ngridk, supercell, num_bands, symmetry)), ".")
 
 
 @pytest.fixture(scope="module")
@@ -182,7 +182,7 @@ def _kset_inputs(ctx, nb):
     return ps, psi
 
 
-def _fused(ctx):
+def _fused(ctx, symmetrize=False):
     from sirius_tpu.dft.fused import FusedScf
     from sirius_tpu.dft.mixer import Mixer
     from sirius_tpu.dft.xc import XCFunctional
@@ -191,7 +191,7 @@ def _fused(ctx):
     mixer = Mixer(cfg.mixer, ctx.gvec.glen2, num_components=1,
                   omega=ctx.unit_cell.omega)
     return FusedScf(ctx, XCFunctional(cfg.parameters.xc_functionals), mixer,
-                    False, False, wf_dtype=jnp.complex64)
+                    False, symmetrize, wf_dtype=jnp.complex64)
 
 
 def _fused_args(fused, ctx, nb, rep, psi_sh, ev_sh):
@@ -354,6 +354,23 @@ def test_fused_step_one_chip(topo, no_compile_cache, ctx_kmesh):
     fused = _fused(ctx)
     args = _fused_args(fused, ctx, ctx.num_bands, one, one, one)
     _check(_compile(lambda: fused._step.lower(*args)), no_64bit=True)
+
+
+def test_fused_step_with_symmetry_one_chip(topo, no_compile_cache):
+    """The step of the stock deck (use_symmetry at its default): the wedge of
+    the 2x2x2 mesh, the 48-operation symmetrisers at pw_cutoff 20 (36 325
+    G-vectors in 972 stars). They gather and sum; nothing scatters."""
+    ctx = _ctx((2, 2, 2), symmetry=True)
+    assert ctx.symmetry.num_ops == 48 and ctx.gkvec.num_kpoints == 3
+    one = SingleDeviceSharding(topo.devices[0])
+    fused = _fused(ctx, symmetrize=True)
+    sym = fused.tables["sym"]
+    assert sym["members"].shape == (48, 972) and sym["members"].dtype == np.int32
+    assert sym["m_re"].shape == (48, 48, 972) and sym["m_re"].dtype == np.float32
+    args = _fused_args(fused, ctx, ctx.num_bands, one, one, one)
+    txt = _check(_compile(lambda: fused._step.lower(*args)), no_64bit=True)
+    named = [ln for ln in txt.splitlines() if "/sym_pw/" in ln]
+    assert named and not any(" scatter(" in ln for ln in named)
 
 
 def test_fft_pair_c64_one_chip(topo, no_compile_cache, ctx_kmesh):
