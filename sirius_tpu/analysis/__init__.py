@@ -30,7 +30,9 @@ mechanically:
   values (loop indices, ``time.*``/``random.*``) at
   ``static_argnums``/``static_argnames`` positions, and the
   serve/cache.py cross-check — any ``self.<attr>`` a cache-shared
-  jitted impl reads but its ``_trace_signature()`` omits.
+  jitted impl reads but its ``_trace_signature()`` omits, and any value
+  a ``partial`` binds into a jitted impl that the key of the table it
+  is kept in omits (dft/fused.py ``step_program``).
 - **Transfer-budget rules** (analysis/transferrules.py): device→host
   crossings statically enumerated from the dataflow model and checked
   against the checked-in ``TRANSFER_BUDGET.json`` manifest — the fused

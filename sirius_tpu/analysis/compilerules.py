@@ -24,7 +24,12 @@ what that smells like). These rules flag the static patterns that
   missing from the signature means two instances that differ only in
   that attr would *share an executable and silently compute with the
   wrong constant*. The analysis and the cache share one definition of
-  "same executable": the signature tuple.
+  "same executable": the signature tuple. The same rule reads the other
+  way of baking a constant in, ``jax.jit(partial(impl, rec))`` kept in a
+  table of programs (dft/fused.py ``step_program``: the fused step reads
+  no ``self``, its constants are one frozen record bound by the partial):
+  every value the partial binds must be in the key the wrapper is stored
+  under, or two calls that differ in it would share a program.
 """
 
 from __future__ import annotations
@@ -35,6 +40,7 @@ from sirius_tpu.analysis.core import (
     FunctionInfo,
     ProjectIndex,
     _JIT_WRAPPERS,
+    _PARTIAL,
     call_name,
     dotted_name,
 )
@@ -227,7 +233,64 @@ class CacheKeyTraceConstant:
             out.add(node.attr)
         return out
 
+    def _bound_partials(self, fn_node: ast.AST):
+        """jit wrappers over a partial in one function: yields (the
+        wrapper's local name or None, the jit call, the partial call), the
+        partial written in place or bound to a local first."""
+        partials = {}  # local name -> partial call
+        for node in ast.walk(fn_node):
+            if (isinstance(node, ast.Assign) and len(node.targets) == 1
+                    and isinstance(node.targets[0], ast.Name)
+                    and isinstance(node.value, ast.Call)
+                    and call_name(node.value) in _PARTIAL):
+                partials[node.targets[0].id] = node.value
+        named = {id(assign.value): tgt  # jit call -> the local it is bound to
+                 for tgt, _, assign in _local_jit_bindings(fn_node)}
+        for node in ast.walk(fn_node):
+            if not (isinstance(node, ast.Call)
+                    and call_name(node) in _JIT_WRAPPERS and node.args):
+                continue
+            a = node.args[0]
+            part = (a if isinstance(a, ast.Call)
+                    and call_name(a) in _PARTIAL
+                    else partials.get(getattr(a, "id", None)))
+            if part is not None:
+                yield named.get(id(node)), node, part
+
+    def _keys_of(self, fn_node: ast.AST, name: str | None,
+                 jit_call: ast.Call):
+        """Key expressions the wrapper is stored under in this function
+        (``table[key] = wrapper``)."""
+        for node in ast.walk(fn_node):
+            if isinstance(node, ast.Assign) and (
+                    node.value is jit_call or (
+                        isinstance(node.value, ast.Name)
+                        and node.value.id == name)):
+                for t in node.targets:
+                    if isinstance(t, ast.Subscript):
+                        yield t.slice
+
+    def _unkeyed_partial_args(self, project: ProjectIndex):
+        for fi in project.iter_functions():
+            for name, jit_call, part in self._bound_partials(fi.node):
+                bound = [(dotted_name(a), a) for a in (
+                    *part.args[1:], *(k.value for k in part.keywords))]
+                for key in self._keys_of(fi.node, name, jit_call):
+                    in_key = {dotted_name(n) for n in ast.walk(key)
+                              if isinstance(n, (ast.Name, ast.Attribute))}
+                    for d, a in bound:
+                        if d and d not in in_key:
+                            yield project.finding(
+                                self.name, fi, a,
+                                f"`{d}` bound into jitted "
+                                f"`{dotted_name(part.args[0])}` by partial "
+                                f"in `{fi.qualname}` but absent from the "
+                                f"key the wrapper is stored under: equal "
+                                f"keys would reuse an executable with the "
+                                f"wrong baked-in value")
+
     def run(self, project: ProjectIndex):
+        yield from self._unkeyed_partial_args(project)
         model = DeviceModel.of(project)
         for (mod, cls, attr), impl in sorted(model.jit_attr_impl.items()):
             mi = project.modules.get(mod)

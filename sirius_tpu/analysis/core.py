@@ -236,6 +236,7 @@ class ProjectIndex:
         for p in paths:
             self._index_file(p)
         self._jit_reachable: set[tuple[str, str]] | None = None
+        self._jit_factories: dict | None = None
         self._lambda_counter = 0
 
     # -- indexing ----------------------------------------------------------
@@ -350,6 +351,18 @@ class ProjectIndex:
         out = self._resolve_call(mi, cls, d)
         if out or enclosing is None or "." in d:
             return out
+        # a local bound to a partial: bound = partial(f, rec), then
+        # jax.jit(bound)
+        for node in ast.walk(enclosing.node):
+            if (isinstance(node, ast.Assign) and len(node.targets) == 1
+                    and isinstance(node.targets[0], ast.Name)
+                    and node.targets[0].id == d
+                    and isinstance(node.value, ast.Call)
+                    and call_name(node.value) in _PARTIAL
+                    and node.value.args
+                    and dotted_name(node.value.args[0]) != d):
+                return self._seed_target(mi, cls, node.value.args[0],
+                                         enclosing)
         # a nested def: jax.jit(run) where run is local to `enclosing`
         for node in ast.walk(enclosing.node):
             if (isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef))
@@ -428,6 +441,66 @@ class ProjectIndex:
     def iter_functions(self):
         for mi in self.modules.values():
             yield from mi.functions.values()
+
+    # -- jit wrappers handed out by a function -----------------------------
+
+    def jit_factories(self) -> dict[tuple[str, str], tuple]:
+        """Functions that return a jit wrapper they built (a process-level
+        table of programs, dft/fused.step_program): key -> (the wrapper's
+        place in a returned tuple, None where it is the whole value; the
+        jit call's keyword arguments)."""
+        if self._jit_factories is not None:
+            return self._jit_factories
+        from sirius_tpu.analysis.jaxrules import _local_jit_bindings
+
+        out: dict[tuple[str, str], tuple] = {}
+        for fi in self.iter_functions():
+            built = {tgt: kwargs  # local name -> the jit call's kwargs
+                     for tgt, kwargs, _ in _local_jit_bindings(fi.node)
+                     if "." not in tgt}
+            if not built:
+                continue
+            for node in ast.walk(fi.node):
+                if not isinstance(node, ast.Return) or node.value is None:
+                    continue
+                in_tuple = isinstance(node.value, ast.Tuple)
+                elts = node.value.elts if in_tuple else [node.value]
+                for i, e in enumerate(elts):
+                    if isinstance(e, ast.Name) and e.id in built:
+                        out[fi.key] = (i if in_tuple else None, built[e.id])
+        self._jit_factories = out
+        return out
+
+    def factory_jit_bindings(self, fi: FunctionInfo):
+        """``name = factory(...)`` / ``self.attr, flag = factory(...)``
+        inside ``fi``, factory one of `jit_factories`: yields (binding,
+        the jit call's kwargs, assign), as jaxrules._local_jit_bindings
+        does for a jit call written in place."""
+        factories = self.jit_factories()
+        if not factories:
+            return
+        short = {q.rsplit(".", 1)[-1] for _, q in factories}
+        for node in ast.walk(fi.node):
+            if not (isinstance(node, ast.Assign) and len(node.targets) == 1
+                    and isinstance(node.value, ast.Call)):
+                continue
+            d = call_name(node.value)
+            if not d or d.rsplit(".", 1)[-1] not in short:
+                continue
+            for callee in self._resolve_call(fi.module, fi.cls, d):
+                if callee.key not in factories:
+                    continue
+                place, kwargs = factories[callee.key]
+                tgt = node.targets[0]
+                if place is not None:
+                    if not (isinstance(tgt, ast.Tuple)
+                            and place < len(tgt.elts)):
+                        continue
+                    tgt = tgt.elts[place]
+                name = dotted_name(tgt)
+                if name:
+                    yield name, kwargs, node
+                break
 
     # -- findings ----------------------------------------------------------
 

@@ -148,6 +148,11 @@ class DeviceModel:
                                     (fi.module.name, fi.cls, attr)
                                 ] = d[5:]
                         break
+            # ... or handed out by a function that built it
+            for tgt, _, _ in self.project.factory_jit_bindings(fi):
+                if tgt.startswith("self."):
+                    self.jit_attrs.setdefault(
+                        (fi.module.name, fi.cls), set()).add(tgt[5:])
 
     def _resolve_class(self, mi: ModuleInfo,
                        name: str) -> tuple[ModuleInfo, str] | None:
@@ -260,6 +265,9 @@ class DeviceModel:
                         and isinstance(node.value, ast.Call)
                         and call_name(node.value) in _JIT_WRAPPERS):
                     names.add(node.targets[0].id)
+            names.update(t for t, _, _ in
+                         self.project.factory_jit_bindings(fi)
+                         if "." not in t)
             fi._local_jit_names = names
         return names
 

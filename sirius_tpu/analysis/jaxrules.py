@@ -229,10 +229,12 @@ class JitDonatedReuse:
     def _donated_map(self, project):
         """(module, owner-name) -> donated positions, from both local
         ``g = jax.jit(f, donate_argnums=...)`` bindings and
-        ``self.X = jax.jit(...)`` class-level bindings."""
+        ``self.X = jax.jit(...)`` class-level bindings, the jit call in
+        place or in a function that hands the wrapper out."""
         out: dict[tuple[str, str, str], list[int]] = {}
         for fi in project.iter_functions():
-            for tgt, kwargs, _ in _local_jit_bindings(fi.node):
+            for tgt, kwargs, _ in (*_local_jit_bindings(fi.node),
+                                   *project.factory_jit_bindings(fi)):
                 if "donate_argnums" not in kwargs:
                     continue
                 pos = _int_elements(kwargs["donate_argnums"])

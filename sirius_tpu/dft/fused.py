@@ -35,6 +35,10 @@ tests/test_fused_scf.py pins the two paths to ~1e-8 Ha agreement.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+import threading
+from collections import OrderedDict
 from typing import NamedTuple
 
 import jax
@@ -62,6 +66,7 @@ from sirius_tpu.dft.potential import (
     num_box_fills,
     num_gradient_transforms,
 )
+from sirius_tpu.dft.xc import XCFunctional
 from sirius_tpu.ops.augmentation import (
     build_aug_device_tables,
     d_operator_device,
@@ -73,6 +78,7 @@ from sirius_tpu.parallel.batched import (
     join_cplx,
     split_cplx,
 )
+from sirius_tpu.utils.profiler import counters
 
 # indices into the per-iteration scalar record (the ONLY device->host
 # traffic of a fused iteration)
@@ -118,6 +124,65 @@ def fold_scalars(record) -> np.ndarray:
     out = raw[:NUM_SCALARS].copy()
     out[list(S_PAIRED)] += raw[NUM_SCALARS:]
     return out
+
+
+@dataclasses.dataclass(frozen=True)
+class StepConstants:
+    """Every value _step_impl bakes into its trace, and nothing else: the
+    step's one static argument. Frozen and hashable (dtypes, numbers,
+    tuples, names: no context, no array), so equal records mean equal
+    programs for equal input shapes, and the record is the key under
+    which a process finds the step it has already built."""
+
+    cdt: np.dtype  # the working precision: complex64/float32 on a TPU
+    rdt: np.dtype
+    ns: int
+    ng: int
+    omega: float
+    nel: float
+    charge_tol: float
+    dims: tuple
+    dims_coarse: tuple
+    kind: str  # the mixer's
+    mix_beta: float
+    max_history: int
+    has_aug: bool
+    do_symmetrize: bool
+    polarized: bool
+    xc: tuple  # the functional by its names
+
+
+# The steps this process has built, by their constants, least recently
+# used out past STEP_PROGRAMS_MAX (the bound the serving engine's cache
+# always had). An entry is a jax.jit of _step_impl with its record bound:
+# it holds no FusedScf, no context and no device array, jit keys its
+# traces on the inputs' shapes, dtypes and shardings as it does for every
+# module-level program, and dropping the entry drops its traces and loaded
+# executables with it (one jit over a static argument could not let one
+# record go).
+STEP_PROGRAMS_MAX = 32
+_step_programs: OrderedDict = OrderedDict()
+_step_programs_lock = threading.Lock()
+
+
+def step_program(rec: StepConstants):
+    """(the process's compiled step for `rec`, whether it was there):
+    the one way to obtain the step, whoever calls run_scf."""
+    with _step_programs_lock:
+        step = _step_programs.get(rec)
+        found = step is not None
+        if found:
+            _step_programs.move_to_end(rec)
+        else:
+            bound = functools.partial(_step_impl, rec)
+            # jit names a program after its function, and a partial has no
+            # name of its own: the module stays jit__step_impl
+            bound.__name__ = _step_impl.__name__
+            step = jax.jit(bound, donate_argnums=(1,))
+            _step_programs[rec] = step
+            while len(_step_programs) > STEP_PROGRAMS_MAX:
+                _step_programs.popitem(last=False)
+        return step, found
 
 
 class FusedCarry(NamedTuple):
@@ -231,19 +296,25 @@ class FusedScf:
         self.xc_gradient_transforms = num_gradient_transforms(
             xc, self.polarized)
         self.xc_kind = "gga" if xc.is_gga else "lda"  # the step's XC branch
+        self.constants = StepConstants(
+            cdt=self.cdt, rdt=self.rdt, ns=self.ns, ng=self.ng,
+            omega=self.omega, nel=self.nel, charge_tol=self.charge_tol,
+            dims=self.dims, dims_coarse=self.dims_coarse, kind=self.kind,
+            mix_beta=self.mix_beta, max_history=self.max_history,
+            has_aug=self.has_aug, do_symmetrize=self.do_symmetrize,
+            polarized=self.polarized, xc=tuple(xc.names))
+        # The step is a program of the process (step_program), found by
+        # its constants: a second FusedScf with an equal record and equal
+        # input shapes, dtypes and shardings runs the first one's trace,
+        # lowering and loaded executable, whoever built it (run_scf in a
+        # loop, the engine, a relaxation, MD). The tables are program
+        # inputs, so the reuse is exact.
+        self._step, self.step_reused = step_program(self.constants)
         if exec_cache is not None:
-            # serving: reuse a previously-jitted step whose trace signature
-            # matches. The jitted callable is a bound method of the FIRST
-            # instance in the bucket; every trace constant it bakes in is
-            # part of the signature, and the tables it operates on are
-            # program inputs, so reuse is exact — padded decks in one shape
-            # bucket skip XLA compilation entirely.
-            self._step = exec_cache.get(
-                ("fused_step", *self._trace_signature()),
-                lambda: jax.jit(self._step_impl, donate_argnums=(1,)),
-            )
-        else:
-            self._step = jax.jit(self._step_impl, donate_argnums=(1,))
+            # the serving engine's books (hits, misses, /metrics): what it
+            # is told, not what decides whether anything is traced
+            exec_cache.get(("fused_step", *self._trace_signature()),
+                           lambda: self._step)
 
     def _table(self, a):
         """Upload one table leaf in the working precision (index leaves
@@ -255,19 +326,14 @@ class FusedScf:
         return jnp.asarray(a)
 
     def _trace_signature(self) -> tuple:
-        """Everything _step_impl bakes into its trace (instance attrs used
-        inside the jitted body) plus the shapes/dtypes of its table inputs
-        and the per-call array ranks (nk/nb/ngk). Two FusedScf instances
-        with equal signatures compile to identical programs."""
+        """What decides which compiled step a call of step() runs: the
+        constants and the shapes/dtypes of the table inputs and the
+        per-call arrays (nk/nb/ngk). The serving engine books its
+        executable hits and misses under it."""
         leaves, treedef = jax.tree_util.tree_flatten(self.tables)
         tab = tuple((tuple(x.shape), str(x.dtype)) for x in leaves)
         return (
-            str(self.cdt), str(self.rdt),
-            self.ns, self.ng, self.nx, self.omega, self.nel, self.charge_tol,
-            self.dims, self.dims_coarse,
-            self.kind, self.mix_beta, self.max_history,
-            self.has_aug, self.do_symmetrize, self.polarized,
-            tuple(self.xc.names),
+            self.constants,
             self.ctx.gkvec.num_kpoints, self.ctx.num_bands,
             self.ctx.gkvec.ngk_max,
             str(treedef), tab,
@@ -377,208 +443,217 @@ class FusedScf:
             "dm_blocks_by_spin": dm_blocks_by_spin,
         }
 
-    # -- the compiled program --------------------------------------------
 
-    def _step_impl(self, tables, carry, acc, dm_re, dm_im, ev, occ_w, ent,
-                   pr, pi):
-        ng, ns, omega = self.ng, self.ns, self.omega
-        cdt, rdt = self.cdt, self.rdt
+# -- the compiled program ------------------------------------------------
 
-        # The step_* scopes name the stages for a capture's scope table
-        # (obs/device_scopes.py; generate_potential_device holds
-        # step_hartree, step_xc and step_vloc): metadata of the emitted
-        # operations only, the traced order is the one it always was.
-        with jax.named_scope("step_density"):
-            # density_from_coarse_acc, traced: 1/Omega, coarse r -> coarse G,
-            # scatter onto the fine sphere
-            acc = acc.astype(rdt)
-            rho_c = r_to_g(
-                (acc / omega).astype(cdt), tables["fft_index_coarse"],
-                self.dims_coarse,
-            )
-            rho_spin = jnp.zeros((ns, ng), dtype=cdt).at[:, tables["c2f"]].set(
-                rho_c
-            )
 
-            dm = jax.lax.complex(dm_re.astype(rdt), dm_im.astype(rdt))
-            if self.has_aug:
-                if self.do_symmetrize:
-                    dm = symmetrize_density_matrix_device(dm, tables["dm_sym"])
-                rho_spin = rho_spin + rho_aug_g_device(dm, tables["aug"], ng)
+def _step_impl(rec: StepConstants, tables, carry, acc, dm_re, dm_im, ev,
+               occ_w, ent, pr, pi):
+    """One fused iteration, traced: FusedScf.step's arguments behind the
+    record of its constants and the tables. It reads nothing else, so the
+    program is a function of `rec` and the inputs' avals alone."""
+    # runs where JAX traces the body, not where a table was asked: the
+    # tracing job's count of what was really built
+    counters["num_fused_step_traces"] += 1
+    ng, ns, omega = rec.ng, rec.ns, rec.omega
+    cdt, rdt = rec.cdt, rec.rdt
+    xc = XCFunctional(list(rec.xc))
 
-            rho_new = jnp.sum(rho_spin, axis=0)
-            mag_new = rho_spin[0] - rho_spin[1] if self.polarized else None
-            nel_got = jnp.real(rho_new[0]) * omega
-            # A 32-bit step accumulates the electron count to a few eps of
-            # it (band norms, the FFT's 1/N), differently in every iteration,
-            # and the occupations were solved for the count itself: where what
-            # was accumulated is the count to that rounding, the G = 0
-            # component is set to it (the 54-atom cell converges in 14
-            # iterations on the chip with this and in 17 without, PERF.md,
-            # PR 27). A count further off is not rounding but a fault (a lost
-            # band norm, wrong occupations, a wrong augmentation charge): it
-            # stays in the density, in S_NEL and in the energy. A 64-bit step,
-            # like the host tail, carries what it accumulated.
-            if hilo.compensated(rdt):
-                near = jnp.abs(nel_got - self.nel) <= self.charge_tol
-                rho_new = rho_new.at[0].set(jnp.where(
-                    near, jnp.asarray(self.nel / omega, dtype=cdt), rho_new[0]
-                ))
-            if self.do_symmetrize:
-                rho_new = symmetrize_pw_device(rho_new, tables["sym"])
-                if self.polarized:
-                    mag_new = symmetrize_pw_device(
-                        mag_new, tables["sym"], axial_z=True
-                    )
-            mag_moment = (
-                jnp.real(mag_new[0]) * omega if self.polarized
-                else jnp.zeros((), dtype=rdt)
-            )
-
-        with jax.named_scope("step_mixing"):
-            # mixing (host-sequence semantics: rms pre-mix, eha post-mix)
-            x_new = (
-                jnp.concatenate([rho_new, mag_new]) if self.polarized
-                else rho_new
-            )
-            x_in = jax.lax.complex(carry.x_re, carry.x_im)
-            state = DeviceMixerState(
-                carry.hx_re, carry.hx_im, carry.hf_re, carry.hf_im,
-                carry.count,
-            )
-            state, x_mixed, rms, eha = device_mix(
-                state, x_in, x_new, tables["mixw"], self.mix_beta, self.kind,
-                self.max_history,
-            )
-            # output - input density (scf-corr force)
-            resid = rho_new - x_in[:ng]
-
-        # Harris term e1 against the potential this iteration's bands saw
-        # (the energy terms are (hi, lo) pairs, core/hilo.py)
-        veff_old = jax.lax.complex(carry.veff_re, carry.veff_im)
-        e1 = hilo.cdot_scaled(rho_new, veff_old, omega)
-        if self.polarized:
-            bz_old = jax.lax.complex(carry.bz_re, carry.bz_im)
-            e1 = hilo.add_pairs(e1, hilo.cdot_scaled(mag_new, bz_old, omega))
-
-        # potential from the MIXED density
-        rho_mix = x_mixed[:ng]
-        mag_mix = x_mixed[ng:] if self.polarized else None
-        pot = generate_potential_device(
-            self.xc, rho_mix, mag_mix, tables["pot"], self.dims,
-            self.dims_coarse, omega,
-            sym_tb=tables["sym"] if self.do_symmetrize else None,
+    # The step_* scopes name the stages for a capture's scope table
+    # (obs/device_scopes.py; generate_potential_device holds
+    # step_hartree, step_xc and step_vloc): metadata of the emitted
+    # operations only, the traced order is the one it always was.
+    with jax.named_scope("step_density"):
+        # density_from_coarse_acc, traced: 1/Omega, coarse r -> coarse G,
+        # scatter onto the fine sphere
+        acc = acc.astype(rdt)
+        rho_c = r_to_g(
+            (acc / omega).astype(cdt), tables["fft_index_coarse"],
+            rec.dims_coarse,
         )
-        veff_new = pot["veff_g"]
-        bz_new = pot["bz_g"]
-        e2 = hilo.cdot_scaled(rho_new, veff_new, omega)
-        if self.polarized:
-            e2 = hilo.add_pairs(e2, hilo.cdot_scaled(mag_new, bz_new, omega))
-        v0 = jnp.real(veff_new[0])
+        rho_spin = jnp.zeros((ns, ng), dtype=cdt).at[:, tables["c2f"]].set(
+            rho_c
+        )
 
-        with jax.named_scope("step_d_matrix"):
-            # next iteration's D matrices and H diagonal
-            if self.has_aug:
-                ds = []
-                for s in range(ns):
-                    if self.polarized:
-                        vs = veff_new + (bz_new if s == 0 else -bz_new)
-                    else:
-                        vs = veff_new
-                    ds.append(
-                        d_operator_device(vs, tables["dion"], tables["aug"],
-                                          omega)
-                    )
-                dion_new = jnp.stack(ds)
-            else:
-                dion_new = jnp.broadcast_to(
-                    tables["dion"][None], (ns,) + tables["dion"].shape
-                )
-            h_diag = compute_h_diag_device(
-                tables["ekin"], tables["gmask"], tables["beta_re"],
-                tables["beta_im"], dion_new, v0,
-            )
+        dm = jax.lax.complex(dm_re.astype(rdt), dm_im.astype(rdt))
+        if rec.has_aug:
+            if rec.do_symmetrize:
+                dm = symmetrize_density_matrix_device(dm, tables["dm_sym"])
+            rho_spin = rho_spin + rho_aug_g_device(dm, tables["aug"], ng)
 
-        with jax.named_scope("step_ledger"):
-            # ---- numerics ledger: per-iteration invariants, same record ----
-            # Note the choice of invariants: quantities whose exact value is
-            # known (I, 0) so the scalar directly reads as accumulated rounding
-            # + algorithmic drift. The Gram matrix itself and the density
-            # matrix are hermitian BITWISE in IEEE arithmetic (conjugate-mirror
-            # products round identically), so their asymmetry is useless; the
-            # chained-GEMM subspace H_nl below is not mirror-exact and does
-            # measure rounding. dion here is the BARE table (not dion_new):
-            # host and device then score the identical quantity regardless of
-            # where each path is in its D-refresh cycle.
-            psi_c = jax.lax.complex(
-                pr.astype(rdt), pi.astype(rdt)
-            ) * tables["gmask"][:, None, None, :]
-            beta_c = jax.lax.complex(
-                tables["beta_re"].astype(rdt), tables["beta_im"].astype(rdt),
-            )
-            qmat_r = tables["qmat"].astype(rdt)
-            bp = jnp.einsum("kxg,ksbg->ksbx", jnp.conj(beta_c), psi_c)
-            gram = jnp.einsum("ksbg,kscg->ksbc", jnp.conj(psi_c), psi_c)
-            gram = gram + jnp.einsum(
-                "ksbx,xy,kscy->ksbc", jnp.conj(bp), qmat_r, bp
-            )
-            nb = psi_c.shape[2]
-            s_ortho = jnp.max(jnp.abs(gram - jnp.eye(nb, dtype=gram.dtype)))
-            s_chg = jnp.abs(
-                jnp.real(x_mixed[0]) - jnp.real(x_new[0])
-            ) * omega
-            if self.do_symmetrize:
-                s_sym = jnp.max(jnp.abs(
-                    symmetrize_pw_device(rho_new, tables["sym"]) - rho_new
-                ))
-            else:
-                s_sym = jnp.zeros((), dtype=rdt)
-            dion_r = tables["dion"].astype(rdt)
-            h_nl = jnp.einsum("ksbx,xy,kscy->ksbc", jnp.conj(bp), dion_r, bp)
-            s_herm = jnp.max(jnp.abs(
-                h_nl - jnp.conj(jnp.swapaxes(h_nl, -1, -2))
+        rho_new = jnp.sum(rho_spin, axis=0)
+        mag_new = rho_spin[0] - rho_spin[1] if rec.polarized else None
+        nel_got = jnp.real(rho_new[0]) * omega
+        # A 32-bit step accumulates the electron count to a few eps of
+        # it (band norms, the FFT's 1/N), differently in every iteration,
+        # and the occupations were solved for the count itself: where what
+        # was accumulated is the count to that rounding, the G = 0
+        # component is set to it (the 54-atom cell converges in 14
+        # iterations on the chip with this and in 17 without, PERF.md,
+        # PR 27). A count further off is not rounding but a fault (a lost
+        # band norm, wrong occupations, a wrong augmentation charge): it
+        # stays in the density, in S_NEL and in the energy. A 64-bit step,
+        # like the host tail, carries what it accumulated.
+        if hilo.compensated(rdt):
+            near = jnp.abs(nel_got - rec.nel) <= rec.charge_tol
+            rho_new = rho_new.at[0].set(jnp.where(
+                near, jnp.asarray(rec.nel / omega, dtype=cdt), rho_new[0]
             ))
-
-        eval_sum = hilo.dot_scaled(occ_w.astype(rdt), ev.astype(rdt), 1.0)
-        e = pot["energies"]
-        paired = [e["vha"], e["vxc"], e["vloc"], e["veff"], e["exc"],
-                  e["bxc"], e1, e2, eval_sum]  # S_PAIRED's order
-        # device-side health sentinel (dft/recovery.py): a NaN anywhere in
-        # the mixed vector or the new potential collapses every scalar to
-        # NaN anyway, but jnp.isfinite makes the check explicit and also
-        # catches an Inf confined to a single G component that the energy
-        # sums could mask by cancellation
-        finite = (
-            jnp.all(jnp.isfinite(jnp.real(x_mixed)))
-            & jnp.all(jnp.isfinite(jnp.imag(x_mixed)))
-            & jnp.all(jnp.isfinite(jnp.real(veff_new)))
-            & jnp.all(jnp.isfinite(jnp.imag(veff_new)))
-            & jnp.all(jnp.isfinite(ev))
-        ).astype(rdt)
-        scalars = jnp.stack([
-            rms, eha, *[hi for hi, _ in paired], nel_got, mag_moment, v0,
-            ent.astype(rdt), finite,
-            s_ortho, s_chg, s_sym, s_herm,
-            *[lo for _, lo in paired],  # the second words
-        ])
-
-        if self.polarized:
-            bz_re, bz_im = jnp.real(bz_new), jnp.imag(bz_new)
-        else:
-            bz_re = bz_im = jnp.zeros(ng, dtype=rdt)
-        new_carry = FusedCarry(
-            jnp.real(x_mixed), jnp.imag(x_mixed),
-            state.hx_re, state.hx_im, state.hf_re, state.hf_im, state.count,
-            jnp.real(veff_new), jnp.imag(veff_new), bz_re, bz_im,
+        if rec.do_symmetrize:
+            rho_new = symmetrize_pw_device(rho_new, tables["sym"])
+            if rec.polarized:
+                mag_new = symmetrize_pw_device(
+                    mag_new, tables["sym"], axial_z=True
+                )
+        mag_moment = (
+            jnp.real(mag_new[0]) * omega if rec.polarized
+            else jnp.zeros((), dtype=rdt)
         )
-        out = {
-            "scalars": scalars,
-            "veff_r_coarse": pot["veff_r_coarse"],
-            "dion": dion_new,
-            "h_diag": h_diag,
-            "dm_re": jnp.real(dm),
-            "dm_im": jnp.imag(dm),
-            "resid_re": jnp.real(resid),
-            "resid_im": jnp.imag(resid),
-        }
-        return new_carry, out
+
+    with jax.named_scope("step_mixing"):
+        # mixing (host-sequence semantics: rms pre-mix, eha post-mix)
+        x_new = (
+            jnp.concatenate([rho_new, mag_new]) if rec.polarized
+            else rho_new
+        )
+        x_in = jax.lax.complex(carry.x_re, carry.x_im)
+        state = DeviceMixerState(
+            carry.hx_re, carry.hx_im, carry.hf_re, carry.hf_im,
+            carry.count,
+        )
+        state, x_mixed, rms, eha = device_mix(
+            state, x_in, x_new, tables["mixw"], rec.mix_beta, rec.kind,
+            rec.max_history,
+        )
+        # output - input density (scf-corr force)
+        resid = rho_new - x_in[:ng]
+
+    # Harris term e1 against the potential this iteration's bands saw
+    # (the energy terms are (hi, lo) pairs, core/hilo.py)
+    veff_old = jax.lax.complex(carry.veff_re, carry.veff_im)
+    e1 = hilo.cdot_scaled(rho_new, veff_old, omega)
+    if rec.polarized:
+        bz_old = jax.lax.complex(carry.bz_re, carry.bz_im)
+        e1 = hilo.add_pairs(e1, hilo.cdot_scaled(mag_new, bz_old, omega))
+
+    # potential from the MIXED density
+    rho_mix = x_mixed[:ng]
+    mag_mix = x_mixed[ng:] if rec.polarized else None
+    pot = generate_potential_device(
+        xc, rho_mix, mag_mix, tables["pot"], rec.dims,
+        rec.dims_coarse, omega,
+        sym_tb=tables["sym"] if rec.do_symmetrize else None,
+    )
+    veff_new = pot["veff_g"]
+    bz_new = pot["bz_g"]
+    e2 = hilo.cdot_scaled(rho_new, veff_new, omega)
+    if rec.polarized:
+        e2 = hilo.add_pairs(e2, hilo.cdot_scaled(mag_new, bz_new, omega))
+    v0 = jnp.real(veff_new[0])
+
+    with jax.named_scope("step_d_matrix"):
+        # next iteration's D matrices and H diagonal
+        if rec.has_aug:
+            ds = []
+            for s in range(ns):
+                if rec.polarized:
+                    vs = veff_new + (bz_new if s == 0 else -bz_new)
+                else:
+                    vs = veff_new
+                ds.append(
+                    d_operator_device(vs, tables["dion"], tables["aug"],
+                                      omega)
+                )
+            dion_new = jnp.stack(ds)
+        else:
+            dion_new = jnp.broadcast_to(
+                tables["dion"][None], (ns,) + tables["dion"].shape
+            )
+        h_diag = compute_h_diag_device(
+            tables["ekin"], tables["gmask"], tables["beta_re"],
+            tables["beta_im"], dion_new, v0,
+        )
+
+    with jax.named_scope("step_ledger"):
+        # ---- numerics ledger: per-iteration invariants, same record ----
+        # Note the choice of invariants: quantities whose exact value is
+        # known (I, 0) so the scalar directly reads as accumulated rounding
+        # + algorithmic drift. The Gram matrix itself and the density
+        # matrix are hermitian BITWISE in IEEE arithmetic (conjugate-mirror
+        # products round identically), so their asymmetry is useless; the
+        # chained-GEMM subspace H_nl below is not mirror-exact and does
+        # measure rounding. dion here is the BARE table (not dion_new):
+        # host and device then score the identical quantity regardless of
+        # where each path is in its D-refresh cycle.
+        psi_c = jax.lax.complex(
+            pr.astype(rdt), pi.astype(rdt)
+        ) * tables["gmask"][:, None, None, :]
+        beta_c = jax.lax.complex(
+            tables["beta_re"].astype(rdt), tables["beta_im"].astype(rdt),
+        )
+        qmat_r = tables["qmat"].astype(rdt)
+        bp = jnp.einsum("kxg,ksbg->ksbx", jnp.conj(beta_c), psi_c)
+        gram = jnp.einsum("ksbg,kscg->ksbc", jnp.conj(psi_c), psi_c)
+        gram = gram + jnp.einsum(
+            "ksbx,xy,kscy->ksbc", jnp.conj(bp), qmat_r, bp
+        )
+        nb = psi_c.shape[2]
+        s_ortho = jnp.max(jnp.abs(gram - jnp.eye(nb, dtype=gram.dtype)))
+        s_chg = jnp.abs(
+            jnp.real(x_mixed[0]) - jnp.real(x_new[0])
+        ) * omega
+        if rec.do_symmetrize:
+            s_sym = jnp.max(jnp.abs(
+                symmetrize_pw_device(rho_new, tables["sym"]) - rho_new
+            ))
+        else:
+            s_sym = jnp.zeros((), dtype=rdt)
+        dion_r = tables["dion"].astype(rdt)
+        h_nl = jnp.einsum("ksbx,xy,kscy->ksbc", jnp.conj(bp), dion_r, bp)
+        s_herm = jnp.max(jnp.abs(
+            h_nl - jnp.conj(jnp.swapaxes(h_nl, -1, -2))
+        ))
+
+    eval_sum = hilo.dot_scaled(occ_w.astype(rdt), ev.astype(rdt), 1.0)
+    e = pot["energies"]
+    paired = [e["vha"], e["vxc"], e["vloc"], e["veff"], e["exc"],
+              e["bxc"], e1, e2, eval_sum]  # S_PAIRED's order
+    # device-side health sentinel (dft/recovery.py): a NaN anywhere in
+    # the mixed vector or the new potential collapses every scalar to
+    # NaN anyway, but jnp.isfinite makes the check explicit and also
+    # catches an Inf confined to a single G component that the energy
+    # sums could mask by cancellation
+    finite = (
+        jnp.all(jnp.isfinite(jnp.real(x_mixed)))
+        & jnp.all(jnp.isfinite(jnp.imag(x_mixed)))
+        & jnp.all(jnp.isfinite(jnp.real(veff_new)))
+        & jnp.all(jnp.isfinite(jnp.imag(veff_new)))
+        & jnp.all(jnp.isfinite(ev))
+    ).astype(rdt)
+    scalars = jnp.stack([
+        rms, eha, *[hi for hi, _ in paired], nel_got, mag_moment, v0,
+        ent.astype(rdt), finite,
+        s_ortho, s_chg, s_sym, s_herm,
+        *[lo for _, lo in paired],  # the second words
+    ])
+
+    if rec.polarized:
+        bz_re, bz_im = jnp.real(bz_new), jnp.imag(bz_new)
+    else:
+        bz_re = bz_im = jnp.zeros(ng, dtype=rdt)
+    new_carry = FusedCarry(
+        jnp.real(x_mixed), jnp.imag(x_mixed),
+        state.hx_re, state.hx_im, state.hf_re, state.hf_im, state.count,
+        jnp.real(veff_new), jnp.imag(veff_new), bz_re, bz_im,
+    )
+    out = {
+        "scalars": scalars,
+        "veff_r_coarse": pot["veff_r_coarse"],
+        "dion": dion_new,
+        "h_diag": h_diag,
+        "dm_re": jnp.real(dm),
+        "dm_im": jnp.imag(dm),
+        "resid_re": jnp.real(resid),
+        "resid_im": jnp.imag(resid),
+    }
+    return new_carry, out

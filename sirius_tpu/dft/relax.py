@@ -6,8 +6,10 @@ Each objective evaluation is a converged SCF; successive steps warm-start
 from the previous step's wave functions and a delta-extrapolated density
 (rho_prev - rho_atomic(old positions) + rho_atomic(new positions)). The
 geometry-step plumbing (fixed-shape context rebuild, delta-density guess,
-warm-start assembly) is shared with the MD driver via dft/geometry.py, and
-a shared ExecutableCache keeps the fused SCF compiled once across steps."""
+warm-start assembly) is shared with the MD driver via dft/geometry.py; the
+fused step is compiled once a process (dft/fused.step_program), so every
+step after the first reuses it. exec_cache, where the serving engine passes
+its own, is handed on to run_scf for its books."""
 
 from __future__ import annotations
 
@@ -34,10 +36,6 @@ def relax_atoms(
     cfg.control.print_forces = True
     if ctx is None:
         ctx = cm.SimulationContext.create(cfg, base_dir)
-    if exec_cache is None:
-        from sirius_tpu.serve.cache import ExecutableCache
-
-        exec_cache = ExecutableCache()
     uc0 = ctx.unit_cell
     lat = uc0.lattice
     pos = uc0.positions.copy()

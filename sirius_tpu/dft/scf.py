@@ -175,9 +175,10 @@ def _run_scf_inner(
     the host path; unlike restart_from (density-only warm start of a NEW
     run), resume continues the SAME run after preemption.
 
-    exec_cache: optional serve.cache.ExecutableCache — FusedScf reuses a
-    previously-jitted step program when the trace signature matches (the
-    serving engine's compile amortization). devices: explicit device list
+    exec_cache: optional serve.cache.ExecutableCache — the serving
+    engine's books of executable hits and misses; the fused step itself is
+    a program of the process (fused.step_program) with or without one.
+    devices: explicit device list
     to run on (a scheduler slice); defaults to jax.devices()."""
     t0 = time.time()
     from sirius_tpu.utils.profiler import reset_timers
@@ -685,8 +686,8 @@ def _run_scf_inner(
             # (re)build the fused program and/or its carry. The recovery
             # ladder calls this after a rollback: the donated carry of a
             # diverged step holds poisoned buffers, and a beta/kind change
-            # needs a full rebuild because FusedScf bakes mixer.beta and
-            # mixer.kind into the trace. The program, its tables and the
+            # needs a full rebuild because mixer.beta and mixer.kind are
+            # constants of the step's trace. The program, its tables and the
             # scalars below are in the band solve's working precision
             # (wf_dtype), so the fp32 -> fp64 polish switch rebuilds too.
             nonlocal fused, fused_carry, fused_out, fused_np, fused_beta
@@ -726,6 +727,9 @@ def _run_scf_inner(
             fused_out, fused_np = keep
 
         fused_beta = fused_nel = fused_width = fused_occmax = fused_dm0 = None
+        # booked where _step_impl's body is traced (dft/fused.py); a job
+        # that reuses the process's step reports the 0, not a missing key
+        counters["num_fused_step_traces"] += 0
         _fused_setup(
             x_mix, pot,
             history=mixer.export_history() or None
@@ -989,6 +993,10 @@ def _run_scf_inner(
     counters["num_kpoints_solved"] = nk
     _setup_span.close(
         fused=fused is not None,
+        # what the process's table of steps answered this job's constants
+        # (counters.num_fused_step_traces is what JAX then did)
+        **({} if fused is None else {
+            "fused_step": "reused" if fused.step_reused else "traced"}),
         **({"symmetry": {
             "num_ops": int(ctx.symmetry.num_ops),
             "kpoints_mesh": int(np.prod(p.ngridk)),
@@ -1750,6 +1758,7 @@ def _run_scf_inner(
         num_sym_pw=int(counters["num_sym_pw"]),
         num_xc_gradient_transforms=int(
             counters["num_xc_gradient_transforms"]),
+        num_fused_step_traces=int(counters["num_fused_step_traces"]),
         energy_resolution_ha=abs(e_total) * pair_eps(
             fused.rdt if fused is not None else np.float64),
     )

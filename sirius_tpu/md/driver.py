@@ -9,8 +9,8 @@ new positions. Three pieces make the stepping cheap:
   context_at_positions), so the fused SCF iteration and every module-jit
   helper hit their compiled executables — zero XLA recompiles after the
   first step (tracked via serve/cache.py's jax.monitoring listener);
-- a shared ExecutableCache carries the fused-step program across run_scf
-  calls (the serving engine's compile amortization, reused here);
+- the fused step is a program of the process (dft/fused.step_program),
+  found again by every run_scf call of the trajectory;
 - the SCF warm-starts from ASPC-extrapolated density and subspace-aligned
   extrapolated wave functions (md/extrapolate.py), which cuts the
   iterations per step severalfold against the superposition-of-atoms cold
@@ -145,12 +145,13 @@ def _run_md_impl(
 
     resume: path to a /md checkpoint (default_md_autosave_path) — continues
     the trajectory from the saved step, replaying the uninterrupted run.
-    exec_cache: shared serve.cache.ExecutableCache (created when None)."""
+    exec_cache: the serving engine's serve.cache.ExecutableCache, handed on
+    to run_scf for its books (the fused step is compiled once a process
+    with or without one, dft/fused.step_program)."""
     from sirius_tpu.dft.geometry import context_at_positions, warm_start_state
     from sirius_tpu.dft.scf import run_scf
     from sirius_tpu.io.checkpoint import load_state, save_state
     from sirius_tpu.serve.cache import (
-        ExecutableCache,
         backend_compiles_total,
         install_compile_listener,
     )
@@ -170,8 +171,6 @@ def _run_md_impl(
     cfg.control.autosave_every = 0
 
     install_compile_listener()
-    if exec_cache is None:
-        exec_cache = ExecutableCache()
     if ctx is None:
         # honours the species-file-free "synthetic" deck section the same
         # way sirius-serve does; plain decks fall through to
@@ -438,7 +437,7 @@ def _run_md_impl(
             60.0 * steps_run / elapsed if elapsed > 0 else 0.0
         ),
         "elapsed_s": elapsed,
-        "exec_cache": exec_cache.stats(),
+        "exec_cache": None if exec_cache is None else exec_cache.stats(),
         "autosave_path": autosave_path,
     }
 

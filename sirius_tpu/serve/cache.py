@@ -8,9 +8,13 @@ Two levels:
   first; `control.ngk_pad_quantum` rounds the |G+k| sphere up so decks
   with slightly different spheres coalesce.
 - **Executables** (`get`): named jitted callables keyed by their full
-  trace signature (dft/fused.py `_trace_signature`), LRU-evicted. The
-  cached value for the fused step is a bound method of the first FusedScf
-  in the bucket — its tables are program *inputs*, so reuse is exact.
+  trace signature (dft/fused.py `_trace_signature`), LRU-evicted. For the
+  fused step these are the engine's books only: the step is a program of
+  the process (dft/fused.py `step_program`, found by the record of its
+  trace constants, its tables program *inputs*), built once whoever calls
+  run_scf, and `FusedScf` tells this cache which one it runs. A hit here
+  says the engine has seen the signature before; whether JAX traced
+  anything is `counters.num_fused_step_traces`.
 
 Hit/miss counters are exported through utils/profiler.py (thread-local,
 so each job's result reports its own), aggregated on the cache object
@@ -70,8 +74,9 @@ class ExecutableCache:
     """Thread-safe LRU of named jitted executables + bucket bookkeeping.
 
     capacity bounds the number of cached executables; evicting one drops
-    the reference to the jitted callable (and, for the fused step, the
-    FusedScf instance bound to it), letting XLA free the program.
+    this cache's reference to the jitted callable. The fused step's
+    callable holds no FusedScf and no device array, and the process's own
+    table (dft/fused.py) is what keeps it compiled.
     """
 
     def __init__(self, capacity: int = 32):

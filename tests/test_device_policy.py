@@ -3,6 +3,7 @@ the fused step follows the band solve's precision, 64-bit device work is
 refused on a TPU, run_scf reports truthfully where each stage ran, and the
 compile-cache helper never sets a directory when the environment does."""
 
+import functools
 import os
 from types import SimpleNamespace
 
@@ -41,7 +42,9 @@ def _step_jaxpr(fused, ctx):
     pot0 = SimpleNamespace(veff_g=np.zeros(fused.ng, np.complex128), bz_g=None)
     carry = fused.init_carry(np.zeros(fused.nx, np.complex128), pot0)
     z = lambda *shape: jnp.zeros(shape, rdt)
-    return jax.make_jaxpr(fused._step_impl)(
+    from sirius_tpu.dft.fused import _step_impl
+
+    return jax.make_jaxpr(functools.partial(_step_impl, fused.constants))(
         fused.tables, carry, z(1, *fused.dims_coarse), z(1, nbeta, nbeta),
         z(1, nbeta, nbeta), z(nk, 1, nb), z(nk, 1, nb), z(),
         z(nk, 1, nb, ngk), z(nk, 1, nb, ngk))

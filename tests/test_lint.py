@@ -646,6 +646,64 @@ def test_cache_key_trace_constant_complete_signature_ok(tmp_path):
     assert found == []
 
 
+KEYED_PARTIAL = JIT_HEADER + """
+    from functools import partial
+
+    _programs = {{}}
+
+    def _impl(rec, flavor, x):
+        return x * rec.scale + flavor
+
+    def program(rec, flavor):
+        step = _programs.get({key})
+        if step is None:
+            bound = partial(_impl, rec, flavor)
+            step = jax.jit(bound)
+            _programs[{key}] = step
+        return step
+    """
+
+
+@pytest.mark.parametrize("key, missing", [
+    ("rec", ["flavor"]),
+    ("(rec, flavor)", []),
+])
+def test_cache_key_trace_constant_partial_bound(tmp_path, key, missing):
+    """The rule's other subject since the fused step reads no self: what a
+    partial binds into a jitted impl has to be in the key of the table the
+    wrapper is kept in (dft/fused.step_program keys its one record)."""
+    eng, found = lint(
+        tmp_path, {"sirius_tpu/tab.py": KEYED_PARTIAL.format(key=key)},
+        rules=[compilerules.CacheKeyTraceConstant])
+    assert names(found) == ["cache-key-trace-constant"] * len(missing)
+    for f, m in zip(found, missing):
+        assert f"`{m}` bound into jitted `_impl`" in f.message
+    # the impl behind the local partial is a jit seed all the same
+    assert ("sirius_tpu.tab", "_impl") in eng.project.jit_reachable()
+
+
+def test_step_program_is_seen_through(tmp_path):
+    """The tree's own table of fused steps: the analysis finds _step_impl
+    behind the named partial, takes FusedScf._step for the jit binding it
+    is (so step() returns device values and its donated carry is watched),
+    and the table's key holds everything the partial binds."""
+    from sirius_tpu.analysis.dataflow import DeviceModel
+
+    path = os.path.join(REPO, "sirius_tpu", "dft", "fused.py")
+    eng = LintEngine(REPO, [path], rules=[compilerules.CacheKeyTraceConstant])
+    assert eng.run() == []
+    project = eng.project
+    assert ("sirius_tpu.dft.fused", "_step_impl") in project.jit_reachable()
+    (place, kwargs), = [
+        v for k, v in project.jit_factories().items()
+        if k == ("sirius_tpu.dft.fused", "step_program")]
+    assert place == 0 and "donate_argnums" in kwargs
+    model = DeviceModel.of(project)
+    assert model.jit_attrs[("sirius_tpu.dft.fused", "FusedScf")] == {"_step"}
+    step = project.modules["sirius_tpu.dft.fused"].functions["FusedScf.step"]
+    assert model.return_origins[step.key] == frozenset({"dev"})
+
+
 # ------------------------------------------------- transfer-budget rules
 
 
