@@ -150,6 +150,9 @@ class _Booked:
     subspace eigenproblems and the steps (davidson.count_solve)."""
 
     rows_per_box = 1
+    # complex Hermitian subspace matrices: every solver but the packed-real
+    # Gamma one and a k-set on its real subspace
+    complex_subspace = True
 
     def _rule(self, itsol):
         # the most steps a solve takes, and what res_tol bars: a step's
@@ -176,7 +179,8 @@ class _Booked:
         unbooked, self._unbooked = self._unbooked, []
         for ran, copies in jax.device_get(unbooked):
             count_solve(counters, ran, self.ctx.num_bands, copies=copies,
-                        rows_per_box=self.rows_per_box)
+                        rows_per_box=self.rows_per_box,
+                        complex_subspace=self.complex_subspace)
 
 
 def _host_evals(ctx, ev_by_spin):
@@ -185,6 +189,14 @@ def _host_evals(ctx, ev_by_spin):
     for ispn, ev in enumerate(ev_by_spin):
         evals[0, ispn] = np.asarray(ev)
     return evals
+
+
+def generic_kpoints(kpoints) -> np.ndarray:
+    """[nk] bool: the k-points (fractional) that are not their own -k, 2k
+    being no reciprocal lattice vector; one of them in a set makes the
+    set's subspace complex (time_reversal_index)."""
+    two_k = 2.0 * np.asarray(kpoints, dtype=np.float64).reshape(-1, 3)
+    return np.abs(two_k - np.rint(two_k)).max(axis=1) > 1e-9
 
 
 def time_reversal_index(gkvec) -> np.ndarray | None:
@@ -196,10 +208,10 @@ def time_reversal_index(gkvec) -> np.ndarray | None:
     commutes with H and S for a real local potential and real D and Q; a
     block of Theta-real rows has real subspace matrices
     (solvers/davidson.py, REAL SUBSPACE). Padding slots map to themselves."""
-    two_k = 2.0 * np.asarray(gkvec.kpoints, dtype=np.float64)
-    shift = np.rint(two_k).astype(np.int64)
-    if np.abs(two_k - shift).max() > 1e-9:
+    kpoints = np.asarray(gkvec.kpoints, dtype=np.float64)
+    if generic_kpoints(kpoints).any():
         return None
+    shift = np.rint(2.0 * kpoints).astype(np.int64)
     nk, ngk = gkvec.mask.shape
     out = np.tile(np.arange(ngk), (nk, 1))
     for ik in range(nk):
@@ -269,6 +281,7 @@ class KsetSolver(_Booked):
                    or ctx.gkvec.num_kpoints == 1
                    else time_reversal_index(ctx.gkvec))
         self._tr_dev = None
+        self.complex_subspace = self.tr is None
         if mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -306,9 +319,14 @@ class KsetSolver(_Booked):
         shard of the [nk, ns, nb, ngk] block a device is given: under the
         vmap over k the k-points of a spin channel go through together,
         rows on the minor axis (``local_layout``: the one form the k-set
-        programs have, ops/local.py)."""
+        programs have, ops/local.py). ``generic_kpoints``: the k-points
+        solved that are not their own -k (one makes ``real_subspace``
+        false); ``weights``: the distinct k-weights in units of the
+        smallest share, one point of the mesh (or of an explicit list)."""
         ctx = self.ctx
         nk, nb = ctx.gkvec.num_kpoints, ctx.num_bands
+        p = ctx.cfg.parameters
+        mesh_points = len(p.vk) or int(np.prod(p.ngridk))
         ndev = 1 if self.mesh is None else self.mesh.size
         rows = nk * ctx.num_spins * 2 * nb
         block = (nk, ctx.num_spins, nb, int(ctx.gkvec.ngk_max))
@@ -319,6 +337,9 @@ class KsetSolver(_Booked):
         return {"kset": {
             "nk": nk, "ngk_max": int(ctx.gkvec.ngk_max),
             "subspace_rows": 3 * nb, "real_subspace": self.tr is not None,
+            "generic_kpoints": int(generic_kpoints(ctx.gkvec.kpoints).sum()),
+            "weights": sorted({int(round(float(w) * mesh_points))
+                               for w in ctx.kweights}),
             "workspace_bytes": rows * int(np.prod(ctx.fft_coarse.dims))
             * np.dtype(wf_dtype).itemsize // ndev,
             "local_rows": [local_rows, 2 * local_rows],
@@ -527,6 +548,7 @@ class GammaSolver(_Booked):
     vectors make the solver's GEMMs/eigh real, two bands share a box."""
 
     name = "gamma"
+    complex_subspace = False  # packed-real vectors: real symmetric matrices
     name_fused = "gamma"  # one word under both tails (the parent's record)
     feeds_fused = True
     gshard_devices = 0

@@ -1753,6 +1753,8 @@ def _run_scf_inner(
         num_loc_op_applied=int(counters["num_loc_op_applied"]),
         num_fft_boxes=int(counters["num_fft_boxes"]),
         num_subspace_eigh=int(counters["num_subspace_eigh"]),
+        num_complex_subspace_eigh=int(
+            counters["num_complex_subspace_eigh"]),
         num_davidson_steps=int(counters["num_davidson_steps"]),
         num_tail_box_fills=int(counters["num_tail_box_fills"]),
         num_sym_pw=int(counters["num_sym_pw"]),

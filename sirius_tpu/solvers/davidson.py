@@ -163,20 +163,28 @@ def count_applies(counters, blocks, copies: int = 1, rows_per_box: int = 1,
 
 
 def count_solve(counters, ran, nb: int, copies: int = 1,
-                rows_per_box: int = 1, components: int = 1) -> None:
+                rows_per_box: int = 1, components: int = 1,
+                complex_subspace: bool = True) -> None:
     """Book one band solve of a set from what its loops ran: ``ran`` is the
     fetched (steps, chunks) of every loop of the solve (an array [..., 2] or
     a list of them; a k-set's rows are a k-point each), ``copies`` the
     lanes behind a row. H applications and boxes through count_applies,
     num_subspace_eigh, and num_davidson_steps: the steps of the solve's
-    longest loop."""
+    longest loop. ``complex_subspace``: the subspace matrices are complex
+    Hermitian (every solve but the packed-real Gamma one and a k-set's real
+    subspace), so each eigenproblem is also one of
+    num_complex_subspace_eigh: those a program lowered for the TPU puts
+    through the reduction (subspace_eigh.form ``tridiagonal_real``); an
+    explicit 0 on a real subspace."""
     import numpy as np
 
     rows = np.asarray(ran).reshape(-1, 2)
     for steps, chunks in rows.tolist():
         count_applies(counters, apply_blocks(steps, chunks, nb), copies=copies,
                       rows_per_box=rows_per_box, components=components)
-        counters["num_subspace_eigh"] += copies * num_eigh(steps)
+        solved = copies * num_eigh(steps)
+        counters["num_subspace_eigh"] += solved
+        counters["num_complex_subspace_eigh"] += solved * complex_subspace
     counters["num_davidson_steps"] += int(rows[:, 0].max())
 
 
