@@ -12,7 +12,8 @@ same questions:
   re-cast on a precision switch and the H-application counters are inside;
 - ``density_acc(occ_w)``: the coarse-box density accumulator off the
   solver's own storage, or None: the tail then uses
-  ``generate_density_g(ctx, host_psi())``;
+  ``generate_density_g(ctx, host_psi())``; ``density_route()``: what the
+  loop books of it (counters.num_density_rows, the scf.density span);
 - ``book()``: the counters of the solves since the last call, from the steps
   and chunks each ran (device integers until then: the loop calls it where
   it fetches anyway, at its end);
@@ -175,6 +176,13 @@ class _Booked:
         return band_solve_cost(self.ctx.num_bands, ngk, nbeta, box, ran,
                                copies=copies)
 
+    def density_route(self) -> tuple[int, dict]:
+        """(band rows one density_acc carries sphere -> box through the
+        cube's products: counters.num_density_rows, the fields of its
+        scf.density span); a solver that does not take that route books an
+        explicit 0 and no field."""
+        return 0, {}
+
     def book(self):
         unbooked, self._unbooked = self._unbooked, []
         for ran, copies in jax.device_get(unbooked):
@@ -308,6 +316,15 @@ class KsetSolver(_Booked):
                 self._tr_dev = up(tr, self.dev)
         return self._tr_dev
 
+    def _block_shard(self):
+        """The shape of one device's shard of the [nk, ns, nb, ngk] block."""
+        ctx = self.ctx
+        block = (ctx.gkvec.num_kpoints, ctx.num_spins, ctx.num_bands,
+                 int(ctx.gkvec.ngk_max))
+        if self.mesh is not None:
+            block = self._psi_sharding.shard_shape(block)
+        return block
+
     def plan(self, wf_dtype) -> dict:
         """Fields of the ``scf.setup`` span: the size of the one program.
         The whole (k, spin) set goes through one vmap with no chunk over k,
@@ -329,9 +346,7 @@ class KsetSolver(_Booked):
         mesh_points = len(p.vk) or int(np.prod(p.ngridk))
         ndev = 1 if self.mesh is None else self.mesh.size
         rows = nk * ctx.num_spins * 2 * nb
-        block = (nk, ctx.num_spins, nb, int(ctx.gkvec.ngk_max))
-        if self.mesh is not None:
-            block = self._psi_sharding.shard_shape(block)
+        block = self._block_shard()
         local_rows = block[0] * block[2]
         sub_dtype = real_dtype_of(wf_dtype) if self.tr is not None else wf_dtype
         return {"kset": {
@@ -492,7 +507,16 @@ class KsetSolver(_Booked):
     def density_acc(self, occ_w):
         from sirius_tpu.parallel.batched import density_kset
 
-        return density_kset(self.ps, self.pr, self.pi, occ_w)
+        return density_kset(self.ps, self.pr, self.pi, occ_w, mesh=self.mesh)
+
+    def density_route(self):
+        # every row of the set goes through the one form density_kset has;
+        # ``rows``: what one call carries on one device, off its shard
+        ctx = self.ctx
+        nk, ns, nb, _ = self._block_shard()
+        return (ctx.gkvec.num_kpoints * ctx.num_spins * ctx.num_bands,
+                {"form": "rows_minor", "rows": nk * ns * nb,
+                 "cube": list(self.ps.cube.shape[1:])})
 
     def tau_acc(self, occ_w):
         """Coarse-box kinetic-energy density of the block (mGGA)."""

@@ -523,6 +523,14 @@ def _run_scf_inner(
             stage, flops=c.flops if c else 0.0,
             bytes=c.bytes if c else 0.0, **attrs)
 
+    def _density_stage(it):
+        # the scf.density span, with what the solver's density_acc carries
+        # through the cube's products booked beside it (0 and no field for
+        # a solver off that route)
+        rows, route = band.density_route()
+        counters["num_density_rows"] += rows
+        return _stage("scf.density", it=it + 1, **route)
+
     _it_span = None
 
     def _close_iteration():
@@ -1137,7 +1145,7 @@ def _run_scf_inner(
                 if _span_fence:
                     _fence(occ_w)
                 _sp.close()
-                _sp = _stage("scf.density", it=it + 1)
+                _sp = _density_stage(it)
                 from sirius_tpu.parallel.batched import density_matrix_kset
 
                 acc = band.density_acc(occ_w)
@@ -1329,7 +1337,7 @@ def _run_scf_inner(
             )
 
         # --- density (per spin, then charge/magnetization assembly) ---
-        _sp = _stage("scf.density", it=it + 1)
+        _sp = _density_stage(it)
         occ_w = jnp.asarray(occ_np * ctx.kweights[:, None, None])
         with profile("scf::density"):
             from sirius_tpu.dft.density import density_from_coarse_acc
@@ -1757,6 +1765,7 @@ def _run_scf_inner(
             counters["num_complex_subspace_eigh"]),
         num_davidson_steps=int(counters["num_davidson_steps"]),
         num_tail_box_fills=int(counters["num_tail_box_fills"]),
+        num_density_rows=int(counters["num_density_rows"]),
         num_sym_pw=int(counters["num_sym_pw"]),
         num_xc_gradient_transforms=int(
             counters["num_xc_gradient_transforms"]),

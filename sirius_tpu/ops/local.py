@@ -35,7 +35,9 @@ table is refused. A potential per slice of the mapped axis (the spin
 channels) is a loop over the slices, so that each folds by itself under the
 vmap over k outside it. Called on one block, nothing is batched, the table
 is not read (ops/hamiltonian.make_hk_params leaves it out) and the lines are
-the ones apply_h_s always ran.
+the ones apply_h_s always ran. The k-set density is the inverse half alone
+(``rows_to_box``, then the weighted squares summed over the rows:
+parallel/batched.density_kset, PR 42).
 """
 
 from __future__ import annotations
@@ -132,6 +134,29 @@ def _dft_pass(w, xr, xi, axis: int):
     return tr[0] - ti[1], tr[1] + ti[0]
 
 
+def rows_to_box(psi, cube, dims, rdt):
+    """The inverse half of the round trip for a k-set: psi [nk, rows, ngk]
+    and cube [nk, m1, m2, m3] (``cube_inverse_map``) -> the (re, im) planes
+    [n1, n2, n3, nk x rows] of jnp.fft.ifftn of every row's box (its 1/n
+    with it), rows minor. A gather of whole lane rows through the cube's
+    map (an empty cell reads the row of zeros appended at ngk; padded
+    slots are never read), then cube -> box one axis a pass. The local
+    operator's first half and all of the k-set density's transform
+    (parallel/batched.density_kset)."""
+    nk, rows, _ = psi.shape
+    m = cube.shape[1:]
+    psi_t = jnp.concatenate(
+        [jnp.swapaxes(psi, 1, 2), jnp.zeros((nk, 1, rows), psi.dtype)],
+        axis=1)
+    x = jax.vmap(lambda p, i: p[i], out_axes=1)(psi_t, cube.reshape(nk, -1))
+    x = x.reshape(m + (nk * rows,))  # [m1, m2, m3, k x rows]
+    xr, xi = jnp.real(x), jnp.imag(x)
+    for axis in (2, 1, 0):
+        w = _dft_matrix(dims[axis], m[axis], True, rdt)
+        xr, xi = _dft_pass(w, xr, xi, axis)
+    return xr, xi
+
+
 def _round_trip_rows_minor(psi, fft_index, veff_r, cube):
     """The round trip for a k-set, psi [nk, rows, ngk], fft_index [nk, ngk]
     and cube [nk, m1, m2, m3] (``cube_inverse_map``) with one V(r), as one
@@ -142,15 +167,7 @@ def _round_trip_rows_minor(psi, fft_index, veff_r, cube):
     dims, m = veff_r.shape, cube.shape[1:]
     rdt = veff_r.dtype
     with jax.named_scope("local_op"):
-        psi_t = jnp.concatenate(
-            [jnp.swapaxes(psi, 1, 2), jnp.zeros((nk, 1, rows), psi.dtype)],
-            axis=1)
-        x = jax.vmap(lambda p, i: p[i], out_axes=1)(psi_t, cube.reshape(nk, -1))
-        x = x.reshape(m + (nk * rows,))  # [m1, m2, m3, k x rows]
-        xr, xi = jnp.real(x), jnp.imag(x)
-        for axis in (2, 1, 0):
-            w = _dft_matrix(dims[axis], m[axis], True, rdt)
-            xr, xi = _dft_pass(w, xr, xi, axis)
+        xr, xi = rows_to_box(psi, cube, dims, rdt)
         v = veff_r[..., None]
         xr, xi = xr * v, xi * v
         for axis in (0, 1, 2):

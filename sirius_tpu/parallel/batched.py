@@ -5,8 +5,8 @@ The reference loops local k-points serially per MPI rank
 (diagonalize.hpp:58); on TPU the padded fixed-shape per-k arrays (GkVec)
 make the entire k-set one solve (the solver's loops over stages vmapped over
 the set, solvers/davidson.py) — a single XLA program that
-shards over the mesh with zero hand-written collectives (density reduction
-over "k" is a psum XLA inserts from the einsum).
+shards over the mesh: each device runs its own k-points (over_k_pool), and
+the one collective is the psum over "k" that closes the density.
 
 REAL-BOUNDARY CONTRACT: every jitted entry point here takes and returns
 REAL arrays only; complex leaves of the parameter pytree are stored as
@@ -412,25 +412,39 @@ def _davidson_kset(params, psi_re, psi_im, res_tol, theta_index, num_steps,
             jnp.broadcast_to(ran, (ev.shape[0], 2)))
 
 
-@jax.jit
-def density_kset(params: HkSetParams, psi_re, psi_im, occ_w):
-    """Coarse-box density sum_{k,b} occ_w |psi(r)|^2 per spin — contracts
-    over the whole k-set in one program (psum over "k" under sharding).
+@partial(jax.jit, static_argnames=("mesh",))
+def density_kset(params: HkSetParams, psi_re, psi_im, occ_w, mesh=None):
+    """Coarse-box density sum_{k,b} occ_w |psi(r)|^2 per spin, over the
+    whole k-set in one program: every band row goes sphere -> cube -> box
+    by the local operator's inverse passes, k x (spin, band) rows on the
+    minor axis (ops/local.py, ROWS ON THE LANES), and the weighted squares
+    are summed over the rows. ``mesh``: the ("k", "b") mesh the operands are
+    sharded on (over_k_pool): each device carries its own k-points' rows
+    and the program closes with one psum over "k".
 
     occ_w: [nk, ns, nb] occupation x k-weight. Returns [ns, n1, n2, n3]
     (real)."""
-    psi = _cplx(psi_re, psi_im)
-    dims = params.veff_r.shape[-3:]
+    return over_k_pool(
+        partial(_density_kset, dims=params.veff_r.shape[-3:],
+                axis=None if mesh is None else "k"),
+        mesh, (_K, _K, _K, _K), _REP,
+    )(params.cube, psi_re, psi_im, occ_w)
+
+
+def _density_kset(cube, psi_re, psi_im, occ_w, dims, axis):
+    from sirius_tpu.ops.local import rows_to_box
+
+    nk, ns, nb, ngk = psi_re.shape
     n = dims[0] * dims[1] * dims[2]
-
-    def one_k(fft_index, psi_k, ow):
-        batch = psi_k.shape[:-1]
-        box = jnp.zeros(batch + (n,), dtype=psi_k.dtype).at[..., fft_index].add(psi_k)
-        fr = jnp.fft.ifftn(box.reshape(batch + dims), axes=(-3, -2, -1)) * n
-        return jnp.einsum("sb,sbxyz->sxyz", ow, jnp.abs(fr) ** 2)
-
     with jax.named_scope("density_kset"):  # the name in a capture's table
-        return jnp.sum(jax.vmap(one_k)(params.fft_index, psi, occ_w), axis=0)
+        xr, xi = rows_to_box(
+            _cplx(psi_re, psi_im).reshape(nk, ns * nb, ngk), cube, dims,
+            psi_re.dtype)
+        # the passes carry ifftn's 1/n: psi(r) is n times the planes
+        a = (xr * xr + xi * xi).reshape(dims + (nk, ns, nb))
+        rho = jnp.einsum("xyzksb,ksb->sxyz", a, occ_w * (float(n) * n),
+                         precision=jax.lax.Precision.HIGHEST)
+        return rho if axis is None else jax.lax.psum(rho, axis)
 
 
 @jax.jit

@@ -289,6 +289,19 @@ def test_gamma_fused_tail_one_chip(topo, no_compile_cache):
     _check(_compile(lambda: fused._step.lower(*args)), no_64bit=True)
 
 
+def _collectives(txt, kind):
+    """The lines of a compiled program that start a collective `kind`."""
+    return [ln for ln in txt.splitlines()
+            if f" {kind}(" in ln or f" {kind}-start(" in ln]
+
+
+def _cube_density(txt):
+    """density_kset goes sphere -> cube -> box by gathers and products
+    (ops/local.rows_to_box): no FFT and no scatter is left in it."""
+    ops = [ln for ln in txt.splitlines() if " fft(" in ln or " scatter(" in ln]
+    assert not ops, ops[:3]
+
+
 def test_kset_band_solve_one_chip(topo, no_compile_cache, ctx_kmesh):
     """The batched k-set solve (complex Hermitian eigh at 3*nb inside):
     reduced to real tridiagonal matrices, all the set's in each kernel call."""
@@ -305,8 +318,8 @@ def test_kset_band_solve_one_chip(topo, no_compile_cache, ctx_kmesh):
     _one_step_body(txt)
     assert set(_eigh_batches(txt)) == {ctx.gkvec.num_kpoints}
     occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=one)
-    _check(_compile(lambda: density_kset.lower(ps, psi, psi, occ)),
-           no_64bit=True)
+    _cube_density(_check(_compile(
+        lambda: density_kset.lower(ps, psi, psi, occ)), no_64bit=True))
 
 
 def test_kset_band_solve_real_subspace_one_chip(topo, no_compile_cache,
@@ -433,7 +446,7 @@ def test_kb_mesh_step_four_chips(topo, no_compile_cache, ctx_kmesh):
     assert set(_eigh_batches(txt)) == {ctx444.gkvec.num_kpoints // 4} == {9}
     for kind in ("all-gather", "all-reduce", "all-to-all",
                  "collective-permute", "reduce-scatter"):
-        assert f" {kind}(" not in txt and f" {kind}-start(" not in txt, kind
+        assert not _collectives(txt, kind), kind
     # ... and with real subspace matrices, the index sharded over "k" as
     # band_solve.KsetSolver places it (this mesh is all of them invariant)
     from sirius_tpu.dft.band_solve import time_reversal_index
@@ -445,9 +458,15 @@ def test_kb_mesh_step_four_chips(topo, no_compile_cache, ctx_kmesh):
         mesh=mesh)), no_64bit=True)
     assert set(_eigh_batches(txt)) == {ctx.gkvec.num_kpoints // 4}
     occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=ev_sh)
-    txt = _check(_compile(lambda: density_kset.lower(ps, psi, psi, occ)),
-                 no_64bit=True)
-    assert "all-reduce" in txt  # sum over the k-sharded axis
+    txt = _check(_compile(lambda: density_kset.lower(
+        ps, psi, psi, occ, mesh=mesh)), no_64bit=True)
+    _cube_density(txt)
+    # each chip's k-points go through the cube by themselves (over_k_pool);
+    # the sum over the k-sharded axis is the program's one collective
+    assert len(_collectives(txt, "all-reduce")) == 1
+    for kind in ("all-gather", "all-to-all", "collective-permute",
+                 "reduce-scatter"):
+        assert not _collectives(txt, kind), kind
     fused = _fused(ctx)
     args = _fused_args(fused, ctx, nb, rep, psi_sh, ev_sh)
     _check(_compile(lambda: fused._step.lower(*args)), no_64bit=True)
