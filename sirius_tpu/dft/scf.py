@@ -392,7 +392,13 @@ def _run_scf_inner(
     tau_g = (
         np.zeros((ns, ctx.gvec.num_gvec), dtype=np.complex128) if mgga else None
     )
-    pot = generate_potential(ctx, rho_g, xc, mag_g, tau_g=tau_g)
+    # the start potential: f64 on the host like the reported energy's
+    # (scf.finalize.potential), its XC the process's compiled program
+    # (dft/xc._host_xc; counters.num_host_xc_traces counts new ones)
+    counters["num_host_xc_traces"] += 0
+    with obs_spans.span("scf.setup.potential",
+                        xc="gga" if xc.is_gga else "lda", host_xc="compiled"):
+        pot = generate_potential(ctx, rho_g, xc, mag_g, tau_g=tau_g)
     om_size = 0 if hub is None else ns * hub.num_hub_total * hub.num_hub_total
     nl_sizes = [] if hub is None else [
         ns * (2 * e["il"] + 1) * (2 * e["jl"] + 1) for e in hub.nonloc
@@ -1660,9 +1666,11 @@ def _run_scf_inner(
         rho_resid_g = fin["rho_resid_g"]
         dm_blocks_by_spin = fin["dm_blocks_by_spin"]
         # the reported energy's potential: f64 on the host, with a gradient
-        # correction its seven more transforms and the autodiff on the CPU
+        # correction its seven more transforms; the functional and its
+        # derivatives are one compiled program on the CPU (dft/xc._host_xc)
         with profile("scf::potential"), obs_spans.span(
-                "scf.finalize.potential", xc=fused.xc_kind):
+                "scf.finalize.potential", xc=fused.xc_kind,
+                host_xc="compiled"):
             pot = generate_potential(ctx, rho_g, xc, mag_g)
     psi = band.host_psi()
     if psi is None:
@@ -1770,6 +1778,7 @@ def _run_scf_inner(
         num_xc_gradient_transforms=int(
             counters["num_xc_gradient_transforms"]),
         num_fused_step_traces=int(counters["num_fused_step_traces"]),
+        num_host_xc_traces=int(counters["num_host_xc_traces"]),
         energy_resolution_ha=abs(e_total) * pair_eps(
             fused.rdt if fused is not None else np.float64),
     )

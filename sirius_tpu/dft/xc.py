@@ -17,13 +17,19 @@ convention.
 evaluate()/evaluate_polarized() are traced inside the fused device-resident
 SCF step (dft/fused.py) in addition to the host path: they must stay pure
 jnp on traced inputs — no numpy coercion, python branching on data, or host
-callbacks.
+callbacks. Handed a tracer they emit the lines of _eval into the caller's
+program; handed concrete arrays (every host caller) they run _host_xc, one
+compiled program of the process.
 """
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
+
+from sirius_tpu.utils.profiler import counters
 
 _TINY = 1e-25
 # vacuum threshold for a spin channel (libxc dens_threshold analog)
@@ -425,49 +431,40 @@ class XCFunctional:
         return e
 
     def _eval(self, nu, nd, suu, sud, sdd, tu, td):
-        # libxc-style density threshold: a spin channel below _DENS_TH is
-        # vacuum. The clip in the caller can produce EXACTLY zero channels
-        # (fully polarized points, m = -rho); autodiff of the GGA chain at
-        # n = 0 with finite sigma yields inf * 0 = NaN in v/vsigma even
-        # though the energy itself is finite (observed: test30 NiO FM mid-
-        # SCF). Inputs are sanitized BEFORE the grad (the double-where
-        # pattern) and dead-channel outputs masked to zero, which is what
-        # libxc's dens_threshold does.
-        th = _DENS_TH
-        up0 = nu < th
-        dn0 = nd < th
-        nu_s = jnp.where(up0, th, nu)
-        nd_s = jnp.where(dn0, th, nd)
-        suu_s = jnp.where(up0, 0.0, suu)
-        sud_s = jnp.where(up0 | dn0, 0.0, sud)
-        sdd_s = jnp.where(dn0, 0.0, sdd)
+        """The traced form (inside a device program): all seven derivatives,
+        the energy density by a second pass. Its operations and their order
+        are the fused step's compiled program: leave them as they are."""
+        up0, dn0, clean = _sanitized(nu, nd, suu, sud, sdd)
         grads = jax.grad(
             lambda a, b, c, d, f, g, h: jnp.sum(
                 self._energy(a, b, c, d, f, g, h)
             ),
             argnums=(0, 1, 2, 3, 4, 5, 6),
         )
-        vu, vd, vsuu, vsud, vsdd, vtu, vtd = grads(
-            nu_s, nd_s, suu_s, sud_s, sdd_s, tu, td
-        )
-        vu = jnp.where(up0, 0.0, vu)
-        vd = jnp.where(dn0, 0.0, vd)
-        vsuu = jnp.where(up0, 0.0, vsuu)
-        vsud = jnp.where(up0 | dn0, 0.0, vsud)
-        vsdd = jnp.where(dn0, 0.0, vsdd)
-        # de/dtau diverges as n^{-2/3} at the sanitized point n = th — a
-        # dead channel must get vtau = 0 too (libxc dens_threshold)
-        vtu = jnp.where(up0, 0.0, vtu)
-        vtd = jnp.where(dn0, 0.0, vtd)
-        return (
-            self._energy(nu_s, nd_s, suu_s, sud_s, sdd_s, tu, td),
-            vu, vd, vsuu, vsud, vsdd, vtu, vtd,
-        )
+        masked = _mask_dead(up0, dn0, grads(*clean, tu, td))
+        return (self._energy(*clean, tu, td), *masked)
 
-    def evaluate_polarized(self, rho_up, rho_dn, sigma_uu=None, sigma_ud=None,
-                           sigma_dd=None, tau_up=None, tau_dn=None):
+    def _eval_once(self, nu, nd, suu, sud, sdd, tu, td):
+        """The host form (under _host_xc's jit): energy density and
+        derivatives from one pass, with respect to the arguments the class
+        reads; the derivatives it does not have are None."""
+        up0, dn0, clean = _sanitized(nu, nd, suu, sud, sdd)
+
+        def total(*args):
+            e = self._energy(*args)
+            return jnp.sum(e), e
+
+        nargs = 7 if self.is_mgga else 5 if self.is_gga else 2
+        (_, e), grads = jax.value_and_grad(
+            total, argnums=tuple(range(nargs)), has_aux=True
+        )(*clean, tu, td)
+        masked = _mask_dead(up0, dn0, grads)
+        return (e, *masked, *(None,) * (7 - nargs))
+
+    def _polarized(self, eval_fn, rho_up, rho_dn, sigma_uu=None,
+                   sigma_ud=None, sigma_dd=None, tau_up=None, tau_dn=None):
         z = jnp.zeros_like(rho_up)
-        e, vu, vd, vsuu, vsud, vsdd, vtu, vtd = self._eval(
+        e, vu, vd, vsuu, vsud, vsdd, vtu, vtd = eval_fn(
             rho_up, rho_dn,
             z if sigma_uu is None else sigma_uu,
             z if sigma_ud is None else sigma_ud,
@@ -482,15 +479,12 @@ class XCFunctional:
             out.update(vtau_up=vtu, vtau_dn=vtd)
         return out
 
-    def evaluate(self, rho, sigma=None, tau=None):
-        """Unpolarized: rho is the total density, sigma = |grad rho|^2,
-        tau the total positive KS kinetic-energy density. Returns e (per
-        volume), v = de/drho, vsigma = de/dsigma, vtau = de/dtau."""
+    def _unpolarized(self, eval_fn, rho, sigma=None, tau=None):
         half = 0.5 * rho
         z = jnp.zeros_like(rho)
         s4 = z if sigma is None else 0.25 * sigma
         t2 = z if tau is None else 0.5 * tau
-        e, vu, vd, vsuu, vsud, vsdd, vtu, vtd = self._eval(
+        e, vu, vd, vsuu, vsud, vsdd, vtu, vtd = eval_fn(
             half, half, s4, s4, s4, t2, t2
         )
         out = {"e": e, "v": 0.5 * (vu + vd)}
@@ -499,3 +493,70 @@ class XCFunctional:
         if self.is_mgga:
             out["vtau"] = 0.5 * (vtu + vtd)
         return out
+
+    def evaluate_polarized(self, rho_up, rho_dn, sigma_uu=None, sigma_ud=None,
+                           sigma_dd=None, tau_up=None, tau_dn=None):
+        args = (rho_up, rho_dn, sigma_uu, sigma_ud, sigma_dd, tau_up, tau_dn)
+        if _traced(args):
+            return self._polarized(self._eval, *args)
+        return _host_xc(tuple(self.names), True, args)
+
+    def evaluate(self, rho, sigma=None, tau=None):
+        """Unpolarized: rho is the total density, sigma = |grad rho|^2,
+        tau the total positive KS kinetic-energy density. Returns e (per
+        volume), v = de/drho, vsigma = de/dsigma, vtau = de/dtau."""
+        args = (rho, sigma, tau)
+        if _traced(args):
+            return self._unpolarized(self._eval, *args)
+        return _host_xc(tuple(self.names), False, args)
+
+
+def _sanitized(nu, nd, suu, sud, sdd):
+    """libxc-style density threshold: a spin channel below _DENS_TH is
+    vacuum. The clip in the caller can produce EXACTLY zero channels (fully
+    polarized points, m = -rho); autodiff of the GGA chain at n = 0 with
+    finite sigma yields inf * 0 = NaN in v/vsigma even though the energy
+    itself is finite (observed: test30 NiO FM mid-SCF). Inputs are sanitized
+    BEFORE the grad (the double-where pattern) and dead-channel outputs
+    masked to zero (_mask_dead), which is what libxc's dens_threshold does.
+    Returns the two dead-channel masks and the five sanitized inputs."""
+    th = _DENS_TH
+    up0 = nu < th
+    dn0 = nd < th
+    return up0, dn0, (
+        jnp.where(up0, th, nu),
+        jnp.where(dn0, th, nd),
+        jnp.where(up0, 0.0, suu),
+        jnp.where(up0 | dn0, 0.0, sud),
+        jnp.where(dn0, 0.0, sdd),
+    )
+
+
+def _mask_dead(up0, dn0, grads):
+    """Zero the derivatives (v_up, v_dn, vsigma_uu, vsigma_ud, vsigma_dd,
+    vtau_up, vtau_dn, or the first few of them) of a dead channel. de/dtau
+    diverges as n^{-2/3} at the sanitized point n = th: a dead channel must
+    get vtau = 0 too (libxc dens_threshold)."""
+    dead = (up0, dn0, up0, None, dn0, up0, dn0)  # None: either channel
+    return tuple(
+        jnp.where(up0 | dn0 if d is None else d, 0.0, g)
+        for d, g in zip(dead, grads)
+    )
+
+
+def _traced(args) -> bool:
+    return any(isinstance(a, jax.core.Tracer) for a in args)
+
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _host_xc(names: tuple, polarized: bool, args: tuple):
+    """evaluate / evaluate_polarized of concrete arrays as one compiled
+    program where the arrays live (the CPU backend in float64 under
+    runtime.host_scope()): a program of the process for the functional's
+    names, the spin treatment, which inputs are there, their length and
+    dtype, whichever XCFunctional of whichever job asks."""
+    # runs where JAX traces the body: the asking job's count of new programs
+    counters["num_host_xc_traces"] += 1
+    xc = XCFunctional(list(names))
+    wrap = xc._polarized if polarized else xc._unpolarized
+    return wrap(xc._eval_once, *args)

@@ -62,6 +62,8 @@ UNCOSTED_SPANS = (
     # inside a context build, the group search with the mesh's wedge
     "scf.setup.symmetry",
     "context.symmetry",
+    # the two host potentials of a job (dft/potential.generate_potential)
+    "scf.setup.potential",
     "scf.finalize",
     "scf.finalize.potential",
     "scf.autosave",
