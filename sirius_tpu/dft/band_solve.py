@@ -152,7 +152,7 @@ class _Booked:
 
     rows_per_box = 1
     # complex Hermitian subspace matrices: every solver but the packed-real
-    # Gamma one and a k-set on its real subspace
+    # Gamma one
     complex_subspace = True
 
     def _rule(self, itsol):
@@ -201,74 +201,20 @@ def _host_evals(ctx, ev_by_spin):
 
 def generic_kpoints(kpoints) -> np.ndarray:
     """[nk] bool: the k-points (fractional) that are not their own -k, 2k
-    being no reciprocal lattice vector; one of them in a set makes the
-    set's subspace complex (time_reversal_index)."""
+    being no reciprocal lattice vector (the ``kset.generic_kpoints`` field
+    of the ``scf.setup`` span)."""
     two_k = 2.0 * np.asarray(kpoints, dtype=np.float64).reshape(-1, 3)
     return np.abs(two_k - np.rint(two_k)).max(axis=1) > 1e-9
-
-
-def time_reversal_index(gkvec) -> np.ndarray | None:
-    """[nk, ngk]: for every plane-wave slot G + k the slot of -G - 2k, where
-    every k-point of the set is time-reversal invariant (2k a reciprocal
-    lattice vector: Gamma and the zone-boundary points, e.g. all of a
-    Gamma-centred 2x2x2 mesh); None where one k-point is not. With it
-    Theta x (G) = conj(x(-G - 2k)) is complex conjugation of psi(r), which
-    commutes with H and S for a real local potential and real D and Q; a
-    block of Theta-real rows has real subspace matrices
-    (solvers/davidson.py, REAL SUBSPACE). Padding slots map to themselves."""
-    kpoints = np.asarray(gkvec.kpoints, dtype=np.float64)
-    if generic_kpoints(kpoints).any():
-        return None
-    shift = np.rint(2.0 * kpoints).astype(np.int64)
-    nk, ngk = gkvec.mask.shape
-    out = np.tile(np.arange(ngk), (nk, 1))
-    for ik in range(nk):
-        n = int(np.sum(np.asarray(gkvec.mask[ik]) > 0))  # valid slots lead
-        m = np.asarray(gkvec.millers[ik, :n], dtype=np.int64)
-        off = int(np.abs(m).max(initial=0)) + int(np.abs(shift[ik]).max()) + 1
-        base = 2 * off + 1
-
-        def key(v):
-            v = v + off
-            return (v[:, 0] * base + v[:, 1]) * base + v[:, 2]
-
-        order = np.argsort(key(m))
-        have, want = key(m)[order], key(-m - shift[ik])
-        pos = np.clip(np.searchsorted(have, want), 0, max(n - 1, 0))
-        if n and not np.array_equal(have[pos], want):
-            return None  # a sphere that is not its own mirror image
-        out[ik, :n] = order[pos]
-    return out
-
-
-def _theta(x, tr):
-    """Theta x of a block [nk, ns, n, ngk] (host numpy)."""
-    return np.conj(np.take_along_axis(x, tr[:, None, None, :], axis=-1))
-
-
-def theta_real_block(x, tr):
-    """The block with each row replaced by a Theta-real one that spans the
-    same complex line where the row was Theta-real or Theta-imaginary (an
-    atomic orbital) and is as good a trial vector where it was neither (the
-    random tail): (x + Theta x) / 2, or i (x - Theta x) / 2 where that is
-    the larger of the two."""
-    tx = _theta(x, tr)
-    plus, minus = 0.5 * (x + tx), 0.5j * (x - tx)
-    norm2 = lambda a: np.sum(np.abs(a) ** 2, axis=-1, keepdims=True)
-    return np.where(norm2(plus) >= norm2(minus), plus, minus)
 
 
 class KsetSolver(_Booked):
     """Production path: the whole (k, spin) set as ONE program
     (parallel/batched.py; shards over the ("k", "b") mesh). Real-boundary:
     psi crosses the jit boundary as a (re, im) pair and stays device-
-    resident between iterations. Where the set has several k-points, every
-    one time-reversal invariant, and the operator commutes with conjugation
-    (no Hubbard V with its k-phases, no mGGA operator), every block that
-    enters is made Theta-real and the subspace eigenproblems are real
-    (``tr``): one program a deck, whatever the block came from. Gamma alone
+    resident between iterations. The subspace eigenproblems are complex
+    Hermitian whatever the k-points are: one program a shape. Gamma alone
     has GammaSolver; where that is refused (several devices, reduce_gvec
-    off) the deck keeps the complex program it had."""
+    off) the deck runs this program too."""
 
     name = "batched"
     name_fused = "batched+fused"  # the result's path word under the fused tail
@@ -284,12 +230,6 @@ class KsetSolver(_Booked):
         self._gkc: dict = {}
         self.ps = self.rdt = None
         self.psi = self.psi_big = self.pr = self.pi = None
-        # the slot of -G - 2k where the solve runs on the real subspace
-        self.tr = (None if hub is not None or mgga
-                   or ctx.gkvec.num_kpoints == 1
-                   else time_reversal_index(ctx.gkvec))
-        self._tr_dev = None
-        self.complex_subspace = self.tr is None
         if mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -299,22 +239,6 @@ class KsetSolver(_Booked):
         if self.mesh is not None:
             return jax.device_put(x, self._psi_sharding)
         return up(x, self.dev)
-
-    def _theta_index(self):
-        """The device copy of ``tr`` (sharded over "k" on a mesh); None
-        where the solve is the complex one."""
-        if self.tr is None:
-            return None
-        if self._tr_dev is None:
-            tr = self.tr.astype(np.int32)
-            if self.mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec
-
-                self._tr_dev = jax.device_put(
-                    tr, NamedSharding(self.mesh, PartitionSpec("k", None)))
-            else:
-                self._tr_dev = up(tr, self.dev)
-        return self._tr_dev
 
     def _block_shard(self):
         """The shape of one device's shard of the [nk, ns, nb, ngk] block."""
@@ -337,9 +261,9 @@ class KsetSolver(_Booked):
         vmap over k the k-points of a spin channel go through together,
         rows on the minor axis (``local_layout``: the one form the k-set
         programs have, ops/local.py). ``generic_kpoints``: the k-points
-        solved that are not their own -k (one makes ``real_subspace``
-        false); ``weights``: the distinct k-weights in units of the
-        smallest share, one point of the mesh (or of an explicit list)."""
+        solved that are not their own -k; ``weights``: the distinct
+        k-weights in units of the smallest share, one point of the mesh (or
+        of an explicit list)."""
         ctx = self.ctx
         nk, nb = ctx.gkvec.num_kpoints, ctx.num_bands
         p = ctx.cfg.parameters
@@ -348,10 +272,9 @@ class KsetSolver(_Booked):
         rows = nk * ctx.num_spins * 2 * nb
         block = self._block_shard()
         local_rows = block[0] * block[2]
-        sub_dtype = real_dtype_of(wf_dtype) if self.tr is not None else wf_dtype
         return {"kset": {
             "nk": nk, "ngk_max": int(ctx.gkvec.ngk_max),
-            "subspace_rows": 3 * nb, "real_subspace": self.tr is not None,
+            "subspace_rows": 3 * nb,
             "generic_kpoints": int(generic_kpoints(ctx.gkvec.kpoints).sum()),
             "weights": sorted({int(round(float(w) * mesh_points))
                                for w in ctx.kweights}),
@@ -363,7 +286,7 @@ class KsetSolver(_Booked):
             # program's own choice comes from, and what one call carries on
             # one device (its k-points x spin channels)
             "subspace_eigh": {
-                "form": subspace_eigh.form(sub_dtype, self.dev.platform),
+                "form": subspace_eigh.form(wf_dtype, self.dev.platform),
                 "rows": 3 * nb, "batch": block[0] * block[1]},
         }}
 
@@ -443,8 +366,6 @@ class KsetSolver(_Booked):
             # (reference initialize_subspace.hpp:279)
             from sirius_tpu.parallel.batched import initialize_subspace_kset
 
-            if self.tr is not None:
-                self.psi_big = theta_real_block(self.psi_big, self.tr)
             pb_re, pb_im = split_cplx(self.psi_big, rdt)
             if self.mesh is not None:
                 # the LCAO block has nbig >= nb orbitals — shard it over
@@ -456,8 +377,7 @@ class KsetSolver(_Booked):
                 pb_re = jax.device_put(jnp.asarray(pb_re), _big)
                 pb_im = jax.device_put(jnp.asarray(pb_im), _big)
             pr, pi = initialize_subspace_kset(
-                ps, jnp.asarray(pb_re), jnp.asarray(pb_im), nb,
-                theta_index=self._theta_index(), mesh=self.mesh,
+                ps, jnp.asarray(pb_re), jnp.asarray(pb_im), nb, mesh=self.mesh,
             )
             pr, pi = self._place_psi(pr), self._place_psi(pi)
             count_applies(counters, [(self.psi_big.shape[2], 1)],
@@ -482,8 +402,7 @@ class KsetSolver(_Booked):
             ev, pr, pi, rn, ran = davidson_kset(
                 ps, pr, pi,
                 num_steps=self.num_steps,
-                res_tol=_rtol(res_tol, rdt),
-                theta_index=self._theta_index(), mesh=self.mesh,
+                res_tol=_rtol(res_tol, rdt), mesh=self.mesh,
                 by_energy=self.by_energy,
             )
         # canonicalize the pair onto the explicit psi sharding (a no-op
@@ -537,13 +456,8 @@ class KsetSolver(_Booked):
         self.psi_big = psi_big
 
     def load(self, psi):
-        # a block from a resume file or a warm start enters the real
-        # subspace as the LCAO block does: what a real-subspace solve left
-        # comes back bit for bit (so a resumed run repeats the uninterrupted
-        # one), a row with another phase as the Theta-real row of its line
         self.restart(None)
-        self.psi = psi if self.tr is None else theta_real_block(
-            np.asarray(psi), self.tr)
+        self.psi = psi
 
     def rescue(self, inputs, out, res_tol):
         """One deeper retry, warm-started from the stagnated block (a static
@@ -554,8 +468,7 @@ class KsetSolver(_Booked):
 
         ev, self.pr, self.pi, rn, _ = davidson_kset(
             self.ps, self.pr, self.pi, num_steps=2 * self.num_steps,
-            res_tol=res_tol, theta_index=self._theta_index(), mesh=self.mesh,
-            by_energy=self.by_energy,
+            res_tol=res_tol, mesh=self.mesh, by_energy=self.by_energy,
         )
         return BandOut(np.asarray(ev, dtype=np.float64), rn, self.pr, self.pi)
 

@@ -258,34 +258,32 @@ def over_k_pool(fn, mesh, in_specs, out_specs):
 
 @partial(jax.jit, static_argnames=("nb", "mesh"))
 def initialize_subspace_kset(params: HkSetParams, psi_re, psi_im, nb: int,
-                             theta_index=None, mesh=None):
+                             mesh=None):
     """LCAO subspace initialization for the whole (k, spin) set: one H/S
     application to the full atomic-orbital block (+ random tail), one
     generalized Rayleigh-Ritz, keep the lowest nb Ritz vectors (reference
     initialize_subspace.hpp:27 per-k, :279 kset driver). The input block is
     [nk, ns, nbig, ngk] with nbig >= nb; truncating atomic orbitals to nb
     BEFORE the rotation loses orbital characters and mis-seeds the band
-    solver (Fe 3d, test03). ``theta_index`` [nk, ngk]: every k-point is
-    time-reversal invariant, every row of the block Theta-real there, and so
-    are the rotated vectors (solvers/davidson.py, REAL SUBSPACE). ``mesh``:
-    the ("k", "b") mesh the operands are sharded on (over_k_pool).
+    solver (Fe 3d, test03). ``mesh``: the ("k", "b") mesh the operands are
+    sharded on (over_k_pool).
 
     Returns (psi_re, psi_im) [nk, ns, nb, ngk]."""
     return over_k_pool(
         partial(_initialize_subspace_kset, nb=nb), mesh,
-        (kset_param_specs(params), _K, _K, None if theta_index is None else _K),
+        (kset_param_specs(params), _K, _K),
         (_K, _K),
-    )(params, psi_re, psi_im, theta_index)
+    )(params, psi_re, psi_im)
 
 
-def _initialize_subspace_kset(params, psi_re, psi_im, theta_index, nb):
+def _initialize_subspace_kset(params, psi_re, psi_im, nb):
     from sirius_tpu.solvers.davidson import subspace_rotate
 
     psi = _cplx(psi_re, psi_im)
     has_hub = params.hub_re is not None
 
     def one_k(ekin, mask, fft_index, beta_re, beta_im, hub_re_k, hub_im_k,
-              vhub_re_k, vhub_im_k, psi_k, theta_k, cube_k):
+              vhub_re_k, vhub_im_k, psi_k, cube_k):
         def one_spin(veff_s, dion_s, vhub_re_s, vhub_im_s, x0):
             pk = HkParams(
                 veff_r=veff_s,
@@ -301,8 +299,7 @@ def _initialize_subspace_kset(params, psi_re, psi_im, theta_index, nb):
             )
             x = x0 * mask
             hx, sx = apply_h_s(pk, x)
-            return subspace_rotate(x, hx, sx, nb, mask=mask,
-                                   theta_index=theta_k)
+            return subspace_rotate(x, hx, sx, nb, mask=mask)
 
         return jax.vmap(
             one_spin,
@@ -313,12 +310,11 @@ def _initialize_subspace_kset(params, psi_re, psi_im, theta_index, nb):
     hub_ax = 0 if has_hub else None
     x = jax.vmap(
         one_k,
-        in_axes=(0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0,
-                 None if theta_index is None else 0, 0),
+        in_axes=(0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0, 0),
     )(
         params.ekin, params.mask, params.fft_index, params.beta_re,
         params.beta_im, params.hub_re, params.hub_im,
-        params.vhub_re, params.vhub_im, psi, theta_index, params.cube,
+        params.vhub_re, params.vhub_im, psi, params.cube,
     )
     return jnp.real(x), jnp.imag(x)
 
@@ -326,17 +322,14 @@ def _initialize_subspace_kset(params, psi_re, psi_im, theta_index, nb):
 @partial(jax.jit, static_argnames=("num_steps", "mesh", "by_energy"))
 def davidson_kset(
     params: HkSetParams, psi_re, psi_im, num_steps: int = 20,
-    res_tol: float = 1e-2, theta_index=None, mesh=None, by_energy: bool = True,
+    res_tol: float = 1e-2, mesh=None, by_energy: bool = True,
 ):
     """Solve bands at every (k, spin) in one program: one pair of loops over
     the solver's stages, each vmapped over the set (solvers/davidson.py, THE
     TRIP COUNT: the set takes its slowest k-point's steps, a k-point that is
-    done is held). ``theta_index``
-    [nk, ngk]: every k-point of the set is time-reversal invariant and every
-    row of psi Theta-real there, so the subspace eigenproblems are real
-    symmetric (solvers/davidson.py, REAL SUBSPACE). ``mesh``: the ("k", "b")
-    mesh the operands are sharded on (over_k_pool): each device's loop ends
-    on its own k-points, so the program still holds no collective.
+    done is held). ``mesh``: the ("k", "b") mesh the operands are sharded
+    on (over_k_pool): each device's loop ends on its own k-points, so the
+    program still holds no collective.
 
     psi_re/psi_im: [nk, ns, nb, ngk] real pair ->
     (evals [nk, ns, nb], psi_re', psi_im', rnorm [nk, ns, nb], ran [nk, 2]:
@@ -345,14 +338,12 @@ def davidson_kset(
     return over_k_pool(
         partial(_davidson_kset, num_steps=num_steps, by_energy=by_energy),
         mesh,
-        (kset_param_specs(params), _K, _K, _REP,
-         None if theta_index is None else _K),
+        (kset_param_specs(params), _K, _K, _REP),
         (_K, _K, _K, _K, _K),
-    )(params, psi_re, psi_im, res_tol, theta_index)
+    )(params, psi_re, psi_im, res_tol)
 
 
-def _davidson_kset(params, psi_re, psi_im, res_tol, theta_index, num_steps,
-                   by_energy):
+def _davidson_kset(params, psi_re, psi_im, res_tol, num_steps, by_energy):
     has_hub = params.hub_re is not None
     hub_ax = 0 if has_hub else None
 
@@ -362,8 +353,7 @@ def _davidson_kset(params, psi_re, psi_im, res_tol, theta_index, num_steps,
         outside the vmap, so the set has one trip count."""
 
         def one_k(ekin, mask, fft_index, beta_re, beta_im, h_diag_k, o_diag,
-                  hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, theta_k, cube_k,
-                  *blocks_k):
+                  hub_re_k, hub_im_k, vhub_re_k, vhub_im_k, cube_k, *blocks_k):
             def one_spin(veff_s, dion_s, vhub_re_s, vhub_im_s, h_diag_s,
                          *blocks):
                 pk = HkParams(
@@ -381,7 +371,7 @@ def _davidson_kset(params, psi_re, psi_im, res_tol, theta_index, num_steps,
                 )
                 return getattr(stages(
                     apply_h_s, pk, h_diag_s, o_diag, mask, res_tol,
-                    theta_index=theta_k, by_energy=by_energy), name)(*blocks)
+                    by_energy=by_energy), name)(*blocks)
 
             return jax.vmap(
                 one_spin,
@@ -392,14 +382,13 @@ def _davidson_kset(params, psi_re, psi_im, res_tol, theta_index, num_steps,
         def over_set(*blocks):
             return jax.vmap(
                 one_k,
-                in_axes=(0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax,
-                         None if theta_index is None else 0, 0)
+                in_axes=(0, 0, 0, 0, 0, 0, 0, hub_ax, hub_ax, hub_ax, hub_ax, 0)
                 + (0,) * len(blocks),
             )(
                 params.ekin, params.mask, params.fft_index, params.beta_re,
                 params.beta_im, params.h_diag, params.o_diag,
                 params.hub_re, params.hub_im, params.vhub_re, params.vhub_im,
-                theta_index, params.cube, *blocks,
+                params.cube, *blocks,
             )
 
         return over_set

@@ -72,34 +72,9 @@ device and a mesh, whose devices each leave on their own k-points, give the
 same bands to the bit. The select is on the 3nb x nb coefficients, never on
 a block, and a held problem stays held: its solve has ended.
 
-REAL SUBSPACE (``theta_index``). Where H and S commute with an antiunitary
-map Theta and every row of the block is Theta-real (Theta x = x), the
-subspace matrices V^H H V and V^H S V are real symmetric: the Theta-real
-vectors are a real vector space on which H and S act as real symmetric
-operators. That is so at a time-reversal-invariant k-point (2k a reciprocal
-lattice vector) for a real local potential and real D and Q, with
-Theta x (G) = conj(x(-G - 2k)), complex conjugation of psi(r);
-``theta_index`` is the slot of -G - 2k for every slot
-(dft/band_solve.time_reversal_index). Given it, the eigensolver gets the
-real parts. On the TPU that is another program: a complex Hermitian eigh is
-expanded into Jacobi sweep loops of about a microsecond an operation, a real
-symmetric one up to 256 rows is one kernel (PERF.md section 6, PR 31).
-
-The block V = [X, W, P] has to be Theta-real exactly, not to rounding: what
-lies outside the real space is invisible to the real Rayleigh-Ritz step and
-is carried along by its coefficients all the same. The new block W is where
-it comes in: W is a residual divided by its own norm, so near convergence it
-is rounding noise of norm one, half of it outside the real space, and in
-float32 the bands lost their orthonormality within one SCF (a total energy
-1.2 Ha too low on the CPU backend). So W, and H W and S W as they leave the
-operator, are replaced by their Theta-real parts (w + Theta w) / 2, a gather
-each (it costs nothing measurable beside the transforms), and so is the
-block that enters. X and P then stay Theta-real by themselves: they are
-real combinations (`_combine`), masks and real scalings of Theta-real rows,
-and slot -G - 2k sees the conjugate of the arithmetic slot G sees.
-Projecting W alone was tried on the chip and is not enough there: 17 SCF
-iterations for 13 and an energy 1.0e-4 Ha off where this form reads 1e-5
-(PERF.md section 6, PR 31).
+ONE SUBSPACE. The subspace matrices V^H H V and V^H S V are complex
+Hermitian for every k-set, whatever its k-points; they are real only at
+Gamma, through ops/gamma.py's packed form of the block.
 """
 
 from __future__ import annotations
@@ -171,11 +146,10 @@ def count_solve(counters, ran, nb: int, copies: int = 1,
     lanes behind a row. H applications and boxes through count_applies,
     num_subspace_eigh, and num_davidson_steps: the steps of the solve's
     longest loop. ``complex_subspace``: the subspace matrices are complex
-    Hermitian (every solve but the packed-real Gamma one and a k-set's real
-    subspace), so each eigenproblem is also one of
-    num_complex_subspace_eigh: those a program lowered for the TPU puts
-    through the reduction (subspace_eigh.form ``tridiagonal_real``); an
-    explicit 0 on a real subspace."""
+    Hermitian (every solve but the packed-real Gamma one), so each
+    eigenproblem is also one of num_complex_subspace_eigh: those a program
+    lowered for the TPU puts through the reduction (subspace_eigh.form
+    ``tridiagonal_real``); an explicit 0 on the packed-real subspace."""
     import numpy as np
 
     rows = np.asarray(ran).reshape(-1, 2)
@@ -230,48 +204,19 @@ def _rayleigh_ritz(hsub: jax.Array, ssub: jax.Array, nev: int):
     return e[:nev], c[:, :nev]
 
 
-def theta_real(a, theta_index):
-    """The Theta-real part of every row of a block [..., ng]; the block as
-    it is where the solve is complex (no index). Module docstring."""
-    if theta_index is None:
-        return a
-    return 0.5 * (a + jnp.conj(a[..., theta_index]))
-
-
-def _combine(c, v, theta_index):
-    """c^T v for Ritz coefficients c [m, n] and a block v [m, ng]. With real
-    coefficients (``theta_index``) it is two real products, of the real and
-    of the imaginary parts: half the arithmetic, and Theta-real rows give
-    Theta-real rows to the last bit, which a complex product with a zero
-    imaginary part does not on the TPU (its three-product form rounds the
-    imaginary part with the real one's size)."""
-    if theta_index is None:
-        return c.T @ v
-    return jax.lax.complex(c.T @ jnp.real(v), c.T @ jnp.imag(v))
-
-
-def _subspace_matrix(a, theta_index):
-    """A subspace matrix as the eigensolver gets it: real where the block
-    is Theta-real."""
-    return a if theta_index is None else jnp.real(a)
-
-
-def subspace_rotate(x, hx, sx, nb: int, mask=None, theta_index=None):
+def subspace_rotate(x, hx, sx, nb: int, mask=None):
     """Lowest-nb Ritz vectors of the trial block x given carried H x / S x:
     shared by the LCAO initialize-subspace paths (serial host and batched
-    device); pure jnp, callable inside or outside jit. With ``theta_index``
-    the rows of x are Theta-real and so are the Ritz vectors."""
-    x = theta_real(x, theta_index)
-    hx, sx = theta_real(hx, theta_index), theta_real(sx, theta_index)
-    hsub = _subspace_matrix(x.conj() @ hx.T, theta_index)
-    ssub = _subspace_matrix(x.conj() @ sx.T, theta_index)
+    device); pure jnp, callable inside or outside jit."""
+    hsub = x.conj() @ hx.T
+    ssub = x.conj() @ sx.T
     hsub = 0.5 * (hsub + hsub.conj().T)
     ssub = 0.5 * (ssub + ssub.conj().T)
     _, c = _rayleigh_ritz(hsub, ssub, nb)
-    xn = _combine(c, x, theta_index)
+    xn = c.T @ x
     if mask is not None:
         xn = xn * mask
-    nrm = jnp.real(jnp.sum(xn.conj() * _combine(c, sx, theta_index), axis=1))
+    nrm = jnp.real(jnp.sum(xn.conj() * (c.T @ sx), axis=1))
     return xn / jnp.sqrt(jnp.maximum(nrm, 1e-30))[:, None]
 
 
@@ -295,13 +240,9 @@ class Stages(NamedTuple):
 
 
 def stages(apply_fn, params, h_diag, o_diag, mask, res_tol,
-           theta_index=None, by_energy: bool = True) -> Stages:
+           by_energy: bool = True) -> Stages:
     """The Stages of one (H, S) problem; davidson()'s arguments. Pure
     functions of arrays: a set's solve vmaps each over its lanes."""
-    def apply_h_s(psi):
-        hpsi, spsi = apply_fn(params, psi)
-        return theta_real(hpsi, theta_index), theta_real(spsi, theta_index)
-
     def ritz(x, hx, sx):
         # <x|S|x> and the Rayleigh quotients of the rows, off carried blocks.
         # Guard the quotient: a rank-deficient Rayleigh-Ritz (heavy Kramers
@@ -313,13 +254,13 @@ def stages(apply_fn, params, h_diag, o_diag, mask, res_tol,
 
     def start(x0):
         with jax.named_scope("davidson_ortho"):
-            x = theta_real(x0 * mask, theta_index)
-            g = _subspace_matrix((x * mask) @ (x * mask).conj().T, theta_index)
+            x = x0 * mask
+            g = (x * mask) @ (x * mask).conj().T
             s, u = eigh(g)
             good = s > 50.0 * jnp.finfo(g.real.dtype).eps * jnp.max(jnp.abs(s))
             t = u * jnp.where(
                 good, jax.lax.rsqrt(jnp.where(good, s, 1.0)), 0.0)[None, :]
-            return _combine(t.conj(), x, theta_index)
+            return t.conj().T @ x
 
     def refresh(x, p):
         # a chunk's boundary: a true H/S application to [X; P]. In the first
@@ -329,7 +270,7 @@ def stages(apply_fn, params, h_diag, o_diag, mask, res_tol,
         # PR 27)
         nb = x.shape[0]
         with jax.named_scope("davidson_hpsi"):
-            hxp, sxp = apply_h_s(jnp.concatenate([x, p], axis=0))
+            hxp, sxp = apply_fn(params, jnp.concatenate([x, p], axis=0))
         return hxp[:nb], sxp[:nb], hxp[nb:], sxp[nb:]
 
     def step(x, hx, sx, p, hp, sp, conv):
@@ -351,17 +292,17 @@ def stages(apply_fn, params, h_diag, o_diag, mask, res_tol,
             # project out X and normalize rows: keeps the 3nb overlap
             # matrix well-conditioned so the rank-revealing cutoff doesn't
             # stall convergence near the solution
-            w = theta_real(w - (w @ x.conj().T) @ x, theta_index)
+            w = w - (w @ x.conj().T) @ x
             w = w / jnp.maximum(jnp.linalg.norm(w, axis=1, keepdims=True), 1e-30)
         # the ONLY H/S application of the step: the new block
         with jax.named_scope("davidson_hpsi"):
-            hw, sw = apply_h_s(w)
+            hw, sw = apply_fn(params, w)
         with jax.named_scope("davidson_inner"):
             v = jnp.concatenate([x, w, p], axis=0)  # (3nb, ng)
             hv = jnp.concatenate([hx, hw, hp], axis=0)
             sv = jnp.concatenate([sx, sw, sp], axis=0)
-            hsub = _subspace_matrix(v.conj() @ hv.T, theta_index)
-            ssub = _subspace_matrix(v.conj() @ sv.T, theta_index)
+            hsub = v.conj() @ hv.T
+            ssub = v.conj() @ sv.T
             hsub = 0.5 * (hsub + hsub.conj().T)
             ssub = 0.5 * (ssub + ssub.conj().T)
         with jax.named_scope("davidson_rr"):
@@ -372,19 +313,19 @@ def stages(apply_fn, params, h_diag, o_diag, mask, res_tol,
                 jnp.all(conv), jnp.eye(3 * nb, nb, dtype=c.dtype), c)
         with jax.named_scope("davidson_rotate"):
             # X' = V C and the carried H X' = (H V) C, S X' = (S V) C exactly
-            xn = _combine(c, v, theta_index) * mask
-            hxn = _combine(c, hv, theta_index) * mask
-            sxn = _combine(c, sv, theta_index) * mask
+            xn = (c.T @ v) * mask
+            hxn = (c.T @ hv) * mask
+            sxn = (c.T @ sv) * mask
             # new search direction: the non-X part of the update
             # (row-normalized, with the same scale applied to the carried
             # H P / S P)
             cp = c.at[:nb, :].set(0.0)
-            pn = _combine(cp, v, theta_index) * mask
+            pn = (cp.T @ v) * mask
             pscale = 1.0 / jnp.maximum(
                 jnp.linalg.norm(pn, axis=1, keepdims=True), 1e-30)
             pn = pn * pscale
-            hpn = _combine(cp, hv, theta_index) * mask * pscale
-            spn = _combine(cp, sv, theta_index) * mask * pscale
+            hpn = (cp.T @ hv) * mask * pscale
+            spn = (cp.T @ sv) * mask * pscale
         with jax.named_scope("davidson_residual"):
             # which bands this step leaves converged: the reference's rule
             # (the eigenvalue's move in the step) or the new block's
@@ -411,7 +352,7 @@ def stages(apply_fn, params, h_diag, o_diag, mask, res_tol,
         # fresh application for the exit values: the carried H X accumulates
         # linear-combination rounding (matters in c64)
         with jax.named_scope("davidson_hpsi"):
-            hx, sx = apply_h_s(x)
+            hx, sx = apply_fn(params, x)
         with jax.named_scope("davidson_residual"):
             den, evals = ritz(x, hx, sx)
             rnorm = jnp.sqrt(jnp.real(jnp.sum(
@@ -484,12 +425,11 @@ def davidson(
     num_steps: int = 20,  # the most steps a solve takes
     res_tol: float = 1e-2,
     refresh_every: int = REFRESH_EVERY,
-    theta_index: jax.Array | None = None,  # [ng] int: REAL SUBSPACE above
     by_energy: bool = True,  # res_tol bars the eigenvalue's move in a step
     # (iterative_solver.converge_by_energy); False: the residual norm
 ):
     """Returns (eval [nb], X [nb, ng], res_norms [nb], ran [2]): ran holds
     the steps and the chunks the solve ran (int32), THE TRIP COUNT above."""
     st = stages(apply_fn, params, h_diag, o_diag, mask, res_tol,
-                theta_index=theta_index, by_energy=by_energy)
+                by_energy=by_energy)
     return solve(st, x0, num_steps, refresh_every)

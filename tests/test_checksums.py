@@ -66,20 +66,16 @@ def _agree(a, b, stages, what):
 
 @pytest.mark.parametrize("ngridk, stages", [
     ((2, 2, 3), ("evals", "rho_new", "veff")),
-    ((2, 2, 2), ("rho_new", "veff"))])
+    ((2, 2, 2), ("evals", "rho_new", "veff"))])
 @RULES
 def test_single_vs_mesh_checksums_agree(ngridk, stages, itsol):
     """Sharded (8 virtual devices via conftest) vs serial paths: the same
     physics to near-machine precision, caught stage by stage. The tripwire
     presumes one algorithm on both sides, which a k-set with a generic
-    point has (mesh [2,2,3]: 8 k-points solved, 4 generic). On the [2,2,2]
-    mesh every k-point is time-reversal invariant, the mesh side solves on
-    the real subspace and the serial side stays complex (since PR 31: the
-    independent witness): density and potential still agree stage by stage,
-    while the eigenvalue sum after two iterations holds two empty bands
-    neither side has converged (1e-2 apart; bands 1-6 agree to 5e-10) and
-    is not compared there. Converged agreement of the two subspaces is
-    tests/test_real_subspace.py's.
+    point has (mesh [2,2,3]: 8 k-points solved, 4 generic) and, the k-set
+    solve having one subspace, a k-set without one too (mesh [2,2,2]: every
+    k-point its own -k). Converged agreement of the k-set solve with the
+    serial one is tests/test_kset_solver.py's.
 
     Since PR 33 the two sides also apply the local operator in two forms,
     the serial side the FFT of one block and the k-set the set's rows

@@ -1,11 +1,11 @@
 """A k-set with generic k-points (the benchmark's si16-k223-us): on the
 Gamma-centred [2, 2, 3] mesh the four points with k_z = 0 are their own -k
 and the eight with k_z = +-1/3 pair under time reversal into four generic
-ones, so 8 k-points are solved with weights 1/12 and 2/12, and one generic
-point makes the whole set's subspace complex (band_solve.time_reversal_index
-returns None; solvers/subspace_eigh.py's reduction on a TPU). The twin deck,
-si16-k222-us on [2, 2, 2], has the same count of k-points, all invariant, and
-runs the real subspace.
+ones, so 8 k-points are solved with weights 1/12 and 2/12 on the k-set
+solve's complex subspace (solvers/subspace_eigh.py's reduction on a TPU). The
+twin deck, si16-k222-us on [2, 2, 2], has the same count of k-points, all
+invariant, and since PR 44 runs the same program (until then a second one
+with real subspace matrices; tests/test_kset_solver.py).
 
 Held here at the rehearsal's size (the 2-atom cell, gk 3 / pw 7, 8 bands):
 what the context and the set-up span say, the fold on an anisotropic mesh
@@ -90,23 +90,26 @@ def test_the_223_mesh_is_eight_kpoints_four_of_them_generic(k223, k222):
     # the invariant ones (k_z = 0) stand for themselves
     assert np.array_equal(np.rint(w).astype(int) == 2, generic)
     assert np.all(np.abs(np.asarray(ctx.gkvec.kpoints)[~generic, 2]) < 1e-12)
-    # one generic point is enough: no index for the set
-    assert band_solve.time_reversal_index(ctx.gkvec) is None
+    # the twin: as many k-points, one weight, none generic
     _, twin = context(k222[0])
-    tr = band_solve.time_reversal_index(twin.gkvec)
-    assert tr is not None and tr.shape == twin.gkvec.mask.shape
+    assert twin.gkvec.num_kpoints == 8
     assert not band_solve.generic_kpoints(twin.gkvec.kpoints).any()
+    assert np.allclose(np.asarray(twin.kweights), 1.0 / 8)
 
 
-def test_one_generic_point_makes_the_solver_complex(k223, k222, one_device):
-    for (deck, _), real in ((k223, False), (k222, True)):
+def test_generic_points_or_none_the_solver_is_the_complex_one(k223, k222,
+                                                              one_device):
+    for (deck, _), generic in ((k223, 4), (k222, 0)):
         cfg, ctx = context(deck)
         band = band_solve.choose(ctx, cfg, one_device, serial_bands=False,
                                  hub=None, paw=None, mgga=False,
                                  wf_dtype=jnp.complex64)
         assert isinstance(band, band_solve.KsetSolver)
-        assert (band.tr is not None) == real
-        assert band.complex_subspace == (not real)
+        assert band.complex_subspace is True
+        kset = band.plan(jnp.complex64)["kset"]
+        assert kset["generic_kpoints"] == generic
+        assert kset["subspace_eigh"]["form"] == subspace_eigh.form(
+            jnp.complex64, one_device[0].platform)
 
 
 # --- (c) the f32 fused path against the stored plain reference -------------
@@ -138,7 +141,7 @@ def test_every_eigenproblem_of_the_job_is_a_complex_one(job223):
 def test_setup_span_says_which_kset_this_is(job223, k223):
     kset = job223["_setup"]["kset"]
     nb = k223[0]["parameters"]["num_bands"]
-    assert kset["nk"] == 8 and kset["real_subspace"] is False
+    assert kset["nk"] == 8 and "real_subspace" not in kset
     assert kset["generic_kpoints"] == 4 and kset["weights"] == [1, 2]
     # the complex subspace lowered for this platform, the CPU: LAPACK's call
     # (on the chip the same deck reads "tridiagonal_real")
@@ -149,7 +152,8 @@ def test_setup_span_says_which_kset_this_is(job223, k223):
     assert subspace_eigh.form(jnp.complex64, "tpu") == "tridiagonal_real"
 
 
-def test_the_twin_on_222_books_no_complex_eigenproblem(k223, k222, one_device):
+def test_the_twin_on_222_books_the_same_complex_program(k223, k222,
+                                                        one_device):
     first = copy.deepcopy(k223[0])
     first["parameters"]["num_dft_iter"] = 1
     run(first, one_device)
@@ -162,11 +166,9 @@ def test_the_twin_on_222_books_no_complex_eigenproblem(k223, k222, one_device):
     # shapes, so a process that has run one runs the other on its program
     assert r["_setup"]["fused_step"] == "reused"
     assert c["num_fused_step_traces"] == 0
-    assert c["num_subspace_eigh"] > 0
-    assert "num_complex_subspace_eigh" in c  # an explicit 0, not a gap
-    assert c["num_complex_subspace_eigh"] == 0
+    assert c["num_complex_subspace_eigh"] == c["num_subspace_eigh"] > 0
     kset = r["_setup"]["kset"]
-    assert kset["real_subspace"] is True and kset["generic_kpoints"] == 0
+    assert "real_subspace" not in kset and kset["generic_kpoints"] == 0
     assert kset["weights"] == [1]
 
 

@@ -186,8 +186,8 @@ def test_form_follows_dtype_and_platform():
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64], ids=["f32", "f64"])
 def test_real_input_traces_to_the_parents_jaxpr(dtype):
-    """GammaSolver and the real subspace of a k-set: eigh on the input
-    itself, no platform switch, nothing of the reduction."""
+    """GammaSolver's packed-real subspace: eigh on the input itself, no
+    platform switch, nothing of the reduction."""
     a = jnp.zeros((5, 24, 24), dtype)
     assert str(jax.make_jaxpr(se.eigh)(a)) == str(jax.make_jaxpr(jnp.linalg.eigh)(a))
 
@@ -214,7 +214,7 @@ def test_davidson_with_the_reduction_meets_the_librarys_bands():
     from sirius_tpu.dft import band_solve
     from sirius_tpu.dft.scf import _initial_subspace
     from sirius_tpu.ops.hamiltonian import apply_h_s, make_hk_params
-    from tests.test_real_subspace import context, deck
+    from tests.test_kset_solver import context, deck
 
     _, ctx = context(deck((1, 1, 1), vk=[[0.11, 0.23, 0.31]]))
     rng = np.random.default_rng(8)

@@ -116,13 +116,13 @@ def test_setup_span_says_how_large_the_kset_program_is(rehearsal_deck,
     # one application to [X; P]: a complex64 coarse box a row, 2 nb rows a k
     assert setup["kset"] == {
         "nk": nk, "ngk_max": int(ctx.gkvec.ngk_max), "subspace_rows": 3 * nb,
-        "real_subspace": True,
         # PR 41: every point of the mesh its own -k, one weight
         "generic_kpoints": 0, "weights": [1],
         "workspace_bytes": nk * 2 * nb * int(np.prod(ctx.fft_coarse.dims)) * 8,
         # PR 33: the set's rows go through each box transform together
         "local_rows": [nk * nb, 2 * nk * nb], "local_layout": "rows_minor",
-        # PR 35: real matrices take the library's eigh on every backend
+        # PR 44: one complex subspace, lowered here for the CPU (LAPACK's
+        # call; on the chip the same deck reads "tridiagonal_real")
         "subspace_eigh": {"form": "library", "rows": 3 * nb, "batch": nk}}
     assert setup["kset"]["ngk_max"] % 16 == 0  # control.ngk_pad_quantum
 

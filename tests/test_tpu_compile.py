@@ -10,7 +10,11 @@ the all_to_all pair and beta psum inside shard_map), that each fits the
 
 The topology is described inside a fixture of this file — never at import,
 in a skipif or in parametrize (on-chip-measurement guide section 2): only
-the xdist worker that runs this file may load the TPU library.
+the xdist worker that runs this file may load the TPU library. The two
+Gamma compiles are tests/test_tpu_compile_gamma.py, which imports this
+file's fixtures and helpers: where ``--dist loadfile`` gives the two files
+to two workers, both load the library, which the driver's command allows
+(ALLOW_MULTIPLE_LIBTPU_LOAD=1); without it the second file's fixture skips.
 """
 
 import contextlib
@@ -75,11 +79,6 @@ def _ctx(ngridk, supercell=1, num_bands=None, symmetry=False):
 
     return build_job_context(
         load_config(_deck(ngridk, supercell, num_bands, symmetry)), ".")
-
-
-@pytest.fixture(scope="module")
-def ctx_gamma():
-    return _ctx((1, 1, 1))
 
 
 @pytest.fixture(scope="module")
@@ -216,79 +215,6 @@ def _fused_args(fused, ctx, nb, rep, psi_sh, ev_sh):
     )
 
 
-def test_gamma_band_solve_one_chip(topo, no_compile_cache, ctx_gamma):
-    """The packed-real Gamma solve of run_scf's `gamma` path (band_solve.GammaSolver)."""
-    from sirius_tpu.ops.gamma import (
-        build_gamma_map, davidson_gamma, initialize_subspace_gamma,
-        make_gamma_params,
-    )
-
-    ctx = ctx_gamma
-    one = SingleDeviceSharding(topo.devices[0])
-    gm = build_gamma_map(np.asarray(ctx.gkvec.millers[0]),
-                         np.asarray(ctx.gkvec.mask[0]))
-    gp = _shapes(make_gamma_params(
-        ctx, np.zeros(ctx.fft_coarse.dims), gm, rdtype=jnp.float32), one)
-    nb, ngk = ctx.num_bands, ctx.gkvec.ngk_max
-    x0 = jax.ShapeDtypeStruct((nb, ngk), np.float32, sharding=one)
-    diag = jax.ShapeDtypeStruct((ngk,), np.float32, sharding=one)
-    tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
-    lower = lambda: davidson_gamma.lower(
-        gp, x0, diag, diag, num_steps=NUM_STEPS, res_tol=tol, **RULE)
-    _one_step_body(_check(_compile(lower), no_64bit=True))
-    # real matrices bypass solvers/subspace_eigh.py's reduction by dtype:
-    # the program is lowered to the text it has with the library's call
-    mine = _lowered_text(lower)
-    with _library_eigh():
-        assert mine == _lowered_text(lower)
-    nbig = jax.ShapeDtypeStruct((nb + 6, ngk), np.float32, sharding=one)
-    _check(_compile(lambda: initialize_subspace_gamma.lower(gp, nbig, nb=nb)),
-           no_64bit=True)
-
-
-def test_gamma_fused_tail_one_chip(topo, no_compile_cache):
-    """The Gamma path's iteration tail at the widths of the benchmark's
-    si16-gamma-us (16 atoms, 64 bands, gk 6 / pw 20): the hand-off of the
-    packed solve (solve_inputs_device, unpack_device), density_gamma, the
-    density matrix and the fused step, as run_scf's `gamma` path
-    feeds them."""
-    from sirius_tpu.ops.gamma import (
-        build_gamma_map, density_gamma, make_gamma_params, pack_index,
-        solve_inputs_device, unpack_device,
-    )
-    from sirius_tpu.parallel.batched import density_matrix_kset
-
-    ctx = _ctx((1, 1, 1), supercell=2, num_bands=64)
-    assert ctx.unit_cell.num_atoms == 16
-    one = SingleDeviceSharding(topo.devices[0])
-    f32 = np.float32
-    gm = build_gamma_map(np.asarray(ctx.gkvec.millers[0]),
-                         np.asarray(ctx.gkvec.mask[0]))
-    gp = _shapes(make_gamma_params(
-        ctx, np.zeros(ctx.fft_coarse.dims), gm, rdtype=jnp.float32), one)
-    nb, ngk = ctx.num_bands, ctx.gkvec.ngk_max
-    nbeta = ctx.beta.num_beta_total
-    dims = tuple(ctx.fft_coarse.dims)
-
-    def sds(*shape):
-        return jax.ShapeDtypeStruct(shape, f32, sharding=one)
-
-    _check(_compile(lambda: solve_inputs_device.lower(
-        _shapes(pack_index(gm, ngk), one), gp.mask_p, sds(ngk),
-        sds(1, *dims), sds(1, nbeta, nbeta), sds(1, 1, ngk))),
-        no_64bit=True)
-    _check(_compile(lambda: unpack_device.lower(gp, sds(1, nb, ngk))),
-           no_64bit=True)
-    _check(_compile(lambda: density_gamma.lower(
-        gp, sds(1, nb, ngk), sds(1, nb))), no_64bit=True)
-    _check(_compile(lambda: density_matrix_kset.lower(
-        sds(1, nbeta, ngk), sds(1, nbeta, ngk), sds(1, 1, nb, ngk),
-        sds(1, 1, nb, ngk), sds(1, 1, nb))), no_64bit=True)
-    fused = _fused(ctx)
-    args = _fused_args(fused, ctx, nb, one, one, one)
-    _check(_compile(lambda: fused._step.lower(*args)), no_64bit=True)
-
-
 def _collectives(txt, kind):
     """The lines of a compiled program that start a collective `kind`."""
     return [ln for ln in txt.splitlines()
@@ -305,7 +231,9 @@ def _cube_density(txt):
 def test_kset_band_solve_one_chip(topo, no_compile_cache, ctx_kmesh):
     """The batched k-set solve (complex Hermitian eigh at 3*nb inside):
     reduced to real tridiagonal matrices, all the set's in each kernel call."""
-    from sirius_tpu.parallel.batched import davidson_kset, density_kset
+    from sirius_tpu.parallel.batched import (
+        davidson_kset, density_kset, initialize_subspace_kset,
+    )
 
     ctx = ctx_kmesh
     one = SingleDeviceSharding(topo.devices[0])
@@ -317,47 +245,11 @@ def test_kset_band_solve_one_chip(topo, no_compile_cache, ctx_kmesh):
     _no_jacobi(txt)
     _one_step_body(txt)
     assert set(_eigh_batches(txt)) == {ctx.gkvec.num_kpoints}
+    _no_jacobi(_check(_compile(lambda: initialize_subspace_kset.lower(
+        ps, psi, psi, nb=ctx.num_bands)), no_64bit=True))
     occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=one)
     _cube_density(_check(_compile(
         lambda: density_kset.lower(ps, psi, psi, occ)), no_64bit=True))
-
-
-def test_kset_band_solve_real_subspace_one_chip(topo, no_compile_cache,
-                                                ctx_kmesh):
-    """The same solve with real subspace matrices (every k-point of the
-    2x2x2 mesh is time-reversal invariant; solvers/davidson.py, REAL
-    SUBSPACE): what it is for is the eigensolver the TPU builds. A real
-    symmetric eigh of 78 rows is the EighTpu kernel; the library's complex
-    Hermitian one is expanded into Jacobi sweep loops, which is why the
-    complex program reduces its matrices to real ones first (PR 35). The
-    real program does not go through that: its text is the parent's."""
-    from sirius_tpu.dft.band_solve import time_reversal_index
-    from sirius_tpu.parallel.batched import (
-        davidson_kset, initialize_subspace_kset,
-    )
-
-    ctx = ctx_kmesh
-    one = SingleDeviceSharding(topo.devices[0])
-    ps, psi = _kset_inputs(ctx, ctx.num_bands)
-    theta = time_reversal_index(ctx.gkvec)
-    assert theta is not None
-    ps, psi = _shapes(ps, one), _shapes(psi, one)
-    theta = _shapes(theta.astype(np.int32), one)
-    tol = jax.ShapeDtypeStruct((), np.float32, sharding=one)
-    lower = lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, **RULE, theta_index=theta)
-    real = _check(_compile(lower), no_64bit=True)
-    assert "EighTpu" in real and "EighJacobiSweeps" not in real
-    mine = _lowered_text(lower)
-    with _library_eigh():
-        assert mine == _lowered_text(lower)
-    # the library's complex eigh, the parent's program: Jacobi sweep loops
-    lower = lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, **RULE)
-    with _library_eigh():
-        assert "EighJacobiSweeps" in lower().compile().as_text()
-    _check(_compile(lambda: initialize_subspace_kset.lower(
-        ps, psi, psi, nb=ctx.num_bands, theta_index=theta)), no_64bit=True)
 
 
 def test_fused_step_one_chip(topo, no_compile_cache, ctx_kmesh):
@@ -447,15 +339,12 @@ def test_kb_mesh_step_four_chips(topo, no_compile_cache, ctx_kmesh):
     for kind in ("all-gather", "all-reduce", "all-to-all",
                  "collective-permute", "reduce-scatter"):
         assert not _collectives(txt, kind), kind
-    # ... and with real subspace matrices, the index sharded over "k" as
-    # band_solve.KsetSolver places it (this mesh is all of them invariant)
-    from sirius_tpu.dft.band_solve import time_reversal_index
-
-    theta = _shapes(time_reversal_index(ctx.gkvec).astype(np.int32),
-                    NamedSharding(mesh, P("k", None)))
+    # ... and the 2x2x2 mesh, every k-point its own -k: the same program
+    # at two k-points a chip
     txt = _check(_compile(lambda: davidson_kset.lower(
-        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, **RULE, theta_index=theta,
-        mesh=mesh)), no_64bit=True)
+        ps, psi, psi, num_steps=NUM_STEPS, res_tol=tol, **RULE, mesh=mesh)),
+        no_64bit=True)
+    _no_jacobi(txt)
     assert set(_eigh_batches(txt)) == {ctx.gkvec.num_kpoints // 4}
     occ = jax.ShapeDtypeStruct(psi.shape[:3], np.float32, sharding=ev_sh)
     txt = _check(_compile(lambda: density_kset.lower(
