@@ -147,8 +147,11 @@ class RadialIntegralTable:
             num_q = int(20 * qspan)
             qmax = qspan
         qgrid = np.linspace(0.0, qmax, num_q)
+        # a function that is zero everywhere (most (pair, l3) slots of an
+        # augmentation with few channels) has a zero table: no Bessel grid
         tab = np.stack(
-            [sbessel_integral(r, fn, int(l), qgrid, m=m) for fn, l in zip(functions, ls)]
+            [sbessel_integral(r, fn, int(l), qgrid, m=m) if np.any(fn)
+             else np.zeros(num_q) for fn, l in zip(functions, ls)]
         )
         return RadialIntegralTable(qgrid=qgrid, table=tab)
 

@@ -407,38 +407,41 @@ def _xc_fields(xc, rho_g, mag_g, rho_r, rho_core_g, rho_core_r, dims, to_r,
     polarized = mag_g is not None
     mag_r = None
     if polarized:
-        mag_r = to_r(mag_g)
-        rho_xc = jnp.maximum(rho_r + rho_core_r, 1e-20)
-        m = jnp.clip(mag_r, -rho_xc, rho_xc)
-        n_up = 0.5 * (rho_xc + m)
-        n_dn = 0.5 * (rho_xc - m)
-        if xc.is_gga:
-            with jax.named_scope("xc_gga"):
-                gu = gradient_r(0.5 * (rho_g + rho_core_g + mag_g))
-                gd = gradient_r(0.5 * (rho_g + rho_core_g - mag_g))
-                suu = sum(g * g for g in gu)
-                sdd = sum(g * g for g in gd)
-                sud = sum(a * b for a, b in zip(gu, gd))
-                out = xc.evaluate_polarized(
-                    n_up.ravel(), n_dn.ravel(),
-                    suu.ravel(), sud.ravel(), sdd.ravel(),
-                )
+        # the two-channel branch, named for a capture's trace.scopes
+        # (step_xc/xc_spin), as xc_gga is
+        with jax.named_scope("xc_spin"):
+            mag_r = to_r(mag_g)
+            rho_xc = jnp.maximum(rho_r + rho_core_r, 1e-20)
+            m = jnp.clip(mag_r, -rho_xc, rho_xc)
+            n_up = 0.5 * (rho_xc + m)
+            n_dn = 0.5 * (rho_xc - m)
+            if xc.is_gga:
+                with jax.named_scope("xc_gga"):
+                    gu = gradient_r(0.5 * (rho_g + rho_core_g + mag_g))
+                    gd = gradient_r(0.5 * (rho_g + rho_core_g - mag_g))
+                    suu = sum(g * g for g in gu)
+                    sdd = sum(g * g for g in gd)
+                    sud = sum(a * b for a, b in zip(gu, gd))
+                    out = xc.evaluate_polarized(
+                        n_up.ravel(), n_dn.ravel(),
+                        suu.ravel(), sud.ravel(), sdd.ravel(),
+                    )
+                    v_up = out["v_up"].reshape(dims)
+                    v_dn = out["v_dn"].reshape(dims)
+                    vsuu = out["vsigma_uu"].reshape(dims)
+                    vsud = out["vsigma_ud"].reshape(dims)
+                    vsdd = out["vsigma_dd"].reshape(dims)
+                    v_up = v_up - to_r(divergence_g(
+                        [2 * vsuu * a + vsud * b for a, b in zip(gu, gd)]))
+                    v_dn = v_dn - to_r(divergence_g(
+                        [2 * vsdd * b + vsud * a for a, b in zip(gu, gd)]))
+            else:
+                out = xc.evaluate_polarized(n_up.ravel(), n_dn.ravel())
                 v_up = out["v_up"].reshape(dims)
                 v_dn = out["v_dn"].reshape(dims)
-                vsuu = out["vsigma_uu"].reshape(dims)
-                vsud = out["vsigma_ud"].reshape(dims)
-                vsdd = out["vsigma_dd"].reshape(dims)
-                v_up = v_up - to_r(divergence_g(
-                    [2 * vsuu * a + vsud * b for a, b in zip(gu, gd)]))
-                v_dn = v_dn - to_r(divergence_g(
-                    [2 * vsdd * b + vsud * a for a, b in zip(gu, gd)]))
-        else:
-            out = xc.evaluate_polarized(n_up.ravel(), n_dn.ravel())
-            v_up = out["v_up"].reshape(dims)
-            v_dn = out["v_dn"].reshape(dims)
-        e_r = out["e"].reshape(dims)
-        vxc_r = 0.5 * (v_up + v_dn)
-        bz_r = 0.5 * (v_up - v_dn)
+            e_r = out["e"].reshape(dims)
+            vxc_r = 0.5 * (v_up + v_dn)
+            bz_r = 0.5 * (v_up - v_dn)
     else:
         rho_xc = jnp.maximum(rho_r + rho_core_r, 0.0)
         if xc.is_gga:

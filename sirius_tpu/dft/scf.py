@@ -553,6 +553,10 @@ def _run_scf_inner(
                 _it_span.close(incomplete=True)
             _it_span = None
 
+    def _moment_attr():
+        # the step's total moment, the scalar mag_history already holds
+        return {"moment_ub": mag_history[-1]} if polarized else {}
+
     def _hbm_attr():
         # per-iteration HBM high-water sample (device memory_stats peak;
         # host RSS fallback on CPU) — attached to scf.iteration spans
@@ -1007,6 +1011,9 @@ def _run_scf_inner(
     counters["num_kpoints_solved"] = nk
     _setup_span.close(
         fused=fused is not None,
+        spin={"num_spins": ns, "num_mag_dims": int(ctx.num_mag_dims),
+              "start_moment_ub": float(
+                  np.sum(ctx.unit_cell.moments[:, 2]))},
         # what the process's table of steps answered this job's constants
         # (counters.num_fused_step_traces is what JAX then did)
         **({} if fused is None else {
@@ -1171,7 +1178,7 @@ def _run_scf_inner(
                 _sp = _stage(
                     "scf.fused_step", it=it + 1, box_fill="gather",
                     box_fills=fused.box_fills, xc=fused.xc_kind,
-                    sym_ops=fused.sym_ops)
+                    sym_ops=fused.sym_ops, polarized=polarized)
                 counters["num_tail_box_fills"] += fused.box_fills
                 counters["num_sym_pw"] += fused.sym_pw
                 counters["num_xc_gradient_transforms"] += (
@@ -1232,7 +1239,7 @@ def _run_scf_inner(
             _ETOT.set(e_total)
             # the span runs on to the loop's head: snapshot, autosave and
             # the straggler check below are the iteration's too
-            _it_span.set(path="fused", **_hbm_attr())
+            _it_span.set(path="fused", **_moment_attr(), **_hbm_attr())
             # numerics ledger: the invariants ride the existing [NUM_SCALARS]
             # readback (dft/fused.py) — naming them here costs no transfer
             ledger = obs_numerics.ledger_from_scalars(fused_np)
@@ -1535,7 +1542,7 @@ def _run_scf_inner(
         _ETOT.set(e_total)
         # the span runs on to the loop's head: probe, snapshot and
         # autosave below are the iteration's too
-        _it_span.set(path="host", **_hbm_attr())
+        _it_span.set(path="host", **_moment_attr(), **_hbm_attr())
         # numpy twin of the fused on-device numerics ledger (obs/numerics.py)
         # — same invariants from the same operands, so the fused values can
         # be validated against this path (tests/test_fused_scf.py)
@@ -1626,6 +1633,7 @@ def _run_scf_inner(
     # the steps and chunks every band solve ran leave the device here, and
     # become the counters of the H applications and eigenproblems that ran
     band.book()
+    counters["num_spin_channels"] = ns
     # read-only record of the path taken and of where each stage of the last
     # iteration ran and in which dtype, read off the arrays themselves
     placement = {
@@ -1779,6 +1787,7 @@ def _run_scf_inner(
             counters["num_xc_gradient_transforms"]),
         num_fused_step_traces=int(counters["num_fused_step_traces"]),
         num_host_xc_traces=int(counters["num_host_xc_traces"]),
+        num_spin_channels=int(counters["num_spin_channels"]),
         energy_resolution_ha=abs(e_total) * pair_eps(
             fused.rdt if fused is not None else np.float64),
     )

@@ -128,7 +128,7 @@ def _lda_x_e(nu: jnp.ndarray, nd: jnp.ndarray) -> jnp.ndarray:
     return -cx / 2.0 * ((2 * nu) ** (4.0 / 3.0) + (2 * nd) ** (4.0 / 3.0))
 
 
-def _pz_eps(rs: jnp.ndarray, pol: bool) -> jnp.ndarray:
+def _pz_eps(rs: jnp.ndarray, pol: bool, log=jnp.log) -> jnp.ndarray:
     """Perdew-Zunger 81 correlation energy per particle at zeta=0 or 1."""
     if pol:
         gamma, b1, b2 = -0.0843, 1.3981, 0.2611
@@ -137,12 +137,12 @@ def _pz_eps(rs: jnp.ndarray, pol: bool) -> jnp.ndarray:
         gamma, b1, b2 = -0.1423, 1.0529, 0.3334
         a, b, c, d = 0.0311, -0.048, 0.002, -0.0116
     lo = gamma / (1.0 + b1 * jnp.sqrt(rs) + b2 * rs)
-    hi = a * jnp.log(rs) + b + c * rs * jnp.log(rs) + d * rs
+    hi = a * log(rs) + b + c * rs * log(rs) + d * rs
     return jnp.where(rs >= 1.0, lo, hi)
 
 
-def _zeta_f(zeta: jnp.ndarray) -> jnp.ndarray:
-    return ((1 + zeta) ** (4.0 / 3.0) + (1 - zeta) ** (4.0 / 3.0) - 2.0) / (
+def _zeta_f(zeta: jnp.ndarray, pow43=lambda x: x ** (4.0 / 3.0)) -> jnp.ndarray:
+    return (pow43(1 + zeta) + pow43(1 - zeta) - 2.0) / (
         2.0 ** (4.0 / 3.0) - 2.0
     )
 
@@ -154,6 +154,48 @@ def _lda_c_pz_e(nu: jnp.ndarray, nd: jnp.ndarray) -> jnp.ndarray:
     eu = _pz_eps(rs, False)
     ep = _pz_eps(rs, True)
     return n * (eu + _zeta_f(zeta) * (ep - eu))
+
+
+# The LDA pair with two spin channels (PR 45). In float32 the TPU's log is
+# off by up to 4e-4 of itself near 1 (1.4e-6 in the mean on [1e-3, 4]) and
+# the general pow that jax.grad makes of x ** (4/3), x ** (4/3 - 1), by 1e-6
+# with a bias (v_up, v_dn +7.6e-7 of themselves in the mean against float64;
+# PERF.md section 6, PR 45), where cbrt and the polynomial log are good to
+# 7e-8 there: some 5e-6 Ha of a 1e-5 Ha bar on the 2-atom ferromagnetic cell. So
+# the polarised call (evaluate_polarized) takes the forms below: 4/3 powers
+# as x cbrt(x) with the derivative written out (it is finite at zeta = +-1,
+# where autodiff of x cbrt(x) is 0/0), r_s by cbrt, log(r_s) by _log1p. The
+# unpolarised call keeps the lines above: every deck with one spin channel
+# runs the program it ran, bit for bit.
+
+@jax.custom_jvp
+def _pow43(x):
+    return x * jnp.cbrt(x)
+
+
+@_pow43.defjvp
+def _pow43_jvp(primals, tangents):
+    (x,), (g,) = primals, tangents
+    c = jnp.cbrt(x)
+    return x * c, g * (4.0 / 3.0) * c
+
+
+def _log_rs(rs):
+    return _log1p(rs - 1.0)
+
+
+def _lda_x_spin_e(nu: jnp.ndarray, nd: jnp.ndarray) -> jnp.ndarray:
+    cx = (3.0 / 4.0) * (3.0 / jnp.pi) ** (1.0 / 3.0)
+    return -cx / 2.0 * (_pow43(2 * nu) + _pow43(2 * nd))
+
+
+def _lda_c_pz_spin_e(nu: jnp.ndarray, nd: jnp.ndarray) -> jnp.ndarray:
+    n = nu + nd
+    zeta = jnp.clip((nu - nd) / n, -1.0, 1.0)
+    rs = jnp.cbrt(3.0 / (4.0 * jnp.pi * n))
+    eu = _pz_eps(rs, False, _log_rs)
+    ep = _pz_eps(rs, True, _log_rs)
+    return n * (eu + _zeta_f(zeta, _pow43) * (ep - eu))
 
 
 def _pw92_g(rs: jnp.ndarray, a, a1, b1, b2, b3, b4) -> jnp.ndarray:
@@ -382,6 +424,11 @@ _LDA_FUNCS = {
     "XC_LDA_C_PW": _lda_c_pw_e,
     "XC_LDA_C_VWN": _lda_c_vwn_e,
 }
+# what evaluate_polarized runs in place of the entries above
+_LDA_SPIN_FUNCS = {
+    "XC_LDA_X": _lda_x_spin_e,
+    "XC_LDA_C_PZ": _lda_c_pz_spin_e,
+}
 _GGA_FUNCS = {
     "XC_GGA_X_PBE": _pbe_x_e,
     "XC_GGA_C_PBE": _pbe_c_e,
@@ -417,41 +464,43 @@ class XCFunctional:
         # mGGA needs the full gradient machinery too
         self.is_gga = self.is_mgga or any(n in _GGA_FUNCS for n in names)
 
-    def _energy(self, nu, nd, suu, sud, sdd, tu, td):
+    def _energy(self, nu, nd, suu, sud, sdd, tu, td, spin=False):
         nu = jnp.maximum(nu, _TINY)
         nd = jnp.maximum(nd, _TINY)
         e = jnp.zeros_like(nu)
+        lda = {**_LDA_FUNCS, **_LDA_SPIN_FUNCS} if spin else _LDA_FUNCS
         for name in self.names:
-            if name in _LDA_FUNCS:
-                e = e + _LDA_FUNCS[name](nu, nd)
+            if name in lda:
+                e = e + lda[name](nu, nd)
             elif name in _GGA_FUNCS:
                 e = e + _GGA_FUNCS[name](nu, nd, suu, sud, sdd)
             else:
                 e = e + _MGGA_FUNCS[name](nu, nd, suu, sud, sdd, tu, td)
         return e
 
-    def _eval(self, nu, nd, suu, sud, sdd, tu, td):
+    def _eval(self, nu, nd, suu, sud, sdd, tu, td, spin=False):
         """The traced form (inside a device program): all seven derivatives,
         the energy density by a second pass. Its operations and their order
-        are the fused step's compiled program: leave them as they are."""
+        are the fused step's compiled program: leave them as they are.
+        ``spin``: the call has two channels (_energy takes _LDA_SPIN_FUNCS)."""
         up0, dn0, clean = _sanitized(nu, nd, suu, sud, sdd)
         grads = jax.grad(
             lambda a, b, c, d, f, g, h: jnp.sum(
-                self._energy(a, b, c, d, f, g, h)
+                self._energy(a, b, c, d, f, g, h, spin)
             ),
             argnums=(0, 1, 2, 3, 4, 5, 6),
         )
         masked = _mask_dead(up0, dn0, grads(*clean, tu, td))
-        return (self._energy(*clean, tu, td), *masked)
+        return (self._energy(*clean, tu, td, spin), *masked)
 
-    def _eval_once(self, nu, nd, suu, sud, sdd, tu, td):
+    def _eval_once(self, nu, nd, suu, sud, sdd, tu, td, spin=False):
         """The host form (under _host_xc's jit): energy density and
         derivatives from one pass, with respect to the arguments the class
         reads; the derivatives it does not have are None."""
         up0, dn0, clean = _sanitized(nu, nd, suu, sud, sdd)
 
         def total(*args):
-            e = self._energy(*args)
+            e = self._energy(*args, spin)
             return jnp.sum(e), e
 
         nargs = 7 if self.is_mgga else 5 if self.is_gga else 2
@@ -471,6 +520,7 @@ class XCFunctional:
             z if sigma_dd is None else sigma_dd,
             z if tau_up is None else tau_up,
             z if tau_dn is None else tau_dn,
+            spin=True,
         )
         out = {"e": e, "v_up": vu, "v_dn": vd}
         if self.is_gga:

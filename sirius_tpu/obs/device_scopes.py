@@ -68,6 +68,7 @@ SCOPES = (
     "eigh_reduce", "eigh_kernel",
     # dft/fused.py::_step_impl and dft/potential.generate_potential_device
     "step_density", "step_mixing", "step_hartree", "step_xc", "xc_gga",
+    "xc_spin",
     "step_vloc", "step_d_matrix", "step_ledger",
     # core/fftgrid.g_to_r_gather
     "box_fill",

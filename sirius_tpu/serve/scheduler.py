@@ -60,8 +60,6 @@ import random
 import threading
 import time
 
-import numpy as np
-
 from sirius_tpu.obs import events as obs_events
 from sirius_tpu.obs import metrics as obs_metrics
 from sirius_tpu.obs import spans as obs_spans
@@ -103,11 +101,15 @@ def build_job_context(cfg, base_dir: str = "."):
     """SimulationContext for a deck Config.
 
     A ``synthetic`` extra section ({"ultrasoft": bool, "positions": [...],
-    "supercell": n, "a": lattice const}) builds the in-memory Si-like test
-    species instead of reading species files — the species-file-free deck
-    form used by tests and tools/loadgen.py. Everything else (cutoffs,
-    k-mesh, control knobs incl. ngk_pad_quantum) comes from the normal
-    config sections.
+    "supercell": n, "a": lattice const, "species": "si" | "dshell",
+    "moments": [mx, my, mz] for all atoms or one such vector an atom})
+    builds an in-memory test species (sirius_tpu.testing: the Si-like one,
+    or the one with an open d-like shell) instead of reading species files
+    — the species-file-free deck form used by tests and tools/loadgen.py.
+    ``moments`` are the atoms' starting moments (they matter where
+    ``num_mag_dims`` is 1); an unknown species or a moments array of the
+    wrong length raises here. Everything else (cutoffs, k-mesh, control
+    knobs incl. ngk_pad_quantum) comes from the normal config sections.
 
     Spanned here (``serve.context_build``), not at the call sites: the
     scheduler, the MD driver and a plain ``run_scf`` client all get it.
@@ -124,41 +126,18 @@ def _build_job_context(cfg, base_dir: str):
         with _CTX_LOCK:
             return SimulationContext.create(cfg, base_dir)
 
-    import sirius_tpu.crystal.unit_cell as ucm
-    from sirius_tpu.testing import synthetic_silicon_type
+    from sirius_tpu.testing import context_of_cell, synthetic_cell
 
-    a = float(syn.get("a", 10.26))
-    lattice = a / 2 * np.array([[0.0, 1, 1], [1, 0, 1], [1, 1, 0]])
-    t = synthetic_silicon_type(ultrasoft=bool(syn.get("ultrasoft", True)))
-    positions = np.asarray(
-        syn.get("positions", [[0.0, 0, 0], [0.25, 0.25, 0.25]]),
-        dtype=np.float64,
-    )
-    n = int(syn.get("supercell", 1))
-    if n > 1:
-        shifts = np.array(
-            [[i, j, k]
-             for i in range(n) for j in range(n) for k in range(n)],
-            dtype=np.float64,
-        )
-        positions = (
-            (positions[None, :, :] + shifts[:, None, :]) / n
-        ).reshape(-1, 3)
-        lattice = lattice * n
-    uc = ucm.UnitCell(
-        lattice=lattice,
-        atom_types=[t],
-        type_of_atom=np.zeros(len(positions), dtype=np.int32),
-        positions=positions,
-        moments=np.zeros((len(positions), 3)),
+    uc = synthetic_cell(
+        species=str(syn.get("species", "si")),
+        ultrasoft=bool(syn.get("ultrasoft", True)),
+        a=float(syn.get("a", 10.26)),
+        positions=syn.get("positions"),
+        supercell=int(syn.get("supercell", 1)),
+        moments=syn.get("moments"),
     )
     with _CTX_LOCK:
-        orig = ucm.UnitCell.from_config
-        try:
-            ucm.UnitCell.from_config = staticmethod(lambda c, b=".": uc)
-            return SimulationContext.create(cfg, base_dir)
-        finally:
-            ucm.UnitCell.from_config = orig
+        return context_of_cell(cfg, uc, base_dir)
 
 
 class SliceScheduler:

@@ -17,10 +17,11 @@ from sirius_tpu.crystal.atom_type import (
 )
 
 
-def synthetic_silicon_type(zn: float = 4.0, ultrasoft: bool = True) -> AtomType:
+def synthetic_silicon_type(zn: float = 4.0, ultrasoft: bool = True,
+                           nr: int = 700) -> AtomType:
     from scipy.special import erf
 
-    r = np.geomspace(1e-6, 12.0, 700)
+    r = np.geomspace(1e-6, 12.0, nr)
     vloc = -zn * erf(r) / r
     # two beta channels (l=0, l=1), smooth nodeless shapes (r*beta(r))
     rb0 = r * np.exp(-(r**2)) * 2.0
@@ -48,6 +49,108 @@ def synthetic_silicon_type(zn: float = 4.0, ultrasoft: bool = True) -> AtomType:
     )
 
 
+# the d-shell species' numbers, fixed by f64 runs of the deck fm2-k444-us
+# (benchmark/configs/fm2-k444-us/config.json has the readings)
+DSHELL_ZN = 8.0
+DSHELL_D_ION = (2.0, 3.0, -6.0)
+DSHELL_WIDTH = 0.4
+# radial points: on the silicon type's 700 the spline integrals of the narrow
+# channel leave 7e-7 Ha a cell against its closed-form transforms, on 1400
+# 1e-7 (f64 CPU runs of the deck on the 2x2x2 mesh, PR 45)
+DSHELL_NR = 1400
+
+
+def synthetic_dshell_type(ultrasoft: bool = True) -> AtomType:
+    """A synthetic species with an open d-like shell: the silicon type's grid
+    (at DSHELL_NR points), erf-Coulomb local potential at DSHELL_ZN, l = 0 and
+    l = 1 Gaussian channels and two l = 0 augmentation channels, plus one
+    narrow attractive l = 2 channel
+    r*beta_2(r) ~ r^3 exp(-r^2 / (2 w^2)), normalised to int (r beta)^2 dr =
+    1. In the 2-atom diamond cell it holds a ferromagnetic ground state
+    several 1e-2 Ha under the non-magnetic one. No real material: every
+    radial function is a Gaussian times a power, so its Bessel transforms
+    have closed forms (benchmark/plain_pwus_spin.py restates them)."""
+    zn = DSHELL_ZN
+    si = synthetic_silicon_type(zn=zn, ultrasoft=ultrasoft, nr=DSHELL_NR)
+    r = si.r
+    w = DSHELL_WIDTH
+    # int r^6 exp(-r^2 / w^2) dr = 15 sqrt(pi) w^7 / 16
+    rb2 = r**3 * np.exp(-(r**2) / (2 * w * w))
+    rb2 /= np.sqrt(15.0 * np.sqrt(np.pi) * w**7 / 16.0)
+    wfs = [
+        AtomicWf(l=0, occupation=2.0, chi=r * np.exp(-0.8 * r), label="S"),
+        AtomicWf(l=1, occupation=0.0, chi=r * r * np.exp(-0.8 * r), label="P"),
+        AtomicWf(l=2, occupation=zn - 2.0, chi=r**3 * np.exp(-1.2 * r), label="D"),
+    ]
+    return AtomType(
+        label="Xd", symbol="Xd", zn=zn, pseudo_type=si.pseudo_type,
+        r=r, vloc=si.vloc,
+        beta=si.beta + [BetaProjector(l=2, rbeta=rb2, nr=len(r))],
+        d_ion=np.diag(DSHELL_D_ION), augmentation=si.augmentation,
+        atomic_wfs=wfs, rho_total=si.rho_total, rho_core=None,
+        core_correction=False, mass=50.0,
+    )
+
+
+SYNTHETIC_SPECIES = {"si": synthetic_silicon_type, "dshell": synthetic_dshell_type}
+
+
+def synthetic_cell(species: str = "si", ultrasoft: bool = True, a: float = 10.26,
+                   positions=None, supercell: int = 1, moments=None):
+    """The diamond-like cell of a synthetic species as a UnitCell: the
+    2-atom fcc cell of lattice constant ``a``, or ``positions`` (fractional)
+    in it, tiled ``supercell`` times a direction. ``moments``: one starting
+    moment vector for every atom, or one vector an atom of the tiled cell."""
+    import sirius_tpu.crystal.unit_cell as ucm
+
+    if species not in SYNTHETIC_SPECIES:
+        raise ValueError(f"unknown synthetic species {species!r} "
+                         f"(have {sorted(SYNTHETIC_SPECIES)})")
+    t = SYNTHETIC_SPECIES[species](ultrasoft=ultrasoft)
+    lattice = a / 2 * np.array([[0.0, 1, 1], [1, 0, 1], [1, 1, 0]])
+    if positions is None:
+        positions = [[0.0, 0, 0], [0.25, 0.25, 0.25]]
+    positions = np.asarray(positions, dtype=np.float64)
+    n = int(supercell)
+    if n > 1:
+        shifts = np.array(
+            [[i, j, k] for i in range(n) for j in range(n) for k in range(n)],
+            dtype=np.float64,
+        )
+        positions = (
+            (positions[None, :, :] + shifts[:, None, :]) / n
+        ).reshape(-1, 3)
+        lattice = lattice * n
+    nat = len(positions)
+    if moments is None:
+        moments = np.zeros((nat, 3))
+    moments = np.asarray(moments, dtype=np.float64)
+    if moments.shape == (3,):
+        moments = np.tile(moments, (nat, 1))
+    if moments.shape != (nat, 3):
+        raise ValueError(f"moments of shape {moments.shape}: one [mx, my, mz] "
+                         f"for all atoms or one for each of the {nat}")
+    return ucm.UnitCell(
+        lattice=lattice, atom_types=[t],
+        type_of_atom=np.zeros(nat, dtype=np.int32),
+        positions=positions, moments=moments,
+    )
+
+
+def context_of_cell(cfg, uc, base_dir: str = ".") -> SimulationContext:
+    """SimulationContext.create reads species from files; hand it a built
+    cell instead (same code path below the unit-cell level). Not
+    thread-safe: serve/scheduler.py holds its lock around it."""
+    import sirius_tpu.crystal.unit_cell as ucm
+
+    orig = ucm.UnitCell.from_config
+    try:
+        ucm.UnitCell.from_config = staticmethod(lambda c, b=".": uc)
+        return SimulationContext.create(cfg, base_dir)
+    finally:
+        ucm.UnitCell.from_config = orig
+
+
 def synthetic_silicon_context(
     gk_cutoff: float = 6.0,
     pw_cutoff: float = 20.0,
@@ -63,9 +166,9 @@ def synthetic_silicon_context(
     """Diamond-Si-like 2-atom cell with the synthetic species.
 
     supercell=n replicates the cell n x n x n (2 n^3 atoms) — the
-    Si-supercell-class bench tier (BASELINE.md flagship regime)."""
-    import sirius_tpu.crystal.unit_cell as ucm
-
+    Si-supercell-class bench tier (BASELINE.md flagship regime).
+    ``moments``: one vector for all atoms or one an atom of the tiled
+    cell (synthetic_cell)."""
     params = {
         "gk_cutoff": gk_cutoff,
         "pw_cutoff": pw_cutoff,
@@ -78,46 +181,9 @@ def synthetic_silicon_context(
     if extra_params:
         params.update(extra_params)
     cfg = Config.from_dict({"parameters": params})
-    a = 10.26
-    lattice = a / 2 * np.array([[0.0, 1, 1], [1, 0, 1], [1, 1, 0]])
-    t = synthetic_silicon_type(ultrasoft=ultrasoft)
-    if positions is None:
-        positions = np.array([[0.0, 0, 0], [0.25, 0.25, 0.25]])
-    positions = np.asarray(positions, dtype=np.float64)
-    if supercell > 1 and moments is not None:
-        raise ValueError("supercell>1 with explicit moments: tile them "
-                         "yourself (per-atom moments must cover all images)")
-    if supercell > 1:
-        n = supercell
-        shifts = np.array(
-            [[i, j, k] for i in range(n) for j in range(n) for k in range(n)],
-            dtype=np.float64,
-        )
-        positions = (
-            (positions[None, :, :] + shifts[:, None, :]) / n
-        ).reshape(-1, 3)
-        lattice = lattice * n
-    uc = ucm.UnitCell(
-        lattice=lattice,
-        atom_types=[t],
-        type_of_atom=np.zeros(len(positions), dtype=np.int32),
-        positions=positions,
-        moments=(
-            np.zeros((len(positions), 3))
-            if moments is None else np.asarray(moments, float)
-        ),
-    )
-    # SimulationContext.create reads species from files; build the parts
-    # directly instead (same code path below the unit-cell level).
-    import sirius_tpu.context as cm
-
-    orig = ucm.UnitCell.from_config
-    try:
-        ucm.UnitCell.from_config = staticmethod(lambda c, b=".": uc)
-        ctx = cm.SimulationContext.create(cfg, ".")
-    finally:
-        ucm.UnitCell.from_config = orig
-    return ctx
+    uc = synthetic_cell("si", ultrasoft=ultrasoft, positions=positions,
+                        supercell=supercell, moments=moments)
+    return context_of_cell(cfg, uc)
 
 
 # --------------------------------------------------------------------------
