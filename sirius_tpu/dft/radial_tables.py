@@ -103,26 +103,26 @@ def structure_factors(uc: UnitCell, gvec: Gvec) -> np.ndarray:
 
 
 def make_periodic_function(
-    uc: UnitCell, gvec: Gvec, form_factor_fn, sfact: np.ndarray | None = None,
+    uc: UnitCell, gvec: Gvec, ff_shells: list, sfact: np.ndarray,
     hook: str | None = None,
 ) -> np.ndarray:
-    """f(G) = (4 pi / Omega) sum_t ff_t(|G|) conj(S_t(G)), evaluated on
-    shells then scattered to the full G array.
+    """f(G) = (4 pi / Omega) sum_t ff_t(|G|) conj(S_t(G)), summed on shells
+    then scattered to the full G array.
 
+    ff_shells: each atom type's form factor on sqrt(gvec.shell_g2) (the
+    context's species tables keep them; they read no position).
+    sfact: structure_factors(uc, gvec).
     hook: name of a host radial-integral callback (C API
     sirius_set_callback_function); when registered in HOST_CALLBACKS the
-    host's integrals replace form_factor_fn for every atom type."""
-    if sfact is None:
-        sfact = structure_factors(uc, gvec)
-    qshell = np.sqrt(gvec.shell_g2)
+    host's integrals replace ff_shells for every atom type."""
     cb = HOST_CALLBACKS.get(hook) if hook else None
+    if cb is not None:
+        qshell = np.sqrt(gvec.shell_g2)
+        # reference callback convention: 1-based atom-type index
+        ff_shells = [np.asarray(cb(it + 1, qshell))
+                     for it in range(len(uc.atom_types))]
     f = np.zeros(gvec.num_gvec, dtype=np.complex128)
-    for it, at in enumerate(uc.atom_types):
-        if cb is not None:
-            # reference callback convention: 1-based atom-type index
-            ff_shell = np.asarray(cb(it + 1, qshell))
-        else:
-            ff_shell = np.asarray(form_factor_fn(at, qshell))
+    for it, ff_shell in enumerate(ff_shells):
         f += ff_shell[gvec.shell_idx] * np.conj(sfact[it])
     return f * (4.0 * np.pi / uc.omega)
 

@@ -1634,6 +1634,9 @@ def _run_scf_inner(
     # become the counters of the H applications and eigenproblems that ran
     band.book()
     counters["num_spin_channels"] = ns
+    # of the context's position-independent table sets (the lattice's and
+    # one an atom type), how many an earlier context of the process built
+    counters["context_tables_reused"] = ctx.tables_reused
     # read-only record of the path taken and of where each stage of the last
     # iteration ran and in which dtype, read off the arrays themselves
     placement = {
@@ -1788,6 +1791,7 @@ def _run_scf_inner(
         num_fused_step_traces=int(counters["num_fused_step_traces"]),
         num_host_xc_traces=int(counters["num_host_xc_traces"]),
         num_spin_channels=int(counters["num_spin_channels"]),
+        context_tables_reused=int(counters["context_tables_reused"]),
         energy_resolution_ha=abs(e_total) * pair_eps(
             fused.rdt if fused is not None else np.float64),
     )

@@ -174,7 +174,10 @@ def _run_md_impl(
     if ctx is None:
         # honours the species-file-free "synthetic" deck section the same
         # way sirius-serve does; plain decks fall through to
-        # SimulationContext.create
+        # SimulationContext.create. The lattice does not move in a run, so
+        # every later step's context (dft/geometry.context_at_positions)
+        # finds this one's G-vector sets, k-spheres and species tables in
+        # the process's memo and builds only what reads the positions
         from sirius_tpu.serve.scheduler import build_job_context
 
         ctx = build_job_context(cfg, base_dir)

@@ -44,14 +44,10 @@ class AugmentationType:
 
 @dataclasses.dataclass
 class Augmentation:
-    per_type: list[AugmentationType | None]
+    """One entry an atom type, None where the type has no augmentation
+    (the context's species tables build and keep them: build_type)."""
 
-    @staticmethod
-    def build(uc: UnitCell, gvec: Gvec) -> "Augmentation":
-        out = []
-        for t in uc.atom_types:
-            out.append(_build_type(t, gvec, uc.omega) if t.augmentation else None)
-        return Augmentation(per_type=out)
+    per_type: list[AugmentationType | None]
 
 
 def aug_radial_tables(t, qmax: float) -> list:
@@ -74,7 +70,7 @@ def aug_radial_tables(t, qmax: float) -> list:
     ]
 
 
-def _build_type(t, gvec: Gvec, omega: float) -> AugmentationType:
+def build_type(t, gvec: Gvec, omega: float) -> AugmentationType:
     nbf = t.num_beta_lm
     qshell = np.sqrt(gvec.shell_g2)
     tabs = aug_radial_tables(t, qmax=qshell[-1] + 1e-9)
@@ -95,7 +91,7 @@ def _build_type(t, gvec: Gvec, omega: float) -> AugmentationType:
 
 def q_pw_at(t, tabs, gcart: np.ndarray, omega: float) -> np.ndarray:
     """Q_{packed}(G) for arbitrary Cartesian G vectors (no atom phase):
-    the _build_type formula with the radial tables evaluated at |G| and the
+    the build_type formula with the radial tables evaluated at |G| and the
     real harmonics at ^G — the strained-lattice evaluation path of the
     stress calculator (reference sigma_us uses d/dq tables instead,
     stress.cpp)."""

@@ -43,6 +43,37 @@ def beta_radial_table(t, qmax: float) -> RadialIntegralTable | None:
     )
 
 
+def gk_directions(gkvec: GkVec) -> tuple[np.ndarray, np.ndarray]:
+    """|G+k| [nk, ngk] and the unit vectors [nk, ngk, 3] (z where G+k = 0):
+    what a species' projector form reads of a k-set."""
+    gk = gkvec.gkcart  # (nk, ngk, 3)
+    qlen = np.linalg.norm(gk, axis=-1)
+    rhat = gk / np.maximum(qlen, 1e-30)[..., None]
+    rhat = np.where(qlen[..., None] > 1e-30, rhat, np.array([0.0, 0, 1.0]))
+    return qlen, rhat
+
+
+def beta_form(t, qlen: np.ndarray, rhat: np.ndarray, omega: float,
+              qmax: float) -> np.ndarray | None:
+    """beta_t,xi(G+k) of one species with no atom phase and no mask:
+    (4 pi / sqrt(Omega)) (-i)^l R_lm(^G+k) RI_xi(|G+k|), shape
+    [nbeta_lm, nk, ngk] (one contiguous [nk, ngk] block a projector, the
+    left factor of BetaProjectors.build's product as it stands there).
+    Reads no position: a function of the k-spheres and the species."""
+    if not t.num_beta:
+        return None
+    nk, ngk = qlen.shape
+    ri = beta_radial_table(t, qmax)(qlen.reshape(-1)).reshape(t.num_beta, nk, ngk)
+    rlm = ylm_real(t.lmax_beta, rhat)  # (nk, ngk, nlm)
+    pref = 4.0 * np.pi / np.sqrt(omega)
+    idxrf, ls, ms = t.beta_lm_table()
+    form = np.empty((t.num_beta_lm, nk, ngk), dtype=np.complex128)
+    for xi in range(t.num_beta_lm):
+        l, m, ir = int(ls[xi]), int(ms[xi]), int(idxrf[xi])
+        form[xi] = pref * (-1j) ** l * rlm[..., lm_index(l, m)] * ri[ir]
+    return form
+
+
 @dataclasses.dataclass
 class BetaProjectors:
     """Dense per-k beta-projector tables + packed D/Q matrices.
@@ -74,11 +105,12 @@ class BetaProjectors:
             yield ia, int(self.offsets[ia]), nbf
 
     @staticmethod
-    def build(uc: UnitCell, gkvec: GkVec, qmax: float) -> "BetaProjectors":
+    def build(uc: UnitCell, gkvec: GkVec, qmax: float,
+              forms: list | None = None) -> "BetaProjectors":
+        """``forms``: beta_form of every atom type on this k-set, where the
+        caller keeps them (the context's species tables); built here
+        otherwise. Everything below them reads the atoms' positions."""
         nk, ngk = gkvec.num_kpoints, gkvec.ngk_max
-        lmax = max((t.lmax_beta for t in uc.atom_types), default=-1)
-        # per-type radial integral tables RI(idxrf, q)
-        tables = [beta_radial_table(t, qmax) for t in uc.atom_types]
         # count total projectors (lm-expanded) over atoms
         counts = [uc.atom_types[it].num_beta_lm for it in uc.type_of_atom]
         nbeta_tot = int(np.sum(counts))
@@ -88,38 +120,25 @@ class BetaProjectors:
         dion = np.zeros((nbeta_tot, nbeta_tot))
         offsets = np.concatenate([[0], np.cumsum(counts)[:-1]]).astype(np.int32)
 
-        if nbeta_tot and lmax >= 0:
-            gk = gkvec.gkcart  # (nk, ngk, 3)
-            qlen = np.linalg.norm(gk, axis=-1)
-            rhat = gk / np.maximum(qlen, 1e-30)[..., None]
-            rhat = np.where(qlen[..., None] > 1e-30, rhat, np.array([0.0, 0, 1.0]))
-            rlm = ylm_real(lmax, rhat)  # (nk, ngk, nlm)
-            minus_i_pow = [(-1j) ** l for l in range(lmax + 1)]
-            pref = 4.0 * np.pi / np.sqrt(uc.omega)
-
+        if nbeta_tot:
+            if forms is None:
+                qlen, rhat = gk_directions(gkvec)
+                forms = [beta_form(t, qlen, rhat, uc.omega, qmax)
+                         for t in uc.atom_types]
+            # phase e^{-i(G+k).r_a}: (G+k).r_a = 2 pi (m + k) . x_a
+            mk = gkvec.millers + gkvec.kpoints[:, None, :]
             off = 0
             for ia in range(uc.num_atoms):
                 it = uc.type_of_atom[ia]
                 t = uc.atom_types[it]
                 if not t.num_beta:
                     continue
-                ri = tables[it](qlen.reshape(-1)).reshape(t.num_beta, nk, ngk)
-                # phase e^{-i(G+k).r_a}: (G+k).r_a = 2 pi (m + k) . x_a
-                mk = gkvec.millers + gkvec.kpoints[:, None, :]
                 phase = np.exp(-2j * np.pi * (mk @ uc.positions[ia]))  # (nk, ngk)
                 idxrf, ls, ms = t.beta_lm_table()
                 for xi in range(t.num_beta_lm):
-                    l, m, ir = int(ls[xi]), int(ms[xi]), int(idxrf[xi])
-                    beta_gk[:, off + xi, :] = (
-                        pref
-                        * minus_i_pow[l]
-                        * rlm[..., lm_index(l, m)]
-                        * ri[ir]
-                        * phase
-                        * gkvec.mask
-                    )
+                    beta_gk[:, off + xi, :] = forms[it][xi] * phase * gkvec.mask
                     atom_of_beta[off + xi] = ia
-                    l_of_beta[off + xi] = l
+                    l_of_beta[off + xi] = int(ls[xi])
                 # D_ion expansion: D_{xi xi'} = D_ion[ir, ir'] delta_{l l'} delta_{m m'}
                 sel = (ls[:, None] == ls[None, :]) & (ms[:, None] == ms[None, :])
                 dion[off : off + t.num_beta_lm, off : off + t.num_beta_lm] = np.where(

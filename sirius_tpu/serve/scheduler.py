@@ -113,6 +113,16 @@ def build_job_context(cfg, base_dir: str = "."):
 
     Spanned here (``serve.context_build``), not at the call sites: the
     scheduler, the MD driver and a plain ``run_scf`` client all get it.
+
+    The context shares its position-independent tables (G-vector sets,
+    k-spheres, each species' tables on them) with every earlier context of
+    the process on the same lattice, cutoffs, k-points and species content,
+    read-only (`SimulationContext.create`); the species is compared by its
+    arrays, so the fresh ``AtomType`` built here every job finds them.
+    ``ctx.tables_reused`` says how many of the 1 + (atom types) table sets
+    were found; the child spans
+    ``context.lattice_tables``, ``context.species_tables`` (``hit``,
+    ``bytes``) and ``context.positions`` split the build.
     """
     with obs_spans.span("serve.context_build"):
         return _build_job_context(cfg, base_dir)

@@ -258,6 +258,7 @@ def test_scf_spans_off_with_telemetry_disabled(tmp_path):
     assert res["num_scf_iterations"] == 2
     # the deck's control.telemetry takes effect at run_scf entry: the
     # context the caller built before it is the one thing spanned (with its
-    # child, the group search)
-    assert [r["name"] for r in cap.records] == ["context.symmetry",
-                                                "serve.context_build"]
+    # children: the group search, the two table stages, the position stage)
+    assert [r["name"] for r in cap.records] == [
+        "context.symmetry", "context.lattice_tables",
+        "context.species_tables", "context.positions", "serve.context_build"]
