@@ -27,7 +27,7 @@ import numpy as np
 from sirius_tpu import runtime
 from sirius_tpu.config.schema import Config
 from sirius_tpu.core.fftgrid import FFTGrid
-from sirius_tpu.core.gvec import Gvec, GkVec
+from sirius_tpu.core.gvec import AtomPhases, Gvec, GkVec
 from sirius_tpu.crystal.kpoints import irreducible_kmesh
 from sirius_tpu.crystal.symmetry import CrystalSymmetry
 from sirius_tpu.crystal.unit_cell import UnitCell
@@ -41,8 +41,10 @@ from sirius_tpu.dft.radial_tables import (
     vloc_ff,
 )
 from sirius_tpu.obs import spans as obs_spans
+from sirius_tpu.ops.atomic import ao_form
 from sirius_tpu.ops.augmentation import Augmentation, AugmentationType, build_type
 from sirius_tpu.ops.beta import BetaProjectors, beta_form, gk_directions
+from sirius_tpu.ops.gamma import build_gamma_map
 
 # ---------------------------------------------------------------------------
 # Tables that read no atomic position, kept between contexts
@@ -123,11 +125,35 @@ class _TableMemo:
 
     def bytes(self) -> int:
         with self._cond:
-            return sum(n for _, n in self._entries.values())
+            return sum(n + getattr(getattr(v, "deferred", None), "nbytes", 0)
+                       for v, n in self._entries.values())
 
     def clear(self) -> None:
         with self._cond:
             self._entries.clear()
+
+
+class _Deferred:
+    """Tables of a memo entry that only some jobs read, built at the first
+    request, frozen, kept with the entry and counted in its bytes (the
+    bounds are enforced at the memo's next insert). One table a name: a
+    request under another ``tag`` (the LCAO start's random rows for another
+    row count) replaces it."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._kept: dict = {}  # name -> (tag, value, bytes)
+        self.nbytes = 0
+
+    def get(self, name: str, tag, build):
+        with self._lock:
+            ent = self._kept.get(name)
+            if ent is None or ent[0] != tag:
+                value = build()
+                nbytes = _freeze(value)
+                self.nbytes += nbytes - (0 if ent is None else ent[2])
+                ent = self._kept[name] = (tag, value, nbytes)
+            return ent[1]
 
 
 _TABLES = _TableMemo(_TABLES_MAX_ENTRIES, _TABLES_MAX_BYTES)
@@ -156,6 +182,8 @@ def _type_digest(t) -> str:
              t.rho_core, t.rho_total]
     for b in t.beta:
         parts += [int(b.l), int(b.nr), b.j, b.rbeta]
+    for w in t.atomic_wfs:
+        parts += [int(w.l), w.chi]
     for ch in t.augmentation:
         parts += [int(ch.i), int(ch.j), int(ch.l), ch.qr]
     return _digest(*parts)
@@ -175,6 +203,9 @@ class _LatticeTables:
     gk_len: np.ndarray  # (nk, ngk) |G+k|
     gk_hat: np.ndarray  # (nk, ngk, 3) unit vectors
     qshell: np.ndarray  # (nshell,) sqrt(gvec.shell_g2)
+    # the LCAO start's random rows and the Gamma sphere's pairing: read by
+    # some jobs only, so built at the first one's request
+    deferred: _Deferred
 
 
 def _build_lattice_tables(lattice, pw_cutoff, gk_cutoff, fgs, kpts, kw,
@@ -205,7 +236,7 @@ def _build_lattice_tables(lattice, pw_cutoff, gk_cutoff, fgs, kpts, kw,
     return _LatticeTables(
         gvec=gvec, gvec_coarse=gvec_coarse, fft_coarse=fft_coarse,
         coarse_to_fine=c2f, gkvec=gkvec, gk_len=gk_len, gk_hat=gk_hat,
-        qshell=np.sqrt(gvec.shell_g2))
+        qshell=np.sqrt(gvec.shell_g2), deferred=_Deferred())
 
 
 # the three form factors on the fine set's shells (local potential, core
@@ -221,6 +252,7 @@ class _SpeciesTables:
     No atom phase anywhere."""
 
     beta_form: np.ndarray | None  # (nbeta_lm, nk, ngk), ops/beta.beta_form
+    ao_form: np.ndarray | None  # (nao_lm, nk, ngk), ops/atomic.ao_form
     aug: AugmentationType | None
     ff_shells: tuple  # by _FF_HOOKS; None where a host callback stands in
 
@@ -229,6 +261,7 @@ def _build_species_tables(t, lat: _LatticeTables, qmax, rc,
                           hooked) -> _SpeciesTables:
     return _SpeciesTables(
         beta_form=beta_form(t, lat.gk_len, lat.gk_hat, lat.gvec.omega, qmax),
+        ao_form=ao_form(t, lat.gk_len, lat.gk_hat, lat.gvec.omega, qmax),
         aug=build_type(t, lat.gvec, lat.gvec.omega) if t.augmentation else None,
         ff_shells=tuple(
             None if skip else np.asarray(fn(t, lat.qshell))
@@ -242,9 +275,12 @@ def _position_stage(uc: UnitCell, lat: _LatticeTables, species: list,
                     pw_cutoff: float, qmax: float):
     """Everything of a context that reads ``uc.positions`` or
     ``uc.moments``: the projectors' atom phases, the block-diagonal D_ion
-    and Q matrices, the structure factors, the three periodic functions
-    summed over them and the Ewald energy. Built every time; a shared
-    atom-phase table belongs here."""
+    and Q matrices, the atoms' phases on the fine G set, the structure
+    factors, the three periodic functions summed over them and the Ewald
+    energy. Built every time. The phase table is built here once
+    (``context.phases``) and goes with the context: the structure factors
+    and the Ewald sum read it here, the augmentation's host and device
+    tables later in the job."""
     gvec = lat.gvec
     beta = BetaProjectors.build(uc, lat.gkvec, qmax=qmax,
                                 forms=[s.beta_form for s in species])
@@ -258,7 +294,10 @@ def _position_stage(uc: UnitCell, lat: _LatticeTables, species: list,
             if at is not None:
                 qmat[off : off + nbf, off : off + nbf] = at.q_mtrx
         beta = dataclasses.replace(beta, qmat=qmat)
-    sfact = structure_factors(uc, gvec)
+    with obs_spans.span("context.phases") as sp:
+        phases = AtomPhases(gvec.millers, uc.positions)
+        sp.set(bytes=phases.table.nbytes)
+    sfact = structure_factors(uc, gvec, phases)
     vloc_g, rho_core_g, rho_at_g = (
         make_periodic_function(uc, gvec, [s.ff_shells[i] for s in species],
                                sfact, hook=hook)
@@ -270,8 +309,9 @@ def _position_stage(uc: UnitCell, lat: _LatticeTables, species: list,
         gvec.gcart,
         gvec.millers,
         pw_cutoff,
+        phases=phases,
     )
-    return beta, aug, vloc_g, rho_core_g, rho_at_g, e_ewald
+    return beta, aug, vloc_g, rho_core_g, rho_at_g, e_ewald, phases
 
 
 @dataclasses.dataclass
@@ -297,6 +337,13 @@ class SimulationContext:
     # of the 1 + (atom types) position-independent table sets of this
     # build, how many an earlier context of the process had built
     tables_reused: int = 0
+    # e^{-2 pi i G.x_a} of the atoms on the fine set, built once in the
+    # position stage: this context's own, gone with it
+    phases: AtomPhases | None = None
+    # each atom type's LCAO form on the k-spheres (ops/atomic.ao_form) and
+    # the lattice entry's on-demand tables: shared, read-only (`_TABLES`)
+    ao_forms: list | None = None
+    deferred: _Deferred = dataclasses.field(default_factory=_Deferred)
 
     @staticmethod
     def create(cfg: Config, base_dir: str = ".") -> "SimulationContext":
@@ -307,11 +354,12 @@ class SimulationContext:
         every other context of the process on the same lattice, cutoffs,
         k-points and species content (`_TABLES`), and read-only: writing
         into ``ctx.gvec``, ``ctx.gvec_coarse``, ``ctx.coarse_to_fine``,
-        ``ctx.gkvec`` or ``ctx.aug.per_type[i]`` raises. Everything that
-        reads a position or a moment (``beta``, ``vloc_g``, ``rho_core_g``,
-        ``rho_atomic_g``, ``e_ewald``, ``symmetry``) is this context's own
-        and built every time, so a build at a new geometry of a seen
-        lattice costs what one at a repeated geometry costs."""
+        ``ctx.gkvec``, ``ctx.aug.per_type[i]``, ``ctx.ao_forms[i]``,
+        ``ctx.random_rows(n)`` or ``ctx.gamma_map()`` raises. Everything
+        that reads a position or a moment (``beta``, ``phases``, ``vloc_g``,
+        ``rho_core_g``, ``rho_atomic_g``, ``e_ewald``, ``symmetry``) is this
+        context's own and built every time, so a build at a new geometry of
+        a seen lattice costs what one at a repeated geometry costs."""
         # set-up tables are host work (runtime.py placement rule)
         with runtime.host_scope():
             return SimulationContext._create(cfg, base_dir)
@@ -379,7 +427,7 @@ class SimulationContext:
                    types=len(species), hits=hits)
         reused += hits
         with obs_spans.span("context.positions"):
-            beta, aug, vloc_g, rho_core_g, rho_at_g, e_ewald = (
+            beta, aug, vloc_g, rho_core_g, rho_at_g, e_ewald, phases = (
                 _position_stage(uc, lat, species, p.pw_cutoff, qmax))
         nval = uc.num_valence_electrons
         nbnd = int(nval / 2.0) + max(10, int(0.1 * nval))
@@ -409,7 +457,37 @@ class SimulationContext:
             num_spins=2 if p.num_mag_dims > 0 else 1,
             num_mag_dims=p.num_mag_dims,
             tables_reused=reused,
+            phases=phases,
+            ao_forms=[s.ao_form for s in species],
+            deferred=lat.deferred,
         )
+
+    def gamma_map(self):
+        """ops/gamma.build_gamma_map of the first k-sphere: a function of
+        the lattice's tables, kept with them."""
+        return self.deferred.get("gamma_map", None, lambda: build_gamma_map(
+            np.asarray(self.gkvec.millers[0]), np.asarray(self.gkvec.mask[0])))
+
+    def random_rows(self, rows: int) -> np.ndarray:
+        """[nk, rows, ngk] complex128: the rows that fill an LCAO start
+        beyond the atomic orbitals (dft/scf._initial_subspace), the same
+        numbers every job: default_rng(42) normals a k-point, damped by
+        1 / (1 + |G+k|^2 / 2) and masked. A function of the k-spheres and
+        the row count, kept with the lattice's tables."""
+        def build():
+            gk = self.gkvec
+            rng = np.random.default_rng(42)
+            damp = 1.0 / (1.0 + gk.kinetic())
+            out = np.empty((gk.num_kpoints, rows, gk.ngk_max),
+                           dtype=np.complex128)
+            for ik in range(gk.num_kpoints):
+                r = (rng.standard_normal((rows, gk.ngk_max))
+                     + 1j * rng.standard_normal((rows, gk.ngk_max)))
+                out[ik] = r * damp[ik]
+                out[ik] *= gk.mask[ik]
+            return out
+
+        return self.deferred.get("random_rows", int(rows), build)
 
     @property
     def max_occupancy(self) -> float:

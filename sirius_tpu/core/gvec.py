@@ -74,6 +74,20 @@ def _enumerate_sphere(
 
 
 _PHASE_ROWS = 1 << 16  # rows of the angle table one task of phase_factors takes
+_SUM_ROWS = 1 << 13  # rows of a phase table one task of atom_sum transposes
+
+
+def _on_row_blocks(fill, num_rows: int, rows: int) -> None:
+    """``fill(lo)`` for every block of ``rows`` rows, on as many threads as
+    the host has cores (numpy's loops release the interpreter lock)."""
+    starts = range(0, num_rows, rows)
+    workers = min(len(starts), os.cpu_count() or 1)
+    if workers <= 1:
+        for lo in starts:
+            fill(lo)
+    else:
+        with ThreadPoolExecutor(max_workers=workers) as pool:
+            list(pool.map(fill, starts))
 
 
 def phase_factors(millers: np.ndarray, positions: np.ndarray,
@@ -81,9 +95,9 @@ def phase_factors(millers: np.ndarray, positions: np.ndarray,
     """exp(sign 2 pi i m . x) for integer Miller rows m [ng, 3] and
     fractional positions x [na, 3], shape [ng, na]: cos + i sin of the real
     angle, bit for bit what np.exp(sign * 2j * np.pi * (m @ x.T)) gives,
-    row blocks on as many threads as the host has cores (numpy's loops
-    release the interpreter lock). A 54-atom cell has 53 million pairs and
-    builds this table four times a job."""
+    row blocks on threads. A 54-atom cell has 53 million pairs: a context
+    builds this table once for all its atoms (`AtomPhases`) and everything
+    of a job that reads the fine G set takes it from there."""
     x = np.atleast_2d(np.asarray(positions, dtype=np.float64))
     dots = np.asarray(millers).reshape(-1, 3) @ x.T
     out = np.empty(dots.shape, dtype=np.complex128)
@@ -93,15 +107,85 @@ def phase_factors(millers: np.ndarray, positions: np.ndarray,
         out.real[lo:lo + _PHASE_ROWS] = np.cos(theta)
         out.imag[lo:lo + _PHASE_ROWS] = np.sin(theta)
 
-    starts = range(0, len(dots), _PHASE_ROWS)
-    workers = min(len(starts), os.cpu_count() or 1)
-    if workers <= 1:
-        for lo in starts:
-            fill(lo)
-    else:
-        with ThreadPoolExecutor(max_workers=workers) as pool:
-            list(pool.map(fill, starts))
+    _on_row_blocks(fill, len(dots), _PHASE_ROWS)
     return out
+
+
+def atom_sum(table: np.ndarray) -> np.ndarray:
+    """Sum over the atoms (axis 1) of a phase table [ng, na], bit for bit
+    what ``table[:, sel].sum(axis=1)`` gives under a ``sel`` of every atom,
+    without that copy: numpy lays an indexed copy out atom by atom and so
+    adds whole columns one after the other, where a sum along the rows of
+    the table itself goes pairwise and rounds otherwise. Here a block of
+    rows is transposed and its columns added in that order, on threads."""
+    out = np.empty(len(table), dtype=table.dtype)
+
+    def fill(lo):
+        out[lo:lo + _SUM_ROWS] = np.asfortranarray(
+            table[lo:lo + _SUM_ROWS]).sum(axis=1)
+
+    _on_row_blocks(fill, len(table), _SUM_ROWS)
+    return out
+
+
+class AtomPhases:
+    """e^{-2 pi i G.x_a} of every atom of a cell on a G set, ``table``
+    [ng, natoms] complex128, read-only: built once a context
+    (context._position_stage) and read by everything of the job that puts an
+    atom on the fine G set (structure factors, Ewald sum, augmentation
+    charge and D matrix on the host and the fused step's tables of them).
+    The sign is the one the augmentation's contractions read as it stands;
+    e^{+2 pi i G.x} is its conjugate, to the bit. It lives as long as its
+    context and is in no memo: it is a function of the positions.
+    What multiplies the Miller indices by ONE atom's position at a time
+    (the starting magnetisation and the atomic moments of dft/density.py,
+    the form-factor forces) rounds the angle through another BLAS routine
+    and keeps its own np.exp: a column of this table is not that number.
+
+    ``reads`` counts the requests the table served (counters.
+    phase_table_reads of a job)."""
+
+    def __init__(self, millers: np.ndarray, positions: np.ndarray):
+        self.millers = millers
+        self.positions = np.array(positions, dtype=np.float64)
+        self.table = phase_factors(millers, self.positions, -1.0)
+        self.table.setflags(write=False)
+        self.reads = 0
+
+    def minus(self, millers: np.ndarray, positions: np.ndarray,
+              atoms=None) -> np.ndarray:
+        """e^{-2 pi i G.x} of ``atoms`` (all of them when None) as columns,
+        equal to the bit to ``phase_factors(millers, positions[atoms],
+        -1.0)``. ``millers`` and ``positions`` are the caller's own and
+        must be the table's."""
+        if (len(millers) != len(self.table)
+                or not np.array_equal(positions, self.positions)):
+            raise ValueError("atom phases of another G set or of other "
+                             "positions than the caller's")
+        natoms = self.table.shape[1]
+        if atoms is None:
+            atoms = np.arange(natoms)
+        atoms = np.asarray(atoms)
+        if len(atoms) == 1 and natoms > 1:
+            # numpy multiplies a matrix by ONE column through another BLAS
+            # routine than by several, and the angle's last bit differs:
+            # a lone atom of a type keeps the call it had
+            return phase_factors(self.millers, self.positions[atoms], -1.0)
+        self.reads += 1
+        if np.array_equal(atoms, np.arange(natoms)):
+            return self.table
+        return self.table[:, atoms]
+
+
+def minus_phases(phases: AtomPhases | None, millers: np.ndarray,
+                 positions: np.ndarray, atoms=None) -> np.ndarray:
+    """e^{-2 pi i m.x} [ng, len(atoms)] for a reader on the fine G set: from
+    the job's table where its caller holds one, built here where not (a
+    strained lattice of the stress calculator, a cell outside a context)."""
+    if phases is not None:
+        return phases.minus(millers, positions, atoms)
+    return phase_factors(
+        millers, positions if atoms is None else positions[atoms], -1.0)
 
 
 def _shells(glen2: np.ndarray) -> tuple[np.ndarray, np.ndarray]:

@@ -492,14 +492,12 @@ class GammaSolver(_Booked):
     mesh = None
 
     def __init__(self, ctx, cfg, devs):
-        from sirius_tpu.ops.gamma import ROWS_PER_BOX, build_gamma_map
+        from sirius_tpu.ops.gamma import ROWS_PER_BOX
 
         self.ctx, self.dev = ctx, devs[0]
         self.rows_per_box = ROWS_PER_BOX  # two real bands share a box
         self._rule(cfg.iterative_solver)
-        self.gm = build_gamma_map(
-            np.asarray(ctx.gkvec.millers[0]), np.asarray(ctx.gkvec.mask[0])
-        )
+        self.gm = ctx.gamma_map()  # the lattice's, read-only
         self.x_packed: list = [None] * ctx.num_spins
         self._cache: dict = {}  # rdtype -> constant-table GammaParams
         self.rdt = None

@@ -87,7 +87,8 @@ def run_direct_min(cfg: Config, base_dir: str = ".", ctx=None,
                 if polarized
                 else pot_.veff_g
             )
-            d = d_operator(ctx.unit_cell, ctx.gvec, ctx.aug, vs_g, ctx.beta)
+            d = d_operator(ctx.unit_cell, ctx.gvec, ctx.aug, vs_g, ctx.beta,
+                           phases=ctx.phases)
         return make_hk_params(ctx, ik, pot_.veff_r_coarse[ispn], d)
 
     for ik in range(nk):

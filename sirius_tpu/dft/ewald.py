@@ -22,7 +22,7 @@ from __future__ import annotations
 import numpy as np
 from scipy.special import erfc
 
-from sirius_tpu.core.gvec import phase_factors
+from sirius_tpu.core.gvec import AtomPhases, phase_factors
 
 
 def ewald_lambda(pw_cutoff: float, omega: float) -> float:
@@ -43,6 +43,7 @@ def ewald_energy(
     gcart: np.ndarray,  # (ng, 3), G=0 first
     millers: np.ndarray,  # (ng, 3)
     pw_cutoff: float,
+    phases: AtomPhases | None = None,  # the context's table on millers
 ) -> float:
     lattice = np.asarray(lattice, dtype=np.float64)
     omega = float(abs(np.linalg.det(lattice)))
@@ -52,7 +53,12 @@ def ewald_energy(
 
     # G-space sum (skip G=0)
     g2 = np.sum(gcart[1:] ** 2, axis=1)
-    phase = phase_factors(millers[1:], positions)  # (ng-1, natom)
+    # e^{-2 pi i m.x}, (ng-1, natom): only |S(G)|^2 is read, and the
+    # conjugate's is the same to the bit
+    if phases is None:
+        phase = phase_factors(millers[1:], positions, -1.0)
+    else:
+        phase = phases.minus(millers, positions)[1:]
     s = phase @ z
     ewald_g = float(np.sum(np.abs(s) ** 2 * np.exp(-g2 / (4 * lam)) / g2))
     ewald_g -= nel * nel / (4.0 * lam)

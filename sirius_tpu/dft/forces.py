@@ -20,6 +20,7 @@ import numpy as np
 from scipy.special import erfc
 
 from sirius_tpu.context import SimulationContext
+from sirius_tpu.core.gvec import minus_phases
 from sirius_tpu.dft.ewald import ewald_lambda
 from sirius_tpu.dft.radial_tables import rho_core_form_factor, vloc_ff
 
@@ -76,7 +77,8 @@ def forces_ewald(ctx: SimulationContext) -> np.ndarray:
     # G-space: F_a = (4 pi / Omega) z_a sum_G!=0 G e^{-G^2/4lam}/G^2
     #                Im[e^{-i G r_a} S(G)]
     g2 = gv.glen2[1:]
-    phases = np.exp(2j * np.pi * (gv.millers[1:] @ uc.positions.T))  # (ng, na)
+    # e^{+iG.r}, (ng-1, na): the context's table holds the conjugate
+    phases = np.conj(minus_phases(ctx.phases, gv.millers, uc.positions)[1:])
     s = phases @ z
     w = np.exp(-g2 / (4 * lam)) / g2
     for ia in range(natom):

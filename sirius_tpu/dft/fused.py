@@ -270,7 +270,7 @@ class FusedScf:
             tables["beta_re"], tables["beta_im"] = z, z
         if self.has_aug:
             tables["aug"] = build_aug_device_tables(
-                ctx.unit_cell, ctx.gvec, ctx.aug, ctx.beta
+                ctx.unit_cell, ctx.gvec, ctx.aug, ctx.beta, phases=ctx.phases
             )
         if self.do_symmetrize:
             tables.update(symmetry_tables(ctx))

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from sirius_tpu.core.gvec import Gvec, phase_factors
+from sirius_tpu.core.gvec import AtomPhases, Gvec, atom_sum, minus_phases
 from sirius_tpu.core.radial import Spline, spline_quadrature_weights
 from sirius_tpu.crystal.unit_cell import UnitCell
 
@@ -92,13 +92,19 @@ def rho_total_form_factor(atype, q: np.ndarray) -> np.ndarray:
     return sbessel_integral(atype.r, atype.rho_total, 0, q, m=0) / (4.0 * np.pi)
 
 
-def structure_factors(uc: UnitCell, gvec: Gvec) -> np.ndarray:
-    """S_t(G) = sum_{a in t} e^{2 pi i m . x_a}, shape (ntypes, ng)."""
+def structure_factors(uc: UnitCell, gvec: Gvec,
+                      phases: AtomPhases | None = None) -> np.ndarray:
+    """S_t(G) = sum_{a in t} e^{2 pi i m . x_a}, shape (ntypes, ng).
+    ``phases``: the context's atom-phase table, where the caller has one."""
     out = np.zeros((len(uc.atom_types), gvec.num_gvec), dtype=np.complex128)
-    phase = phase_factors(gvec.millers, uc.positions)  # (ng, natom)
+    # e^{-2 pi i m.x}, (ng, natom): the conjugate of a sum of conjugates is
+    # the sum, to the bit
+    minus = minus_phases(phases, gvec.millers, uc.positions)
     for it in range(len(uc.atom_types)):
         sel = uc.type_of_atom == it
-        out[it] = phase[:, sel].sum(axis=1)
+        # (one species: no copy of the whole table)
+        out[it] = np.conj(atom_sum(minus) if sel.all()
+                          else minus[:, sel].sum(axis=1))
     return out
 
 

@@ -87,27 +87,23 @@ def _initial_subspace(ctx: SimulationContext) -> jnp.ndarray:
     eigenpairs it can reach instead (reference initialize_subspace.hpp:27
     always spans all atomic wfs and keeps the lowest nb Ritz vectors;
     run_scf performs that rotation at the first iteration)."""
-    nk = ctx.gkvec.num_kpoints
-    nb = ctx.num_bands
-    ngk = ctx.gkvec.ngk_max
-    ao = atomic_orbitals(ctx.unit_cell, ctx.gkvec, ctx.cfg.parameters.gk_cutoff + 1e-9)
-    nao = ao.shape[1]
-    nbig = max(nb, nao)
-    rng = np.random.default_rng(42)
-    psi = np.zeros((nk, ctx.num_spins, nbig, ngk), dtype=np.complex128)
-    for ik in range(nk):
-        base = np.zeros((nbig, ngk), dtype=np.complex128)
-        n0 = min(nao, nbig)
-        if n0:
-            base[:n0] = ao[ik, :n0]
-        if nbig > n0:
-            r = rng.standard_normal((nbig - n0, ngk)) + 1j * rng.standard_normal((nbig - n0, ngk))
-            # damp high-G components so random vectors are smooth-ish
-            damp = 1.0 / (1.0 + ctx.gkvec.kinetic()[ik])
-            base[n0:] = r * damp
-        base *= ctx.gkvec.mask[ik]
-        for ispn in range(ctx.num_spins):
-            psi[ik, ispn] = base
+    with obs_spans.span("scf.setup.subspace") as sp:
+        # the orbitals' forms are the species' and the random rows the
+        # lattice's (context._TABLES): only the atoms' phases are built here
+        ao = atomic_orbitals(ctx.unit_cell, ctx.gkvec,
+                             ctx.cfg.parameters.gk_cutoff + 1e-9,
+                             forms=ctx.ao_forms)
+        nk, nao, ngk = ao.shape
+        nbig = max(ctx.num_bands, nao)
+        psi = np.empty((nk, ctx.num_spins, nbig, ngk), dtype=np.complex128)
+        # (masked a second time, as ever: the padded slots' zeros keep
+        # their signs, and the block its bytes)
+        ao *= ctx.gkvec.mask[:, None, :]
+        psi[:, :, :nao] = ao[:, None]
+        if nbig > nao:
+            # damped so that the random vectors are smooth-ish
+            psi[:, :, nao:] = ctx.random_rows(nbig - nao)[:, None]
+        sp.set(atomic_orbitals=int(nao), random_rows=int(nbig - nao))
     # host numpy: the band solves upload it themselves, as a (re, im) pair
     # on the batched path (parallel/batched.py real-boundary contract)
     return psi
@@ -1066,7 +1062,8 @@ def _run_scf_inner(
                 if ctx.aug is not None:
                     vs_g = pot.veff_g + (pot.bz_g if ispn == 0 else -pot.bz_g) if polarized else pot.veff_g
                     d_by_spin.append(
-                        d_operator(ctx.unit_cell, ctx.gvec, ctx.aug, vs_g, ctx.beta)
+                        d_operator(ctx.unit_cell, ctx.gvec, ctx.aug, vs_g,
+                                   ctx.beta, phases=ctx.phases)
                     )
                 else:
                     d_by_spin.append(ctx.beta.dion)
@@ -1398,7 +1395,8 @@ def _run_scf_inner(
                     for _, off, nbf in ctx.beta.atom_blocks(ctx.unit_cell)
                 ]
                 dm_blocks_by_spin.append(dm_blocks)
-                rho_spin[ispn] += rho_aug_g(ctx.unit_cell, ctx.gvec, ctx.aug, dm_blocks)
+                rho_spin[ispn] += rho_aug_g(ctx.unit_cell, ctx.gvec, ctx.aug,
+                                            dm_blocks, phases=ctx.phases)
         rho_new = rho_spin.sum(axis=0)
         mag_new = rho_spin[0] - rho_spin[1] if polarized else None
         if _cks.enabled():
@@ -1637,6 +1635,10 @@ def _run_scf_inner(
     # of the context's position-independent table sets (the lattice's and
     # one an atom type), how many an earlier context of the process built
     counters["context_tables_reused"] = ctx.tables_reused
+    # the atoms' phases on the fine G set: the tables the job's context
+    # built, and the readers that took one instead of building their own
+    counters["phase_table_builds"] = int(ctx.phases is not None)
+    counters["phase_table_reads"] = ctx.phases.reads if ctx.phases else 0
     # read-only record of the path taken and of where each stage of the last
     # iteration ran and in which dtype, read off the arrays themselves
     placement = {
@@ -1997,7 +1999,7 @@ def run_scf_from_file(
                 d_operator(
                     ctx.unit_cell, ctx.gvec, ctx.aug,
                     pot.veff_g + (0 if pot.bz_g is None else (pot.bz_g if ispn == 0 else -pot.bz_g)),
-                    ctx.beta,
+                    ctx.beta, phases=ctx.phases,
                 )
                 for ispn in range(ctx.num_spins)
             ])

@@ -53,7 +53,9 @@ def _initial_spinors(ctx: SimulationContext) -> np.ndarray:
     nk = ctx.gkvec.num_kpoints
     nb = ctx.num_bands
     ngk = ctx.gkvec.ngk_max
-    ao = atomic_orbitals(ctx.unit_cell, ctx.gkvec, ctx.cfg.parameters.gk_cutoff + 1e-9)
+    ao = atomic_orbitals(ctx.unit_cell, ctx.gkvec,
+                         ctx.cfg.parameters.gk_cutoff + 1e-9,
+                         forms=ctx.ao_forms)
     rng = np.random.default_rng(42)
     psi = np.zeros((nk, nb, 2, ngk), dtype=np.complex128)
     nao = ao.shape[1]
@@ -167,11 +169,12 @@ def run_scf_nc(
     for it in range(p.num_dft_iter):
         # --- spin-block D operator ---
         if ctx.aug is not None:
-            d0 = d_operator(ctx.unit_cell, ctx.gvec, ctx.aug, pot.veff_g, ctx.beta)
+            d0 = d_operator(ctx.unit_cell, ctx.gvec, ctx.aug, pot.veff_g,
+                            ctx.beta, phases=ctx.phases)
             db = [
                 d_operator(
                     ctx.unit_cell, ctx.gvec, ctx.aug, pot.bvec_g[i], ctx.beta,
-                    include_dion=False,
+                    include_dion=False, phases=ctx.phases,
                 )
                 for i in range(3)
             ]
@@ -246,7 +249,8 @@ def run_scf_nc(
 
             def aug(mat):
                 bl = [mat[off : off + nbf, off : off + nbf] for _, off, nbf in blocks]
-                return rho_aug_g(ctx.unit_cell, ctx.gvec, ctx.aug, bl)
+                return rho_aug_g(ctx.unit_cell, ctx.gvec, ctx.aug, bl,
+                                 phases=ctx.phases)
 
             rho_new = rho_new + aug(comp["rho"])
             mvec_new = mvec_new + np.stack(

@@ -62,6 +62,8 @@ UNCOSTED_SPANS = (
     # inside a context build, the group search with the mesh's wedge
     "scf.setup.symmetry",
     "context.symmetry",
+    # the LCAO start (dft/scf._initial_subspace): host numpy on kept tables
+    "scf.setup.subspace",
     # the two host potentials of a job (dft/potential.generate_potential)
     "scf.setup.potential",
     "scf.finalize",

@@ -226,7 +226,8 @@ def probe_stages(ctx, xc, psi, occ, evals, rho_g, mag_g=None,
     has_aug = ctx.aug is not None and ctx.beta.num_beta_total > 0
     if has_aug:
         d64 = np.asarray(
-            d_operator(ctx.unit_cell, ctx.gvec, ctx.aug, veff_g, ctx.beta))
+            d_operator(ctx.unit_cell, ctx.gvec, ctx.aug, veff_g, ctx.beta,
+                       phases=ctx.phases))
         beta = np.asarray(ctx.beta.beta_gk, dtype=np.complex128)
         bp = np.einsum("kxg,ksbg->ksbx", np.conj(beta), psi)
         # first-order nonlocal-energy weight: dE = sum dD_xy M_xy
@@ -316,7 +317,7 @@ def probe_stages(ctx, xc, psi, occ, evals, rho_g, mag_g=None,
         if has_aug:
             d_p = np.asarray(d_operator(
                 ctx.unit_cell, ctx.gvec, ctx.aug, _rt(veff_g, prec),
-                ctx.beta))
+                ctx.beta, phases=ctx.phases))
             out["scf.d_matrix"] = {
                 "energy_impact_ha": abs(float(np.sum(
                     (np.real(d_p) - np.real(d64)) * dm_w))),

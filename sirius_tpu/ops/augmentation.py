@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sirius_tpu.core.gvec import Gvec, phase_factors
+from sirius_tpu.core.gvec import AtomPhases, Gvec, minus_phases
 from sirius_tpu.core.radial import RadialIntegralTable
 from sirius_tpu.core.sht import gaunt_rlm, lm_index, num_lm, ylm_real
 from sirius_tpu.crystal.unit_cell import UnitCell
@@ -140,6 +140,7 @@ def rho_aug_g(
     dm: list,  # per-atom (nbf_a, nbf_a) complex density-matrix blocks
     q_pw_by_type: list | None = None,  # optional Q(G) override (e.g. the
     # strained-lattice tables of the stress calculator)
+    phases: AtomPhases | None = None,  # the context's atom-phase table
 ) -> np.ndarray:
     """Augmentation charge rho_aug(G) on the fine set."""
     out = np.zeros(gvec.num_gvec, dtype=np.complex128)
@@ -154,9 +155,9 @@ def rho_aug_g(
         dmp = np.stack(
             [w * np.real(dm[ia][at.xi1, at.xi2]) for ia in atoms]
         )  # (na_t, nqlm)
-        phases = phase_factors(gvec.millers, uc.positions[atoms], -1.0)  # (ng, na_t)
+        ph = minus_phases(phases, gvec.millers, uc.positions, atoms)  # (ng, na_t)
         # (ng, na_t) @ (na_t, nqlm) -> then contract with q_pw
-        out += np.einsum("ga,aq,qg->g", phases, dmp, q_pw, optimize=True)
+        out += np.einsum("ga,aq,qg->g", ph, dmp, q_pw, optimize=True)
     return out
 
 
@@ -167,6 +168,7 @@ def d_operator(
     veff_g: np.ndarray,
     beta,  # BetaProjectors (bare D + packed block layout)
     include_dion: bool = True,
+    phases: AtomPhases | None = None,  # the context's atom-phase table
 ) -> np.ndarray:
     """Full D matrix: bare D_ion plus the augmentation term
     Omega sum_G conj(V_eff(G)) Q(G) e^{-i G r_a} per atom.
@@ -182,8 +184,8 @@ def d_operator(
         if at is None:
             continue
         atoms = uc.atoms_of_type(it)
-        phases = phase_factors(gvec.millers, uc.positions[atoms], -1.0)  # (ng, na_t)
-        vq = omega * np.real(at.q_pw @ (np.conj(veff_g)[:, None] * phases))  # (nqlm, na_t)
+        ph = minus_phases(phases, gvec.millers, uc.positions, atoms)  # (ng, na_t)
+        vq = omega * np.real(at.q_pw @ (np.conj(veff_g)[:, None] * ph))  # (nqlm, na_t)
         for j, ia in enumerate(atoms):
             vq_by_atom[ia] = (at, vq[:, j])
     for ia, off, nbf in beta.atom_blocks(uc):
@@ -206,7 +208,8 @@ def d_operator(
 
 
 def build_aug_device_tables(uc: UnitCell, gvec: Gvec, aug: Augmentation,
-                            beta) -> list[dict]:
+                            beta, phases: AtomPhases | None = None
+                            ) -> list[dict]:
     """Per-type numpy tables for rho_aug_g_device / d_operator_device.
 
     gidx flattens the (off + xi1, off + xi2) positions of each atom's
@@ -221,7 +224,7 @@ def build_aug_device_tables(uc: UnitCell, gvec: Gvec, aug: Augmentation,
         if at is None:
             continue
         atoms = uc.atoms_of_type(it)
-        phases = phase_factors(gvec.millers, uc.positions[atoms], -1.0)
+        ph = minus_phases(phases, gvec.millers, uc.positions, atoms)
         gidx = np.stack([
             (offs[ia] + at.xi1).astype(np.int64) * nbeta + (offs[ia] + at.xi2)
             for ia in atoms
@@ -233,8 +236,8 @@ def build_aug_device_tables(uc: UnitCell, gvec: Gvec, aug: Augmentation,
         out.append({
             "q_re": np.real(at.q_pw),
             "q_im": np.imag(at.q_pw),
-            "ph_re": np.real(phases),
-            "ph_im": np.imag(phases),
+            "ph_re": np.real(ph),
+            "ph_im": np.imag(ph),
             "w": np.where(at.xi1 == at.xi2, 1.0, 2.0),
             "gidx": gidx,
             "lo_idx": lo_idx,

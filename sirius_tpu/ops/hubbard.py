@@ -155,7 +155,8 @@ class HubbardData:
 
         nk = ctx.gkvec.num_kpoints
         qmax = cfg.parameters.gk_cutoff + 1e-9
-        phi_all = atomic_orbitals(uc, ctx.gkvec, qmax)  # (nk, nao, ngk)
+        phi_all = atomic_orbitals(uc, ctx.gkvec, qmax,
+                                  forms=ctx.ao_forms)  # (nk, nao, ngk)
 
         # global index of (ia, iw, m) in the atomic_orbitals ordering
         ao_off_atom = []

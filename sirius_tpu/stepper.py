@@ -105,7 +105,8 @@ class GroundStateStepper:
                     if self.polarized
                     else 0.0
                 )
-                out.append(d_operator(ctx.unit_cell, ctx.gvec, ctx.aug, vs, ctx.beta))
+                out.append(d_operator(ctx.unit_cell, ctx.gvec, ctx.aug, vs,
+                                      ctx.beta, phases=ctx.phases))
             else:
                 out.append(ctx.beta.dion)
         if self.paw is not None:
@@ -215,7 +216,8 @@ class GroundStateStepper:
                         for _, off, nbf in ctx.beta.atom_blocks(ctx.unit_cell)
                     ]
                     rho_spin[ispn] += rho_aug_g(
-                        ctx.unit_cell, ctx.gvec, ctx.aug, blocks
+                        ctx.unit_cell, ctx.gvec, ctx.aug, blocks,
+                        phases=ctx.phases,
                     )
                 if self.paw is not None:
                     self.paw_dm = self.paw.dm_from_density_matrix(dm)

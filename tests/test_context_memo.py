@@ -4,7 +4,10 @@ species' tables on them are built once a lattice, and a later build at that
 lattice, cutoffs, k-set and species builds only the position stage. A hit's
 context is the cold build's array by array; a new geometry hits, a changed
 value of any key misses; what is shared is read-only; the memo is bounded
-and builds a key once."""
+and builds a key once. A job builds ONE table of its atoms' phases on the
+fine G set, and every reader there takes it; the LCAO start's form factors
+are a species' tables, its random rows and the Gamma sphere's pairing the
+lattice's: all to the bit what each reader built for itself before."""
 
 import collections
 import copy
@@ -17,11 +20,16 @@ import numpy as np
 import pytest
 
 import sirius_tpu.context as cm
+import sirius_tpu.core.gvec as gvm
 from sirius_tpu.config.schema import load_config
+from sirius_tpu.core.radial import RadialIntegralTable
 from sirius_tpu.core.sht import lm_index, ylm_real
 from sirius_tpu.dft import radial_tables
+from sirius_tpu.dft.ewald import ewald_energy
 from sirius_tpu.obs import spans
+from sirius_tpu.ops import augmentation as augm
 from sirius_tpu.ops.beta import beta_radial_table
+from sirius_tpu.ops.gamma import build_gamma_map
 from sirius_tpu.serve.scheduler import build_job_context
 from sirius_tpu.testing import context_of_cell, synthetic_cell
 
@@ -237,9 +245,20 @@ def test_memo_holds_nothing_of_a_geometry():
         "_LatticeTables", "_SpeciesTables"]
     assert {f.name for f in dataclasses.fields(cm._LatticeTables)} == {
         "gvec", "gvec_coarse", "fft_coarse", "coarse_to_fine",
-        "gkvec", "gk_len", "gk_hat", "qshell"}
+        "gkvec", "gk_len", "gk_hat", "qshell", "deferred"}
     assert {f.name for f in dataclasses.fields(cm._SpeciesTables)} == {
-        "beta_form", "aug", "ff_shells"}
+        "beta_form", "ao_form", "aug", "ff_shells"}
+    # nor does what a job asks of the lattice's entry later, and the atoms'
+    # phases are nowhere in it
+    ctx, _ = _build(_deck("gamma", g=1))
+    ctx.gamma_map()
+    ctx.random_rows(3)
+    for v in (v for v, _ in cm._TABLES._entries.values()):
+        if isinstance(v, cm._LatticeTables):
+            assert set(v.deferred._kept) <= {"gamma_map", "random_rows"}
+    held = [a for v, _ in cm._TABLES._entries.values()
+            for a in _arrays(v, "t").values()]
+    assert not any(np.shares_memory(a, ctx.phases.table) for a in held)
 
 
 def test_new_geometry_costs_what_a_repeated_one_costs():
@@ -285,12 +304,16 @@ def _touch(t, what):
         t.rho_core = np.zeros_like(t.r)
         return
     arr = {"rbeta": t.beta[0].rbeta, "vloc": t.vloc,
-           "rho_total": t.rho_total, "qr": t.augmentation[0].qr}[what]
-    arr[len(arr) // 3] *= 1.0 + 1e-12
+           "rho_total": t.rho_total, "qr": t.augmentation[0].qr,
+           "chi": t.atomic_wfs[1].chi}[what]
+    if what == "chi":  # a move the LCAO form's integrals do not round away
+        arr[len(arr) * 9 // 10] *= 1.0 + 1e-6
+    else:
+        arr[len(arr) // 3] *= 1.0 + 1e-12
 
 
 @pytest.mark.parametrize(
-    "what", ["rbeta", "vloc", "rho_core", "rho_total", "qr", "d_ion"])
+    "what", ["rbeta", "vloc", "rho_core", "rho_total", "qr", "d_ion", "chi"])
 def test_same_label_different_content_does_not_hit(what):
     cfg = load_config(_deck("kmesh"))
 
@@ -312,6 +335,11 @@ def test_same_label_different_content_does_not_hit(what):
     assert other.unit_cell.atom_types[0].label == \
         first.unit_cell.atom_types[0].label
     assert rec["hit"] is False and other.tables_reused == 1
+    # two types that differ in one sample share no LCAO form either
+    assert other.ao_forms[0] is not first.ao_forms[0]
+    assert same.ao_forms[0] is first.ao_forms[0]
+    assert np.array_equal(other.ao_forms[0], first.ao_forms[0]) == (
+        what != "chi")
 
 
 def test_host_callback_is_called_on_every_build():
@@ -348,6 +376,9 @@ def test_writing_into_a_shared_table_raises():
               ctx.gvec_coarse.glen2, ctx.coarse_to_fine, ctx.gkvec.mask,
               ctx.gkvec.gkcart, ctx.gkvec.kpoints, ctx.gkvec.weights,
               ctx.aug.per_type[0].q_pw, ctx.aug.per_type[0].q_mtrx]
+    gm = ctx.gamma_map()
+    shared += [ctx.ao_forms[0], ctx.random_rows(2), gm.rep, gm.slot_re,
+               gm.scale, ctx.phases.table]
     for arr in shared:
         with pytest.raises(ValueError, match="read-only"):
             arr[...] = 0
@@ -356,6 +387,342 @@ def test_writing_into_a_shared_table_raises():
                 ctx.rho_core_g, ctx.rho_atomic_g, ctx.kweights,
                 ctx.unit_cell.lattice, ctx.unit_cell.positions):
         assert arr.flags.writeable
+
+
+# ---------------------------------------------------------------------------
+# One atom-phase table a job, and the LCAO start's tables in the memo
+# ---------------------------------------------------------------------------
+
+
+def _two_species_cell(lone):
+    """Silicon and the d-shell species in the fcc cell: two atoms of each,
+    or (``lone``) two silicon atoms and ONE d-shell atom, whose column numpy
+    multiplies through another BLAS routine than a block of columns."""
+    import sirius_tpu.crystal.unit_cell as ucm
+    from sirius_tpu.testing import (
+        synthetic_dshell_type, synthetic_silicon_type)
+
+    pos = np.array([[0.0, 0, 0], [0.25, 0.25, 0.25], [0.52, 0.49, 0.013],
+                    [0.74, 0.77, 0.26]])[:3 if lone else 4]
+    types = np.array([0, 0, 1, 1][:len(pos)], dtype=np.int32)
+    return ucm.UnitCell(
+        lattice=10.26 / 2 * np.array([[0.0, 1, 1], [1, 0, 1], [1, 1, 0]]),
+        atom_types=[synthetic_silicon_type(), synthetic_dshell_type()],
+        type_of_atom=types, positions=pos, moments=np.zeros((len(pos), 3)))
+
+
+def _context(name):
+    """A context of one of this section's cells, with 12 bands on the
+    k-mesh decks so that the LCAO start has random rows."""
+    if name in ("two_species", "lone_atom"):
+        cfg = load_config(_deck("kmesh", parameters__num_bands=40))
+        return context_of_cell(cfg, _two_species_cell(name == "lone_atom"))
+    over = {} if name == "gamma" else {"parameters__num_bands": 12}
+    return _build(_deck(name, g=1, **over))[0]
+
+
+CELLS = ["kmesh", "gamma", "dshell", "two_species", "lone_atom"]
+
+
+def _parent_d_operator(uc, gvec, aug, veff_g, beta):
+    """ops/augmentation.d_operator's augmentation term as the program
+    before the shared table wrote it (a phase_factors call a type)."""
+    out = {}
+    for it, at in enumerate(aug.per_type):
+        atoms = uc.atoms_of_type(it)
+        ph = gvm.phase_factors(gvec.millers, uc.positions[atoms], -1.0)
+        vq = uc.omega * np.real(at.q_pw @ (np.conj(veff_g)[:, None] * ph))
+        for j, ia in enumerate(atoms):
+            out[ia] = vq[:, j]
+    d = beta.dion.copy()
+    for ia, off, nbf in beta.atom_blocks(uc):
+        at = aug.per_type[uc.type_of_atom[ia]]
+        block = np.zeros((nbf, nbf))
+        block[at.xi1, at.xi2] = out[ia]
+        block[at.xi2, at.xi1] = out[ia]
+        d[off:off + nbf, off:off + nbf] += block
+    return d
+
+
+def _parent_rho_aug_g(uc, gvec, aug, dm):
+    out = np.zeros(gvec.num_gvec, dtype=np.complex128)
+    for it, at in enumerate(aug.per_type):
+        atoms = uc.atoms_of_type(it)
+        w = np.where(at.xi1 == at.xi2, 1.0, 2.0)
+        dmp = np.stack([w * np.real(dm[ia][at.xi1, at.xi2]) for ia in atoms])
+        ph = gvm.phase_factors(gvec.millers, uc.positions[atoms], -1.0)
+        out += np.einsum("ga,aq,qg->g", ph, dmp, at.q_pw, optimize=True)
+    return out
+
+
+def _reader_pair(reader, ctx):
+    """(from the context's table, the reader's own phase_factors call of the
+    program before the table) as lists of arrays."""
+    uc, gv, ph = ctx.unit_cell, ctx.gvec, ctx.phases
+    rng = np.random.default_rng(48)
+    if reader == "structure_factors":
+        plus = gvm.phase_factors(gv.millers, uc.positions)
+        want = [plus[:, uc.type_of_atom == it].sum(axis=1)
+                for it in range(len(uc.atom_types))]
+        return list(radial_tables.structure_factors(uc, gv, ph)), want
+    if reader == "ewald_energy":
+        z = np.asarray([uc.atom_types[t].zn for t in uc.type_of_atom])
+        args = (uc.lattice, uc.positions, z, gv.gcart, gv.millers,
+                ctx.cfg.parameters.pw_cutoff)
+        # |S(G)|^2 of the program before: the +i table without G = 0
+        s = gvm.phase_factors(gv.millers[1:], uc.positions) @ z
+        got = ph.minus(gv.millers, uc.positions)[1:] @ z
+        return ([np.abs(got) ** 2, np.asarray(ewald_energy(*args, phases=ph)),
+                 np.asarray(ctx.e_ewald)],
+                [np.abs(s) ** 2, np.asarray(ewald_energy(*args)),
+                 np.asarray(ewald_energy(*args))])
+    if reader == "aug_device_tables":
+        got = augm.build_aug_device_tables(uc, gv, ctx.aug, ctx.beta, ph)
+        want = [gvm.phase_factors(gv.millers, uc.positions[uc.atoms_of_type(it)],
+                                  -1.0) for it in range(len(uc.atom_types))]
+        return ([t[k] for t in got for k in ("ph_re", "ph_im")],
+                [f(w) for w in want for f in (np.real, np.imag)])
+    if reader == "d_operator":
+        veff = (rng.standard_normal(gv.num_gvec)
+                + 1j * rng.standard_normal(gv.num_gvec))
+        return ([augm.d_operator(uc, gv, ctx.aug, veff, ctx.beta, phases=ph),
+                 augm.d_operator(uc, gv, ctx.aug, veff, ctx.beta)],
+                [_parent_d_operator(uc, gv, ctx.aug, veff, ctx.beta)] * 2)
+    if reader == "forces_ewald":
+        from sirius_tpu.dft.forces import forces_ewald
+
+        # without a table the function builds e^{-iG.r} for itself; the
+        # program before read np.exp(+2 pi i m.x) without G = 0
+        plus = np.exp(2j * np.pi * (gv.millers[1:] @ uc.positions.T))
+        return ([forces_ewald(ctx), np.conj(ph.minus(gv.millers, uc.positions)[1:])],
+                [forces_ewald(dataclasses.replace(ctx, phases=None)), plus])
+    assert reader == "rho_aug_g"
+    dm = []
+    for _, _, nbf in ctx.beta.atom_blocks(uc):
+        a = rng.standard_normal((nbf, nbf)) + 1j * rng.standard_normal((nbf, nbf))
+        dm.append(a + a.conj().T)
+    return ([augm.rho_aug_g(uc, gv, ctx.aug, dm, phases=ph),
+             augm.rho_aug_g(uc, gv, ctx.aug, dm)],
+            [_parent_rho_aug_g(uc, gv, ctx.aug, dm)] * 2)
+
+
+@pytest.fixture(scope="module")
+def cells():
+    cm._TABLES.clear()
+    return {name: _context(name) for name in CELLS}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("reader", [
+    "structure_factors", "ewald_energy", "aug_device_tables", "d_operator",
+    "rho_aug_g", "forces_ewald"])
+def test_reader_on_the_shared_table_equals_its_own_call(cells, reader, cell):
+    ctx = cells[cell]
+    reads = ctx.phases.reads
+    got, want = _reader_pair(reader, ctx)
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert ctx.phases.reads > reads
+
+
+def test_the_table_is_the_conjugate_of_the_plus_table(cells):
+    """One sign is kept; the other is its conjugate, to the bit."""
+    ctx = cells["gamma"]
+    plus = gvm.phase_factors(ctx.gvec.millers, ctx.unit_cell.positions)
+    assert np.array_equal(np.conj(ctx.phases.table), plus)
+    assert not ctx.phases.table.flags.writeable
+
+
+def test_a_lone_atom_of_a_type_keeps_its_own_call(cells):
+    """A [ng, 3] by [3, 1] product goes through another BLAS routine than a
+    block of columns and rounds the angle differently: the table's column
+    is NOT what the reader computed before, so it is not handed out."""
+    ctx = cells["lone_atom"]
+    gv, uc = ctx.gvec, ctx.unit_cell
+    reads = ctx.phases.reads
+    got = ctx.phases.minus(gv.millers, uc.positions, uc.atoms_of_type(1))
+    assert np.array_equal(
+        got, gvm.phase_factors(gv.millers, uc.positions[[2]], -1.0))
+    assert ctx.phases.reads == reads
+    pair = ctx.phases.minus(gv.millers, uc.positions, uc.atoms_of_type(0))
+    assert np.array_equal(
+        pair, gvm.phase_factors(gv.millers, uc.positions[:2], -1.0))
+    assert ctx.phases.reads == reads + 1
+
+
+def test_phases_of_other_positions_are_refused(cells):
+    ctx = cells["kmesh"]
+    moved = ctx.unit_cell.positions + 1e-9
+    with pytest.raises(ValueError, match="other positions"):
+        ctx.phases.minus(ctx.gvec.millers, moved)
+    with pytest.raises(ValueError, match="another G set"):
+        ctx.phases.minus(ctx.gvec_coarse.millers, ctx.unit_cell.positions)
+
+
+def _parent_atomic_orbitals(uc, gkvec, qmax):
+    """ops/atomic.atomic_orbitals as the program before the memo wrote it:
+    one radial table a type but its spline an ATOM, every factor inside the
+    loop over atoms."""
+    nk, ngk = gkvec.num_kpoints, gkvec.ngk_max
+    lmax = max(max((w.l for w in t.atomic_wfs), default=-1)
+               for t in uc.atom_types)
+    nao = sum(uc.atom_types[it].num_atomic_wf_lm for it in uc.type_of_atom)
+    out = np.zeros((nk, nao, ngk), dtype=np.complex128)
+    tables = [RadialIntegralTable.build(
+        t.r, np.stack([w.chi for w in t.atomic_wfs]),
+        np.array([w.l for w in t.atomic_wfs]), qmax, m=1)
+        for t in uc.atom_types]
+    gk = gkvec.gkcart
+    qlen = np.linalg.norm(gk, axis=-1)
+    rhat = np.where(qlen[..., None] > 1e-30,
+                    gk / np.maximum(qlen, 1e-30)[..., None],
+                    np.array([0.0, 0, 1.0]))
+    rlm = ylm_real(lmax, rhat)
+    pref = 4.0 * np.pi / np.sqrt(uc.omega)
+    off = 0
+    for ia in range(uc.num_atoms):
+        t = uc.atom_types[uc.type_of_atom[ia]]
+        ri = tables[uc.type_of_atom[ia]](qlen.reshape(-1)).reshape(
+            len(t.atomic_wfs), nk, ngk)
+        mk = gkvec.millers + gkvec.kpoints[:, None, :]
+        phase = np.exp(-2j * np.pi * (mk @ uc.positions[ia]))
+        xi = 0
+        for iw, w in enumerate(t.atomic_wfs):
+            for m in range(-w.l, w.l + 1):
+                out[:, off + xi, :] = (
+                    pref * (-1j) ** w.l * rlm[..., lm_index(w.l, m)] * ri[iw]
+                    * phase * gkvec.mask)
+                xi += 1
+        off += t.num_atomic_wf_lm
+    return out
+
+
+def _parent_initial_subspace(ctx):
+    """dft/scf._initial_subspace as the program before the memo wrote it."""
+    nk, nb, ngk = ctx.gkvec.num_kpoints, ctx.num_bands, ctx.gkvec.ngk_max
+    ao = _parent_atomic_orbitals(ctx.unit_cell, ctx.gkvec,
+                                 ctx.cfg.parameters.gk_cutoff + 1e-9)
+    nao = ao.shape[1]
+    nbig = max(nb, nao)
+    rng = np.random.default_rng(42)
+    psi = np.zeros((nk, ctx.num_spins, nbig, ngk), dtype=np.complex128)
+    for ik in range(nk):
+        base = np.zeros((nbig, ngk), dtype=np.complex128)
+        n0 = min(nao, nbig)
+        if n0:
+            base[:n0] = ao[ik, :n0]
+        if nbig > n0:
+            r = (rng.standard_normal((nbig - n0, ngk))
+                 + 1j * rng.standard_normal((nbig - n0, ngk)))
+            base[n0:] = r * (1.0 / (1.0 + ctx.gkvec.kinetic()[ik]))
+        base *= ctx.gkvec.mask[ik]
+        for ispn in range(ctx.num_spins):
+            psi[ik, ispn] = base
+    return psi
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_lcao_start_from_the_memo_equals_the_parent_program(cell):
+    """Cold and from a hit, on another geometry in between: the start a job
+    takes, its orbitals and (at one k-point) the sphere's pairing."""
+    from sirius_tpu.dft.scf import _initial_subspace
+    from sirius_tpu.ops.atomic import atomic_orbitals
+
+    cold = _context(cell)
+    assert cold.tables_reused == 0
+    random_rows = max(cold.num_bands - sum(
+        cold.unit_cell.atom_types[it].num_atomic_wf_lm
+        for it in cold.unit_cell.type_of_atom), 0)
+    assert (random_rows > 0) == (cell in ("kmesh", "two_species", "lone_atom"))
+    want = _parent_initial_subspace(cold)
+    x_cold = _initial_subspace(cold)
+    if cell in ("kmesh", "gamma", "dshell"):
+        _build(_deck(cell, g=2))
+    hit = _context(cell)
+    assert hit.tables_reused == 1 + len(hit.unit_cell.atom_types)
+    with spans.capture() as cap:
+        x_hit = _initial_subspace(hit)
+    rec = cap.by_name("scf.setup.subspace")[0]
+    assert rec["random_rows"] == random_rows
+    for x in (x_cold, x_hit):  # the signs of the padded slots' zeros too
+        assert x.dtype == want.dtype and x.tobytes() == want.tobytes()
+    qmax = hit.cfg.parameters.gk_cutoff + 1e-9
+    ao = atomic_orbitals(hit.unit_cell, hit.gkvec, qmax, forms=hit.ao_forms)
+    assert np.array_equal(
+        ao, _parent_atomic_orbitals(hit.unit_cell, hit.gkvec, qmax))
+    assert np.array_equal(ao, atomic_orbitals(hit.unit_cell, hit.gkvec, qmax))
+    # shared: the forms and the random rows are the cold context's objects
+    assert all(a is b for a, b in zip(hit.ao_forms, cold.ao_forms))
+    if random_rows:
+        assert hit.random_rows(random_rows) is cold.random_rows(random_rows)
+    gm, again = hit.gamma_map(), cold.gamma_map()
+    fresh = build_gamma_map(np.asarray(hit.gkvec.millers[0]),
+                            np.asarray(hit.gkvec.mask[0]))
+    assert gm is again and gm.zero == fresh.zero
+    for a, b in zip(gm[1:], fresh[1:]):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+def test_deferred_tables_count_in_the_memo_and_replace_by_tag():
+    ctx, _ = _build(_deck("kmesh"))
+    before = cm._TABLES.bytes()
+    rows = ctx.random_rows(4)
+    assert cm._TABLES.bytes() == before + rows.nbytes
+    assert ctx.random_rows(4) is rows
+    more = ctx.random_rows(6)  # another row count takes the first's place
+    assert more.shape[1] == 6
+    assert cm._TABLES.bytes() == before + more.nbytes
+    # the numbers are the parent's stream for that count, not a prefix
+    assert not np.array_equal(more[:, :4], rows)
+
+
+@pytest.fixture(scope="module")
+def phase_job():
+    """One ultrasoft job, context build included, counting every call of
+    core/gvec.phase_factors and the calls on the fine G set."""
+    from sirius_tpu.dft.scf import run_scf
+
+    calls = []
+    orig = gvm.phase_factors
+
+    def counted(millers, positions, sign=1.0):
+        calls.append(len(millers))
+        return orig(millers, positions, sign)
+
+    cm._TABLES.clear()
+    gvm.phase_factors = counted
+    try:
+        with spans.capture() as cap:
+            ctx, _ = _build(_deck("kmesh", g=1))
+            ctx.cfg.control.telemetry = True
+            res = run_scf(ctx.cfg, ctx=ctx, devices=jax.devices()[:1])
+    finally:
+        gvm.phase_factors = orig
+    return {"calls": calls, "ctx": ctx, "res": res, "cap": cap}
+
+
+def test_phase_factors_is_called_once_a_job(phase_job):
+    ctx, res = phase_job["ctx"], phase_job["res"]
+    assert res["converged"]
+    assert phase_job["calls"] == [ctx.gvec.num_gvec]
+    # structure factors, Ewald sum, the fused step's tables, the first
+    # iteration's host D matrix
+    assert res["counters"]["phase_table_builds"] == 1
+    assert res["counters"]["phase_table_reads"] >= 4
+    assert res["counters"]["phase_table_reads"] == ctx.phases.reads
+
+
+def test_spans_of_the_phase_table_and_the_lcao_start(phase_job):
+    cap = phase_job["cap"]
+    (ph,) = cap.by_name("context.phases")
+    (pos,) = cap.by_name("context.positions")
+    assert ph["parent_id"] == pos["span_id"]
+    assert ph["bytes"] == phase_job["ctx"].phases.table.nbytes
+    (sub,) = cap.by_name("scf.setup.subspace")
+    (setup,) = cap.by_name("scf.setup")
+    assert sub["parent_id"] == setup["span_id"]
+    assert sub["atomic_orbitals"] == 8 and sub["random_rows"] == 0
 
 
 def test_bounds_evict_the_oldest():

@@ -135,3 +135,23 @@ def test_shells_in_one_pass_are_the_rule_value_by_value(si_lattice):
         assert np.array_equal(idx, want_idx)
         assert np.array_equal(first, want_first)
     assert len(gm._shells(chain)[1]) > 1
+
+
+@pytest.mark.parametrize("natoms", [1, 2, 7, 54])
+def test_atom_sum_is_the_indexed_copys_sum_to_the_bit(si_lattice, natoms,
+                                                     monkeypatch):
+    """atom_sum adds a phase table's columns in the order numpy adds those
+    of ``table[:, every atom]`` (a copy laid out atom by atom), which a sum
+    along the table's own rows does not, whatever the block size."""
+    from sirius_tpu.core import gvec as gm
+
+    gv = Gvec.build(si_lattice, gmax=9.0)
+    x = np.random.default_rng(5).random((natoms, 3))
+    table = gm.phase_factors(gv.millers, x, -1.0)
+    want = table[:, np.ones(natoms, dtype=bool)].sum(axis=1)
+    assert np.array_equal(gm.atom_sum(table), want)
+    monkeypatch.setattr(gm, "_SUM_ROWS", 37)  # many blocks, a ragged last
+    assert np.array_equal(gm.atom_sum(table), want)
+    if natoms == 54:  # the pairwise sum along a row is another number
+        assert not np.array_equal(table.sum(axis=1), want)
+    assert gm.atom_sum(table[:0]).shape == (0,)

@@ -258,7 +258,9 @@ def test_scf_spans_off_with_telemetry_disabled(tmp_path):
     assert res["num_scf_iterations"] == 2
     # the deck's control.telemetry takes effect at run_scf entry: the
     # context the caller built before it is the one thing spanned (with its
-    # children: the group search, the two table stages, the position stage)
+    # children: the group search, the two table stages, the position stage
+    # and the phase table inside it)
     assert [r["name"] for r in cap.records] == [
         "context.symmetry", "context.lattice_tables",
-        "context.species_tables", "context.positions", "serve.context_build"]
+        "context.species_tables", "context.phases", "context.positions",
+        "serve.context_build"]

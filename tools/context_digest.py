@@ -9,8 +9,9 @@ and ``benchmark``), the contexts of geometries 0, 1, 0, 2 of each benchmark
 configuration through ``serve/scheduler.build_job_context`` (the second 0 is
 a build that finds the process's tables, where the tree keeps any) and takes
 a sha1 of every array and number under the context, by path. Exit code 0
-where every path of every build has one digest in both trees, 1 with the
-paths that differ otherwise. The other tree is a checkout of any commit
+where every path both trees' contexts have has one digest in both, in every
+build, 1 with the paths that differ otherwise; a path only one tree's
+context carries (a table a later PR added) is listed. The other tree is a checkout of any commit
 that has ``benchmark/configs`` (``git archive <commit> | tar -x -C <dir>``):
 PR 47 used it to hold the memoised build to its parent's, cold and hit.
 
@@ -109,15 +110,17 @@ def main() -> int:
             with open(out) as f:
                 found.append(json.load(f))
     mine, other = found
-    differ, paths = [], 0
+    differ, lone, paths = [], set(), 0
     for build in sorted(set(mine) | set(other)):
         a, b = mine.get(build, {}), other.get(build, {})
         paths += len(a)
-        differ += [f"{build} {p}" for p in sorted(set(a) | set(b))
-                   if a.get(p) != b.get(p)]
+        differ += [f"{build} {p}" for p in sorted(set(a) & set(b))
+                   if a[p] != b[p]]
+        # what only one tree's context carries is named, not held against it
+        lone |= set(a) ^ set(b)
     print(json.dumps({"configs": names, "block": args.block,
                       "builds": len(mine), "paths": paths,
-                      "differ": len(differ)}))
+                      "differ": len(differ), "in_one_tree_only": sorted(lone)}))
     for line in differ[:40]:
         print("DIFFERS", line)
     return 1 if differ else 0
